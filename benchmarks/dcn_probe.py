@@ -2,7 +2,7 @@
 
 Round-2's scaling projection (scaling_model.py) argued "gtopk/hier win ~2x
 once the reduction crosses DCN" from a bandwidth model with ZERO measured
-cross-process bytes (VERDICT round-2 weak #8). This probe anchors it: two
+cross-process bytes. This probe anchors it: two
 actual processes over ``jax.distributed`` on localhost TCP (the same
 machinery — gRPC transport, cross-process XLA collectives — a real
 multi-host TPU pod uses over DCN), timing at ResNet-50 gradient size:
@@ -48,12 +48,12 @@ import sys
 import time
 
 sys.path.insert(0, sys.argv[4])
+from gtopkssgd_tpu.utils import enable_compilation_cache, force_cpu_mesh
+# Workers are CPU processes whatever the launcher's environment says: a
+# chip belongs to one process, and this probe starts several.
+force_cpu_mesh(1)
+enable_compilation_cache()
 import jax
-jax.config.update("jax_platforms", "cpu")
-from gtopkssgd_tpu.utils.settings import _default_cache_dir
-jax.config.update("jax_compilation_cache_dir", _default_cache_dir())
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 coord, pid = sys.argv[1], int(sys.argv[2])
 cfg = json.loads(sys.argv[3])
 try:
@@ -379,7 +379,7 @@ def main():
 
     os.makedirs(RESULTS, exist_ok=True)
     # Per-procs filename: a --procs 4 run must not overwrite the
-    # canonical 2-process anchor that PARITY/README/time_to_quality cite.
+    # canonical 2-process anchor that README/time_to_quality cite.
     out = os.path.join(RESULTS, f"dcn_probe_{args.procs}proc.json")
     with open(out, "w") as fh:
         json.dump(report, fh, indent=2)

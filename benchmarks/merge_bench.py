@@ -38,11 +38,7 @@ import jax.numpy as jnp
 
 from gtopkssgd_tpu.ops import merge_sparse_sets, scatter_add_dense, topk_abs
 from gtopkssgd_tpu.ops.topk import k_for_density
-from gtopkssgd_tpu.utils import (
-    sync_round_trip_seconds,
-    timed_window,
-    true_sync,
-)
+from gtopkssgd_tpu.utils import time_calls
 
 SIZES = {
     "resnet20-270k": 272_474,
@@ -67,16 +63,7 @@ def _random_sets(n: int, k: int, count: int):
 
 
 def _time(fn, args, min_seconds: float):
-    out = fn(*args)
-    rtt = sync_round_trip_seconds(out)
-
-    def chunk(c):
-        o = out
-        for _ in range(c):
-            o = fn(*args)
-        true_sync(o)
-
-    return timed_window(chunk, rtt, min_seconds, 4)
+    return time_calls(fn, args, min_seconds, 4)
 
 
 def time_merge(n: int, k: int, min_seconds: float):

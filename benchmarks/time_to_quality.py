@@ -218,7 +218,7 @@ def main():
     batch = block["batch_size_per_chip"]
     k = max(1, math.ceil(args.density * n))
     # The DGC recursion costs extra per step; when the corr bench block
-    # exists (onchip_queue's bench_bs128_corr stage), +corr rows use its
+    # exists (a bench.py --momentum-correction capture), +corr rows use its
     # own measured overhead instead of inheriting plain gtopk's.
     corr_block = bench.get(f"{args.batch_key}_corr")
     corr_overhead_ms = (

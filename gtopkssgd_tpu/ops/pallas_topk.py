@@ -54,6 +54,12 @@ NUM_THRESHOLDS = 8
 BLOCK_ROWS = 2048
 _LANES = 128
 _BLOCK = BLOCK_ROWS * _LANES
+# Stage-1 group counts the TPU compiler accepts (AOT-compiled for v5e in
+# tests/test_pallas_compile.py). Below 8 the (groups, 128) candidate block
+# breaks the lowering's (8, 128) block-shape rule; at 2048 — one row per
+# bucket — the kernel's (groups, rpg, 128) temporaries exhaust VMEM.
+MIN_GROUPS = 8
+MAX_GROUPS = 1024
 
 
 def _count_kernel(thr_ref, x_ref, out_ref):
@@ -215,7 +221,8 @@ def fused_stage1_candidates(
     """One fused pass over `grad` (+ `residual`): per-bucket candidates.
 
     Returns (cand_val f32[L], cand_idx i32[L], counts i32[8] | None) with
-    L = nblocks * groups * 128 buckets. `groups` must divide BLOCK_ROWS.
+    L = nblocks * groups * 128 buckets. `groups` must divide BLOCK_ROWS
+    and, compiled, lie in [MIN_GROUPS, MAX_GROUPS].
     Candidate indices >= n mark padding buckets (value 0). When
     `thresholds` (f32[8]) is given, the same pass also accumulates the
     multisection counts `#{|grad+residual| >= thr}` — the _count_kernel
@@ -227,6 +234,10 @@ def fused_stage1_candidates(
     n = grad.shape[0]
     if BLOCK_ROWS % groups != 0:
         raise ValueError(f"groups={groups} must divide {BLOCK_ROWS}")
+    if not interpret and not MIN_GROUPS <= groups <= MAX_GROUPS:
+        raise ValueError(
+            f"groups={groups} does not compile for the TPU; use "
+            f"{MIN_GROUPS}..{MAX_GROUPS}")
     nblocks = max(1, -(-n // _BLOCK))
     padded = nblocks * _BLOCK
     with_counts = thresholds is not None

@@ -1294,9 +1294,17 @@ def expand_residual_per_device(opt_state: GTopKSGDState, p: int, mesh):
     from jax.sharding import NamedSharding, PartitionSpec
 
     sharding = NamedSharding(mesh, PartitionSpec("dp"))
+    replicated = NamedSharding(mesh, PartitionSpec())
 
     def expand(res):
         res_shape = (p,) + res.shape
+        if res.size == 0:
+            # The dense modes' [P, 0] placeholder. jit hands a zero-size
+            # output back replicated whatever out_specs say, so placing it
+            # P('dp') here would make dispatch 2 see a new input sharding
+            # and recompile the whole step (38 s for ResNet-50 at dp=4 on
+            # the chip, PR 21).
+            return jax.device_put(np.zeros(res_shape, res.dtype), replicated)
 
         def shard_zeros(index):
             shape = tuple(len(range(*s.indices(dim)))

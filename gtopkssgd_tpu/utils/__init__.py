@@ -6,9 +6,8 @@ metrics and real checkpointing.
 from gtopkssgd_tpu.utils.timers import (
     StepTimer,
     TimingStats,
-    sync_round_trip_seconds,
+    time_calls,
     timed_window,
-    true_sync,
 )
 from gtopkssgd_tpu.utils.metrics import MetricsLogger
 from gtopkssgd_tpu.utils.checkpoint import CheckpointManager
@@ -16,23 +15,18 @@ from gtopkssgd_tpu.utils.settings import (
     enable_compilation_cache,
     force_cpu_mesh,
     get_logger,
-    init_backend_with_deadline,
-    safe_donate,
 )
 from gtopkssgd_tpu.utils.prefetch import Prefetcher
 
 __all__ = [
     "StepTimer",
     "TimingStats",
-    "sync_round_trip_seconds",
+    "time_calls",
     "timed_window",
-    "true_sync",
     "MetricsLogger",
     "CheckpointManager",
     "get_logger",
     "enable_compilation_cache",
     "force_cpu_mesh",
-    "init_backend_with_deadline",
-    "safe_donate",
     "Prefetcher",
 ]

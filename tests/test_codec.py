@@ -50,7 +50,7 @@ def run_collective(fn, mesh, vals, idxs):
     body = jax.shard_map(
         lambda v, i: jax.tree.map(lambda x: x[None], fn(v[0], i[0])),
         mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=P("dp"),
-        check_rep=False)
+        check_vma=False)
     return jax.tree.map(np.asarray, jax.jit(body)(jnp.asarray(vals),
                                                   jnp.asarray(idxs)))
 

@@ -42,7 +42,7 @@ def test_convergence_runner_end_to_end(tmp_path, monkeypatch):
 
 
 def test_convergence_runner_arm_suffixes(tmp_path, monkeypatch):
-    """Arm syntax "<mode>+warmup" / "<mode>+corr" (VERDICT round-2 #4's
+    """Arm syntax "<mode>+warmup" / "<mode>+corr" (round-2 review #4's
     arm set) resolves to the right TrainConfig knobs and flows through to
     the artifact rows under the full arm label."""
     mod = _load_runner()

@@ -14,11 +14,11 @@ expected chains here are hand-computed and asserted exactly.
 
 import json
 import os
-import socket
-import subprocess
 import sys
 
 import pytest
+
+from tests.conftest import run_two_process
 
 from gtopkssgd_tpu.obs import critpath
 
@@ -419,10 +419,8 @@ import sys
 sys.path.insert(0, sys.argv[3])  # repo root (script itself lives in tmp)
 import jax
 jax.config.update("jax_platforms", "cpu")
-from gtopkssgd_tpu.utils.settings import _default_cache_dir
-jax.config.update("jax_compilation_cache_dir", _default_cache_dir())
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
+from gtopkssgd_tpu.utils import enable_compilation_cache
+enable_compilation_cache()
 coord, pid = sys.argv[1], int(sys.argv[2])
 try:
     jax.distributed.initialize(coordinator_address=coord, num_processes=2,
@@ -450,12 +448,6 @@ print(f"CRITPATH-OK pid={pid}")
 """
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("localhost", 0))
-        return s.getsockname()[1]
-
-
 @pytest.mark.slow  # ~2-3 min: two real processes + jit compile; the
 # single-rank capture->segments path stays covered tier-1 through
 # test_obs_attr's profiler tests + the unit algebra above, and the
@@ -465,35 +457,7 @@ def test_two_process_critpath_records_and_join(tmp_path):
     --obs-critpath at every-step cadence; each shard must carry durable
     critpath records whose segments the fleet joiner can chain. Skips
     on jax builds without CPU cross-process collectives."""
-    port = _free_port()
-    coord = f"localhost:{port}"
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    flags.append("--xla_force_host_platform_device_count=1")
-    env["XLA_FLAGS"] = " ".join(flags)
-
-    script = tmp_path / "critpath_worker.py"
-    script.write_text(CRITPATH_WORKER)
-    out_dir = str(tmp_path / "run")
-    procs = [
-        subprocess.Popen([sys.executable, str(script), coord, str(pid),
-                          REPO, out_dir],
-                         env=env, cwd=REPO, stdout=subprocess.PIPE,
-                         stderr=subprocess.STDOUT, text=True)
-        for pid in (0, 1)
-    ]
-    outs = []
-    for p in procs:
-        out, _ = p.communicate(timeout=850)
-        outs.append((p.returncode, out))
-    if any(rc == 99 for rc, _ in outs):
-        pytest.skip("jax build lacks CPU cross-process collectives: "
-                    + outs[0][1].splitlines()[-1])
-    for rc, out in outs:
-        assert rc == 0, out
-        assert "CRITPATH-OK" in out
+    out_dir = run_two_process(CRITPATH_WORKER, tmp_path, "CRITPATH-OK")
 
     from gtopkssgd_tpu.obs import fleet
 

@@ -1,11 +1,13 @@
 """The driver contract: __graft_entry__ must work as invoked by the driver.
 
 Round-1 regression: dryrun_multichip asserted on jax.device_count() instead
-of provisioning virtual devices, so the driver's MULTICHIP check failed on
-the 1-chip machine. These tests run the entry exactly the way the driver
+of provisioning virtual devices, so the driver's multi-chip check failed on
+a one-device machine. These tests run the entry exactly the way the driver
 does — `python -c "import __graft_entry__; __graft_entry__.dryrun_multichip(8)"`
-from the repo root — including from a parent process that only sees ONE
-device, which forces the subprocess self-provisioning path.
+from the repo root — including from a CPU parent that only sees ONE
+device, which forces the subprocess rehearsal path. From a parent whose
+backend is an accelerator that path must NOT be taken: too few chips is an
+error there, not a rehearsal.
 """
 
 import os
@@ -30,8 +32,8 @@ def _driver_env(n_parent_devices: int) -> dict:
 
 
 def test_dryrun_multichip_self_provisions_from_one_device():
-    """Parent sees 1 device -> dryrun_multichip(8) must still pass (the
-    exact failure mode of MULTICHIP_r01)."""
+    """CPU parent sees 1 device -> dryrun_multichip(8) must still pass,
+    and every line it prints names the platform it ran on."""
     proc = subprocess.run(
         [sys.executable, "-c",
          "import jax; jax.config.update('jax_platforms', 'cpu');"
@@ -40,7 +42,22 @@ def test_dryrun_multichip_self_provisions_from_one_device():
         timeout=900,
     )
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
-    assert "one gtopk step OK" in proc.stdout
+    assert "dryrun_multichip(8) on cpu: one gtopk step OK" in proc.stdout
+
+
+def test_dryrun_multichip_from_a_chip_parent_does_not_hide_the_device(
+        monkeypatch):
+    """Parent backend is the chip, fewer chips than asked -> raises; no
+    virtual CPU devices, no subprocess, no 'step OK'."""
+    sys.path.insert(0, REPO)
+    import __graft_entry__ as entry
+
+    monkeypatch.setattr(entry.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(entry.jax, "device_count", lambda: 1)
+    monkeypatch.setattr(entry.subprocess, "run", lambda *a, **k: pytest.fail(
+        "spawned a child from a parent that holds the chip"))
+    with pytest.raises(RuntimeError, match="only 1 tpu device"):
+        entry.dryrun_multichip(4)
 
 
 @pytest.mark.slow  # ~43 s subprocess; the self-provisioning variant

@@ -9,15 +9,15 @@ the closest single-machine analogue of the reference's `mpirun -np 2`
 smoke (SURVEY.md §4). Skipped cleanly if the jax build lacks CPU
 cross-process collectives.
 
-Also covers the profiler flag (VERDICT #9) in the single-process path.
+Also covers the profiler flag in the single-process path.
 """
 
 import os
-import socket
-import subprocess
 import sys
 
 import pytest
+
+from tests.conftest import run_two_process
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -26,10 +26,8 @@ import sys
 sys.path.insert(0, sys.argv[3])  # repo root (script itself lives in tmp)
 import jax
 jax.config.update("jax_platforms", "cpu")
-from gtopkssgd_tpu.utils.settings import _default_cache_dir
-jax.config.update("jax_compilation_cache_dir", _default_cache_dir())
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
+from gtopkssgd_tpu.utils import enable_compilation_cache
+enable_compilation_cache()
 coord, pid = sys.argv[1], int(sys.argv[2])
 try:
     jax.distributed.initialize(coordinator_address=coord, num_processes=2,
@@ -101,42 +99,8 @@ print(f"MULTIHOST-OK pid={pid} loss={stats['loss']:.4f} "
 """
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("localhost", 0))
-        return s.getsockname()[1]
-
-
 def test_two_process_distributed_gtopk(tmp_path):
-    port = _free_port()
-    coord = f"localhost:{port}"
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    flags.append("--xla_force_host_platform_device_count=1")
-    env["XLA_FLAGS"] = " ".join(flags)
-
-    script = tmp_path / "worker.py"
-    script.write_text(WORKER)
-    out_dir = str(tmp_path / "run")
-    procs = [
-        subprocess.Popen([sys.executable, str(script), coord, str(pid),
-                          REPO, out_dir],
-                         env=env, cwd=REPO, stdout=subprocess.PIPE,
-                         stderr=subprocess.STDOUT, text=True)
-        for pid in (0, 1)
-    ]
-    outs = []
-    for p in procs:
-        out, _ = p.communicate(timeout=850)
-        outs.append((p.returncode, out))
-    if any(rc == 99 for rc, _ in outs):
-        pytest.skip("jax build lacks CPU cross-process collectives: "
-                    + outs[0][1].splitlines()[-1])
-    for rc, out in outs:
-        assert rc == 0, out
-        assert "MULTIHOST-OK" in out
+    run_two_process(WORKER, tmp_path, "MULTIHOST-OK")
 
 
 def test_profile_dir_writes_trace(tmp_path):
