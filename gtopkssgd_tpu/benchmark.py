@@ -226,7 +226,7 @@ def measure_throughput(cfg: BenchConfig, mode: Optional[str],
 
     compiled = fn.lower(state, batch).compile()
     flops_per_step = _compiled_flops(compiled)
-    with _TRACER.span("bench/throughput", mode=mode or "dense"):
+    with _TRACER.span("bench/throughput"):
         sec, steps, _ = time_compiled_step(compiled, state, batch,
                                            cfg.min_seconds)
 

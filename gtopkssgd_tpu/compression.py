@@ -58,6 +58,7 @@ class TopKCompressor:
     def init_residual(self, n: int, dtype=jnp.float32) -> Array:
         return jnp.zeros((n,), dtype)
 
+    @jax.named_scope("gtopk/accumulate")
     def accumulate(self, grad_flat: Array, residual: Array) -> Array:
         """acc = grad + residual (the error-feedback accumulation)."""
         return grad_flat + residual
@@ -82,12 +83,14 @@ class TopKCompressor:
         entries zeroed.
         """
         n = acc.shape[0]
-        if grad is not None:
-            vals, idx = select_topk(grad, self.k(n), self.method,
-                                    residual=residual)
-        else:
-            vals, idx = select_topk(acc, self.k(n), self.method)
-        residual_out = acc.at[idx].set(0.0, mode="drop")
+        with jax.named_scope("gtopk/select"):
+            if grad is not None:
+                vals, idx = select_topk(grad, self.k(n), self.method,
+                                        residual=residual)
+            else:
+                vals, idx = select_topk(acc, self.k(n), self.method)
+        with jax.named_scope("gtopk/mask"):
+            residual_out = acc.at[idx].set(0.0, mode="drop")
         return vals, idx, residual_out
 
     def compress_by_threshold(
@@ -140,17 +143,20 @@ class TopKCompressor:
         them directly, fusing the error-feedback accumulate into the
         selection pass for the twostage/pallas kernels."""
         n = acc.shape[0]
-        if grad is not None:
-            tau = select_tau(grad, self.k(n), self.method,
-                             residual=residual)
-        else:
-            tau = select_tau(acc, self.k(n), self.method)
-        keep = (jnp.abs(acc) >= tau) & (jnp.abs(acc) > 0.0)
-        kept_tau = jnp.min(jnp.where(keep, jnp.abs(acc), jnp.inf))
-        kept_tau = jnp.where(
-            jnp.isfinite(kept_tau), kept_tau, 0.0).astype(jnp.float32)
-        return keep, jnp.where(keep, 0.0, acc), kept_tau
+        with jax.named_scope("gtopk/select"):
+            if grad is not None:
+                tau = select_tau(grad, self.k(n), self.method,
+                                 residual=residual)
+            else:
+                tau = select_tau(acc, self.k(n), self.method)
+        with jax.named_scope("gtopk/mask"):
+            keep = (jnp.abs(acc) >= tau) & (jnp.abs(acc) > 0.0)
+            kept_tau = jnp.min(jnp.where(keep, jnp.abs(acc), jnp.inf))
+            kept_tau = jnp.where(
+                jnp.isfinite(kept_tau), kept_tau, 0.0).astype(jnp.float32)
+            return keep, jnp.where(keep, 0.0, acc), kept_tau
 
+    @jax.named_scope("gtopk/repair")
     def repair(
         self,
         residual: Array,
@@ -176,6 +182,7 @@ class TopKCompressor:
         put_back = jnp.where(rejected, local_vals, 0.0)
         return residual.at[local_idx].add(put_back, mode="drop")
 
+    @jax.named_scope("gtopk/repair")
     def fold_wire_error(
         self,
         residual: Array,

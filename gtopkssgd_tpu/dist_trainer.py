@@ -917,17 +917,19 @@ def _run(args: argparse.Namespace, trainer: Trainer) -> int:
     if args.profile_dir:
         # SURVEY.md §5 tracing: the reference only had host timer
         # dicts; here a real jax.profiler device trace complements
-        # them. One dispatch first so compilation stays out of the
-        # trace; step counts round up to whole dispatches so the
-        # path composes with --steps-per-dispatch.
+        # them, with the traced steps' spans beside it as spans.json
+        # (obs.tracing.profile). One dispatch first so compilation
+        # stays out of the trace; step counts round up to whole
+        # dispatches so the path composes with --steps-per-dispatch.
+        from gtopkssgd_tpu.obs import tracing
+
         spd = trainer.cfg.steps_per_dispatch
         warm = spd
         traced = max(spd, -(-args.profile_steps // spd) * spd)
         trainer.train(warm)
-        jax.profiler.start_trace(args.profile_dir)
-        trainer.train(traced)
-        jax.profiler.stop_trace()
-        trainer.logger.info("profiler: %d-step trace -> %s",
+        with tracing.profile(args.profile_dir):
+            trainer.train(traced)
+        trainer.logger.info("profiler: %d-step trace + spans.json -> %s",
                             traced, args.profile_dir)
     if args.num_iters is not None:
         stats = trainer.train(args.num_iters)

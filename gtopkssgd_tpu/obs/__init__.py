@@ -12,9 +12,12 @@ residual dynamics). This package turns the repo's scattered primitives
       compression gradient norms, error-feedback residual norm, wire
       bytes) and carried out through the optimizer state, so compression
       quality is a per-step metric for every mode.
-  tracing.py  — span API emitting BOTH host-side records (metrics.jsonl /
-      TimingStats) and jax.profiler.TraceAnnotation scopes, so device
-      traces and host timelines correlate on the same names.
+  tracing.py  — the one span system: every closed span (path, start,
+      duration, step id, thread) goes to a bounded in-memory buffer, the
+      training loop's own also to window means (metrics.jsonl) and the
+      sink; one clock anchor per tracer puts a span on the epoch clock
+      of a profiler trace's profile_start_time, so spans and a device
+      trace line up by time (the TPU runtime's host tracer is off).
   watchdog.py — dispatch stall watchdog: a monitor thread that detects a
       dispatched step failing to become ready within a deadline, emits
       a structured diagnostic and fails fast instead of hanging.

@@ -77,6 +77,10 @@ LAYER_FIELDS = (
 
 _MASS_EPS = 1e-30
 
+# Every device function below runs under this named scope, so a device
+# trace prices what the counters add to the step (perfbench: telemetry_ms).
+SCOPE = "gtopk/telemetry"
+
 
 def zero_telemetry() -> Dict[str, Array]:
     """The fixed telemetry structure at init (all zeros). init_fn uses this
@@ -84,6 +88,7 @@ def zero_telemetry() -> Dict[str, Array]:
     return {f: jnp.zeros((), jnp.float32) for f in TELEMETRY_FIELDS}
 
 
+@jax.named_scope(SCOPE)
 def tree_l2(tree) -> Array:
     """L2 norm over every leaf of a pytree (flat arrays, per-leaf tuples,
     or a single array alike). Empty trees / zero-size leaves give 0."""
@@ -104,6 +109,7 @@ def residual_l2(residual) -> Array:
     return tree_l2(residual)
 
 
+@jax.named_scope(SCOPE)
 def selected_tau(vals: Array) -> Array:
     """Top-k threshold from a selected-values buffer: the smallest NONZERO
     selected magnitude. Selection kernels pad value slots with 0.0 when
@@ -115,6 +121,7 @@ def selected_tau(vals: Array) -> Array:
     return jnp.where(jnp.any(nz), t, 0.0).astype(jnp.float32)
 
 
+@jax.named_scope(SCOPE)
 def keep_tau(keep: Array, acc: Array) -> Array:
     """tau for the mask-form selection (compress_by_threshold): smallest
     kept magnitude, 0 when nothing is kept."""
@@ -123,11 +130,20 @@ def keep_tau(keep: Array, acc: Array) -> Array:
     return jnp.where(jnp.any(keep), t, 0.0).astype(jnp.float32)
 
 
+@jax.named_scope(SCOPE)
 def sent_count(vals: Array) -> Array:
     """Actual nonzeros in a communicated value buffer (f32 scalar)."""
     return jnp.sum((vals != 0).astype(jnp.float32))
 
 
+@jax.named_scope(SCOPE)
+def kept_count(keep: Array) -> Array:
+    """Coordinates a keep mask (compress_by_threshold) selects (f32
+    scalar): the mask-form counterpart of sent_count."""
+    return jnp.sum(keep.astype(jnp.float32))
+
+
+@jax.named_scope(SCOPE)
 def make_telemetry(
     *,
     n: int,
@@ -243,6 +259,7 @@ def zero_layer_telemetry(sizes: Sequence[int], *, per_leaf_age: bool):
     }
 
 
+@jax.named_scope(SCOPE)
 def seg_l2(x: Array, seg: np.ndarray, L: int) -> Array:
     """Per-layer L2 norms of a flat [N] vector in one segment reduction."""
     x = x.astype(jnp.float32)
@@ -257,6 +274,7 @@ def _tree_sq(tree) -> Array:
     )
 
 
+@jax.named_scope(SCOPE)
 def mass_ratio(acc, selected) -> Array:
     """Whole-model mass-capture ratio m(k) = ||selected||^2 / ||acc||^2
     (arXiv:1911.08772). Both args may be arrays or pytrees of arrays;
@@ -265,6 +283,7 @@ def mass_ratio(acc, selected) -> Array:
     return _tree_sq(selected) / jnp.maximum(_tree_sq(acc), _MASS_EPS)
 
 
+@jax.named_scope(SCOPE)
 def leaf_l2(arrs: Sequence[Array]) -> Array:
     """Stacked per-leaf L2 norms, f32[L] — the layerwise-mode counterpart
     of seg_l2 (one small reduction per leaf; no flat vector exists)."""
@@ -273,6 +292,7 @@ def leaf_l2(arrs: Sequence[Array]) -> Array:
     ])
 
 
+@jax.named_scope(SCOPE)
 def selection_layer_stats(
     acc: Array, sel_dense: Array, seg: np.ndarray, L: int
 ) -> Tuple[Dict[str, Array], Array]:
@@ -303,6 +323,7 @@ def selection_layer_stats(
     return {"sent": sent, "tau": tau, "m_k": m_k}, whole
 
 
+@jax.named_scope(SCOPE)
 def sparse_selection_layer_stats(
     acc: Array, vals: Array, idx: Array, seg: np.ndarray, L: int
 ) -> Tuple[Dict[str, Array], Array]:
@@ -328,6 +349,7 @@ def sparse_selection_layer_stats(
     return {"sent": sent, "tau": tau, "m_k": m_k}, whole
 
 
+@jax.named_scope(SCOPE)
 def leafwise_selection_stats(
     accs: Sequence[Array], sel_denses: Sequence[Array]
 ) -> Tuple[Dict[str, Array], Array]:
@@ -353,6 +375,7 @@ def leafwise_selection_stats(
     }, whole
 
 
+@jax.named_scope(SCOPE)
 def leafwise_sparse_selection_stats(
     accs: Sequence[Array], vals_list: Sequence[Array]
 ) -> Tuple[Dict[str, Array], Array]:
@@ -378,6 +401,7 @@ def leafwise_sparse_selection_stats(
     }, whole
 
 
+@jax.named_scope(SCOPE)
 def bucketed_sparse_selection_stats(
     accs: Sequence[Array], vals_list: Sequence[Array],
     idx_list: Sequence[Array], leaf_sizes: Sequence[int],
@@ -406,6 +430,7 @@ def bucketed_sparse_selection_stats(
     return out, mass_ratio(accs, vals_list)
 
 
+@jax.named_scope(SCOPE)
 def dense_phase_selection_stats(
     sizes: Sequence[int],
 ) -> Tuple[Dict[str, Array], Array]:
@@ -421,6 +446,7 @@ def dense_phase_selection_stats(
     }, jnp.float32(1.0)
 
 
+@jax.named_scope(SCOPE)
 def update_age(age, delivered):
     """Residual-age recursion: a coordinate's age resets to 0 the step it
     ships (appears in the applied dense update) and grows by 1 otherwise.
@@ -433,6 +459,7 @@ def update_age(age, delivered):
         lambda a, d: jnp.where(d, 0.0, a + 1.0), age, delivered)
 
 
+@jax.named_scope(SCOPE)
 def layer_age_means(age, seg: np.ndarray = None, L: int = 0,
                     sizes: Sequence[int] = ()) -> Array:
     """Mean residual age per layer: flat [N] buffer via one segment_sum,
@@ -445,6 +472,7 @@ def layer_age_means(age, seg: np.ndarray = None, L: int = 0,
                                .astype(np.float32))
 
 
+@jax.named_scope(SCOPE)
 def assemble_layer_telemetry(
     *,
     sel_stats: Dict[str, Array],
@@ -471,6 +499,7 @@ def assemble_layer_telemetry(
     }
 
 
+@jax.named_scope(SCOPE)
 def topk_recall(hits: Array, exact_vals: Array) -> Array:
     """Recall of the production selection against the exact top-k ground
     truth: fraction of exact-top-k elements (zero-padding slots excluded)

@@ -1,33 +1,116 @@
-"""Tracing spans: one name, two timelines.
+"""Tracing spans: the program's one span system.
 
-A ``Tracer.span("io")`` emits
+A ``Tracer.span("io", step=7)`` records what a span is: its (nested)
+path, its start on ``time.perf_counter()``, its duration, the optimizer
+step it belongs to and the thread that ran it. A closed span goes
 
-  * a host-side duration into a TimingStats accumulator (and optionally a
-    per-span metrics.jsonl record), and
-  * a ``jax.profiler.TraceAnnotation`` scope with the same (nested) path,
+  * into ``SPAN_BUFFER``, one bounded in-memory buffer shared by every
+    tracer and every thread, which outlives the tracer (a reader asks
+    ``buffered_spans()`` after the trainer is gone), and
+  * where it ran on the thread that built the tracer: into a TimingStats
+    accumulator (``flush()`` ships the window's means as one ``spans``
+    record) and to the optional ``sink(path, t0, dur)`` hook (the
+    timeline recorder's, the benchmark's).
 
-so a phase in the host timeline and the same phase in a device trace
-captured via ``--profile-dir`` carry identical names and can be lined up.
-This replaces the ad-hoc StepTimer call sites in trainer.py/benchmark.py
-(utils/timers.py keeps StepTimer for the sync/timing primitives the
-benchmark harness builds on; the span API is the instrumentation layer).
+A worker thread's span (the prefetcher's ``prefetch/assemble``) reaches
+the buffer only, so the stats and the sink go on describing what the
+training loop was doing.
 
-Spans nest: ``span("train")`` containing ``span("io")`` accumulates under
-the path ``"train/io"``. Nesting is tracked per-thread, so the prefetch
-worker's spans cannot interleave into the consumer thread's path.
+Spans and a profiler trace meet on the wall clock, not by name. Each
+tracer takes one clock anchor when it is built (``clock_anchor_ns``: the
+offset from ``perf_counter`` to ``time.time_ns()``); ``epoch_ns(record)``
+is a span's start on the epoch clock, and a trace's ``Task Environment``
+plane gives ``profile_start_time`` in the same unit, its events counting
+from there. The span also opens a ``jax.profiler.TraceAnnotation`` of the
+same path, which the profiler's host tracer records where it is on; on
+the TPU runtime it has to stay off (it logs an event per tile of every
+batch it lays out for the chip, PERF.md), so there the buffer and the
+anchor are the only way to lay the spans on a trace.
+
+Spans nest: ``span("io")`` containing ``span("wait")`` is recorded under
+``"io/wait"`` and inherits the outer span's step. Nesting is tracked
+per thread, so the prefetch worker's spans cannot interleave into the
+consumer thread's path.
 """
 
 from __future__ import annotations
 
-import functools
+import collections
+import json
+import os
+import statistics
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 
 from gtopkssgd_tpu.utils.timers import TimingStats
+
+
+class SpanRecord(NamedTuple):
+    path: str              # nested path, "io/wait"
+    t0: float              # time.perf_counter() at open, seconds
+    dur: float             # seconds
+    step: Optional[int]    # optimizer step (or batch number) it belongs to
+    thread: str            # name of the thread that ran it
+    anchor_ns: int         # its tracer's clock anchor (clock_anchor_ns)
+
+
+# Closed spans of every tracer and thread, oldest dropped first: at six
+# spans a step, the last five thousand steps. deque.append is atomic.
+SPAN_BUFFER: "collections.deque[SpanRecord]" = collections.deque(maxlen=32768)
+
+
+def buffered_spans() -> List[SpanRecord]:
+    """The closed spans still in the buffer, oldest first."""
+    return list(SPAN_BUFFER)
+
+
+def clock_anchor_ns(reads: int = 5) -> int:
+    """``time.time_ns()`` minus ``time.perf_counter()`` in nanoseconds:
+    the median of a few paired reads, each pair a microsecond apart."""
+    return int(statistics.median(
+        time.time_ns() - time.perf_counter() * 1e9 for _ in range(reads)))
+
+
+def epoch_ns(record: SpanRecord) -> int:
+    """The span's start on the ``time.time_ns()`` clock, which is the one a
+    profiler trace's ``profile_start_time`` is on."""
+    return int(record.t0 * 1e9) + record.anchor_ns
+
+
+@contextmanager
+def profile(trace_dir: str):
+    """A ``jax.profiler`` trace of the enclosed steps, taken as the
+    benchmark takes its own: Python tracer off, host tracer off on the TPU
+    platform (on, it makes a step six times slower and the trace twenty
+    times larger: PERF.md) and on elsewhere (the CPU backend's operations
+    run on host threads). The spans that opened meanwhile are written
+    beside the trace as ``spans.json``, each with its start on the epoch
+    clock, so they can be laid on the trace without the benchmark: the
+    trace's ``Task Environment`` plane holds ``profile_start_time`` on the
+    same clock, and its events count from there."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 0 if jax.default_backend() == "tpu" else 2
+    opened = time.perf_counter()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+        rank = jax.process_index()
+        name = "spans.json" if rank == 0 else f"spans.r{rank}.json"
+        with open(os.path.join(trace_dir, name), "w") as fh:
+            json.dump({
+                "clock": "epoch_ns = time.time_ns() at the span's start; "
+                         "anchor_ns = time.time_ns() - perf_counter() * 1e9",
+                "anchor_ns": clock_anchor_ns(),
+                "spans": [dict(r._asdict(), epoch_ns=epoch_ns(r))
+                          for r in buffered_spans() if r.t0 >= opened],
+            }, fh)
 
 
 class Tracer:
@@ -36,21 +119,21 @@ class Tracer:
         stats: Optional[TimingStats] = None,
         metrics=None,
         enabled: bool = True,
-        record_each: bool = False,
         sink=None,
     ):
         """``metrics`` is a utils.metrics.MetricsLogger (or anything with
-        ``.log(kind, **fields)``). ``record_each=True`` writes one jsonl
-        record per span close — verbose; the default accumulates into
-        ``stats`` and ships means via ``flush()``. ``sink`` is an
-        optional callable ``(path, t0_perf_counter, dur_seconds)``
-        invoked on every span close — the timeline recorder's hook
-        (obs.timeline.TimelineRecorder.span_sink matches it)."""
+        ``.log(kind, **fields)``); ``flush()`` ships the accumulated
+        means to it. ``sink`` is an optional callable
+        ``(path, t0_perf_counter, dur_seconds)`` invoked when a span of
+        the thread that built this tracer closes — the timeline
+        recorder's hook (obs.timeline.TimelineRecorder.span_sink matches
+        it)."""
         self.stats = stats or TimingStats()
         self.metrics = metrics
         self.enabled = enabled
-        self.record_each = record_each
         self.sink = sink
+        self.anchor_ns = clock_anchor_ns()
+        self._owner = threading.get_ident()
         self._local = threading.local()
 
     def _stack(self):
@@ -61,23 +144,28 @@ class Tracer:
 
     @property
     def current_path(self) -> str:
-        return "/".join(self._stack())
+        return "/".join(name for name, _ in self._stack())
 
     @contextmanager
-    def span(self, name: str, *, sync: bool = False, value=None, **attrs):
+    def span(self, name: str, *, sync: bool = False, value=None,
+             step: Optional[int] = None):
         """Time a scope under ``name`` (nested under any open spans).
 
-        ``sync=True`` blocks on JAX's async queue before stopping the
-        clock (``value`` fences just that output) — same semantics as the
-        StepTimer this API replaces; leave False for host-only phases
-        like data loading, and for dispatch phases where the async queue
-        must NOT be drained (the whole point of overlap)."""
+        ``step`` is the optimizer step the span belongs to; a nested span
+        without one takes its parent's. ``sync=True`` blocks on JAX's
+        async queue before stopping the clock (``value`` fences just that
+        output) — same semantics as the StepTimer this API replaces;
+        leave False for host-only phases like data loading, and for
+        dispatch phases where the async queue must NOT be drained (the
+        whole point of overlap)."""
         if not self.enabled:
             yield
             return
         stack = self._stack()
-        stack.append(name)
-        path = "/".join(stack)
+        if step is None and stack:
+            step = stack[-1][1]
+        stack.append((name, step))
+        path = "/".join(n for n, _ in stack)
         ann = jax.profiler.TraceAnnotation(path)
         t0 = time.perf_counter()
         ann.__enter__()
@@ -94,29 +182,13 @@ class Tracer:
                 ann.__exit__(None, None, None)
                 dur = time.perf_counter() - t0
                 stack.pop()
-                self.stats.add(path, dur)
-                if self.sink is not None:
-                    self.sink(path, t0, dur)
-                if self.record_each and self.metrics is not None:
-                    self.metrics.log(
-                        "span", name=name, path=path, dur_s=dur, **attrs
-                    )
-
-    def annotate(self, name: Optional[str] = None):
-        """Decorator form (the jax.profiler.annotate_function idiom):
-        every call of the wrapped function runs inside a span."""
-
-        def deco(fn):
-            label = name or fn.__name__
-
-            @functools.wraps(fn)
-            def wrapped(*args, **kwargs):
-                with self.span(label):
-                    return fn(*args, **kwargs)
-
-            return wrapped
-
-        return deco
+                thread = threading.current_thread()
+                SPAN_BUFFER.append(SpanRecord(
+                    path, t0, dur, step, thread.name, self.anchor_ns))
+                if thread.ident == self._owner:
+                    self.stats.add(path, dur)
+                    if self.sink is not None:
+                        self.sink(path, t0, dur)
 
     def flush(self, step: Optional[int] = None) -> Dict[str, float]:
         """Ship accumulated per-path mean seconds as ONE 'spans' record

@@ -621,6 +621,7 @@ def merge_sparse_sets(
     return out_val[:k], out_idx[:k]
 
 
+@jax.named_scope("gtopk/mask")
 def scatter_add_dense(n: int, idx: Array, vals: Array, dtype=jnp.float32) -> Array:
     """Densify a sparse set: zeros(n).at[idx].add(vals), dropping sentinel
     slots (idx == n falls out of range and `mode='drop'` ignores it)."""

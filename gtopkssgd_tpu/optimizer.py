@@ -58,8 +58,8 @@ from gtopkssgd_tpu.ops import (
     topk_abs,
 )
 from gtopkssgd_tpu.parallel import (
-    get_codec, ici_dense_psum, parse_buckets, plan_buckets, resolve_plan,
-    roundtrip_aligned, sparse_allreduce, validate_pin)
+    dense_allreduce, get_codec, ici_dense_psum, parse_buckets, plan_buckets,
+    resolve_plan, roundtrip_aligned, sparse_allreduce, validate_pin)
 from gtopkssgd_tpu.parallel.bucketing import buckets_key, parse_pipeline
 
 Array = jax.Array
@@ -458,6 +458,7 @@ def gtopk_sgd(
             telemetry=_init_telemetry(params) if telemetry else (),
         )
 
+    @jax.named_scope(obs_counters.SCOPE)
     def _finish_telemetry(tel, p):
         """pmean the per-device scalars (and [L] layer stats) when a mesh
         axis is bound so the stored telemetry is replicated (out_specs
@@ -581,7 +582,7 @@ def gtopk_sgd(
                         "tau": jnp.where(
                             jnp.any(kept),
                             jnp.min(jnp.where(kept, taus, jnp.inf)), 0.0),
-                        "sent": sum(jnp.sum(m.astype(jnp.float32))
+                        "sent": sum(obs_counters.kept_count(m)
                                     for m in keeps),
                         "m_k": obs_counters.mass_ratio(accs, dense_fl),
                     }
@@ -762,7 +763,7 @@ def gtopk_sgd(
                         "tau": jnp.where(
                             jnp.any(kept),
                             jnp.min(jnp.where(kept, taus, jnp.inf)), 0.0),
-                        "sent": sum(jnp.sum(m.astype(jnp.float32))
+                        "sent": sum(obs_counters.kept_count(m)
                                     for m in keeps),
                         "m_k": obs_counters.mass_ratio(accs, dense_b),
                     }
@@ -908,7 +909,8 @@ def gtopk_sgd(
         if warmup_dense_steps > 0:
             def dense_branch(srcs, res_in, us):
                 if p > 1:
-                    srcs = [lax.psum(s, axis_name) / p for s in srcs]
+                    srcs = [dense_allreduce(s, axis_name=axis_name) / p
+                            for s in srcs]
                 # dense phase telemetry: no threshold, everything sent,
                 # full mass capture, nothing to audit
                 tel = ()
@@ -942,7 +944,9 @@ def gtopk_sgd(
         avg_grads = treedef.unflatten([
             d.reshape(leaf.shape) for d, leaf in zip(dense_fl, leaves)
         ])
-        updates, inner_state = inner.update(avg_grads, state.inner, params)
+        with jax.named_scope("gtopk/apply"):
+            updates, inner_state = inner.update(
+                avg_grads, state.inner, params)
         if telemetry:
             tel = obs_counters.make_telemetry(
                 n=n, k=wire_k_total, p=p, mode=mode, codec=codec,
@@ -1019,7 +1023,8 @@ def gtopk_sgd(
         btel = None
         plan = None  # dense mode has no sparse wire to plan
         if dense_mode:
-            reduced = lax.psum(flat, axis_name) if p > 1 else flat
+            reduced = (dense_allreduce(flat, axis_name=axis_name)
+                       if p > 1 else flat)
             dense = reduced / p
             residual = state.residual
             res_struct = residual
@@ -1094,7 +1099,7 @@ def gtopk_sgd(
                     if telemetry:
                         tel = {
                             "tau": tau_th,
-                            "sent": jnp.sum(keep.astype(jnp.float32)),
+                            "sent": obs_counters.kept_count(keep),
                             "m_k": obs_counters.mass_ratio(acc, dense),
                         }
                         if telemetry_layers:
@@ -1186,7 +1191,8 @@ def gtopk_sgd(
 
             if warmup_dense_steps > 0:
                 def dense_branch(src, residual_in, u_in):
-                    reduced = lax.psum(src, axis_name) if p > 1 else src
+                    reduced = (dense_allreduce(src, axis_name=axis_name)
+                               if p > 1 else src)
                     # In hier mode the input is already the within-slice
                     # SUM (ici_dense_psum above), so a full-axis psum
                     # counts every original gradient hier_ici_size times —
@@ -1227,7 +1233,9 @@ def gtopk_sgd(
                 residual = {"v": residual, "u": u_new}
 
         avg_grads = unravel(dense)
-        updates, inner_state = inner.update(avg_grads, state.inner, params)
+        with jax.named_scope("gtopk/apply"):
+            updates, inner_state = inner.update(
+                avg_grads, state.inner, params)
         if telemetry:
             tel = obs_counters.make_telemetry(
                 n=n, k=(n if dense_mode else compressor.k(n)), p=p,

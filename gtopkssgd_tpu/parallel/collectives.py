@@ -34,6 +34,7 @@ TPU redesign notes:
 
 from __future__ import annotations
 
+import itertools
 import math
 from typing import Tuple
 
@@ -178,25 +179,31 @@ def _merge_tree(vals, idx, *, k, n, axis_name, part_ranks, my_part,
             pidx = jnp.where(receives, pidx, n)
         return merge_sparse_sets(dvals, didx, pvals, pidx, k, n)
 
+    # One named scope a round (fold, tree rounds, unfold, in order), so a
+    # device trace tells the rounds apart.
+    rounds = itertools.count()
     if e:
         # fold: extra m+t sends its set down to participant t (t < e)
-        vals, idx = exchange(vals, idx,
-                             [(m + t, t) for t in range(e)], my_part < e)
+        with jax.named_scope(f"round{next(rounds)}"):
+            vals, idx = exchange(vals, idx,
+                                 [(m + t, t) for t in range(e)], my_part < e)
     for r in range(int(math.log2(m))):
         bit = 1 << r
-        vals, idx = exchange(vals, idx,
-                             [(a, a ^ bit) for a in range(m)],
-                             my_part < m if e else None)
+        with jax.named_scope(f"round{next(rounds)}"):
+            vals, idx = exchange(vals, idx,
+                                 [(a, a ^ bit) for a in range(m)],
+                                 my_part < m if e else None)
     if e:
         # unfold: extras ADOPT (not merge) the finished global set —
         # through the codec, so extras and finished participants both
         # hold decode(encode(final set)) and stay bit-identical.
         perm = [(s, d) for t in range(e)
                 for s, d in zip(part_ranks[t], part_ranks[m + t])]
-        (dvals, didx), (pvals, pidx) = ship(vals, idx, perm)
-        extra = my_part >= m
-        vals = jnp.where(extra, pvals, dvals)
-        idx = jnp.where(extra, pidx, didx)
+        with jax.named_scope(f"round{next(rounds)}"):
+            (dvals, didx), (pvals, pidx) = ship(vals, idx, perm)
+            extra = my_part >= m
+            vals = jnp.where(extra, pvals, dvals)
+            idx = jnp.where(extra, pidx, didx)
     return vals, idx
 
 
@@ -452,11 +459,13 @@ def topk_allgather(
     return scatter_add_dense(n, all_idx, all_vals)
 
 
+@jax.named_scope("gtopk/allreduce")
 def dense_allreduce(x: Array, *, axis_name: str) -> Array:
     """Dense baseline: one psum over the DP axis (reference MPI.Allreduce)."""
     return lax.psum(x, axis_name)
 
 
+@jax.named_scope("gtopk/allreduce")
 def sparse_allreduce(
     mode: str,
     vals: Array,

@@ -516,9 +516,10 @@ class Trainer:
             TimelineRecorder(rank=self.process_rank)
             if cfg.obs_timeline and self.process_rank == 0 else None
         )
-        # Span tracer (obs.tracing): host phase timing + profiler
-        # TraceAnnotations under one name. Replaces the bare StepTimer
-        # (utils/timers.py keeps the primitive).
+        # Span tracer (obs.tracing): every host phase of the loop as a
+        # span with its step id, into the span buffer, the window means
+        # and the sink. Replaces the bare StepTimer (utils/timers.py
+        # keeps the primitive).
         self.tracer = Tracer(
             metrics=self.metrics,
             sink=self.timeline.span_sink if self.timeline else None,
@@ -1101,7 +1102,7 @@ class Trainer:
         # jax.device_put stays on the consumer thread.
         self._prefetch = (
             Prefetcher(lambda: self._stack_shard_batches(iters),
-                       depth=self.cfg.prefetch)
+                       depth=self.cfg.prefetch, tracer=self.tracer)
             if self.cfg.prefetch > 0 else None
         )
 
@@ -1472,16 +1473,24 @@ class Trainer:
                 grads_sum = jax.tree.map(jnp.add, grads_sum, grads)
                 return (grads_sum, bs, cr), (loss, aux)
 
-            zero_grads = jax.tree.map(jnp.zeros_like, state.params)
-            (grads, new_bs, new_carry), (losses, auxes) = lax.scan(
-                micro, (zero_grads, state.batch_stats, carry),
-                (batch, jnp.arange(cfg.nsteps_update)),
-            )
-            grads = jax.tree.map(lambda g: g / cfg.nsteps_update, grads)
+            # The named scopes (here and in the optimizer, compression,
+            # collectives and counters) are metadata on the compiled
+            # operations: a device trace names each stage of the step by
+            # them (perfbench/metrics/scoped.py); the arithmetic and the
+            # fusion are what they were.
+            with jax.named_scope("gtopk/fwd_bwd"):
+                zero_grads = jax.tree.map(jnp.zeros_like, state.params)
+                (grads, new_bs, new_carry), (losses, auxes) = lax.scan(
+                    micro, (zero_grads, state.batch_stats, carry),
+                    (batch, jnp.arange(cfg.nsteps_update)),
+                )
+                grads = jax.tree.map(
+                    lambda g: g / cfg.nsteps_update, grads)
             updates, opt_state = tx.update(
                 grads, state.opt_state, state.params
             )
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("gtopk/apply"):
+                params = optax.apply_updates(state.params, updates)
             loss = losses.mean()
             aux = jax.tree.map(lambda a: a.mean(), auxes)
             if p > 1:
@@ -1521,7 +1530,10 @@ class Trainer:
             return (s, c2, losses[-1],
                     jax.tree.map(lambda a: a[-1], auxes))
 
-        def shardwise(state, carry, batch):
+        def gtopk_train_step(state, carry, batch):
+            # The function's name is the compiled step's: a reader finds
+            # its programs in a device trace as jit_gtopk_train_step (the
+            # XLA Modules line), whether built directly or under shard_map.
             # Both the p==1 direct path and the per-device shard_map block
             # see a leading shard dim of size 1 — strip it, run, restore.
             # The residual travels the same way: global [P, N], per-device
@@ -1545,7 +1557,7 @@ class Trainer:
             return s, c2, loss, aux
 
         if p == 1:
-            return jax.jit(shardwise, donate_argnums=donate)
+            return jax.jit(gtopk_train_step, donate_argnums=donate)
 
         # Per-leaf specs: everything in the state is replicated EXCEPT the
         # error-feedback residual, which is per-device ([P, N], sharded over
@@ -1565,7 +1577,7 @@ class Trainer:
                                     telemetry=P()),
         )
         smapped = jax.shard_map(
-            shardwise,
+            gtopk_train_step,
             mesh=self.mesh,
             in_specs=(state_spec, P("dp"), P("dp")),
             out_specs=(state_spec, P("dp"), P(), P()),
@@ -1738,15 +1750,18 @@ class Trainer:
                         # Injected slowness is exactly the skew-wait the
                         # taxonomy's `wait` bucket accounts.
                         gp.mark("wait")
-                with self.tracer.span("io"):
-                    hosts = [self._fetch_host(step, spd)
-                             for _ in range(spd)]
+                with self.tracer.span("io", step=step):
+                    # io/wait: blocked on the prefetch queue (or, without
+                    # a prefetcher, assembling the batch here).
+                    with self.tracer.span("wait"):
+                        hosts = [self._fetch_host(step, spd)
+                                 for _ in range(spd)]
                     if spd == 1:
                         host = hosts[0]
                     else:
                         # [P, spd, nsteps_update, B, ...]: the scan axis
-                        # sits after the shard dim (shardwise strips dim 0
-                        # first).
+                        # sits after the shard dim (gtopk_train_step
+                        # strips dim 0 first).
                         host = {
                             k: np.stack([h[k] for h in hosts], axis=1)
                             for k in hosts[0]
@@ -1758,7 +1773,10 @@ class Trainer:
                         host = inj.reshape_batch(
                             host, step, step + spd,
                             axis=2 if spd == 1 else 3)
-                    batch = self._device_batch(host)
+                    # io/put: the hand-over to the runtime. It returns
+                    # before the batch is on the chip.
+                    with self.tracer.span("put"):
+                        batch = self._device_batch(host)
                 if gp is not None:
                     gp.mark("data")  # host batch assembly + H2D
                 if rec is not None:
@@ -1779,10 +1797,12 @@ class Trainer:
                     cfg.obs_critpath and cfg.obs_calib_interval > 0
                     and (step + spd) % cfg.obs_calib_interval < spd)
                 capture_now = calib_now or critpath_now
-                with self.tracer.span("dispatch"):
+                with self.tracer.span("dispatch", step=step):
                     # Async enqueue only — the span must NOT drain the
-                    # queue (the overlap is the point); device time shows
-                    # under the same name in a profiler trace.
+                    # queue (the overlap is the point). The step's device
+                    # time is the jit_gtopk_train_step program in a
+                    # profiler trace, which starts once its inputs are on
+                    # the chip: step_start_lag_ms (perfbench) is that wait.
                     if capture_now:
                         # Calibration sample: profile exactly this
                         # dispatch, blocking inside the capture so the
@@ -1852,7 +1872,7 @@ class Trainer:
                         and step % cfg.obs_interval < spd):
                     tel = self.state.opt_state.telemetry
                     if tel:
-                        with self.tracer.span("obs_read"):
+                        with self.tracer.span("obs_read", step=step - spd):
                             # Scalar counters -> one "obs" record; the
                             # per-layer [L] arrays -> one "layers" record
                             # per layer; the [N] age buffer stays on

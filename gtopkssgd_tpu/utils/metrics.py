@@ -43,7 +43,6 @@ KINDS = frozenset({
     "obs",         # on-device compression/comm counters (obs/counters.py)
     "layers",      # per-layer telemetry, one record per layer per obs step
     "spans",       # Tracer window means (obs/tracing.py flush)
-    "span",        # Tracer per-span record (record_each=True)
     "event",       # anomaly events (obs/events.py)
     "stall",       # watchdog stall diagnostic (obs/watchdog.py)
     "attr",        # T_compute/T_select/T_comm split (obs/trace_attr.py)

@@ -17,6 +17,7 @@ Design constraints honored:
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from typing import Callable
@@ -24,24 +25,38 @@ from typing import Callable
 
 class Prefetcher:
     """Wraps a zero-arg `produce` callable (returns the next host batch)
-    with a bounded background queue of `depth` pre-assembled batches."""
+    with a bounded background queue of `depth` pre-assembled batches.
+    With a ``tracer`` (obs.tracing.Tracer) every ``produce()`` runs inside
+    a ``prefetch/assemble`` span that carries the batch's sequence number;
+    it is a worker thread's span, so it reaches the span buffer only."""
 
     _STOP = object()
 
-    def __init__(self, produce: Callable[[], object], depth: int = 2):
+    def __init__(self, produce: Callable[[], object], depth: int = 2,
+                 tracer=None):
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
         self._produce = produce
+        self._tracer = tracer
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._err = None
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="prefetch")
         self._thread.start()
 
+    def _assemble(self, seq: int):
+        if self._tracer is None:
+            return self._produce()
+        with self._tracer.span("prefetch/assemble", step=seq):
+            return self._produce()
+
     def _run(self):
-        while not self._stop.is_set():
+        for seq in itertools.count():
+            if self._stop.is_set():
+                return
             try:
-                item = self._produce()
+                item = self._assemble(seq)
             except BaseException as e:  # propagate to the consumer
                 self._err = e
                 self._q.put(self._STOP)

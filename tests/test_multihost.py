@@ -114,3 +114,16 @@ def test_profile_dir_writes_trace(tmp_path):
     # The trace lands under <dir>/plugins/profile/<run>/ with a .trace.json.gz
     found = [f for f in prof.rglob("*") if f.is_file()]
     assert any("trace" in f.name for f in found), found
+    # ... and the traced steps' spans beside it, on the epoch clock.
+    import json
+
+    with open(prof / "spans.json") as fh:
+        written = json.load(fh)
+    steps = {s["path"]: set() for s in written["spans"]}
+    for s in written["spans"]:
+        steps[s["path"]].add(s["step"])
+        assert s["epoch_ns"] == int(s["t0"] * 1e9) + s["anchor_ns"]
+    # One warm step first, so the two traced ones are steps 1 and 2.
+    assert steps["dispatch"] == steps["io"] == steps["io/wait"] == {1, 2}
+    assert steps["obs_read"] == {1, 2}
+    assert abs(written["anchor_ns"] - written["spans"][0]["anchor_ns"]) < 1e6

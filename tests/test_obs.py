@@ -7,6 +7,7 @@ armed region (never a real wedged backend); the report CLI round-trips a
 synthetic metrics.jsonl.
 """
 
+import gc
 import json
 import os
 import threading
@@ -28,6 +29,7 @@ from gtopkssgd_tpu.obs import (
     counters as obs_counters,
 )
 from gtopkssgd_tpu.obs import report as obs_report
+from gtopkssgd_tpu.obs import tracing
 from gtopkssgd_tpu.optimizer import gtopk_sgd
 from gtopkssgd_tpu.ops import k_for_density
 from gtopkssgd_tpu.utils.metrics import MetricsLogger
@@ -244,19 +246,97 @@ def test_span_flush_logs_one_record_and_resets(tmp_path):
     assert len(spans) == 1 and spans[0]["step"] == 7 and "io" in spans[0]
 
 
-def test_disabled_tracer_and_decorator():
-    tr = Tracer(enabled=False)
-    with tr.span("x"):
+def test_disabled_tracer_records_nothing():
+    before = tracing.buffered_spans()
+    seen = []
+    tr = Tracer(enabled=False, sink=lambda *a: seen.append(a))
+    with tr.span("x", step=1):
         pass
     assert tr.stats.summary() == {}
-    tr2 = Tracer()
+    assert seen == [] and tracing.buffered_spans() == before
 
-    @tr2.annotate()
-    def compute():
-        return 41 + 1
 
-    assert compute() == 42
-    assert "compute" in tr2.stats.summary()
+def _own(marker):
+    return [r for r in tracing.buffered_spans() if r.path.startswith(marker)]
+
+
+def test_span_records_carry_step_thread_and_parent_path():
+    tr = Tracer()
+    with tr.span("t1io", step=7):
+        with tr.span("wait"):
+            pass
+        with tr.span("put", step=8):      # an explicit step wins
+            pass
+    with tr.span("t1final"):
+        pass
+    recs = {r.path: r for r in _own("t1")}
+    assert set(recs) == {"t1io", "t1io/wait", "t1io/put", "t1final"}
+    assert recs["t1io"].step == recs["t1io/wait"].step == 7
+    assert recs["t1io/put"].step == 8 and recs["t1final"].step is None
+    me = threading.current_thread().name
+    for r in recs.values():
+        assert r.thread == me and r.anchor_ns == tr.anchor_ns
+        assert r.dur >= 0 and r.t0 <= time.perf_counter()
+    # A child closes before its parent and lies inside it.
+    assert recs["t1io"].t0 <= recs["t1io/wait"].t0
+    assert (recs["t1io/put"].t0 + recs["t1io/put"].dur
+            <= recs["t1io"].t0 + recs["t1io"].dur)
+
+
+def test_span_buffer_is_bounded_and_outlives_its_tracer():
+    cap = tracing.SPAN_BUFFER.maxlen
+    assert cap is not None and cap >= 4096
+    tr = Tracer()
+    with tr.span("t2kept", step=3):
+        pass
+    del tr
+    gc.collect()
+    assert [r.step for r in _own("t2kept")] == [3]
+    saved = tracing.buffered_spans()
+    try:
+        tr = Tracer()
+        for i in range(cap + 10):
+            with tr.span("t2flood", step=i):
+                pass
+        spans = tracing.buffered_spans()
+        assert len(spans) == cap
+        assert spans[0].step == 10 and spans[-1].step == cap + 9
+    finally:
+        tracing.SPAN_BUFFER.clear()
+        tracing.SPAN_BUFFER.extend(saved)
+
+
+def test_worker_thread_span_reaches_the_buffer_and_not_the_sink():
+    sunk = []
+    tr = Tracer(sink=lambda path, t0, dur: sunk.append(path))
+
+    def worker():
+        with tr.span("t3prefetch/assemble", step=0):
+            pass
+
+    t = threading.Thread(target=worker, name="t3-worker")
+    t.start()
+    t.join(5.0)
+    assert not t.is_alive()
+    with tr.span("t3io", step=0):
+        pass
+    assert sunk == ["t3io"]
+    assert set(tr.stats.summary()) == {"t3io"}
+    threads = {r.path: r.thread for r in _own("t3")}
+    assert threads == {"t3prefetch/assemble": "t3-worker",
+                       "t3io": threading.current_thread().name}
+
+
+def test_clock_anchor_puts_a_perf_counter_stamp_on_the_epoch_clock():
+    tr = Tracer()
+    gaps = []
+    for _ in range(5):
+        wall = time.time_ns()
+        with tr.span("t4now"):
+            pass
+        gaps.append(abs(tracing.epoch_ns(_own("t4now")[-1]) - wall))
+    assert min(gaps) < 1_000_000, gaps        # within a millisecond
+    assert abs(tracing.clock_anchor_ns() - tr.anchor_ns) < 1_000_000
 
 
 # ------------------------------------------------------------ watchdog

@@ -23,6 +23,36 @@ def test_order_preserved():
     assert got == list(range(50))
 
 
+def test_same_stream_with_and_without_a_tracer():
+    """The tracer only watches: the batch stream is the untraced one, and
+    every assembly is one ``prefetch/assemble`` span of the worker thread
+    that carries the batch's sequence number and never reaches the sink."""
+    from gtopkssgd_tpu.obs import tracing
+
+    def stream():
+        rng = np.random.default_rng(5)
+        return lambda: rng.standard_normal(8)
+
+    plain = Prefetcher(stream(), depth=2)
+    want = [next(plain) for _ in range(20)]
+    plain.close()
+    sunk = []
+    tracer = tracing.Tracer(sink=lambda *span: sunk.append(span))
+    mark = time.perf_counter()
+    traced = Prefetcher(stream(), depth=2, tracer=tracer)
+    got = [next(traced) for _ in range(20)]
+    traced.close()
+    assert all(np.array_equal(a, b) for a, b in zip(want, got))
+    spans = [r for r in tracing.buffered_spans()
+             if r.anchor_ns == tracer.anchor_ns and r.t0 >= mark]
+    assert {r.path for r in spans} == {"prefetch/assemble"}
+    assert {r.thread for r in spans} == {"prefetch"}
+    # The worker runs at most depth + 1 batches ahead of the consumer.
+    assert [r.step for r in spans][:20] == list(range(20))
+    assert 20 <= len(spans) <= 20 + 3
+    assert sunk == [] and tracer.stats.summary() == {}
+
+
 def test_worker_exception_propagates():
     def produce():
         raise ValueError("boom")
