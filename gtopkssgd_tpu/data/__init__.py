@@ -14,6 +14,16 @@ PTB, AN4 — each with:
     transfer overlaps with the previous step; the native C++ reader in
     gtopkssgd_tpu/native accelerates the real-file path).
 
+The batch contract (what ``Trainer`` relies on, checked per dataset in
+tests/test_data.py): **a yielded batch is not written to after it is
+yielded.** The trainer copies nothing on the host that the data does not
+force: it queues the yielded arrays themselves (as views) in the prefetcher
+and hands them to the runtime, which reads them until the transfer to the
+chip has completed. A leaf may be a fresh array or a view of a store the
+dataset only reads (PTB's token grid); it may not be a buffer the dataset
+fills again for a later batch. A dataset that reuses a buffer copies it at
+its own ``yield``, once per shard, not in the trainer.
+
 ``get_dataset`` mirrors the reference's ``--dataset`` flag dispatch.
 """
 

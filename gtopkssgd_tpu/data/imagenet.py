@@ -101,7 +101,7 @@ def _decode_seeded(args) -> np.ndarray:
 
 # One decode pool per PROCESS, refcounted, shared by every dataset that
 # asks for workers: a Trainer builds nworkers train shards + a val set,
-# but _stack_shard_batches drains them strictly sequentially, so private
+# but _shard_batches drains them strictly sequentially, so private
 # per-dataset pools would fork (nworkers+1) x decode_workers processes of
 # which at most one pool is ever busy. Pool size is fixed by the first
 # acquirer (same cfg value for every dataset of a Trainer; per-image

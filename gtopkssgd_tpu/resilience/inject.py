@@ -289,17 +289,17 @@ class FaultInjector:
         leaves[0] = leaves[0] * float("nan")
         return state._replace(params=jax.tree.unflatten(treedef, leaves))
 
-    def reshape_batch(self, batch, prev: int, new: int, axis: int = 2):
-        """Pre-transfer: reshape. Halves the per-shard batch axis of the
-        assembled host batch dict (numpy leaves, [P, nsteps, B, ...] —
-        ``axis`` indexes B; the trainer passes 3 when steps_per_dispatch
-        stacks an extra axis). A changed dispatch shape forces the
-        jitted step to retrace — the deterministic recompile chaos
-        input. Loss stays a batch mean, so training arithmetic survives
-        the smaller step; a 1-sample batch cannot halve and the fault
-        downgrades to a no-op record."""
+    def reshape_batch(self, shards, prev: int, new: int, axis: int = 1):
+        """Pre-transfer: reshape. Halves the batch axis of every shard
+        of the assembled host batch (a list of per-shard dicts, numpy
+        leaves [nsteps, B, ...] — ``axis`` indexes B; the trainer passes
+        2 when steps_per_dispatch stacks an extra axis). A changed
+        dispatch shape forces the jitted step to retrace — the
+        deterministic recompile chaos input. Loss stays a batch mean, so
+        training arithmetic survives the smaller step; a 1-sample batch
+        cannot halve and the fault downgrades to a no-op record."""
         for f, at in self._active("reshape", prev, new):
-            dim = min(v.shape[axis] for v in batch.values())
+            dim = min(v.shape[axis] for s in shards for v in s.values())
             if dim < 2:
                 self._record(f, at, batch_axis=axis, from_dim=dim,
                              to_dim=dim)
@@ -307,8 +307,8 @@ class FaultInjector:
             half = dim // 2
             self._record(f, at, batch_axis=axis, from_dim=dim, to_dim=half)
             cut = (slice(None),) * axis + (slice(0, half),)
-            batch = {k: v[cut] for k, v in batch.items()}
-        return batch
+            shards = [{k: v[cut] for k, v in s.items()} for s in shards]
+        return shards
 
     def maybe_preempt(self, prev: int, new: int, guard=None) -> None:
         """Post-dispatch: preempt. Sends this process a REAL SIGTERM so

@@ -27,13 +27,14 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from gtopkssgd_tpu import native
@@ -475,6 +476,16 @@ def shard_steps_per_epoch(ds, batch_size: int, nsteps_update: int = 1) -> int:
     return max(1, spe // nsteps_update)
 
 
+def _lead_axis(arrays):
+    """``arrays`` as one [len(arrays), ...] leaf, and the bytes copied to
+    make it: a single array is a view of itself (``a[None]``), several are
+    one ``np.stack``, the only copy the data forces."""
+    if len(arrays) == 1:
+        return np.asarray(arrays[0])[None], 0
+    out = np.stack(arrays)
+    return out, out.nbytes
+
+
 class Trainer:
     def __init__(self, config: TrainConfig):
         self.cfg = cfg = config.resolved()
@@ -597,12 +608,18 @@ class Trainer:
         self.p = cfg.nworkers
 
         # In a multi-host run each process feeds only the mesh positions its
-        # own devices occupy; make_array_from_process_local_data assembles
-        # the global [P, ...] batch (single host: all ranks are local).
+        # own devices occupy; _device_batch builds the global [P, ...] batch
+        # from the per-device pieces (single host: all ranks are local).
         self.local_ranks = [
             i for i, d in enumerate(self.mesh.devices.flat)
             if d.process_index == self.process_rank
         ]
+        self._local_devices = [
+            self.mesh.devices.flat[r] for r in self.local_ranks]
+        self._dp_sharding = NamedSharding(self.mesh, P("dp"))
+        # Bytes the batch assembly has copied on the host since the last
+        # "train" record (its host_copied_mb).
+        self._host_copied_bytes = 0
         data_kw = dict(
             batch_size=cfg.batch_size, data_dir=cfg.data_dir, seed=cfg.seed
         )
@@ -1098,10 +1115,11 @@ class Trainer:
         # (Re)start the background prefetcher on the fresh iterators. The
         # closure binds the local `iters` list, not self._iters, so even a
         # leaked worker could only ever touch its own generation of
-        # iterators. The worker assembles numpy batches only;
-        # jax.device_put stays on the consumer thread.
+        # iterators. The worker assembles numpy batches only (views, or
+        # the stack a micro axis forces); jax.device_put stays on the
+        # consumer thread.
         self._prefetch = (
-            Prefetcher(lambda: self._stack_shard_batches(iters),
+            Prefetcher(lambda: self._shard_batches(iters),
                        depth=self.cfg.prefetch, tracer=self.tracer)
             if self.cfg.prefetch > 0 else None
         )
@@ -1338,8 +1356,6 @@ class Trainer:
         # recompile watch (obs/memwatch.py) flags. The residual is
         # already committed P('dp') by expand_residual_per_device and
         # passes through untouched.
-        from jax.sharding import NamedSharding
-
         rep = NamedSharding(self.mesh, P())
 
         def commit(leaf):
@@ -1363,23 +1379,20 @@ class Trainer:
     def _abstract_batch(self):
         """ShapeDtypeStruct pytree of the canonical global dispatch
         batch ([P, (spd,) nsteps_update, B, ...] — the exact leaves
-        _stack_shard_batches assembles), for the AOT compile-accounting
+        _device_batch builds), for the AOT compile-accounting
         pass: lowering against it consumes no data and executes
         nothing. Carries the dispatch's real P('dp') sharding so the
         accounted executable is bit-for-bit the one the first dispatch
         runs — which also lets that dispatch hit the persistent
         compilation cache the AOT pass just warmed."""
-        from jax.sharding import NamedSharding
-
         cfg = self.cfg
         lead = ((self.p, cfg.steps_per_dispatch, cfg.nsteps_update)
                 if cfg.steps_per_dispatch > 1
                 else (self.p, cfg.nsteps_update))
-        dp = NamedSharding(self.mesh, P("dp"))
         return {
             k: jax.ShapeDtypeStruct(
                 lead + tuple(np.asarray(v[0]).shape),
-                np.asarray(v[0]).dtype, sharding=dp)
+                np.asarray(v[0]).dtype, sharding=self._dp_sharding)
             for k, v in self._peek_batch().items()
         }
 
@@ -1637,52 +1650,61 @@ class Trainer:
         return jax.jit(sharded)
 
     # ------------------------------------------------------------- batches
-    def _stack_shard_batches(self, iters) -> Dict[str, np.ndarray]:
-        """[P_local, nsteps_update, B, ...] host-side batch — the leading
-        dim is the shard_map 'dp' dim; this process contributes its local
-        mesh positions only."""
+    def _shard_batches(self, iters):
+        """One host batch as a list of per-shard dicts (this process's
+        mesh positions, in mesh order) with leaves [nsteps_update, B, ...],
+        and the bytes copied to make it. No axis across the shards exists
+        on the host: each shard's rows are bound for a different chip."""
         n = self.cfg.nsteps_update
-        per_shard = []
+        shards, copied = [], 0
         for it in iters:
             micro = [next(it) for _ in range(n)]
-            per_shard.append(
-                {k: np.stack([m[k] for m in micro]) for k in micro[0]}
-            )
+            shard = {}
+            for k in micro[0]:
+                shard[k], nbytes = _lead_axis([m[k] for m in micro])
+                copied += nbytes
+            shards.append(shard)
+        return shards, copied
+
+    def _device_batch(self, shards):
+        """Per-shard host batches -> device arrays [P, ...] sharded
+        P('dp') over the mesh. Shard i's leaves go straight from their
+        host arrays to this process's i-th mesh device, and the global
+        array is built from those pieces: no stack on the host, no stop
+        on the default device, and one path for a single process and for
+        a multi-host run (where the pieces are this process's part)."""
+        pieces = jax.device_put(
+            [{k: np.asarray(v)[None] for k, v in s.items()} for s in shards],
+            self._local_devices)
         return {
-            k: np.stack([s[k] for s in per_shard]) for k in per_shard[0]
+            k: jax.make_array_from_single_device_arrays(
+                (self.p,) + pieces[0][k].shape[1:], self._dp_sharding,
+                [piece[k] for piece in pieces])
+            for k in pieces[0]
         }
 
-    def _device_batch(self, np_batch: Dict[str, np.ndarray]):
-        """Host batch -> device arrays sharded P('dp') over the mesh. In a
-        multi-host run the local [P_local, ...] block is this process's
-        contribution to the global [P, ...] array."""
-        if jax.process_count() == 1:
-            return {k: jnp.asarray(v) for k, v in np_batch.items()}
-        from jax.sharding import NamedSharding
-
-        sharding = NamedSharding(self.mesh, P("dp"))
-        return {
-            k: jax.make_array_from_process_local_data(sharding, v)
-            for k, v in np_batch.items()
-        }
-
-    def _fetch_host(self, step: int, spd: int) -> Dict[str, np.ndarray]:
-        """One host batch from the prefetcher (or synchronously). With an
-        injector active, loader faults (injected or real) are absorbed by
-        the shared retry helper — a transient IOError costs a retry, not
-        the run."""
+    def _fetch_host(self, step: int, spd: int) -> List[Dict[str, np.ndarray]]:
+        """One host batch (per-shard dicts) from the prefetcher (or
+        synchronously); its copied bytes go to the ``train`` record's
+        ``host_copied_mb``. With an injector active, loader faults
+        (injected or real) are absorbed by the shared retry helper — a
+        transient IOError costs a retry, not the run."""
         def fetch():
             if self.injector is not None:
                 self.injector.check_loader(step, step + spd)
             return (next(self._prefetch) if self._prefetch is not None
-                    else self._stack_shard_batches(self._iters))
+                    else self._shard_batches(self._iters))
 
         if self.injector is None:
-            return fetch()
-        from gtopkssgd_tpu.resilience import retry_call
+            shards, copied = fetch()
+        else:
+            from gtopkssgd_tpu.resilience import retry_call
 
-        return retry_call(fetch, retries=2, delay=0.05,
-                          logger=self.logger, desc="host batch fetch")
+            shards, copied = retry_call(
+                fetch, retries=2, delay=0.05, logger=self.logger,
+                desc="host batch fetch")
+        self._host_copied_bytes += copied
+        return shards
 
     # -------------------------------------------------------------- train
     def train(self, num_iters: int, epoch: int = 0) -> Dict[str, float]:
@@ -1759,22 +1781,26 @@ class Trainer:
                     if spd == 1:
                         host = hosts[0]
                     else:
-                        # [P, spd, nsteps_update, B, ...]: the scan axis
-                        # sits after the shard dim (gtopk_train_step
-                        # strips dim 0 first).
-                        host = {
-                            k: np.stack([h[k] for h in hosts], axis=1)
-                            for k in hosts[0]
-                        }
+                        # Per shard [spd, nsteps_update, B, ...]: the
+                        # scan axis leads (gtopk_train_step strips the
+                        # shard dim first). A forced copy.
+                        host = [
+                            {k: np.stack([h[i][k] for h in hosts])
+                             for k in hosts[0][i]}
+                            for i in range(len(hosts[0]))
+                        ]
+                        self._host_copied_bytes += sum(
+                            v.nbytes for s in host for v in s.values())
                     if inj is not None:
                         # reshape fault: a deliberately different
-                        # dispatch shape (B axis sits after the shard —
+                        # dispatch shape (B axis sits after the micro —
                         # and with spd > 1 the scan — dim).
                         host = inj.reshape_batch(
                             host, step, step + spd,
-                            axis=2 if spd == 1 else 3)
-                    # io/put: the hand-over to the runtime. It returns
-                    # before the batch is on the chip.
+                            axis=1 if spd == 1 else 2)
+                    # io/put: the hand-over to the runtime, one put per
+                    # local shard. It returns before the batch is on the
+                    # chips.
                     with self.tracer.span("put"):
                         batch = self._device_batch(host)
                 if gp is not None:
@@ -1912,8 +1938,13 @@ class Trainer:
                     elapsed = time.perf_counter() - t_start
                     row = dict(
                         step=step, epoch=epoch, loss=last_loss,
-                        throughput=samples / elapsed, **last_aux,
+                        throughput=samples / elapsed,
+                        # Megabytes the batch assembly copied on the host
+                        # since the last such record: 0 on the view path.
+                        host_copied_mb=self._host_copied_bytes / 1e6,
+                        **last_aux,
                     )
+                    self._host_copied_bytes = 0
                     if cfg.dataset == "ptb":
                         row["ppl"] = float(np.exp(min(last_loss, 20.0)))
                     self.metrics.log("train", **row)
@@ -2031,12 +2062,8 @@ class Trainer:
             nvalid = len(group)
             while len(group) < self.p:  # pad shards, zero-weighted below
                 group.append(group[-1])
-            stacked = {
-                k: np.stack([np.asarray(b[k]) for b in group])
-                for k in group[0]
-            }
             loss, _, aux = self._eval_step(
-                self.state, (), self._device_batch(stacked))
+                self.state, (), self._device_batch(group))
             loss = np.asarray(loss)
             aux = {k: np.asarray(v) for k, v in aux.items()}
             for i in range(nvalid):
@@ -2233,8 +2260,6 @@ class Trainer:
         grow = zero rows, shrink = masked-fold addition conserving the
         pending gradient mass) and commit it onto the new mesh's
         P('dp') placement. Every other leaf restores shape-identically."""
-        from jax.sharding import NamedSharding
-
         from gtopkssgd_tpu.resilience.elastic import repartition_buffer
 
         rep = NamedSharding(self.mesh, P())
@@ -2492,8 +2517,6 @@ class Trainer:
             step=int(self.state.step))
 
     def _state_template(self):
-        from jax.sharding import NamedSharding
-
         rep = NamedSharding(self.mesh, P())
 
         def leaf(x):

@@ -4,6 +4,13 @@ with GPU compute; here ONE daemon thread overlaps numpy batch assembly —
 including the C++ augment loops, which release the GIL inside
 native.dataprep — with the device step).
 
+What the Trainer's worker assembles is a list of per-shard dicts, one per
+local mesh position: it draws the datasets' batches (decode, augment) and
+gives each shard its micro axis, a view when ``nsteps_update`` is 1 and one
+``np.stack`` per shard otherwise. It never stacks across shards: a queued
+batch holds the datasets' own arrays (data/__init__.py: not written to
+after the yield), and the consumer puts each shard on its own device.
+
 Design constraints honored:
 
   * Determinism: a single worker thread pulls from the underlying

@@ -204,3 +204,30 @@ class TestSynthHard:
 
         cfg = TrainConfig(dnn="resnet20", synth_hard=True).resolved()
         assert cfg.synth_hard and cfg.dataset == "cifar10"
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("cifar10", dict(batch_size=8)),
+    ("imagenet", dict(batch_size=2, num_classes=10)),
+    ("ptb", dict(batch_size=4)),
+    ("an4", dict(batch_size=2)),
+])
+def test_yielded_batch_is_not_written_to_afterwards(name, kw):
+    """The contract in data/__init__.py: the trainer queues and transfers
+    the yielded arrays themselves, so a dataset builds no later batch in
+    the memory of an earlier one. Leaf by leaf, consecutive batches share
+    no memory, and the first batch still reads the same after four more
+    were drawn."""
+    it = iter(get_dataset(name, **kw))
+    first = next(it)
+    snapshot = {k: np.array(v, copy=True) for k, v in first.items()}
+    prev = first
+    for _ in range(4):
+        batch = next(it)
+        assert set(batch) == set(first)
+        for k in batch:
+            assert not np.shares_memory(batch[k], prev[k]), (name, k)
+            assert not np.shares_memory(batch[k], first[k]), (name, k)
+        prev = batch
+    for k, v in first.items():
+        np.testing.assert_array_equal(v, snapshot[k])

@@ -208,22 +208,26 @@ def test_missing_memory_stats_degrades_to_live_arrays():
 # ------------------------------------------------------------ reshape fault
 
 def test_reshape_inject_halves_batch_axis_once():
+    """The host batch is a list of per-shard dicts ([nsteps, B, ...]
+    leaves); the fault cuts every shard's B axis alike."""
     inj = FaultInjector("reshape@3")
-    batch = {"x": np.zeros((2, 1, 4, 8), np.float32),
-             "y": np.zeros((2, 1, 4), np.int32)}
+    batch = [{"x": np.zeros((1, 4, 8), np.float32),
+              "y": np.zeros((1, 4), np.int32)} for _ in range(2)]
     out = inj.reshape_batch(batch, 2, 3)
-    assert out["x"].shape == (2, 1, 2, 8) and out["y"].shape == (2, 1, 2)
+    assert [s["x"].shape for s in out] == [(1, 2, 8)] * 2
+    assert [s["y"].shape for s in out] == [(1, 2)] * 2
     # a point fault is consumed: the next dispatch is back to canonical
     again = inj.reshape_batch(batch, 3, 4)
-    assert again["x"].shape == (2, 1, 4, 8)
+    assert [s["x"].shape for s in again] == [(1, 4, 8)] * 2
     assert inj.summary() == {"reshape": 1}
 
 
 def test_reshape_inject_noop_on_singleton_batch():
     inj = FaultInjector("reshape@1")
-    batch = {"x": np.zeros((2, 1, 1, 8), np.float32)}
+    batch = [{"x": np.zeros((1, 1, 8), np.float32)} for _ in range(2)]
     out = inj.reshape_batch(batch, 0, 1)
-    assert out["x"].shape == (2, 1, 1, 8)   # cannot halve 1: recorded no-op
+    # cannot halve 1: recorded no-op
+    assert [s["x"].shape for s in out] == [(1, 1, 8)] * 2
     assert inj.summary() == {"reshape": 1}
 
 

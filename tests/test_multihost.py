@@ -1,7 +1,7 @@
 """Multi-host path: 2 real processes over jax.distributed on CPU.
 
 Until round 2 the multi-host code (`jax.distributed.initialize`, the
-`make_array_from_process_local_data` batch assembly in
+global batch built from this process's per-device pieces in
 Trainer._device_batch, per-process shard iterators) was dead code in every
 test. This launches TWO actual processes, each owning one CPU device of a
 2-device mesh, and runs distributed gtopk training steps across them —

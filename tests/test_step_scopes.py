@@ -36,7 +36,7 @@ def lowered_step(**flags):
     trainer = Trainer(TrainConfig(**dict(base, **flags)))
     try:
         batch = trainer._device_batch(
-            trainer._stack_shard_batches(trainer._iters))
+            trainer._shard_batches(trainer._iters)[0])
         return trainer._train_step.lower(
             trainer.state, trainer.carry, batch).as_text(debug_info=True)
     finally:

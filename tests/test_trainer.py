@@ -112,7 +112,7 @@ def test_an4_trainer_ctc():
 def test_an4_distributed_accumulated_shapes_stack():
     # Regression: AN4 batches must have fixed shapes so nworkers>1 and
     # nsteps_update>1 can stack them (variable per-batch padding used to
-    # crash np.stack in _stack_shard_batches).
+    # crash np.stack in the trainer's batch assembly).
     t = Trainer(small_cfg(dnn="lstman4", batch_size=2, nworkers=2,
                           nsteps_update=2, compression="gtopk",
                           density=0.05, eval_batches=1))
