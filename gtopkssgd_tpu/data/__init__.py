@@ -2,7 +2,8 @@
 plus the AN4 audio loader files).
 
 Four dataset families matching the reference workloads — CIFAR-10, ImageNet,
-PTB, AN4 — each with:
+PTB, AN4 — and ``tokens`` (data/tokens.py: synthetic windows for a decoder,
+no real-file path), each with:
 
   * deterministic per-rank sharding (reference ``DataPartitioner``:
     every rank sees a disjoint 1/P slice of the epoch, reshuffled per epoch
@@ -36,12 +37,14 @@ from gtopkssgd_tpu.data.cifar import CIFAR10Dataset
 from gtopkssgd_tpu.data.imagenet import ImageNetDataset
 from gtopkssgd_tpu.data.partition import DataPartitioner, partition_indices
 from gtopkssgd_tpu.data.ptb import PTBDataset
+from gtopkssgd_tpu.data.tokens import TokenWindows
 
 _DATASETS = {
     "cifar10": CIFAR10Dataset,
     "imagenet": ImageNetDataset,
     "ptb": PTBDataset,
     "an4": AN4Dataset,
+    "tokens": TokenWindows,
 }
 
 
@@ -91,4 +94,5 @@ __all__ = [
     "ImageNetDataset",
     "PTBDataset",
     "AN4Dataset",
+    "TokenWindows",
 ]

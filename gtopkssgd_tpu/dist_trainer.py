@@ -487,6 +487,9 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--seed", type=int, default=42)
     p.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16"])
+    p.add_argument("--model-preset", default=None,
+                   help="qwen3_next: which size to build "
+                        "(models.qwen3_next.PRESETS: 80b_a3b_ep64, tiny)")
     p.add_argument("--s2d", action="store_true",
                    help="resnet50: space-to-depth stem (4x4x12 conv on 2x2 "
                         "pixel blocks; a superset of the 7x7x3 map — exact "
@@ -783,6 +786,7 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
         out_dir=args.out_dir,
         seed=args.seed,
         dtype=args.dtype,
+        model_preset=args.model_preset,
         space_to_depth=args.s2d,
         synth_hard=args.synth_hard,
         eval_batches=args.eval_batches,

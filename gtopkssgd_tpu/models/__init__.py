@@ -21,6 +21,7 @@ import flax.linen as nn
 from gtopkssgd_tpu.models.alexnet import AlexNet
 from gtopkssgd_tpu.models.lstm import PTBLSTM
 from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
+from gtopkssgd_tpu.models.qwen3_next import Qwen3Next
 from gtopkssgd_tpu.models.resnet import ResNetCIFAR, ResNetImageNet
 from gtopkssgd_tpu.models.vgg import VGG16
 
@@ -28,8 +29,12 @@ from gtopkssgd_tpu.models.vgg import VGG16
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
     """A zoo entry: constructor, canonical dataset, example input shape
-    (without batch dim), and whether the model is recurrent (the trainer
-    branches for BPTT carry + clip-before-compress)."""
+    (without batch dim), whether the model is recurrent, and what the
+    trainer looks up to run it: the batch leaf it reads (``input_key``),
+    how its loss comes about (``loss``: ``classify`` logits against
+    ``label``, ``tokens`` logits against ``targets``, ``ctc``, or ``own``
+    for a model that takes the targets and returns its loss and counts
+    itself) and whether it threads a ``carry`` from window to window."""
 
     name: str
     build: Callable[..., nn.Module]
@@ -37,6 +42,9 @@ class ModelSpec:
     example_shape: Tuple[int, ...]
     recurrent: bool = False
     has_batchnorm: bool = True
+    input_key: str = "image"
+    loss: str = "classify"
+    carry: bool = False
 
 
 _ZOO: Dict[str, ModelSpec] = {}
@@ -88,6 +96,9 @@ _register(
         (35,),  # BPTT window of token ids
         recurrent=True,
         has_batchnorm=False,
+        input_key="tokens",
+        loss="tokens",
+        carry=True,
     )
 )
 _register(
@@ -97,6 +108,19 @@ _register(
         "an4",
         (200, 161),  # (time frames, spectrogram bins)
         recurrent=True,
+        input_key="spectrogram",
+        loss="ctc",
+    )
+)
+_register(
+    ModelSpec(
+        "qwen3_next",
+        Qwen3Next,
+        "tokens",
+        (4096,),  # one sequence of token ids
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
     )
 )
 
@@ -121,6 +145,12 @@ def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
         raise ValueError(
             f"--s2d is a resnet50 stem transform; --dnn {dnn} "
             "does not take it")
+    if kwargs.get("preset") is None:
+        kwargs.pop("preset", None)     # the model's own default
+    elif dnn != "qwen3_next":
+        raise ValueError(
+            f"--model-preset names a size of qwen3_next; --dnn {dnn} has "
+            "none")
     return spec.build(**kwargs), spec
 
 
@@ -138,4 +168,5 @@ __all__ = [
     "AlexNet",
     "PTBLSTM",
     "DeepSpeechAN4",
+    "Qwen3Next",
 ]

@@ -1,0 +1,610 @@
+"""Qwen3-Next's hybrid decoder: Gated DeltaNet x3 : gated attention x1,
+each followed by a sparse mixture of experts with a shared expert
+(https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, ``qwen3_next``).
+
+The layer equations are written out in ``perfbench/refmodels/qwen3_next.py``
+(the frozen plain reference; parameter names and shapes are equal leaf for
+leaf, ``tests/test_qwen3_next.py`` holds the two together). What differs
+here is how they are computed:
+
+  * the delta rule in its **chunked form**: inside a chunk of ``chunk``
+    tokens the recurrence is a unit lower-triangular system, solved for all
+    chunks at once on the MXU (the WY form: ``delta = U - W S``,
+    ``delta_chunks``, a sequence at a time), and only the d_k x d_v state
+    crosses chunks, in one ``lax.scan`` for the whole batch
+    (``scan_chunks``; forward and, by autodiff through the same scan,
+    backward);
+  * attention in blocks of queries, each block against the keys up to its
+    own end, so the [B, H, S, S] scores never exist at once;
+  * the expert layer is told which experts it holds (``experts_held`` from
+    ``expert_offset`` of ``num_experts``): it routes over all of them,
+    sorts the token-slots that fall on its own experts into expert order
+    and runs them as grouped products (``lax.ragged_dot``), a block of
+    slots at a time in a loop as long as this step's routing needs: no
+    token is dropped whatever the imbalance, and a balanced step runs one
+    block. What the absent experts would add is left out; nothing stands
+    in for the other chips or their all-to-all;
+  * the head and the loss a sequence at a time; every layer under
+    ``jax.checkpoint``.
+
+Precision is the reference's: float32 parameters, residual stream, norms,
+router, softmax, recurrence state and loss; matrix products in ``dtype``.
+The chunked algebra's small products run at the highest matmul precision,
+so that they agree with the reference's float32 recurrence before both
+round to ``dtype`` for the output projection.
+
+Stages are named for the device trace (``layer/gdn_proj``,
+``layer/gdn_scan``, ``layer/attn``, ``layer/moe_router``,
+``layer/moe_experts``, ``layer/shared_expert``, ``layer/head``), forward
+and backward alike; the per-layer loads of the held experts and the slots
+dropped (always 0) go out with the loss for ``obs.counters.moe_counters``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
+
+F32 = jnp.float32
+HIGHEST = lax.Precision.HIGHEST
+# The chunked delta rule's float32 products (module docstring, Precision).
+_mm = functools.partial(jnp.einsum, precision=HIGHEST)
+
+# The published sizes (config.json of Qwen3-Next-80B-A3B-Instruct) with the
+# three cuts of perfbench/configs/qwen3_next_80b_a3b_ep64.json, whose
+# ``sizes`` a test holds equal to this preset key for key; and the size
+# every CPU test runs.
+PRESETS = {
+    "80b_a3b_ep64": dict(
+        hidden_size=2048, num_hidden_layers=4, full_attention_interval=4,
+        linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+        num_attention_heads=16, num_key_value_heads=2, head_dim=256,
+        partial_rotary_factor=0.25, rope_theta=10000000, rms_norm_eps=1e-6,
+        num_experts=512, num_experts_per_tok=10, moe_intermediate_size=512,
+        shared_expert_intermediate_size=512, norm_topk_prob=True,
+        experts_held=8, expert_offset=0, expert_parallel=64,
+        vocab_size=151936, vocab_rows=18992, seq_len=4096),
+    "tiny": dict(
+        hidden_size=64, num_hidden_layers=4, full_attention_interval=4,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=16, linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        partial_rotary_factor=0.25, rope_theta=10000000, rms_norm_eps=1e-6,
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, norm_topk_prob=True,
+        experts_held=4, expert_offset=0, expert_parallel=4,
+        vocab_size=1024, vocab_rows=128, seq_len=128),
+}
+
+
+# Sequences whose convolution and chunk algebra are live at once in a
+# Gated DeltaNet layer (their float32 intermediates are what fills the
+# chip: 2.7 GB a sequence of 4,096 tokens at the published widths).
+GDN_SEQUENCES = 1
+
+
+def chunk_of(seq_len: int) -> int:
+    """Tokens in a chunk of the delta rule: 64, less for a short sequence."""
+    return min(64, max(1, seq_len // 4))
+
+
+def query_block_of(seq_len: int) -> int:
+    return min(512, max(1, seq_len // 2))
+
+
+# ------------------------------------------------------------------ pieces
+def dense(x, w, dtype):
+    return jnp.dot(x.astype(dtype), w.astype(dtype))
+
+
+def rms_norm0(x, w, eps):
+    """Zero-centred RMSNorm: the stored weight is the scale minus one."""
+    x = x.astype(F32)
+    return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
+
+
+def _normal():
+    return nn.initializers.normal(0.02)
+
+
+def _a_log(key, shape, dtype=F32):
+    return _ln(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
+
+
+def _dt_bias(key, shape, dtype=F32):
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
+                                    math.log(0.1)))
+    return dt + _ln(-jnp.expm1(-dt))
+
+
+def _conv(key, shape, dtype=F32):
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def causal_conv(x, kernel):
+    """Depthwise causal convolution, x [B, S, C], kernel [K, C]."""
+    width, length = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(kernel[i] * padded[:, i:i + length] for i in range(width))
+
+
+# ----------------------------------------------------- chunked delta rule
+def delta_chunks(q, k, v, g, beta, chunk):
+    """What the chunked delta rule needs of every chunk that does not
+    depend on the state: q, k [B, S, H, d_k], v [B, S, H, d_v], g (log
+    decay, <= 0) and beta [B, S, H], float32, S a multiple of ``chunk``,
+    to ``(u, w, attn, q_in, k_out, decay)`` with the chunk axis in front
+    ([n, B, H, C, ...]; ``decay`` [n, B, H, 1, 1]).
+
+    With gamma_t the running sum of g inside a chunk and S_0 the state at
+    its start, the recurrence  S_t = e^{g_t} S_{t-1} + k_t delta_t^T,
+    delta_t = beta_t (v_t - (e^{g_t} S_{t-1})^T k_t)  unrolls to
+
+        (I + A) Delta = beta V - (beta e^gamma K) S_0,
+        A_tj = beta_t e^{gamma_t - gamma_j} k_t.k_j   for j < t,
+
+    so  Delta = U - W S_0  with  [U | W] = (I + A)^-1 [beta V | beta e^gamma K]:
+    one triangular solve a chunk, all chunks at once. ``attn`` is
+    M * Q K^T with M_tj = e^{gamma_t - gamma_j} for j <= t, ``q_in`` is
+    e^gamma Q, ``k_out`` is e^{gamma_C - gamma} K and ``decay`` e^{gamma_C}."""
+    batch, length, heads, _ = q.shape
+    d_v = v.shape[-1]
+    n = length // chunk
+    # [B, S, H, ...] -> [n, B, H, C, ...]
+    split = lambda a: jnp.moveaxis(
+        a.reshape((batch, n, chunk, heads) + a.shape[3:]), (1, 3), (0, 2))
+    q, k, v, g, beta = map(split, (q, k, v, g, beta))
+    gamma = jnp.cumsum(g, axis=-1)                              # [n, B, H, C]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    decay = jnp.exp(jnp.where(
+        lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+    a = jnp.where(strict, beta[..., :, None] * decay
+                  * _mm("...td,...jd->...tj", k, k), 0.0)
+    rhs = jnp.concatenate(
+        [beta[..., None] * v, (beta * jnp.exp(gamma))[..., None] * k], -1)
+    solved = lax.linalg.triangular_solve(
+        a, rhs, left_side=True, lower=True, unit_diagonal=True)
+    return (solved[..., :d_v], solved[..., d_v:],
+            decay * _mm("...td,...jd->...tj", q, k),
+            q * jnp.exp(gamma)[..., None],
+            k * jnp.exp(gamma[..., -1:] - gamma)[..., None],
+            jnp.exp(gamma[..., -1])[..., None, None])
+
+
+def scan_chunks(u, w, attn, q_in, k_out, decay):
+    """The state's pass over the chunks ``delta_chunks`` prepared, S_0 = 0:
+
+        Delta = U - W S,   O = (e^gamma Q) S + (M * Q K^T) Delta,
+        S <- e^{gamma_C} S + (e^{gamma_C - gamma} K)^T Delta.
+
+    Returns o [B, n C, H, d_v]."""
+    n, batch, heads, chunk, d_v = u.shape
+
+    def step(state, xs):
+        u_i, w_i, attn_i, q_i, k_i, decay_i = xs
+        delta = u_i - _mm("...cd,...dv->...cv", w_i, state)
+        out = _mm("...cd,...dv->...cv", q_i, state) \
+            + _mm("...tj,...jv->...tv", attn_i, delta)
+        return decay_i * state + _mm("...cd,...cv->...dv", k_i, delta), out
+
+    state = jnp.zeros((batch, heads, w.shape[-1], d_v), F32)
+    _, out = lax.scan(step, state, (u, w, attn, q_in, k_out, decay))
+    return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(
+        batch, n * chunk, heads, d_v)
+
+
+def pad_to_chunks(arrays, chunk):
+    """(arrays padded along the sequence to a multiple of ``chunk``, the
+    length before). A padded token decays nothing and writes nothing."""
+    length = arrays[0].shape[1]
+    pad = -length % chunk
+    if pad:
+        arrays = tuple(
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in arrays)
+    return arrays, length
+
+
+def chunked_delta_rule(q, k, v, g, beta, chunk):
+    """The gated delta rule over whole sequences, chunk by chunk: q, k
+    [B, S, H, d_k], v [B, S, H, d_v], g and beta [B, S, H], float32, any
+    S, to o [B, S, H, d_v]."""
+    arrays, length = pad_to_chunks((q, k, v, g, beta), chunk)
+    return scan_chunks(*delta_chunks(*arrays, chunk))[:, :length]
+
+
+# --------------------------------------------------------------- attention
+def rotary(x, theta, rotary_dims):
+    """Rotate-half rotary embedding on the first ``rotary_dims`` of the
+    last axis; x [B, S, H, D] float32, positions 0..S-1."""
+    length, half = x.shape[1], rotary_dims // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=F32) * 2.0 / rotary_dims))
+    angle = jnp.arange(length, dtype=F32)[:, None] * inv[None, :]
+    angle = jnp.concatenate([angle, angle], -1)[None, :, None, :]
+    rot, rest = x[..., :rotary_dims], x[..., rotary_dims:]
+    turned = jnp.concatenate([-rot[..., half:], rot[..., :half]], -1)
+    return jnp.concatenate(
+        [rot * jnp.cos(angle) + turned * jnp.sin(angle), rest], -1)
+
+
+def blocked_causal_attention(q, k, v, dtype, block):
+    """q [B, S, H, D], k, v [B, S, H_kv, D] float32 -> [B, S, H, D] float32.
+    Queries in blocks of ``block``, each against keys 0 .. its own end and
+    rematerialised in the backward pass."""
+    batch, length, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    q = q.reshape(batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
+    k, v = k.astype(dtype), v.astype(dtype)
+
+    @functools.partial(jax.checkpoint, static_argnums=3)
+    def one(q_b, k_b, v_b, start):
+        scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_b, k_b,
+                            preferred_element_type=F32) / math.sqrt(dim)
+        rows = start + jnp.arange(q_b.shape[1])
+        mask = rows[:, None] >= jnp.arange(k_b.shape[1])[None, :]
+        probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v_b,
+                          preferred_element_type=F32)
+
+    outs = []
+    for start in range(0, length, block):
+        end = min(start + block, length)
+        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+    return jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
+
+
+# ------------------------------------------------------------ expert layer
+def route(x, router, top, normalise):
+    """(probabilities of the ``top`` experts [T, top] float32, their ids),
+    over every expert of the model, held here or not."""
+    logits = jnp.dot(x.astype(F32), router, precision=HIGHEST)
+    values, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top)
+    if normalise:
+        values = values / jnp.sum(values, -1, keepdims=True)
+    return values, ids
+
+
+def sort_held_slots(ids, probs, offset, held):
+    """Token-slots in expert order, the held experts' first.
+
+    Returns (token of each sorted slot, its routing weight, slots per held
+    expert [held]); slots of experts held elsewhere sort last with weight 0
+    and belong to no group."""
+    top = ids.shape[1]
+    local = ids.reshape(-1) - offset
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    weight = jnp.where(key[order] < held, probs.reshape(-1)[order], 0.0)
+    return (order // top).astype(jnp.int32), weight, sizes
+
+
+def blocks_run(sizes, rows, max_blocks):
+    """How many blocks of ``rows`` sorted slots are run: as many as hold
+    every slot on a held expert, unless ``max_blocks`` caps them."""
+    need = (jnp.sum(sizes) + rows - 1) // rows
+    return need if max_blocks is None else jnp.minimum(need, max_blocks)
+
+
+def slots_dropped(sizes, rows, max_blocks):
+    """Slots on held experts that no block took: 0 unless ``max_blocks``
+    cuts the loop short, which the model never does."""
+    return jnp.maximum(
+        jnp.sum(sizes) - blocks_run(sizes, rows, max_blocks) * rows, 0)
+
+
+def _expert_block(x, token, weight, sizes, gate, up, down, *, start, rows,
+                  dtype):
+    """The held experts' output for sorted slots start .. start + rows,
+    added at their tokens: [T, d] float32."""
+    ends = jnp.cumsum(sizes)
+    window = lambda a: jnp.clip(a, start, start + rows)
+    inside = (window(ends) - window(ends - sizes)).astype(jnp.int32)
+    # Rows past the block's last group belong to no expert. The grouped
+    # product leaves them unwritten on the TPU, forward and backward, so
+    # they are zeroed on the way in, after every product and (by the
+    # transposes of the same selects) on the way back.
+    valid = (jnp.arange(rows) < jnp.sum(inside))[:, None]
+    with jax.named_scope("layer/moe_router"):
+        token = lax.dynamic_slice(token, (start,), (rows,))
+        weight = lax.dynamic_slice(weight, (start,), (rows,))
+        taken = jnp.where(valid, x[token], 0.0).astype(dtype)
+    with jax.named_scope("layer/moe_experts"):
+        grouped = lambda a, w: jnp.where(
+            valid, lax.ragged_dot(a, w.astype(dtype), inside), 0).astype(F32)
+        hidden = jax.nn.silu(grouped(taken, gate)) * grouped(taken, up)
+        out = grouped(hidden.astype(dtype), down)
+    with jax.named_scope("layer/moe_router"):
+        return jnp.zeros(x.shape, F32).at[token].add(out * weight[:, None])
+
+
+def _held_experts(rows, max_blocks, dtype, x, token, weight, sizes,
+                  gate, up, down):
+    """sum over the held experts j of weight_j E_j(x), [T, d] float32.
+
+    The sorted slots are run ``rows`` at a time in a loop whose trip count
+    is this step's (``blocks_run``): every slot on a held expert is
+    computed whatever the imbalance, the buffer stays one block, and a
+    balanced step runs one block. A loop of unknown length has no
+    automatic transpose, so the backward pass is written out: the same
+    loop, each block's vjp recomputed and accumulated."""
+    def body(i, total):
+        return total + _expert_block(
+            x, token, weight, sizes, gate, up, down,
+            start=i * rows, rows=rows, dtype=dtype)
+
+    return lax.fori_loop(0, blocks_run(sizes, rows, max_blocks), body,
+                         jnp.zeros(x.shape, F32))
+
+
+held_experts = jax.custom_vjp(_held_experts, nondiff_argnums=(0, 1, 2))
+
+
+def _held_fwd(rows, max_blocks, dtype, *args):
+    return _held_experts(rows, max_blocks, dtype, *args), args
+
+
+def _held_bwd(rows, max_blocks, dtype, args, ct):
+    x, token, weight, sizes, gate, up, down = args
+
+    def body(i, grads):
+        start = i * rows
+
+        def block(x, part, gate, up, down):
+            full = lax.dynamic_update_slice(
+                jnp.zeros_like(weight), part, (start,))
+            return _expert_block(x, token, full, sizes, gate, up, down,
+                                 start=start, rows=rows, dtype=dtype)
+
+        part = lax.dynamic_slice(weight, (start,), (rows,))
+        _, pull = jax.vjp(block, x, part, gate, up, down)
+        dx, dpart, *dexperts = pull(ct)
+        gx, gweight, *gexperts = grads
+        return (gx + dx, lax.dynamic_update_slice(gweight, dpart, (start,)),
+                *(g + d for g, d in zip(gexperts, dexperts)))
+
+    zeros = tuple(jnp.zeros_like(a) for a in (x, weight, gate, up, down))
+    dx, dweight, dgate, dup, ddown = lax.fori_loop(
+        0, blocks_run(sizes, rows, max_blocks), body, zeros)
+    return dx, None, dweight, None, dgate, dup, ddown
+
+
+held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+# ------------------------------------------------------------------ modules
+class GatedDeltaNet(nn.Module):
+    sizes: dict
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        s, dtype = self.sizes, self.dtype
+        d = s["hidden_size"]
+        h_k, h_v = s["linear_num_key_heads"], s["linear_num_value_heads"]
+        d_k, d_v = s["linear_key_head_dim"], s["linear_value_head_dim"]
+        key_w, val_w = h_k * d_k, h_v * d_v
+        w_qkvz = self.param("in_proj_qkvz", _normal(),
+                            (d, 2 * key_w + 2 * val_w), F32)
+        w_ba = self.param("in_proj_ba", _normal(), (d, 2 * h_v), F32)
+        conv = self.param("conv", _conv, (s["linear_conv_kernel_dim"],
+                                          2 * key_w + val_w), F32)
+        a_log = self.param("A_log", _a_log, (h_v,), F32)
+        dt_bias = self.param("dt_bias", _dt_bias, (h_v,), F32)
+        w_g = self.param("norm", nn.initializers.ones, (d_v,), F32)
+        w_out = self.param("out_proj", _normal(), (val_w, d), F32)
+
+        batch, length = x.shape[:2]
+        group = math.gcd(batch, GDN_SEQUENCES)
+        chunk = chunk_of(s["seq_len"])
+
+        @jax.checkpoint
+        def prepare(args):
+            """Convolution, heads and the chunks' own algebra for ``group``
+            sequences: their float32 intermediates are live for these
+            sequences only, and again in the backward pass."""
+            qkv, ba = args
+            with jax.named_scope("layer/gdn_proj"):
+                ba = ba.astype(F32)
+                qkv = jax.nn.silu(causal_conv(qkv.astype(F32), conv))
+                heads = lambda a, n, w: a.reshape(group, length, n, w)
+                q = heads(qkv[..., :key_w], h_k, d_k)
+                k = heads(qkv[..., key_w:2 * key_w], h_k, d_k)
+                v = heads(qkv[..., 2 * key_w:], h_v, d_v)
+                unit = lambda a: a * lax.rsqrt(
+                    jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+                q = jnp.repeat(unit(q) / math.sqrt(d_k), h_v // h_k, axis=2)
+                k = jnp.repeat(unit(k), h_v // h_k, axis=2)
+                beta = jax.nn.sigmoid(ba[..., :h_v])
+                g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., h_v:] + dt_bias)
+            with jax.named_scope("layer/gdn_scan"):
+                arrays, _ = pad_to_chunks((q, k, v, g, beta), chunk)
+                return delta_chunks(*arrays, chunk)
+
+        grouped = lambda a: a.reshape((batch // group, group) + a.shape[1:])
+        with jax.named_scope("layer/gdn_proj"):
+            qkvz = dense(x, w_qkvz, dtype)
+            ba = dense(x, w_ba, dtype)
+        prepared = lax.map(prepare, (grouped(qkvz[..., :2 * key_w + val_w]),
+                                     grouped(ba)))
+        with jax.named_scope("layer/gdn_scan"):
+            # [groups, n, group, H, C, ...] -> [n, B, H, C, ...]: the state
+            # crosses the chunks of every sequence in one scan.
+            whole = lambda a: jnp.moveaxis(a, 0, 1).reshape(
+                (a.shape[1], batch) + a.shape[3:])
+            o = scan_chunks(*map(whole, prepared))[:, :length]
+        with jax.named_scope("layer/gdn_proj"):
+            z = qkvz[..., 2 * key_w + val_w:].astype(F32).reshape(
+                batch, length, h_v, d_v)
+            o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + s["rms_norm_eps"]) * w_g
+            gated = (o * jax.nn.silu(z)).reshape(batch, length, val_w)
+            return dense(gated, w_out, dtype)
+
+
+class GatedAttention(nn.Module):
+    sizes: dict
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        s, dtype = self.sizes, self.dtype
+        d, dim = s["hidden_size"], s["head_dim"]
+        heads, kv_heads = s["num_attention_heads"], s["num_key_value_heads"]
+        w_q = self.param("q_proj", _normal(), (d, heads * 2 * dim), F32)
+        w_k = self.param("k_proj", _normal(), (d, kv_heads * dim), F32)
+        w_v = self.param("v_proj", _normal(), (d, kv_heads * dim), F32)
+        w_qn = self.param("q_norm", nn.initializers.zeros, (dim,), F32)
+        w_kn = self.param("k_norm", nn.initializers.zeros, (dim,), F32)
+        w_o = self.param("o_proj", _normal(), (heads * dim, d), F32)
+
+        batch, length = x.shape[:2]
+        eps = s["rms_norm_eps"]
+        rotary_dims = int(dim * s["partial_rotary_factor"])
+        with jax.named_scope("layer/attn"):
+            qg = dense(x, w_q, dtype).reshape(batch, length, heads, 2 * dim)
+            q, gate = qg[..., :dim], qg[..., dim:].astype(F32)
+            k = dense(x, w_k, dtype).reshape(batch, length, kv_heads, dim)
+            v = dense(x, w_v, dtype).reshape(
+                batch, length, kv_heads, dim).astype(F32)
+            q = rotary(rms_norm0(q, w_qn, eps), s["rope_theta"], rotary_dims)
+            k = rotary(rms_norm0(k, w_kn, eps), s["rope_theta"], rotary_dims)
+            out = blocked_causal_attention(
+                q, k, v, dtype, query_block_of(s["seq_len"]))
+            out = out * jax.nn.sigmoid(gate)
+            return dense(out.reshape(batch, length, heads * dim), w_o, dtype)
+
+
+class SparseMoE(nn.Module):
+    """``block_rows`` sorted slots are run at a time (None: 4096, or every
+    slot the layer can hold if that is fewer); ``max_blocks`` caps the
+    loop and so drops slots: only the test that shows ``moe_slots_dropped``
+    counting sets it."""
+    sizes: dict
+    dtype: Any
+    block_rows: Any = None
+    max_blocks: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        s, dtype = self.sizes, self.dtype
+        d, width = s["hidden_size"], s["moe_intermediate_size"]
+        shared_w = s["shared_expert_intermediate_size"]
+        held, offset = s["experts_held"], s["expert_offset"]
+        top = s["num_experts_per_tok"]
+        router = self.param("router", _normal(), (d, s["num_experts"]), F32)
+        gate = self.param("experts_gate", _normal(), (held, d, width), F32)
+        up = self.param("experts_up", _normal(), (held, d, width), F32)
+        down = self.param("experts_down", _normal(), (held, width, d), F32)
+        s_gate = self.param("shared_gate_proj", _normal(), (d, shared_w), F32)
+        s_up = self.param("shared_up_proj", _normal(), (d, shared_w), F32)
+        s_down = self.param("shared_down_proj", _normal(), (shared_w, d), F32)
+        w_s = self.param("shared_gate", _normal(), (d, 1), F32)
+
+        shape = x.shape
+        x = x.reshape(-1, d)
+        # A token's top experts are distinct: at most min(top, held) of its
+        # slots fall here.
+        rows = self.block_rows or min(4096, x.shape[0] * min(top, held))
+        with jax.named_scope("layer/moe_router"):
+            probs, ids = route(x, router, top, s["norm_topk_prob"])
+            token, weight, load = sort_held_slots(ids, probs, offset, held)
+            # The loop's last block may reach past the slots: pad them.
+            token = jnp.pad(token, (0, rows))
+            weight = jnp.pad(weight, (0, rows))
+        y = held_experts(rows, self.max_blocks, dtype, x, token, weight, load,
+                         gate, up, down)
+        with jax.named_scope("layer/shared_expert"):
+            share = jax.nn.sigmoid(dense(x, w_s, dtype).astype(F32))
+            hidden = jax.nn.silu(dense(x, s_gate, dtype).astype(F32)) \
+                * dense(x, s_up, dtype).astype(F32)
+            y = y + share * dense(hidden, s_down, dtype).astype(F32)
+        return (y.reshape(shape), load,
+                slots_dropped(load, rows, self.max_blocks))
+
+
+class Layer(nn.Module):
+    sizes: dict
+    dtype: Any
+    attention: bool
+
+    @nn.compact
+    def __call__(self, x):
+        s = self.sizes
+        d, eps = s["hidden_size"], s["rms_norm_eps"]
+        w_in = self.param("input_norm", nn.initializers.zeros, (d,), F32)
+        w_post = self.param("post_norm", nn.initializers.zeros, (d,), F32)
+        mixer = (GatedAttention if self.attention else GatedDeltaNet)(
+            s, self.dtype, name="mixer")
+        # The layer's own norms and residual adds count for the kind they
+        # feed; scopes inside the mixer and the expert layer are innermost.
+        with jax.named_scope("layer/attn" if self.attention
+                             else "layer/gdn_proj"):
+            x = x + mixer(rms_norm0(x, w_in, eps)).astype(F32)
+        with jax.named_scope("layer/moe_router"):
+            y, load, dropped = SparseMoE(s, self.dtype, name="moe")(
+                rms_norm0(x, w_post, eps))
+            return x + y, (load, dropped)
+
+
+def token_losses(hidden, head, targets, dtype):
+    """Cross-entropy of every position, float32, a sequence at a time."""
+
+    @jax.checkpoint
+    def one(args):
+        h, t = args
+        logits = jnp.dot(h.astype(dtype), head.astype(dtype),
+                         preferred_element_type=F32)
+        picked = jnp.take_along_axis(logits, t[:, None], -1)[:, 0]
+        return jax.nn.logsumexp(logits, axis=-1) - picked
+
+    return lax.map(one, (hidden, targets))
+
+
+class Qwen3Next(nn.Module):
+    """``__call__(tokens, targets)`` gives the mean cross-entropy and the
+    expert layers' counts ``{"moe_load": [layers, held], "moe_dropped":
+    [layers]}``; without targets, the logits [B, S, vocab_rows]."""
+    preset: str = "80b_a3b_ep64"
+    dtype: Any = jnp.float32
+
+    @property
+    def sizes(self):
+        return PRESETS[self.preset]
+
+    @nn.compact
+    def __call__(self, tokens, targets=None, *, train: bool = False):
+        s = self.sizes
+        d, rows = s["hidden_size"], s["vocab_rows"]
+        with jax.named_scope("layer/head"):
+            table = self.param("embed", _normal(), (rows, d), F32)
+            x = table[tokens]
+        counts = []
+        for i in range(s["num_hidden_layers"]):
+            attention = (i + 1) % s["full_attention_interval"] == 0
+            x, count = nn.remat(Layer)(
+                s, self.dtype, attention, name=f"layer_{i}")(x)
+            counts.append(count)
+        with jax.named_scope("layer/head"):
+            w_final = self.param("final_norm", nn.initializers.zeros, (d,), F32)
+            head = self.param("head", _normal(), (d, rows), F32)
+            hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
+            if targets is None:
+                return jnp.dot(hidden.astype(self.dtype),
+                               head.astype(self.dtype),
+                               preferred_element_type=F32)
+            loss = token_losses(hidden, head, targets, self.dtype).mean()
+        return loss, {"moe_load": jnp.stack([c[0] for c in counts]),
+                      "moe_dropped": jnp.stack([c[1] for c in counts])}

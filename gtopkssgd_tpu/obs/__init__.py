@@ -112,6 +112,8 @@ from gtopkssgd_tpu.obs.counters import (
     mass_ratio,
     selected_tau,
     sent_count,
+    model_scalars,
+    moe_counters,
     telemetry_scalars,
     topk_recall,
     tree_l2,
@@ -177,6 +179,8 @@ __all__ = [
     "run_manifest",
     "selected_tau",
     "sent_count",
+    "model_scalars",
+    "moe_counters",
     "telemetry_scalars",
     "timeline_from_records",
     "topk_recall",

@@ -510,6 +510,53 @@ def topk_recall(hits: Array, exact_vals: Array) -> Array:
     return jnp.sum((hits & real).astype(jnp.float32)) / n_real
 
 
+# What an expert layer that holds a share of its model's experts counts
+# (models/qwen3_next.py), per optimizer step: token-slots that fell on
+# the held experts (summed over the layers), the largest and the mean load
+# of a held expert (over layers x held experts), and the slots that fell
+# on a held expert and were not computed, which a dropless layer keeps 0.
+MOE_FIELDS = (
+    "moe_slots_held",
+    "moe_load_max",
+    "moe_load_mean",
+    "moe_slots_dropped",
+)
+
+# The model counters last read on the host (``model_scalars``): like the
+# span buffer, it outlives the trainer, so a reader can ask afterwards.
+_last_model: Dict[str, float] = {}
+
+
+@jax.named_scope(SCOPE)
+def moe_counters(load: Array, dropped: Array) -> Dict[str, Array]:
+    """``MOE_FIELDS`` as f32 scalars from the expert layers' counts:
+    ``load`` [layers, held] slots per held expert, ``dropped`` [layers]."""
+    load = load.astype(jnp.float32)
+    return {
+        "moe_slots_held": jnp.sum(load),
+        "moe_load_max": jnp.max(load),
+        "moe_load_mean": jnp.mean(load),
+        "moe_slots_dropped": jnp.sum(dropped).astype(jnp.float32),
+    }
+
+
+def model_scalars(aux: Dict[str, Array]) -> Dict[str, float]:
+    """Host floats of the model's own counters among a step's ``aux``
+    (``MOE_FIELDS``; {} for a model that counts nothing), kept as the
+    last read. Blocks on the step like ``telemetry_scalars``: call it at
+    the same sync point."""
+    found = {key: float(aux[key]) for key in MOE_FIELDS if key in aux}
+    if found:
+        _last_model.clear()
+        _last_model.update(found)
+    return found
+
+
+def last_model_scalars() -> Dict[str, float]:
+    """What ``model_scalars`` last read in this process ({} before any)."""
+    return dict(_last_model)
+
+
 def telemetry_scalars(telemetry: Dict[str, Array]) -> Dict[str, float]:
     """Host floats of the SCALAR counters in a state's telemetry dict —
     the per-layer "layers" sub-dict and the [N] "age" buffer excluded.

@@ -54,6 +54,8 @@ from gtopkssgd_tpu.obs import (
     TimelineRecorder,
     Tracer,
     layer_names,
+    model_scalars,
+    moe_counters,
     telemetry_scalars,
 )
 from gtopkssgd_tpu.obs.manifest import config_hash, run_manifest
@@ -131,6 +133,12 @@ class TrainConfig:
     out_dir: Optional[str] = None
     seed: int = 42
     dtype: str = "float32"         # compute dtype: 'float32' | 'bfloat16'
+    model_preset: Optional[str] = None  # qwen3_next only: which of
+                                   # models.qwen3_next.PRESETS to build
+                                   # ('80b_a3b_ep64', the published sizes
+                                   # as one chip's share of a 64-chip
+                                   # expert group, or 'tiny'); None = the
+                                   # model's default
     space_to_depth: bool = False   # resnet50: MXU-friendly s2d stem (same
                                    # linear map as the 7x7/2 conv; see
                                    # models/resnet.py and the equivalence
@@ -429,6 +437,7 @@ class TrainConfig:
             "imagenet": (0.01 if cfg.dnn == "alexnet" else 0.1, 1e-4, None),
             "ptb": (1.0, 0.0, 0.25),
             "an4": (3e-4, 0.0, 400.0),
+            "tokens": (0.5, 0.0, 1.0),
         }
         lr, wd, clip = defaults.get(cfg.dataset, (0.1, 0.0, None))
         if cfg.lr is None:
@@ -603,6 +612,7 @@ class Trainer:
             cfg.dnn,
             dtype=jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32,
             space_to_depth=cfg.space_to_depth,
+            preset=cfg.model_preset,
         )
         self.mesh = make_mesh(cfg.nworkers)
         self.p = cfg.nworkers
@@ -627,6 +637,9 @@ class Trainer:
             data_kw["decode_workers"] = cfg.decode_workers
         if cfg.dataset == "cifar10" and cfg.synth_hard:
             data_kw["synth_hard"] = True
+        if cfg.dataset == "tokens":
+            data_kw.update(seq_len=self.model.sizes["seq_len"],
+                           vocab_size=self.model.sizes["vocab_rows"])
         def _dataset(**kw):
             # Data-loader setup rides the shared retry/backoff helper
             # (resilience/preempt.py): a transient storage blip at
@@ -1313,9 +1326,13 @@ class Trainer:
         cfg = self.cfg
         rng = jax.random.PRNGKey(cfg.seed)
         batch = self._peek_batch()
-        x = jnp.asarray(batch[self._input_key()][0])
-        init_kw = {}
-        variables = self.model.init({"params": rng, "dropout": rng}, x, **init_kw)
+        x = jnp.asarray(batch[self.spec.input_key][0])
+        # One jitted call: the initialisers are all it runs (the forward
+        # pass it traces is dead code there), where an eager init runs
+        # every layer op by op (47 s of ResNet-50's set-up, PERF.md).
+        variables = jax.jit(
+            lambda key, x: self.model.init({"params": key, "dropout": key}, x)
+        )(rng, x)
         params = variables["params"]
         batch_stats = variables.get("batch_stats", {})
         opt_state = jax.jit(self.tx.init)(params)
@@ -1340,13 +1357,7 @@ class Trainer:
             batch_stats=batch_stats,
             opt_state=opt_state,
         )
-        if self.spec.name == "lstm":
-            one = self.model.initial_carry(cfg.batch_size)
-            carry = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (self.p,) + a.shape), one
-            )
-        else:
-            carry = ()
+        carry = self._zero_carry()
         # Commit every leaf to its steady-state mesh placement. Freshly
         # built jnp arrays are UNCOMMITTED (SingleDeviceSharding), so
         # dispatch 1 would trace against UnspecifiedValue shardings while
@@ -1365,11 +1376,14 @@ class Trainer:
 
         return jax.tree.map(commit, state), jax.tree.map(commit, carry)
 
-    def _input_key(self) -> str:
-        return {
-            "cifar10": "image", "imagenet": "image",
-            "ptb": "tokens", "an4": "spectrogram",
-        }[self.cfg.dataset]
+    def _zero_carry(self):
+        """The state a model threads from window to window, [P, ...] per
+        leaf; () for a model that has none."""
+        if not self.spec.carry:
+            return ()
+        one = self.model.initial_carry(self.cfg.batch_size)
+        return jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (self.p,) + a.shape), one)
 
     def _peek_batch(self):
         it = iter(self.train_shards[0])
@@ -1399,7 +1413,7 @@ class Trainer:
     # ------------------------------------------------------------ loss fns
     def _loss_fn(self, params, batch_stats, carry, batch, rng, train: bool):
         """Per-device loss. Returns (loss, (new_batch_stats, new_carry, aux))."""
-        model, name = self.model, self.spec.name
+        model, kind = self.model, self.spec.loss
         variables = {"params": params}
         if batch_stats:
             variables["batch_stats"] = batch_stats
@@ -1412,14 +1426,19 @@ class Trainer:
                 return out, mut["batch_stats"]
             return model.apply(variables, x, *args, **kw), batch_stats
 
-        if name == "lstm":
+        if kind == "own":
+            (loss, counts), new_bs = run(batch[self.spec.input_key],
+                                         batch["targets"])
+            aux = moe_counters(counts["moe_load"], counts["moe_dropped"])
+            return loss, (new_bs, carry, aux)
+        if kind == "tokens":
             (logits, new_carry), new_bs = run(batch["tokens"], carry)
             loss = optax.softmax_cross_entropy_with_integer_labels(
                 logits, batch["targets"]
             ).mean()
             aux = {"tokens": jnp.asarray(logits.shape[0] * logits.shape[1])}
             return loss, (new_bs, new_carry, aux)
-        if name == "lstman4":
+        if kind == "ctc":
             logits, new_bs = run(batch["spectrogram"], batch["input_lengths"])
             t_out = logits.shape[1]
             out_len = self.model.output_length(batch["input_lengths"])
@@ -1620,7 +1639,7 @@ class Trainer:
         # step's [P]-leading outputs span non-addressable devices there,
         # so np.asarray on them would raise — and with 1 device per host
         # there is nothing to shard locally anyway.
-        if (self.p == 1 or self.spec.name == "lstm"
+        if (self.p == 1 or self.spec.carry
                 or jax.process_count() > 1):
             def single(state, carry, batch):
                 return ev(state.params, state.batch_stats, carry, batch)
@@ -1903,9 +1922,12 @@ class Trainer:
                             # per-layer [L] arrays -> one "layers" record
                             # per layer; the [N] age buffer stays on
                             # device (its per-layer mean is already in
-                            # the layers record).
+                            # the layers record). The model's own
+                            # counters (an expert layer's loads) ride the
+                            # step's aux: same sync, same "obs" record.
                             scalars = telemetry_scalars(tel)
-                            self.metrics.log("obs", step=step, **scalars)
+                            self.metrics.log("obs", step=step, **scalars,
+                                             **model_scalars(aux))
                             max_age = None
                             lay = tel.get("layers")
                             if lay is not None:
@@ -2040,11 +2062,11 @@ class Trainer:
         from the host-side weighting (weight bookkeeping is per REAL
         batch, so the numbers are identical to the sequential path)."""
         cfg = self.cfg
-        name = self.spec.name
+        spec = self.spec
         losses, top1s, top5s, weights = [], [], [], []
         cer_counts = np.zeros(4, np.int64)  # char errs, chars, word errs, words
         carry = (
-            self.model.initial_carry(cfg.batch_size) if name == "lstm" else ()
+            self.model.initial_carry(cfg.batch_size) if spec.carry else ()
         )
 
         def account(batch, loss, aux):
@@ -2054,7 +2076,7 @@ class Trainer:
                 top1s.append(float(aux["top1"]))
             if "top5" in aux:
                 top5s.append(float(aux["top5"]))
-            if name == "lstman4":
+            if spec.loss == "ctc":
                 cer_counts[:] += self._greedy_error_counts(
                     batch, aux["logits"])
 
@@ -2082,7 +2104,7 @@ class Trainer:
                 continue
             jb = {k: jnp.asarray(v) for k, v in batch.items()}
             loss, carry_out, aux = self._eval_step(self.state, carry, jb)
-            if name == "lstm":
+            if spec.carry:
                 carry = carry_out
             account(jb, loss, aux)
         if group:
@@ -2187,11 +2209,7 @@ class Trainer:
     def reset_carry(self) -> None:
         """Zero the recurrent carry (epoch boundary: each PTB row restarts at
         its stream start, so end-of-corpus state must not leak in)."""
-        if self.spec.name == "lstm":
-            one = self.model.initial_carry(self.cfg.batch_size)
-            self.carry = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (self.p,) + a.shape), one
-            )
+        self.carry = self._zero_carry()
 
     def save(self) -> None:
         """Orbax save of the LIVE (sharded) state. Every process must call

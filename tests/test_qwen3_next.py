@@ -1,0 +1,294 @@
+"""The Qwen3-Next decoder (models/qwen3_next.py) at its ``tiny`` preset on
+the CPU: against the frozen plain reference (perfbench/refmodels/
+qwen3_next.py), the chunked delta rule against the per-token recurrence,
+the expert shares against the uncut layer, and the rule that no token-slot
+is dropped."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from gtopkssgd_tpu.models import get_model, qwen3_next as prog  # noqa: E402
+from gtopkssgd_tpu.obs import counters  # noqa: E402
+from perfbench.refmodels import qwen3_next as ref  # noqa: E402
+
+TINY = prog.PRESETS["tiny"]
+
+
+def leaves(tree):
+    return [(jax.tree_util.keystr(k), v)
+            for k, v in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """Seeded weights (the reference's init, every leaf then moved off its
+    initial value so that a zero-initialised norm weight matters) and a
+    batch of two sequences."""
+    module, example = ref.build(TINY, jnp.float32)
+    params = jax.jit(lambda k: module.init({"params": k}, example, False))(
+        jax.random.PRNGKey(0))["params"]
+    keys = jax.random.split(jax.random.PRNGKey(1), len(jax.tree.leaves(params)))
+    params = jax.tree.unflatten(
+        jax.tree.structure(params),
+        [p + 0.05 * jax.random.normal(k, p.shape)
+         for p, k in zip(jax.tree.leaves(params), keys)])
+    rng = np.random.default_rng(0)
+    draw = lambda: rng.integers(0, TINY["vocab_rows"], (2, TINY["seq_len"])
+                                ).astype(np.int32)
+    return params, {"tokens": draw(), "targets": draw()}
+
+
+@pytest.fixture(scope="module")
+def reference_side(seeded):
+    """((loss, gradients), logits) of the reference in float32."""
+    params, batch = seeded
+    module, _ = ref.build(TINY, jnp.float32)
+    out = jax.jit(jax.value_and_grad(lambda p: ref.loss(
+        module, {"params": p}, (), batch, None, True)[0]))(params)
+    hidden, head = module.apply({"params": params}, batch["tokens"], False)
+    return out, jnp.dot(hidden, head)
+
+
+def program_side(dtype, params, batch):
+    module = prog.Qwen3Next("tiny", dtype)
+    out = jax.jit(jax.value_and_grad(lambda p: module.apply(
+        {"params": p}, batch["tokens"], batch["targets"], train=True)[0]))(params)
+    return out, module.apply({"params": params}, batch["tokens"])
+
+
+def gaps(reference, program):
+    """(relative logit gap, relative loss gap, worst leaf's gradient gap
+    over its norm)."""
+    ((r_loss, r_grad), r_logits), ((p_loss, p_grad), p_logits) = \
+        reference, program
+    logit = float(jnp.linalg.norm(p_logits - r_logits)
+                  / jnp.linalg.norm(r_logits))
+    loss = abs(float(p_loss - r_loss)) / float(r_loss)
+    grad = max(float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+               for a, b in zip(jax.tree.leaves(p_grad), jax.tree.leaves(r_grad)))
+    return logit, loss, grad
+
+
+def test_parameters_are_the_references_leaf_for_leaf(seeded):
+    params, batch = seeded
+    mine = jax.eval_shape(
+        lambda k: prog.Qwen3Next("tiny").init({"params": k}, batch["tokens"]),
+        jax.random.PRNGKey(0))["params"]
+    shape = lambda t: [(k, v.shape, v.dtype) for k, v in leaves(t)]
+    assert shape(mine) == shape(params)
+    assert sum(v.size for _, v in leaves(mine)) == 212_904
+
+
+def test_program_equals_reference_in_float32(seeded, reference_side):
+    logit, loss, grad = gaps(reference_side,
+                             program_side(jnp.float32, *seeded))
+    assert logit < 1e-4 and loss < 1e-5 and grad < 1e-3, (logit, loss, grad)
+
+
+def test_bfloat16_compute_fails_the_float32_tolerances(seeded, reference_side):
+    """The tolerances above are tight enough to see one precision down: the
+    program in bfloat16 against the float32 reference breaks at least one."""
+    logit, loss, grad = gaps(reference_side,
+                             program_side(jnp.bfloat16, *seeded))
+    assert logit >= 1e-4 or loss >= 1e-5 or grad >= 1e-3, (logit, loss, grad)
+
+
+def delta_inputs(length, seed=0, batch=2, heads=2, d_k=8, d_v=8):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (batch, length, heads, d_k))) / d_k ** 0.5
+    k = unit(jax.random.normal(keys[1], (batch, length, heads, d_k)))
+    v = jax.random.normal(keys[2], (batch, length, heads, d_v))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (batch, length, heads)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, length, heads)))
+    return q, k, v, g, beta
+
+
+def recurrence(q, k, v, g, beta):
+    """The delta rule token by token, in numpy float64: the definition."""
+    q, k, v, g, beta = (np.asarray(a, np.float64) for a in (q, k, v, g, beta))
+    batch, length, heads, d_k = q.shape
+    state = np.zeros((batch, heads, d_k, v.shape[-1]))
+    out = np.zeros(v.shape)
+    for t in range(length):
+        state = state * np.exp(g[:, t])[..., None, None]
+        read = np.einsum("bhkv,bhk->bhv", state, k[:, t])
+        delta = beta[:, t][..., None] * (v[:, t] - read)
+        state = state + np.einsum("bhk,bhv->bhkv", k[:, t], delta)
+        out[:, t] = np.einsum("bhkv,bhk->bhv", state, q[:, t])
+    return out
+
+
+@pytest.mark.parametrize("length,chunk", [(64, 16), (150, 16), (7, 16)])
+def test_chunked_delta_rule_equals_the_recurrence(length, chunk):
+    """Forward and gradients, at lengths that are and are not multiples of
+    the chunk: the program's chunked form and the reference's per-token
+    scan in blocks, each against the recurrence in float64."""
+    args = delta_inputs(length)
+    want = recurrence(*args)
+    forms = {"program": lambda *a: prog.chunked_delta_rule(*a, chunk),
+             "reference": ref.delta_rule}
+    weight = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    pull = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                               argnums=(0, 1, 2, 3, 4))(*args)
+    definition = pull(ref.delta_rule)
+    for name, fn in forms.items():
+        assert np.max(np.abs(np.asarray(fn(*args)) - want)) < 1e-5, name
+        for mine, theirs in zip(pull(fn), definition):
+            assert float(jnp.max(jnp.abs(mine - theirs))) < 1e-5, name
+
+
+def moe_params(seed=3):
+    """The parameters of one uncut expert layer: all 16 experts held."""
+    whole = dict(TINY, experts_held=TINY["num_experts"], expert_offset=0)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (2, 48, TINY["hidden_size"]))
+    module = ref.SparseMoE(whole, jnp.float32)
+    params = module.init({"params": jax.random.PRNGKey(seed + 1)}, x)["params"]
+    # A livelier router than N(0, 0.02), so that loads differ.
+    params["router"] = params["router"] * 40.0
+    return whole, params, x
+
+
+def share_of(params, rank, held):
+    cut = lambda a: a[rank * held:(rank + 1) * held]
+    return dict(params, **{k: cut(params[k]) for k in
+                           ("experts_gate", "experts_up", "experts_down")})
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 experts in 4 shares of 4: the sum of the four shares' outputs,
+    with what every chip computes alike (the shared expert) counted once,
+    is the uncut layer's, in the program and in the reference."""
+    whole, params, x = moe_params()
+    held = 4
+    uncut = ref.SparseMoE(whole, jnp.float32).apply({"params": params}, x)
+    only_shared = dict(TINY, experts_held=held, expert_offset=10 ** 6)
+    shared = ref.SparseMoE(only_shared, jnp.float32).apply(
+        {"params": share_of(params, 0, held)}, x)
+    loads = []
+    for side in ("program", "reference"):
+        total = 0.0
+        for rank in range(4):
+            sizes = dict(TINY, experts_held=held, expert_offset=rank * held)
+            p = {"params": share_of(params, rank, held)}
+            if side == "program":
+                y, load, dropped = prog.SparseMoE(sizes, jnp.float32).apply(p, x)
+                loads.append(np.asarray(load))
+                assert int(dropped) == 0
+            else:
+                y = ref.SparseMoE(sizes, jnp.float32).apply(p, x)
+            total = total + (y - shared)
+        assert float(jnp.max(jnp.abs(total + shared - uncut))) < 1e-5, side
+    # Every token-slot landed on exactly one share.
+    assert int(np.sum(loads)) == x.shape[0] * x.shape[1] * TINY["num_experts_per_tok"]
+
+
+def biased_to_held_experts():
+    """An expert layer whose router sends every token to the same four
+    experts, all held here: every one of the layer's slots falls here."""
+    sizes = dict(TINY, experts_held=4, expert_offset=4)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, TINY["hidden_size"]))
+    params = ref.SparseMoE(sizes, jnp.float32).init(
+        {"params": jax.random.PRNGKey(6)}, x)["params"]
+    # |x.sum| grows with the input, the bias does not depend on it.
+    bias = jnp.zeros((TINY["num_experts"],)).at[4:8].set(50.0)
+    params["router"] = params["router"] * 0.0 + bias[None, :] / TINY["hidden_size"]
+    return sizes, params, jnp.abs(x) + 1.0
+
+
+@pytest.mark.parametrize("block_rows", [None, 64])
+def test_no_slot_is_dropped_under_a_router_that_overloads_the_held(block_rows):
+    sizes, params, x = biased_to_held_experts()
+    want = ref.SparseMoE(sizes, jnp.float32).apply({"params": params}, x)
+    y, load, dropped = prog.SparseMoE(
+        sizes, jnp.float32, block_rows=block_rows).apply({"params": params}, x)
+    slots = x.shape[0] * x.shape[1] * TINY["num_experts_per_tok"]
+    assert int(jnp.sum(load)) == slots and int(dropped) == 0
+    assert float(jnp.max(jnp.abs(y - want))) < 1e-5
+    got = counters.moe_counters(load[None], dropped[None])
+    assert float(got["moe_slots_dropped"]) == 0.0
+    assert float(got["moe_slots_held"]) == slots
+    assert float(got["moe_load_max"]) == float(got["moe_load_mean"]) == slots / 4
+
+
+def test_a_capacity_limited_layer_fails_the_no_drop_test():
+    """The same layer with its loop capped at one block of 64 slots: the
+    counter counts what was left out and the output is no longer the
+    reference's."""
+    sizes, params, x = biased_to_held_experts()
+    want = ref.SparseMoE(sizes, jnp.float32).apply({"params": params}, x)
+    y, load, dropped = prog.SparseMoE(
+        sizes, jnp.float32, block_rows=64, max_blocks=1).apply(
+            {"params": params}, x)
+    assert int(dropped) == int(jnp.sum(load)) - 64 > 0
+    assert float(jnp.max(jnp.abs(y - want))) > 1e-3
+    got = counters.moe_counters(load[None], dropped[None])
+    assert float(got["moe_slots_dropped"]) == float(dropped)
+
+
+def test_expert_blocks_give_the_gradients_of_one_block():
+    """The hand-written backward of the slot loop: four blocks of 64 slots
+    against one block of all of them, every gradient."""
+    sizes = dict(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 128, TINY["hidden_size"]))
+    params = ref.SparseMoE(sizes, jnp.float32).init(
+        {"params": jax.random.PRNGKey(8)}, x)["params"]
+
+    def grads(block_rows):
+        module = prog.SparseMoE(sizes, jnp.float32, block_rows=block_rows)
+        return jax.grad(lambda p, x: jnp.sum(
+            module.apply({"params": p}, x)[0] ** 2), argnums=(0, 1))(params, x)
+
+    one, many = grads(None), grads(64)
+    for (name, a), (_, b) in zip(leaves(one), leaves(many)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4, name
+    assert float(jnp.linalg.norm(one[0]["router"])) > 0
+
+
+def test_model_registry_and_trainer_run_the_decoder(tmp_path):
+    """``--dnn qwen3_next`` through ``Trainer`` like every other model: the
+    spec's fields, a preset that only this model takes, two steps, and the
+    expert counters in the ``train`` record."""
+    from gtopkssgd_tpu.trainer import TrainConfig, Trainer
+
+    model, spec = get_model("qwen3_next", preset="tiny")
+    assert (spec.input_key, spec.loss, spec.carry) == ("tokens", "own", False)
+    assert get_model("lstm")[1].carry and get_model("lstm")[1].loss == "tokens"
+    with pytest.raises(ValueError, match="model-preset"):
+        get_model("resnet20", preset="tiny")
+    with Trainer(TrainConfig(dnn="qwen3_next", model_preset="tiny",
+                             batch_size=2, compression="gtopk", density=0.01,
+                             log_interval=1, out_dir=str(tmp_path))) as t:
+        assert t.cfg.dataset == "tokens" and t.num_params == 212_904
+        out = t.train(2)
+        assert np.isfinite(out["loss"]) and out["moe_slots_dropped"] == 0.0
+        assert out["moe_slots_held"] > 0
+        assert np.isfinite(t.test()["val_loss"])
+    import json
+    rows = [json.loads(line) for line in open(tmp_path / "metrics.jsonl")]
+    train = [r for r in rows if r["kind"] == "train"]
+    assert len(train) == 2
+    assert all(r["moe_slots_dropped"] == 0.0 and r["moe_load_max"]
+               >= r["moe_load_mean"] > 0 for r in train)
+    obs = [r for r in rows if r["kind"] == "obs"]
+    assert all(set(counters.MOE_FIELDS) <= set(r) for r in obs)
+    assert counters.last_model_scalars()["moe_slots_held"] == \
+        train[-1]["moe_slots_held"]
+
+
+def test_published_preset_counts_its_parameters():
+    """N = 323,677,248 from the initialised tree's shapes (no memory taken)."""
+    module = prog.Qwen3Next("80b_a3b_ep64", jnp.bfloat16)
+    shapes = jax.eval_shape(
+        lambda k: module.init({"params": k}, jnp.zeros((1, 4096), jnp.int32)),
+        jax.random.PRNGKey(0))["params"]
+    assert sum(v.size for v in jax.tree.leaves(shapes)) == 323_677_248
+    assert all(v.dtype == jnp.float32 for v in jax.tree.leaves(shapes))
