@@ -123,9 +123,9 @@ reference's only telemetry was text logs):
                                          (achieved density, tau, grad/
                                          residual norms, wire bytes) as
                                          per-step "obs" records (default on)
-    --obs-interval N                     log "obs" every N steps (reading
-                                         counters syncs on the step; raise
-                                         to preserve dispatch overlap)
+    --obs-interval N                     log "obs" every N steps (step k is
+                                         read after step k+1 is queued, so
+                                         the chip does not wait for it)
     --obs-layers                         per-layer compression telemetry
                                          (density, tau, norms, residual
                                          age, mass-capture m(k)) as one
@@ -519,8 +519,10 @@ def build_argparser() -> argparse.ArgumentParser:
                         "the step exactly as before the obs subsystem)")
     p.add_argument("--obs-interval", type=int, default=1,
                    help="log an 'obs' record every N optimizer steps; "
-                        "reading counters syncs on the dispatched step, "
-                        "so raise this to keep async dispatch overlap")
+                        "step k's counters are read after step k+1 is "
+                        "queued, so the chip does not wait for the read "
+                        "(it waits under --recover-policy, --inject, "
+                        "--obs-halt-on, --obs-mem and --elastic)")
     p.add_argument("--obs-layers", action=argparse.BooleanOptionalAction,
                    default=False,
                    help="per-layer compression-quality telemetry "

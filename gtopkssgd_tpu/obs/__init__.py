@@ -114,6 +114,7 @@ from gtopkssgd_tpu.obs.counters import (
     sent_count,
     model_scalars,
     moe_counters,
+    readable_counters,
     telemetry_scalars,
     topk_recall,
     tree_l2,
@@ -181,6 +182,7 @@ __all__ = [
     "sent_count",
     "model_scalars",
     "moe_counters",
+    "readable_counters",
     "telemetry_scalars",
     "timeline_from_records",
     "topk_recall",

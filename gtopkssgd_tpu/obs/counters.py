@@ -557,6 +557,13 @@ def last_model_scalars() -> Dict[str, float]:
     return dict(_last_model)
 
 
+def readable_counters(telemetry: Dict[str, Array]) -> Dict[str, Array]:
+    """What the trainer's loop reads of a state's telemetry dict: the
+    scalar counters and the per-layer "layers" columns, never the [N]
+    "age" buffer (its per-layer mean is already among the columns)."""
+    return {key: val for key, val in telemetry.items() if key != "age"}
+
+
 def telemetry_scalars(telemetry: Dict[str, Array]) -> Dict[str, float]:
     """Host floats of the SCALAR counters in a state's telemetry dict —
     the per-layer "layers" sub-dict and the [N] "age" buffer excluded.

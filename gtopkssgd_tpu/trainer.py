@@ -56,6 +56,7 @@ from gtopkssgd_tpu.obs import (
     layer_names,
     model_scalars,
     moe_counters,
+    readable_counters,
     telemetry_scalars,
 )
 from gtopkssgd_tpu.obs.manifest import config_hash, run_manifest
@@ -157,11 +158,15 @@ class TrainConfig:
                                    # logged as "obs" records; off -> the
                                    # step traces identically to pre-obs
     obs_interval: int = 1          # log an "obs" record every N optimizer
-                                   # steps. Reading the counters blocks on
-                                   # the dispatched step, so raise this to
-                                   # keep async dispatch overlap on real
-                                   # accelerators (CPU-mesh runs are
-                                   # synchronous anyway)
+                                   # steps. The read of step k's counters
+                                   # blocks after step k+1 is queued, so
+                                   # the chip does not wait for it; it
+                                   # blocks on the newest step only under
+                                   # a recovery policy, an injector,
+                                   # obs_halt_on, obs_mem or elastic
+                                   # (something may act on the reading
+                                   # before the next dispatch): raise
+                                   # this there to keep the overlap
     obs_layers: bool = False       # per-layer compression-quality
                                    # telemetry (obs.counters.LAYER_FIELDS:
                                    # density, tau, grad/residual norms,
@@ -495,6 +500,16 @@ def _lead_axis(arrays):
     return out, out.nbytes
 
 
+@jax.jit
+def _hold_counters(counters):
+    """Copies of a step's readable counters in buffers of their own, so
+    that the next dispatch, which donates the state they live in, leaves
+    them readable: the same values and dtypes, no arithmetic. One small
+    program queued behind the step, the same one for every step of a run
+    (``Trainer.train(1)`` runs it too, so a later call compiles nothing)."""
+    return jax.tree.map(jnp.copy, counters)
+
+
 class Trainer:
     def __init__(self, config: TrainConfig):
         self.cfg = cfg = config.resolved()
@@ -630,6 +645,11 @@ class Trainer:
         # Bytes the batch assembly has copied on the host since the last
         # "train" record (its host_copied_mb).
         self._host_copied_bytes = 0
+        # Counter reads since the last "train" record (its
+        # obs_reads_lagged / obs_reads_sync): those made with a later
+        # dispatch already queued behind the step read, and those that
+        # waited for the newest dispatched step.
+        self._obs_reads = {"lagged": 0, "sync": 0}
         data_kw = dict(
             batch_size=cfg.batch_size, data_dir=cfg.data_dir, seed=cfg.seed
         )
@@ -1725,6 +1745,44 @@ class Trainer:
         self._host_copied_bytes += copied
         return shards
 
+    def _read_counters(self, step: int, counters, loss, aux, *,
+                       lagged: bool) -> None:
+        """Read one dispatched step's counters, loss and model counters
+        in one transfer (it blocks until that step ran) and write what
+        the step says: the scalars as one "obs" record, the per-layer [L]
+        columns as one "layers" record a layer, and the anomaly monitor's
+        observation. ``step`` is the optimizer step the dispatch ended
+        on; ``counters`` are ``readable_counters`` of its state, or their
+        held copies where a later dispatch has donated that state
+        (``lagged``: one is already queued behind the step read). The
+        model's own counters (an expert layer's loads) ride the step's
+        aux: same sync, same "obs" record."""
+        spd = self.cfg.steps_per_dispatch
+        with self.tracer.span("obs_read", step=step - spd):
+            counters, loss, aux = jax.device_get((counters, loss, aux))
+            scalars = telemetry_scalars(counters)
+            self.metrics.log("obs", step=step, **scalars,
+                             **model_scalars(aux))
+            max_age = None
+            cols = counters.get("layers")
+            if cols is not None:
+                ages = cols.get("residual_age")
+                if ages is not None and ages.size:
+                    max_age = float(np.max(ages))
+                for i, lname in enumerate(self._layer_names):
+                    self.metrics.log(
+                        "layers", step=step, layer=lname,
+                        **{f: float(c[i]) for f, c in cols.items()})
+            if self.timeline is not None:
+                self.timeline.counter("obs", scalars)
+        # The step is synced by the read above, so feeding the monitor
+        # costs nothing extra.
+        if self.monitor is not None:
+            self.monitor.observe(
+                step, loss=float(loss), telemetry=scalars,
+                max_residual_age=max_age)
+        self._obs_reads["lagged" if lagged else "sync"] += 1
+
     # -------------------------------------------------------------- train
     def train(self, num_iters: int, epoch: int = 0) -> Dict[str, float]:
         """Run `num_iters` optimizer steps (reference DLTrainer.train)."""
@@ -1764,6 +1822,24 @@ class Trainer:
             # inter-epoch span (eval/ckpt marked their own shares; the
             # rest is honestly `other`).
             gp.train_started()
+        # Whether the counter read may lag the dispatch by one (see the
+        # read below): not where a recovery policy, an injector, a halt
+        # rule, the memory watch or an elastic fleet's eviction check
+        # may act on a step's reading before the next step is queued.
+        lag_ok = (rec is None and inj is None and cfg.obs_halt_on is None
+                  and self.memwatch is None and not cfg.elastic)
+        # (step, counters, loss, aux) of a dispatched step not yet read.
+        held = None
+
+        def read_held(lagged):
+            """Read the held step, if any; returns its step id."""
+            nonlocal held
+            if held is None:
+                return None
+            reading, held = held, None
+            self._read_counters(*reading, lagged=lagged)
+            return reading[0]
+
         try:
             for _ in range(num_iters // spd if spd > 1 else num_iters):
                 # Preemption flag check at the iteration boundary: the
@@ -1775,6 +1851,7 @@ class Trainer:
                 # in which case _resize_now falls back to exit-45
                 # preempt semantics.
                 if guard is not None and guard.triggered:
+                    read_held(lagged=False)
                     if cfg.elastic:
                         self._resize_now(self.p - 1, reason="preempt")
                     self._preempt_now()
@@ -1847,7 +1924,8 @@ class Trainer:
                     # queue (the overlap is the point). The step's device
                     # time is the jit_gtopk_train_step program in a
                     # profiler trace, which starts once its inputs are on
-                    # the chip: step_start_lag_ms (perfbench) is that wait.
+                    # the chip and the step queued before it has ended:
+                    # step_start_lag_ms (perfbench) is that wait.
                     if capture_now:
                         # Calibration sample: profile exactly this
                         # dispatch, blocking inside the capture so the
@@ -1904,57 +1982,48 @@ class Trainer:
                     # either way; no-op warning without --elastic).
                     self._check_injected_resize(step - spd, step)
                 if guard is not None and guard.triggered:
+                    # The step before's records first, as they always
+                    # were written before this save and unwind.
+                    read_held(lagged=True)
                     if cfg.elastic:
                         self._resize_now(self.p - 1, reason="preempt")
                     self._preempt_now()
-                synced = False
-                # On-device counters (obs.counters, carried in
-                # opt_state.telemetry). float() blocks until the
-                # dispatched step actually ran — which is also the
-                # watchdog's honest progress proof.
-                observed = False
+                # With spd > 1 a dispatch may jump over the exact
+                # boundary; log when any step inside it crossed one.
+                log_now = step % cfg.log_interval < spd
+                # The counter read (obs.counters, carried in
+                # opt_state.telemetry) blocks until the step it reads
+                # ran, which is also the watchdog's honest progress
+                # proof. It lags the dispatch by one: this step's
+                # counters are copied out (the next dispatch donates the
+                # state they live in) and held, and the read that blocks
+                # here is the step before's, with this step queued behind
+                # it. So the chip goes from one step to the next while
+                # the host reads, fetches the next batch and dispatches.
+                # The read stays on this step where something may act on
+                # it before the next dispatch (lag_ok, a capture), or
+                # where the "train" row below waits for this step anyway.
+                counters = None
                 if (cfg.obs_counters and cfg.obs_interval > 0
                         and step % cfg.obs_interval < spd):
                     tel = self.state.opt_state.telemetry
                     if tel:
-                        with self.tracer.span("obs_read", step=step - spd):
-                            # Scalar counters -> one "obs" record; the
-                            # per-layer [L] arrays -> one "layers" record
-                            # per layer; the [N] age buffer stays on
-                            # device (its per-layer mean is already in
-                            # the layers record). The model's own
-                            # counters (an expert layer's loads) ride the
-                            # step's aux: same sync, same "obs" record.
-                            scalars = telemetry_scalars(tel)
-                            self.metrics.log("obs", step=step, **scalars,
-                                             **model_scalars(aux))
-                            max_age = None
-                            lay = tel.get("layers")
-                            if lay is not None:
-                                cols = {f: np.asarray(v)
-                                        for f, v in lay.items()}
-                                ages = cols.get("residual_age")
-                                if ages is not None and ages.size:
-                                    max_age = float(np.max(ages))
-                                for i, lname in enumerate(
-                                        self._layer_names):
-                                    self.metrics.log(
-                                        "layers", step=step, layer=lname,
-                                        **{f: float(c[i])
-                                           for f, c in cols.items()})
-                            if self.timeline is not None:
-                                self.timeline.counter("obs", scalars)
-                        # The step is already synced by the reads above,
-                        # so feeding the monitor costs nothing extra.
-                        if self.monitor is not None:
-                            self.monitor.observe(
-                                step, loss=float(loss), telemetry=scalars,
-                                max_residual_age=max_age)
-                            observed = True
-                        synced = True
-                # With spd > 1 a dispatch may jump over the exact
-                # boundary; log when any step inside it crossed one.
-                if step % cfg.log_interval < spd:
+                        counters = readable_counters(tel)
+                hold = (counters is not None and lag_ok
+                        and not (capture_now or log_now))
+                if hold:
+                    # Queued behind this step, before the host blocks.
+                    counters = _hold_counters(counters)
+                # The step a blocking read proved to have run, if any;
+                # whether the monitor saw this iteration's step.
+                synced, observed = read_held(lagged=True), False
+                if hold:
+                    held = (step, counters, loss, aux)
+                elif counters is not None:
+                    self._read_counters(step, counters, loss, aux,
+                                        lagged=False)
+                    synced, observed = step, self.monitor is not None
+                if log_now:
                     last_loss = float(loss)
                     last_aux = {k: float(v) for k, v in aux.items()}
                     elapsed = time.perf_counter() - t_start
@@ -1964,9 +2033,13 @@ class Trainer:
                         # Megabytes the batch assembly copied on the host
                         # since the last such record: 0 on the view path.
                         host_copied_mb=self._host_copied_bytes / 1e6,
+                        # Counter reads since the last such record.
+                        obs_reads_lagged=self._obs_reads["lagged"],
+                        obs_reads_sync=self._obs_reads["sync"],
                         **last_aux,
                     )
                     self._host_copied_bytes = 0
+                    self._obs_reads = {"lagged": 0, "sync": 0}
                     if cfg.dataset == "ptb":
                         row["ppl"] = float(np.exp(min(last_loss, 20.0)))
                     self.metrics.log("train", **row)
@@ -1979,7 +2052,7 @@ class Trainer:
                     if self.monitor is not None and not observed:
                         self.monitor.observe(step, loss=last_loss)
                         observed = True
-                    synced = True
+                    synced = step
                 if gp is not None:
                     # The obs/log blocking reads drained the dispatched
                     # step — that wait IS step time, same split as the
@@ -1997,9 +2070,14 @@ class Trainer:
                             pending, prev_state, prev_carry, step)
                     elif observed:
                         rec.note_ok()
-                if wd is not None and synced:
-                    wd.heartbeat(step=step)
-                if self.memwatch is not None and synced:
+                if synced is not None:
+                    # The newest step known complete that the state
+                    # still holds: behind `step` after a lagged read,
+                    # `step` itself after a rewind.
+                    synced = min(synced, step)
+                if wd is not None and synced is not None:
+                    wd.heartbeat(step=synced)
+                if self.memwatch is not None and synced is not None:
                     # Compile/memory watch at a sync the loop already
                     # paid: accounts a never-seen dispatch shape (one
                     # fsync'd "compile" record), logs executable-cache
@@ -2015,12 +2093,12 @@ class Trainer:
                         # A never-seen dispatch shape AOT-compiles here;
                         # warm polls cost ~nothing.
                         gp.mark("compile")
-                if gp is not None and synced:
+                if gp is not None and synced is not None:
                     # Periodic durable "goodput" record + the
                     # goodput_collapse feed, at a sync the loop already
                     # paid. AnomalyHalt propagates AFTER the record is
                     # durable, like every monitor halt.
-                    gp.tick(step)
+                    gp.tick(synced)
                     # Elastic eviction self-check, every
                     # evict_after_windows goodput windows (rank 0 — it
                     # owns the merged fleet view): a persistently
@@ -2034,6 +2112,12 @@ class Trainer:
                                         * cfg.evict_after_windows)
                             < spd):
                         self._maybe_evict(step)
+            # The last dispatched step's read: nothing is queued behind
+            # it. Every call returns with every record written and
+            # nothing held.
+            if read_held(lagged=False) is not None and gp is not None:
+                gp.step_mark(degraded=self._degraded)
+                gp.tick(step)
             with self.tracer.span("final_sync"):
                 jax.block_until_ready(self.state)
             if gp is not None:
