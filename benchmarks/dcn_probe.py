@@ -25,7 +25,7 @@ the dense:sparse RATIO (bytes-dominated) is the robust readout.
 
 Usage:
   python benchmarks/dcn_probe.py [--n 25557032] [--density 0.001]
-Writes benchmarks/results/dcn_probe_2proc.json and re-emits the
+Writes gtopkssgd_tpu/parallel/fits/dcn_probe_2proc.json and re-emits the
 scaling-model curve with the measured cross-process bandwidth.
 """
 
@@ -39,7 +39,9 @@ import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results")
+# The fits are the program's own: the planner's default search
+# directory (gtopkssgd_tpu.parallel.comm_model.FIT_DIR).
+RESULTS = os.path.join(REPO, "gtopkssgd_tpu", "parallel", "fits")
 
 WORKER = r"""
 import json
@@ -379,7 +381,7 @@ def main():
 
     os.makedirs(RESULTS, exist_ok=True)
     # Per-procs filename: a --procs 4 run must not overwrite the
-    # canonical 2-process anchor that README/time_to_quality cite.
+    # canonical 2-process anchor.
     out = os.path.join(RESULTS, f"dcn_probe_{args.procs}proc.json")
     with open(out, "w") as fh:
         json.dump(report, fh, indent=2)

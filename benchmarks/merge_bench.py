@@ -20,8 +20,8 @@ This benchmark measures, at the reference's real (N, k) operating points:
                     sparse formulation was chosen.
 
 The verdict this artifact encodes: whether the XLA merge is already cheap
-relative to its train step (ResNet-50's measured fused step is ~55-65 ms at
-batch 128 — bench.py), i.e. whether a hand-fused Pallas merge kernel could
+relative to its train step (ResNet-50's device_step_ms, 217 ms at batch
+512 — PERF_LEDGER.jsonl), i.e. whether a hand-fused Pallas merge kernel could
 buy anything measurable.
 
 Run:  python -m benchmarks.merge_bench [--out PATH] [--quick]

@@ -4,8 +4,9 @@ Only ONE real TPU chip is reachable from this environment, so multi-chip
 performance cannot be measured directly. This tool does the next honest
 thing: it combines
 
-  * the MEASURED single-chip step decomposition (compute time from
-    bench.py / sweep.py on the real chip),
+  * a MEASURED single-chip step decomposition (--compute-ms and
+    --overhead-ms: a cell's fwd_bwd_ms + apply_ms and its compress_ms,
+    PERF_LEDGER.jsonl),
   * a link-aware bandwidth model of the per-device communication volume,
     matching the complexity classes the collectives implement (dense
     ring O(N), DGC allgather O(kP), gtopk O(k log P), hier O(N on ICI +
@@ -48,18 +49,10 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-# The implemented tree's own round count (pow2: log2; ragged: masked
-# fold/unfold around the 2^m block) — imported from the collectives so
-# model and implementation cannot drift.
-from gtopkssgd_tpu.parallel import tree_rounds as _tree_rounds  # noqa: E402
-from gtopkssgd_tpu.parallel import get_codec as _get_codec  # noqa: E402
-from gtopkssgd_tpu.parallel import balanced_cap as _balanced_cap  # noqa: E402
-
-
-def _ring_allreduce_bytes(n_bytes: int, p: int) -> float:
-    """Bandwidth-optimal dense allreduce moves 2(p-1)/p x the buffer per
-    device — 0 at p=1 (no collective), ~2x asymptotically."""
-    return 2.0 * (p - 1) / p * n_bytes
+# The comm model itself is the package's (the planner scores wire plans
+# with it and the comm ledger audits against it); this script adds the
+# compute/overhead/throughput bookkeeping around it.
+from gtopkssgd_tpu.parallel.comm_model import predict  # noqa: E402
 
 
 def project(mode: str, p: int, *, n: int, k: int, compute_ms: float,
@@ -68,28 +61,12 @@ def project(mode: str, p: int, *, n: int, k: int, compute_ms: float,
             codec: str = "fp32") -> dict:
     """Projected step time at P devices for one reduction mode.
 
-    Comm cost = messages x per-message latency + bytes / link-bandwidth
-    on the link each phase actually crosses. For flat modes every P is
-    assumed to sit behind the slower of the two links when P exceeds one
-    ICI domain (`ici_size` chips): conservative for ICI-only pods,
-    realistic for multislice.
-
-    ``dcn_alpha_ms`` is the fitted per-message latency of the slow link
-    (dcn_probe.py's alpha_beta_fit). At alpha=0 and P inside one slice
-    this reduces to the round-2 bandwidth-only model. ICI latency is
-    kept at 0 — microseconds-class, invisible next to ms-scale DCN
-    terms.
-
-    Topology consistency (round-4 review): when P spans slices, EVERY
-    mode decomposes into an intra-slice phase on ICI plus an inter-slice
-    phase on DCN — charging flat modes DCN latency on intra-slice hops
-    while the hier mode gets slice-aware accounting would rig the
-    comparison. Phase shapes: dense = ring within the slice + ring over
-    the n_slices slice aggregates (a topology-aware dense allreduce, the
-    decomposition XLA itself applies to multislice meshes); gtopk = the
-    hypercube's first log2(s) rounds pair intra-slice partners, the last
-    log2(n_slices) rounds cross DCN; allgather = gather s*k within the
-    slice, then pull the other slices' (p-s)*k over DCN.
+    step = compute + (selection overhead, sparse modes only) + the comm
+    model's comm_ms (``gtopkssgd_tpu.parallel.comm_model.predict`` — the
+    link split and phase shapes are documented there). ``dcn_alpha_ms``
+    is the fitted per-message latency of the slow link (dcn_probe.py's
+    alpha_beta_fit); at alpha=0 and P inside one slice this reduces to a
+    bandwidth-only model.
     """
     comm_ms = predict(mode, p, n=n, k=k, ici_gbps=ici_gbps,
                       dcn_gbps=dcn_gbps, ici_size=ici_size,
@@ -106,111 +83,12 @@ def project(mode: str, p: int, *, n: int, k: int, compute_ms: float,
     }
 
 
-def predict(mode: str, p: int, *, n: int, k: int, ici_gbps: float,
-            dcn_gbps: float, ici_size: int,
-            dcn_alpha_ms: float = 0.0, codec: str = "fp32",
-            buckets=None) -> float:
-    """Predicted comm_ms alone — the comm-model ledger's entry point
-    (obs/ledger.py joins this against measured per-step T_comm). Same
-    model as project(), with the compute/overhead/throughput bookkeeping
-    stripped: the ledger compares communication, the only term the
-    alpha-beta model actually predicts. Unrounded (ratio math should not
-    inherit display rounding); map gtopk_layerwise to gtopk on the wire
-    exactly as project() documents.
-
-    ``codec`` sets the per-set sparse payload
-    (parallel.codec.WireCodec.wire_set_bytes — packed values + bf16
-    block scales + Elias-Fano bitpacked indices; fp32 identity = the
-    historical 8 bytes/element). Every sparse exchange — ICI and DCN
-    rounds alike — ships codec bytes, because the tree encodes every
-    round; the hier mode's dense intra-slice psum stays 4n fp32.
-
-    ``buckets`` — ((n_b, k_b), ...) from a layerwise BucketPlan
-    (gtopkssgd_tpu.parallel.bucketing) — prices the bucketed wire as B
-    independent merges of this mode, each over its bucket-local index
-    space, summed. That is exactly what the bucketed optimizer path
-    issues, so the ledger's bucketed rows reconcile against the same
-    per-merge model as everything else."""
-    if buckets:
-        return sum(
-            predict(mode, p, n=int(n_b), k=int(k_b), ici_gbps=ici_gbps,
-                    dcn_gbps=dcn_gbps, ici_size=ici_size,
-                    dcn_alpha_ms=dcn_alpha_ms, codec=codec)
-            for n_b, k_b in buckets)
-    # The layerwise mode's wire cost IS gtopk's: the layerwise K differs
-    # from ceil(rho*N) only by the +1-per-tiny-leaf ceil rounding (<1%
-    # for ResNet-50 at rho=1e-3).
-    wire_mode = "gtopk" if mode == "gtopk_layerwise" else mode
-    set_bytes = _get_codec(codec).wire_set_bytes(k, n)
-    ici_Bps = ici_gbps * 1e9 / 8
-    dcn_Bps = dcn_gbps * 1e9 / 8
-    s = min(ici_size, p)
-    # ceil, not floor: p=24 with 16-chip slices IS a 2-slice job that
-    # crosses DCN (a floor would model it as one all-ICI slice and
-    # charge zero DCN cost). Ragged counts are first-class: non-pow2 axes
-    # run the masked hypercube in-tree (parallel.collectives._merge_tree),
-    # log2(m) + 2 rounds with m = 2^floor(log2 x) — modeled by
-    # _tree_rounds (the implementation's own round count).
-    n_slices = max(1, math.ceil(p / s))
-    dcn_rounds = _tree_rounds(n_slices)
-    if wire_mode == "dense":
-        return (_ring_allreduce_bytes(4 * n, s) / ici_Bps * 1e3
-                + _ring_allreduce_bytes(4 * n, n_slices) / dcn_Bps * 1e3
-                + 2 * (n_slices - 1) * dcn_alpha_ms)
-    if wire_mode == "gtopk":
-        # Split the flat tree's tree_rounds(p) by the link each round
-        # actually crosses: hypercube rounds whose XOR bit stays inside a
-        # slice pair ICI neighbors; larger bits — and the ragged
-        # fold/unfold, which spans slices whenever p > s — cross DCN.
-        # (p=24, s=16: 6 rounds total = 4 ICI + fold/unfold on DCN; a
-        # tree_rounds(s)+tree_rounds(n_slices) split drops one DCN round
-        # at exactly those ragged shapes.)
-        total_rounds = _tree_rounds(p)
-        if n_slices == 1:
-            ici_rounds, flat_dcn_rounds = total_rounds, 0
-        else:
-            m = 1 << (p.bit_length() - 1)
-            # floor(log2) via bit_length, not int(math.log2(...)): s is
-            # whatever --ici-size the user typed, and the float path
-            # silently truncates non-powers-of-two (and can misround at
-            # large exact powers); hypercube rounds pair by XOR bit, so
-            # floor(log2) is the intended count for ragged s too.
-            ici_rounds = min(m, s).bit_length() - 1
-            flat_dcn_rounds = total_rounds - ici_rounds
-        return (ici_rounds * set_bytes / ici_Bps * 1e3
-                + flat_dcn_rounds * (set_bytes / dcn_Bps * 1e3
-                                     + dcn_alpha_ms))
-    if wire_mode == "gtopk_balanced":
-        # Ok-Topk split-and-reduce (parallel.collectives
-        # balanced_gtopk_allreduce): p-1 scatter ppermutes + a p-slice
-        # allgather, each moving ONE cap-of-n encoded set — O(k) volume
-        # vs the tree's O(k log p), paid for with O(p) message count.
-        # Link split mirrors allgather's: of each phase's p-1 partner
-        # hops, s-1 stay inside the slice, the rest cross DCN; every
-        # DCN hop pays the fitted per-message alpha (the term that makes
-        # the planner prefer the tree on latency-bound fabrics).
-        cap_bytes = _get_codec(codec).wire_set_bytes(
-            _balanced_cap(k, p, n), n)
-        ici_hops = 2 * (s - 1) + 1   # scatter + gather + own-set share
-        dcn_hops = 2 * (p - s)
-        return (ici_hops * cap_bytes / ici_Bps * 1e3
-                + dcn_hops * (cap_bytes / dcn_Bps * 1e3 + dcn_alpha_ms))
-    if wire_mode == "allgather":
-        return ((set_bytes * s) / ici_Bps * 1e3
-                + (set_bytes * (p - s)) / dcn_Bps * 1e3
-                + (n_slices - 1) * dcn_alpha_ms)
-    if wire_mode == "gtopk_hier":
-        return (_ring_allreduce_bytes(4 * n, s) / ici_Bps * 1e3
-                + dcn_rounds * (set_bytes / dcn_Bps * 1e3
-                                + dcn_alpha_ms))
-    raise ValueError(mode)
-
-
 def main():
     ap = argparse.ArgumentParser()
-    # Defaults = the committed ResNet-50 measurements from TPU v5e
-    # (bench.py / breakdown artifacts): 60.1 ms fwd+bwd+apply at b128,
-    # 5.4 ms measured gtopk overhead (compress + residual + scatter).
+    # Defaults = ResNet-50 on one TPU v5e at b128, as measured before
+    # the ledger: 60.1 ms fwd+bwd+apply, 5.4 ms gtopk overhead
+    # (compress + residual + scatter). Pass a cell's own stage times
+    # from PERF_LEDGER.jsonl for today's numbers.
     ap.add_argument("--compute-ms", type=float, default=60.1)
     ap.add_argument("--overhead-ms", type=float, default=5.4)
     ap.add_argument("--n", type=int, default=25_557_032)

@@ -150,5 +150,6 @@ EXPERIMENTS: Dict[str, Dict[str, Any]] = {
 }
 
 # BASELINE.json config #5 (density sweep) is a benchmark, not a training
-# run — it lives in benchmarks/sweep.py; experiments/run.py forwards it.
-SWEEP_NAME = "resnet50_density_sweep"
+# run, so it has no entry here: it is the density cells PERF.md section 7
+# queues for perfbench/ (resnet50.gtopk_r01 first), measured on the chip
+# and recorded in PERF_LEDGER.jsonl.

@@ -12,10 +12,9 @@ paper's exact configuration from the registry.
 from __future__ import annotations
 
 import argparse
-import sys
 from typing import Optional, Sequence
 
-from experiments import EXPERIMENTS, SWEEP_NAME
+from experiments import EXPERIMENTS
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -39,16 +38,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         for name, spec in EXPERIMENTS.items():
             print(f"{name:<{width}}  [{spec['_baseline']:>14}]  "
                   f"{spec['_desc']}")
-        print(f"{SWEEP_NAME:<{width}}  [{'#5':>14}]  density sweep "
-              "{1, 0.01, 0.001, 0.0001} x ResNet-50 -> benchmarks/sweep.py")
-        return 0
-
-    if args.name == SWEEP_NAME:
-        from benchmarks import sweep  # noqa: F401  (its main reads argv)
-
-        sys.argv = ["sweep.py", "--dnn", "resnet50",
-                    "--densities", "1", "0.01", "0.001", "0.0001"]
-        sweep.main()
         return 0
 
     if args.name not in EXPERIMENTS:

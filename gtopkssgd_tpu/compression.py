@@ -119,8 +119,7 @@ class TopKCompressor:
         blocks XLA from fusing the selection into the surrounding
         elementwise pipeline (measured: the fused-step gtopk-over-dense
         overhead was ~3x the isolated compress cost before this path —
-        see benchmarks/results/fused_variants_TPU_v5_lite.json and the
-        p1_threshold entry of the round-3 bench artifact).
+        see benchmarks/results/fused_variants_TPU_v5_lite.json).
 
         Set-membership caveats vs ``compress``, both convergence-neutral
         under error feedback (the keep/residual partition stays exact by

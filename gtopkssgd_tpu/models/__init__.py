@@ -128,8 +128,8 @@ _register(
 def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
     """Build a zoo model by its reference ``--dnn`` flag string.
 
-    ``space_to_depth`` is accepted for every model so each entry point
-    (trainer CLI, benchmark) can forward its flag unconditionally, but it
+    ``space_to_depth`` is accepted for every model so an entry point can
+    forward its flag unconditionally, but it
     is a resnet50-only stem transform: any other model rejects a truthy
     value with a clean error here rather than a constructor TypeError
     deep in flax."""

@@ -21,8 +21,8 @@ residual dynamics). This package turns the repo's scattered primitives
   watchdog.py — dispatch stall watchdog: a monitor thread that detects a
       dispatched step failing to become ready within a deadline, emits
       a structured diagnostic and fails fast instead of hanging.
-  trace_attr.py — chrome-trace parser shared with benchmarks/
-      profile_step.py: buckets device-lane self times into the paper's
+  trace_attr.py — chrome-trace parser: buckets device-lane self times
+      into the paper's
       T_compute/T_select/T_comm decomposition (annotation names when the
       platform propagates them to device lanes, an op-name classifier —
       sort/top-k → select, collectives → comm — as the fallback), plus
@@ -58,8 +58,8 @@ residual dynamics). This package turns the repo's scattered primitives
       anomaly rule, so --obs-halt-on covers it).
   ledger.py   — comm-model ledger: joins measured per-step T_comm (attr
       records) and wire bytes (obs counters) against the alpha-beta
-      scaling model (benchmarks/scaling_model.predict, fed by
-      dcn_probe's fitted alpha/beta when present) into
+      comm model (parallel/comm_model.predict, fed by a fit
+      artifact's alpha/beta when present) into
       predicted-vs-measured ratio rows.
   exporter.py — live OpenMetrics endpoint (``--obs-export-port``):
       stdlib http.server thread serving the latest value of every

@@ -45,15 +45,12 @@ import os
 import statistics
 from typing import Any, Dict, List, Mapping, Optional, Tuple
 
-from gtopkssgd_tpu.obs.ledger import (
-    DEFAULT_DCN_GBPS,
-    _tree_rounds_fallback,
-)
 from gtopkssgd_tpu.obs import linkmap as _linkmap
-
-# ICI fallback bandwidth for the per-axis split/fit baseline (same
-# value parallel/planner.py prices un-measured ici hops with).
-_DEFAULT_ICI_GBPS = 1600.0
+from gtopkssgd_tpu.parallel import tree_rounds
+from gtopkssgd_tpu.parallel.comm_model import (
+    DEFAULT_DCN_GBPS,
+    DEFAULT_ICI_GBPS,
+)
 
 # bytes -> ms conversion at 1 Gbps: t_ms = bytes * 8 / (beta_gbps * 1e9)
 # * 1e3 = bytes * _MS_PER_BYTE_AT_1GBPS / beta_gbps.
@@ -83,9 +80,9 @@ def message_count(wire_mode: str, p: int, *, ici_size: int = 1) -> int:
     if wire_mode == "allgather":
         return p - 1
     if wire_mode == "gtopk_hier":
-        return _tree_rounds_fallback(max(1, p // max(1, int(ici_size))))
+        return tree_rounds(max(1, p // max(1, int(ici_size))))
     # gtopk / gtopk_layerwise hypercube tree
-    return _tree_rounds_fallback(p)
+    return tree_rounds(p)
 
 
 def _finite(x: Any) -> bool:
@@ -277,7 +274,7 @@ class CommCalibrator:
             beta_gbps=(self.baseline.get("beta_gbps")
                        or DEFAULT_DCN_GBPS),
             ici_gbps=(self.baseline.get("ici_gbps")
-                      or _DEFAULT_ICI_GBPS))
+                      or DEFAULT_ICI_GBPS))
         carved = _linkmap.carve_rounds(t_comm_ms, weights)
         per_round_bytes = wire_bytes / len(mine)
         scale = msgs / self.msgs if self.msgs > 0 else 1.0
@@ -307,7 +304,7 @@ class CommCalibrator:
             if len(pool) < self.min_samples:
                 continue
             base_beta = (
-                (self.baseline.get("ici_gbps") or _DEFAULT_ICI_GBPS)
+                (self.baseline.get("ici_gbps") or DEFAULT_ICI_GBPS)
                 if axis == _linkmap.AXIS_ICI
                 else (self.baseline.get("beta_gbps")
                       or DEFAULT_DCN_GBPS))

@@ -1,7 +1,7 @@
 """Comm-model ledger: measured T_comm / wire bytes vs the alpha-beta model.
 
 The paper's scaling argument (arXiv:1901.04359 §3, re-parameterized in
-``benchmarks/scaling_model.py``) predicts per-step communication time
+``parallel/comm_model.py``) predicts per-step communication time
 from mode, worker count, gradient size and link constants. PRs 1–3 made
 the MEASURED side observable — per-rank ``attr`` records carry the
 profiler-derived T_comm split, ``obs`` counter records carry the achieved
@@ -22,97 +22,25 @@ Reading a ratio:
            classified as comm leaked out of attribution)
 
 Model constants come from, in priority order: explicit arguments, a
-``dcn_probe`` artifact's ``alpha_beta_fit`` (``load_alpha_beta``), and
-the scaling model's documented defaults. The scaling model itself is
-loaded from ``benchmarks/`` by path (benchmarks is not a package); when
-the benchmarks tree is absent (installed-package use) a self-contained
-pure alpha-beta fallback keeps the ledger functional.
+fit artifact's ``alpha_beta_fit`` (``load_alpha_beta``), and the comm
+model's documented defaults. The model itself, the fit-file grammar and
+the defaults live in ``parallel/comm_model.py``, beside the collectives
+they price; this module keeps their names for its callers.
 """
 
 from __future__ import annotations
 
-import glob
-import importlib.util
-import json
 import math
-import os
-import re
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
-# scaling_model.py main() defaults — mirrored here for the fallback path
-# and for callers that pass no constants at all.
-DEFAULT_ICI_GBPS = 1600.0
-DEFAULT_DCN_GBPS = 25.0
-
-
-def _load_scaling_model():
-    """Import benchmarks/scaling_model.py by path (repo root is 3 hops
-    up from this file); None when the benchmarks tree is absent."""
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    path = os.path.join(repo, "benchmarks", "scaling_model.py")
-    if not os.path.exists(path):
-        return None
-    spec = importlib.util.spec_from_file_location("_obs_scaling_model",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    try:
-        spec.loader.exec_module(mod)
-    except Exception:
-        return None
-    return mod
-
-
-def _tree_rounds_fallback(p: int) -> int:
-    if p <= 1:
-        return 0
-    m = 1 << (p.bit_length() - 1)
-    return (m.bit_length() - 1) + (0 if m == p else 2)
-
-
-def _codec_set_bytes(codec: str, k: int, n: int) -> int:
-    """On-wire bytes of one encoded k-of-n sparse set under `codec` —
-    the one shared definition (parallel.codec.WireCodec.wire_set_bytes)
-    when the package is importable, else the fp32 identity (8 bytes per
-    element), so a bare-ledger install still reconciles uncompressed
-    runs."""
-    try:
-        from gtopkssgd_tpu.parallel.codec import get_codec
-        return get_codec(codec).wire_set_bytes(k, n)
-    except Exception:
-        return 8 * k
-
-
-def _balanced_cap(k: int, p: int, n: int) -> int:
-    """Per-destination capacity of the balanced schedule — the shared
-    definition (parallel.collectives.balanced_cap) when importable, else
-    the same closed form, so a bare-ledger install still models it."""
-    try:
-        from gtopkssgd_tpu.parallel.collectives import balanced_cap
-        return balanced_cap(k, p, n)
-    except Exception:
-        return max(1, min(-(-3 * k // (2 * p)), k, -(-n // p)))
-
-
-def wire_mode_for(mode: str, schedule: Optional[str] = None,
-                  bucketing: Optional[str] = None) -> str:
-    """Comm-model key for (semantic mode, wire schedule, bucketing): the
-    layerwise mode shares the flat tree's wire, and the 'balanced'
-    schedule maps the gtopk family onto the Ok-Topk model branch.
-    None/'auto'/'tree' keep the mode's historical model — exactly
-    sparse_allreduce's plan dispatch, so the ledger always prices the
-    schedule that actually ran.
-
-    ``bucketing`` (parallel.bucketing.buckets_key grammar) changes the
-    merge MULTIPLICITY, not the per-merge model, so the key stays the
-    same base wire mode; pricing callers pass the bucket (n_b, k_b)
-    pairs to ``predict_comm_ms(buckets=...)`` and the model sums B
-    independent merges of that key. The parameter exists here so every
-    plan/ledger call site names the full wire decision in one place."""
-    wm = "gtopk" if mode == "gtopk_layerwise" else mode
-    if schedule == "balanced" and wm in ("gtopk", "gtopk_hier"):
-        return "gtopk_balanced"
-    return wm
+from gtopkssgd_tpu.parallel import balanced_cap, get_codec, tree_rounds
+from gtopkssgd_tpu.parallel.comm_model import (  # noqa: F401 (re-exported)
+    DEFAULT_DCN_GBPS,
+    DEFAULT_ICI_GBPS,
+    load_alpha_beta,
+    predict,
+    wire_mode_for,
+)
 
 
 def predict_comm_ms(mode: str, p: int, *, n: int, k: int,
@@ -123,155 +51,12 @@ def predict_comm_ms(mode: str, p: int, *, n: int, k: int,
                     codec: str = "fp32",
                     buckets: Optional[Sequence[Sequence[int]]] = None
                     ) -> float:
-    """Predicted comm_ms via scaling_model.predict when benchmarks/ is
-    importable, else a pure alpha-beta tree model (rounds x alpha +
-    bytes/beta on the slow link) — the degenerate ici_size=1 case of the
-    full model, which is exactly the multi-process CPU/DCN topology the
-    ledger's tests and typical --multihost runs live on. ``codec`` sets
-    the per-round sparse payload size (parallel.codec wire bytes).
-
-    ``buckets`` — ((n_b, k_b), ...) from a BucketPlan — prices the
-    bucketed layerwise wire: B independent merges, each over its
-    bucket-local index space, summed. The per-merge model is unchanged,
-    which is exactly what the bucketed optimizer path executes."""
-    if buckets:
-        return sum(
-            predict_comm_ms(mode, p, n=int(n_b), k=int(k_b),
-                            alpha_ms=alpha_ms, beta_gbps=beta_gbps,
-                            ici_gbps=ici_gbps, ici_size=ici_size,
-                            codec=codec)
-            for n_b, k_b in buckets)
-    sm = _load_scaling_model()
-    if sm is not None and hasattr(sm, "predict"):
-        return sm.predict(mode, p, n=n, k=k, ici_gbps=ici_gbps,
-                          dcn_gbps=beta_gbps, ici_size=ici_size,
-                          dcn_alpha_ms=alpha_ms, codec=codec)
-    beta_Bps = beta_gbps * 1e9 / 8
-    wire_mode = "gtopk" if mode == "gtopk_layerwise" else mode
-    if wire_mode == "dense":
-        bytes_per_dev = 2.0 * (p - 1) / p * 4 * n if p > 1 else 0.0
-        return (bytes_per_dev / beta_Bps * 1e3
-                + 2 * (p - 1) * alpha_ms)
-    rounds = _tree_rounds_fallback(p)
-    set_bytes = _codec_set_bytes(codec, k, n)
-    if wire_mode == "gtopk":
-        return rounds * (set_bytes / beta_Bps * 1e3 + alpha_ms)
-    if wire_mode == "gtopk_balanced":
-        # Ok-Topk schedule: p-1 scatter rounds + p-1 gather hops, each
-        # moving one cap-of-n encoded set over the slow link.
-        cap_bytes = _codec_set_bytes(codec, _balanced_cap(k, p, n), n)
-        msgs = 2 * (p - 1)
-        return msgs * (cap_bytes / beta_Bps * 1e3 + alpha_ms)
-    if wire_mode == "allgather":
-        return (set_bytes * (p - 1) / beta_Bps * 1e3
-                + (p - 1) * alpha_ms)
-    if wire_mode == "gtopk_hier":
-        return rounds * (set_bytes / beta_Bps * 1e3 + alpha_ms)
-    raise ValueError(mode)
-
-
-# Fit-artifact filename grammar: the probe writes dcn_probe_{P}proc.json,
-# the in-run calibrator (obs/calib.py) writes calib_fit_{P}proc.json with
-# the same alpha_beta_fit payload. One regex recovers (family, P) for the
-# numeric precedence sort below.
-_FIT_ARTIFACT_RE = re.compile(r"^(dcn_probe|calib_fit)_(\d+)proc\.json$")
-
-
-def _fit_artifact_key(path: str):
-    """Precedence sort key (higher wins): proc count NUMERICALLY first —
-    the docstring's "largest proc count present" contract, which a plain
-    lexicographic basename sort breaks the moment two counts share no
-    digit width (it ranked 8proc over 16proc) — then, at equal P, a
-    calib_fit over a dcn_probe: the calibrator measured THIS workload's
-    wire in-situ, the probe measured synthetic pings."""
-    m = _FIT_ARTIFACT_RE.match(os.path.basename(path))
-    if m is None:
-        return (-1, 0, os.path.basename(path))
-    return (int(m.group(2)), 1 if m.group(1) == "calib_fit" else 0,
-            os.path.basename(path))
-
-
-def _parse_fit_artifact(path: str) -> Optional[Dict[str, Any]]:
-    """{alpha_ms, beta_gbps, source[, axes]} from one fit artifact, or
-    None when unreadable/unusable. The optional ``axes`` section maps
-    axis name -> per-axis fit ({"ici": {...}, "dcn": {...}} today,
-    arbitrary mesh-axis names later); only axes with numeric alpha_ms
-    and beta_gbps > 0 survive parsing."""
-    try:
-        with open(path) as fh:
-            doc = json.load(fh)
-    except (OSError, json.JSONDecodeError):
-        return None
-    fit = doc.get("alpha_beta_fit") or {}
-    alpha, beta = fit.get("alpha_ms"), fit.get("beta_gbps")
-    if not (isinstance(alpha, (int, float))
-            and isinstance(beta, (int, float)) and beta > 0):
-        return None
-    out: Dict[str, Any] = {"alpha_ms": float(alpha),
-                           "beta_gbps": float(beta),
-                           "source": os.path.basename(path)}
-    # Theil-Sen residual noise floor (obs/calib.py) — the forecast
-    # plane's uncertainty-band source. Probe-era artifacts predate it;
-    # absent means "no measured band", never 0-invented.
-    if isinstance(fit.get("resid_ms"), (int, float)) \
-            and fit["resid_ms"] >= 0:
-        out["resid_ms"] = float(fit["resid_ms"])
-    axes = doc.get("axes")
-    if isinstance(axes, dict):
-        clean: Dict[str, Dict[str, float]] = {}
-        for name, ax in axes.items():
-            if (isinstance(ax, dict)
-                    and isinstance(ax.get("alpha_ms"), (int, float))
-                    and isinstance(ax.get("beta_gbps"), (int, float))
-                    and ax["beta_gbps"] > 0):
-                clean[str(name)] = {"alpha_ms": float(ax["alpha_ms"]),
-                                    "beta_gbps": float(ax["beta_gbps"])}
-                if isinstance(ax.get("resid_ms"), (int, float)) \
-                        and ax["resid_ms"] >= 0:
-                    clean[str(name)]["resid_ms"] = float(ax["resid_ms"])
-        if clean:
-            out["axes"] = clean
-    return out
-
-
-def load_alpha_beta(search_dir: Optional[str] = None,
-                    nprocs: Optional[int] = None
-                    ) -> Optional[Dict[str, Any]]:
-    """The fitted {alpha_ms, beta_gbps} from a fit artifact —
-    ``dcn_probe_{n}proc.json`` (benchmarks/dcn_probe.py) or
-    ``calib_fit_{n}proc.json`` (obs/calib.py, the in-run calibrator) —
-    or None. ``nprocs`` restricts to that exact proc count; otherwise
-    the largest proc count present wins (closest to a real fleet), with
-    proc counts compared numerically. At equal proc count an artifact
-    carrying a per-axis ``axes`` section outranks an axis-blind one
-    (two measured hops price a hierarchical plan better than one
-    blended fit — same spirit as the calib-over-probe rule), then a
-    calib_fit outranks a dcn_probe (the calibrator measured the actual
-    workload's collectives; the probe measured synthetic pings). The
-    returned dict carries the ``axes`` section through when present.
-    Default search dir: benchmarks/results/."""
-    if search_dir is None:
-        repo = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        search_dir = os.path.join(repo, "benchmarks", "results")
-    if nprocs is not None:
-        paths = [os.path.join(search_dir, f"calib_fit_{nprocs}proc.json"),
-                 os.path.join(search_dir, f"dcn_probe_{nprocs}proc.json")]
-    else:
-        paths = sorted(
-            glob.glob(os.path.join(search_dir, "dcn_probe_*proc.json"))
-            + glob.glob(os.path.join(search_dir, "calib_fit_*proc.json")),
-            key=_fit_artifact_key, reverse=True)
-    best_key, best = None, None
-    for path in paths:
-        parsed = _parse_fit_artifact(path)
-        if parsed is None:
-            continue
-        p_key, calib_key, name = _fit_artifact_key(path)
-        key = (p_key, 1 if "axes" in parsed else 0, calib_key, name)
-        if best_key is None or key > best_key:
-            best_key, best = key, parsed
-    return best
+    """Predicted comm_ms: ``comm_model.predict`` under the ledger's
+    names for the slow link's constants (alpha_ms / beta_gbps), with the
+    documented defaults for any the caller leaves out."""
+    return predict(mode, p, n=n, k=k, ici_gbps=ici_gbps,
+                   dcn_gbps=beta_gbps, ici_size=ici_size,
+                   dcn_alpha_ms=alpha_ms, codec=codec, buckets=buckets)
 
 
 def _manifest_params(manifest: Optional[Mapping[str, Any]]
@@ -413,15 +198,16 @@ def ledger_rows(records: Sequence[Mapping[str, Any]],
             p = params["p"]
 
             def _sparse_pred_bytes(k, nn):
-                set_bytes = _codec_set_bytes(params["codec"], k, nn)
+                codec = get_codec(params["codec"])
+                set_bytes = codec.wire_set_bytes(k, nn)
                 if wm == "gtopk_balanced":
                     # comm_bytes_per_step's balanced formula verbatim:
                     # p-1 scatter rounds + a p-slice allgather, one
                     # encoded cap-of-n set each.
-                    return max(1, 2 * p - 1) * _codec_set_bytes(
-                        params["codec"], _balanced_cap(k, p, nn), nn)
+                    return max(1, 2 * p - 1) * codec.wire_set_bytes(
+                        balanced_cap(k, p, nn), nn)
                 if wm in ("gtopk", "gtopk_hier"):
-                    return _tree_rounds_fallback(
+                    return tree_rounds(
                         p if wm == "gtopk"
                         else max(1, p // ici_size)) * set_bytes
                 if wm == "allgather":

@@ -18,8 +18,7 @@ sync the train loop already pays):
     ``compiled.cost_analysis()`` (dict OR list-of-dict across jax
     versions) and ``compiled.memory_analysis()`` (CompiledMemoryStats)
     into flat numeric dicts. ``compiled_flops`` is the ONE code path for
-    XLA flop counts — benchmark.py's MFU consumes it, so bench and obs
-    cannot drift. The peak-HBM estimate is the standard decomposition
+    XLA flop counts. The peak-HBM estimate is the standard decomposition
     arguments + outputs + temps + generated code − aliased bytes.
   * ``CompileWatch`` — tracks a jitted callable's executable-cache size
     (``_cache_size()``; a ``jax.monitoring`` event listener counts
@@ -91,8 +90,8 @@ def cost_summary(compiled) -> Dict[str, float]:
 
 def compiled_flops(compiled) -> Optional[float]:
     """Per-step FLOPs as XLA counts them (cost_analysis), None if
-    absent. The single flop-count code path: benchmark.py's MFU and the
-    "compile" records both read this."""
+    absent. The single flop-count code path: the "compile" records
+    read this."""
     return cost_summary(compiled).get("flops")
 
 

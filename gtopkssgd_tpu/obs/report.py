@@ -1915,7 +1915,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                              "newest dcn_probe artifact, else 25)")
         ap.add_argument("--probe-dir", default=None,
                         help="where to look for dcn_probe_*proc.json "
-                             "(default benchmarks/results/)")
+                             "(default gtopkssgd_tpu/parallel/fits/)")
         ap.add_argument("--json", dest="json_out", default=None)
         a = ap.parse_args(argv[1:])
         return run_ledger(a.targets, json_out=a.json_out,
@@ -1951,7 +1951,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         ap.add_argument("--probe-dir", default=None,
                         help="where to look for fit artifacts when the "
                              "stream has no calib records (default "
-                             "benchmarks/results/)")
+                             "gtopkssgd_tpu/parallel/fits/)")
         ap.add_argument("--json", dest="json_out", default=None)
         a = ap.parse_args(argv[1:])
         return run_forecast(a.targets, json_out=a.json_out,

@@ -3,18 +3,17 @@
 The paper's entire argument is a measured three-term decomposition of the
 step time (arXiv:1901.04359 §5): forward/backward compute, top-k
 selection, and the sparse collective. This module turns a ``jax.profiler``
-chrome trace into that decomposition, promoted out of
-``benchmarks/profile_step.py``'s ad-hoc parser so every consumer (the
-profile tool, the gate smoke, bench.py, the report CLI, tests) shares one
-implementation.
+chrome trace into that decomposition, one implementation for every
+consumer (the calibrator's capture, the gate smoke, the report CLI,
+tests).
 
 Two attribution sources, in preference order:
 
   spans — device-lane events named by the ``Tracer``/``TraceAnnotation``
-      scopes the trainer and benchmark already emit ("train/step",
-      "bench/compress", "bench/comm", ...). On TPU the runtime propagates
-      annotations onto the device lanes, so when enough device time is
-      covered by annotated scopes the named buckets are the ground truth.
+      scopes the trainer emits ("train/step", ...). On TPU the runtime
+      propagates annotations onto the device lanes, so when enough
+      device time is covered by annotated scopes the named buckets are
+      the ground truth.
   ops — fallback op-level classifier over per-op device events: sort /
       top-k → select; all-reduce / all-gather / all-to-all /
       collective-permute / reduce-scatter → comm; everything else
@@ -165,8 +164,7 @@ def lane_index(events: Iterable[dict]) -> Tuple[Dict, Dict]:
 
 
 def device_pids(pnames: Dict) -> set:
-    """Processes that look like accelerator devices (the profile_step
-    heuristic, shared)."""
+    """Processes that look like accelerator devices."""
     return {pid for pid, name in pnames.items()
             if any(t in name.lower()
                    for t in ("tpu", "device", "xla", "/device"))}
@@ -424,83 +422,6 @@ def host_span_means(trace) -> Dict[str, float]:
         if classify_span(name) is not None or "/" in name:
             acc[name].append(float(e.get("dur", 0.0)))
     return {n: sum(v) / len(v) for n, v in acc.items() if v}
-
-
-# ------------------------------------------------- profile_step's ranking
-
-def op_ranking(trace_dir: str, top: int = 40) -> dict:
-    """Aggregate device-lane durations from the chrome trace.
-
-    The op-ranking table benchmarks/profile_step.py has always emitted
-    (moved here verbatim so the profile tool and this module share one
-    parser; its output stays byte-compatible). Lane layout in the
-    2026-07-31 TPU v5 lite captures (device pid's thread names): "Steps"
-    (one event per device program execution), "XLA Modules", "XLA Ops"
-    (per-op detail). In those captures the main shard_map'd train-step
-    module appeared ONLY in the Steps lane, so the op table covered just
-    the small host-built jits; no capture has been taken since."""
-    paths = glob.glob(os.path.join(
-        trace_dir, "**", "*.trace.json.gz"), recursive=True)
-    if not paths:
-        raise SystemExit(f"no trace found under {trace_dir}")
-    path = max(paths, key=os.path.getmtime)
-    with gzip.open(path, "rt") as fh:
-        doc = json.load(fh)
-    events = doc.get("traceEvents", [])
-    pnames = {e.get("pid"): e.get("args", {}).get("name", "")
-              for e in events if e.get("name") == "process_name"}
-    dev_pids = {pid for pid, name in pnames.items()
-                if any(t in name.lower()
-                       for t in ("tpu", "device", "xla", "/device"))}
-    tnames = {(e.get("pid"), e.get("tid")): e.get("args", {}).get("name", "")
-              for e in events if e.get("name") == "thread_name"}
-
-    def lane(e):
-        return tnames.get((e.get("pid"), e.get("tid")), "")
-
-    step_durs, agg, count, cat = [], collections.defaultdict(float), \
-        collections.defaultdict(int), collections.defaultdict(float)
-    for e in events:
-        if e.get("ph") != "X" or e.get("pid") not in dev_pids:
-            continue
-        ln = lane(e)
-        if ln == "Steps":
-            step_durs.append(_event_us(e))
-        elif ln == "XLA Ops":
-            a = e.get("args", {})
-            us = _event_us(e)
-            agg[e.get("name", "?")] += us
-            count[e.get("name", "?")] += 1
-            cat[a.get("hlo_category", "?")] += us
-    op_total = sum(agg.values())
-    step_durs.sort(reverse=True)
-    # Histogram of program executions: the main train step dominates the
-    # tail of repeated near-identical durations.
-    buckets = collections.Counter(round(d / 1000, 1) for d in step_durs)
-    rows = sorted(agg.items(), key=lambda kv: -kv[1])[:top]
-    return {
-        "trace_file": os.path.relpath(path, trace_dir),
-        "steps_lane": {
-            "executions": len(step_durs),
-            "total_device_ms": round(sum(step_durs) / 1000, 1),
-            "largest_ms": [round(d / 1000, 2) for d in step_durs[:10]],
-            "top_duration_ms_histogram": {
-                f"{ms}ms": n for ms, n in buckets.most_common(12)
-            },
-        },
-        "attributed_op_us_total": round(op_total, 1),
-        "attribution_note": (
-            "per-op detail covers only the small helper jits on this "
-            "platform; the train-step module is visible only as Steps-"
-            "lane executions"),
-        "hlo_category_us": {k: round(v, 1) for k, v in
-                            sorted(cat.items(), key=lambda kv: -kv[1])},
-        "top_ops": [
-            {"name": n[:160], "total_us": round(us, 1), "calls": count[n],
-             "pct": round(100 * us / op_total, 2) if op_total else None}
-            for n, us in rows
-        ],
-    }
 
 
 # ---------------------------------------------------------------- capture

@@ -60,7 +60,7 @@ from gtopkssgd_tpu.ops import (
 from gtopkssgd_tpu.parallel import (
     dense_allreduce, get_codec, ici_dense_psum, parse_buckets, plan_buckets,
     resolve_plan, roundtrip_aligned, sparse_allreduce, validate_pin)
-from gtopkssgd_tpu.parallel.bucketing import buckets_key, parse_pipeline
+from gtopkssgd_tpu.parallel.bucketing import parse_pipeline
 
 Array = jax.Array
 ScalarOrSchedule = Union[float, Callable[[Array], Array]]
@@ -360,12 +360,12 @@ def gtopk_sgd(
     # Validate the codec spec at build time (bad --wire-codec fails here,
     # not inside the jitted step); the instance is reused every step.
     codec = get_codec(wire_codec)
-    # Same build-time discipline for the wire plan: a pin that does not
-    # realize this mode fails here. The plan itself is resolved at TRACE
-    # time (resolve_plan below), when the mesh axis size is known — the
-    # planner memoizes per shape, so retracing costs a dict lookup. The
-    # codec's canonical name keys the planner cache (wire_codec may be a
-    # WireCodec instance).
+    # Same build-time discipline for the wire plan: a name that does not
+    # realize this mode fails here. The plan was decided above this
+    # function (Trainer.__init__, parallel.planner.build_decision);
+    # resolve_plan below looks its name up, and 'auto' is the mode's
+    # historical schedule. The codec's canonical name labels the plan
+    # (wire_codec may be a WireCodec instance).
     comm_plan = validate_pin(comm_plan, mode, ici_size=hier_ici_size)
     codec_spec = getattr(codec, "name", "fp32")
     # Same build-time discipline for --buckets: the spec parses (or
@@ -515,14 +515,8 @@ def gtopk_sgd(
         # the concat wire has no stage loop and is serial by
         # construction.
         pipe = bplan.pipeline if bplan is not None else "serial"
-        # Wire plan for this (mode, mesh, n, k, codec) — chosen by the
-        # topology planner unless pinned; None at p=1 (no wire).
-        # Bucketed runs key and score the candidates on the (n_b, k_b)
-        # pairs — B merges each, not one concatenated merge.
-        plan = (resolve_plan(mode, p, n, wire_k_total, codec_spec, 1,
-                             comm_plan, None, buckets_key(bucket_spec),
-                             bplan.pairs() if bplan is not None else None,
-                             pipe)
+        # The wire plan named at build time; None at p=1 (no wire).
+        plan = (resolve_plan(mode, comm_plan, codec=codec_spec)
                 if p > 1 else None)
 
         if correction:
@@ -1037,10 +1031,9 @@ def gtopk_sgd(
                 if audit:
                     btel["recall"] = jnp.float32(-1.0)
         else:
-            # Wire plan for this (mode, mesh, n, k, codec) — chosen by
-            # the topology planner unless pinned; None at p=1 (no wire).
-            plan = (resolve_plan(mode, p, n, compressor.k(n), codec_spec,
-                                 hier_ici_size if hier else 1, comm_plan)
+            # The wire plan named at build time; None at p=1 (no wire).
+            plan = (resolve_plan(mode, comm_plan, codec=codec_spec,
+                                 ici_size=hier_ici_size if hier else 1)
                     if p > 1 else None)
             if correction:
                 # DGC velocity recursion on the LOCAL (or slice-summed, in
@@ -1082,8 +1075,7 @@ def gtopk_sgd(
                     # the flat [N] vector, and that chain is what kept
                     # XLA from fusing selection into the backward
                     # epilogue (fused-step overhead was ~3x the isolated
-                    # compress cost — fused_variants artifact; the
-                    # before/after is in the round-3 bench artifact).
+                    # compress cost — fused_variants artifact).
                     # Masking u at the same keep-mask is exact here:
                     # every local pick is delivered at p=1. The tau
                     # search reads (src, residual_in) unfused so the
@@ -1295,8 +1287,7 @@ def expand_residual_per_device(opt_state: GTopKSGDState, p: int, mesh):
     would materialize the dense [P, N] array on one device first (1.6 GB
     for ResNet-50 x 16 workers), and a jitted zeros-with-out_shardings
     hits a jax sharding-override assertion when the persistent compilation
-    cache is enabled. Shared by the trainer and the benchmark so their
-    measured paths cannot drift.
+    cache is enabled.
     """
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
@@ -1323,47 +1314,3 @@ def expand_residual_per_device(opt_state: GTopKSGDState, p: int, mesh):
 
     return opt_state._replace(
         residual=jax.tree.map(expand, opt_state.residual))
-
-
-def wire_k(
-    compression: Optional[str],
-    density: float,
-    n: int,
-    leaf_sizes: Optional[tuple] = None,
-) -> int:
-    """Elements actually COMMUNICATED per device per step (n for dense).
-
-    Flat sparse modes send k = ceil(rho*N). LAYERWISE_MODES send the
-    concatenation of per-leaf selections, k_total = sum_l ceil(rho*n_l),
-    which per-leaf ceil rounding can push SEVERALFOLD above ceil(rho*N)
-    at low densities (ResNet-20 at rho=0.001 has dozens of
-    sub-1000-element BN/bias leaves, each forced to k_l >= 1). Layerwise
-    therefore REQUIRES ``leaf_sizes`` (e.g. ``[p.size for p in
-    jax.tree.leaves(params)]``); calling without them raises instead of
-    silently underestimating. Single source of the wire-K definition —
-    the benchmark comm model and effective_density both derive from it."""
-    if compression in DENSE_MODES:
-        return n
-    if compression in LAYERWISE_MODES:
-        if not leaf_sizes:
-            raise ValueError(
-                "wire_k/effective_density for layerwise modes needs "
-                "leaf_sizes: per-leaf ceil rounding makes the communicated "
-                "set sum(ceil(rho*n_l)), not ceil(rho*N)")
-        return sum(k_for_density(int(s), density) for s in leaf_sizes)
-    return k_for_density(n, density)
-
-
-def effective_density(
-    compression: Optional[str],
-    density: float,
-    leaf_sizes: Optional[tuple] = None,
-) -> float:
-    """Density actually communicated (1.0 for the dense baseline) —
-    ``wire_k / N``; see wire_k for the layerwise leaf_sizes requirement."""
-    if compression in DENSE_MODES:
-        return 1.0
-    if compression in LAYERWISE_MODES:
-        n = sum(int(s) for s in leaf_sizes) if leaf_sizes else 0
-        return wire_k(compression, density, n, leaf_sizes) / n
-    return density

@@ -1,6 +1,6 @@
 """Byte-balanced gradient bucketing for the layerwise path.
 
-The committed DCN probe fit (benchmarks/results/dcn_probe_*.json) puts
+The committed DCN probe fit (parallel/fits/dcn_probe_*.json) puts
 alpha — the per-collective latency term — at ~22 ms, three orders of
 magnitude above the per-byte term at realistic rho. Any schedule that
 issues one sparse merge per leaf therefore pays L alpha terms per step
@@ -481,8 +481,7 @@ def plan_buckets(leaf_sizes: Sequence[int], density: float, *,
     # still does; only fetch probe inputs when something will use them.
     needs_pricing = spec != "leaf" or pipe == "auto"
     if needs_pricing and (alpha_ms is None or beta_gbps is None):
-        # Late import: planner imports ledger, and pulling it at module
-        # import time would cycle through parallel/__init__.
+        # Late import: the planner imports this module.
         from .planner import planner_inputs
         inputs = planner_inputs(probe_dir)
         alpha_ms = inputs["alpha_ms"] if alpha_ms is None else alpha_ms

@@ -7,11 +7,13 @@ dispatch table. The planner splits those concerns. A mode fixes the
 SEMANTICS (what sparse set is applied, what repair contract the
 optimizer gets); a :class:`CommPlan` fixes the WIRE — per-axis
 algorithm, schedule, codec, and ici/dcn split — and is chosen ONCE at
-startup by scoring every semantics-preserving candidate with the same
-alpha-beta model the comm ledger audits against
-(``benchmarks/scaling_model.predict`` via ``obs.ledger.predict_comm_ms``,
-parameterized from a ``dcn_probe`` ``alpha_beta_fit`` artifact when one
-is present, pure alpha-beta fallback otherwise).
+startup (``Trainer.__init__`` -> :func:`build_decision`) by scoring
+every semantics-preserving candidate with the same alpha-beta model the
+comm ledger audits against (``comm_model.predict``, parameterized from
+a ``dcn_probe`` / ``calib_fit`` ``alpha_beta_fit`` artifact when one is
+present, documented defaults otherwise). The compiled step is handed
+the chosen plan's NAME and looks it up (:func:`resolve_plan`): tracing
+scores nothing and reads no file.
 
 Candidate sets are deliberately semantics-preserving: the planner never
 swaps gtopk for allgather behind the user's back — it only picks among
@@ -24,17 +26,13 @@ the full decision — chosen plan plus the score of every candidate — is
 logged as a ``"plan"`` metrics record and stamped into the run manifest,
 so every ledger row can be traced back to why its schedule won.
 
-Import discipline: scoring needs obs.ledger, and obs imports parallel —
-so the ledger import is lazy (inside functions), keeping
-``parallel.planner`` importable from ``parallel/__init__`` without a
-cycle. Collectives never import the planner: ``sparse_allreduce`` takes
-the plan duck-typed (anything with ``.schedule``).
+Collectives never import the planner: ``sparse_allreduce`` takes the
+plan duck-typed (anything with ``.schedule``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 from gtopkssgd_tpu.modes import (
@@ -45,13 +43,18 @@ from gtopkssgd_tpu.modes import (
     LAYERWISE_MODES,
     default_schedule,
 )
-from gtopkssgd_tpu.parallel.collectives import (
-    balanced_cap,
-    comm_bytes_per_step,
+from gtopkssgd_tpu.parallel import bucketing as _bucketing
+from gtopkssgd_tpu.parallel.collectives import comm_bytes_per_step
+from gtopkssgd_tpu.parallel.comm_model import (
+    DEFAULT_DCN_GBPS,
+    DEFAULT_ICI_GBPS,
+    load_alpha_beta,
+    predict,
+    wire_mode_for,
 )
 
-# Per-message slow-link latency assumed when NO dcn_probe artifact is
-# available (benchmarks/results/dcn_probe_*proc.json). Deliberately
+# Per-message slow-link latency assumed when NO fit artifact is
+# available (comm_model.FIT_DIR's dcn_probe_*proc.json). Deliberately
 # nonzero: the degenerate alpha=0 bandwidth-only model would let any
 # many-small-messages schedule (balanced sends O(p) messages where the
 # tree sends O(log p)) win on volume alone and silently change the wire
@@ -91,9 +94,8 @@ class CommPlan:
 
     @property
     def wire_mode(self) -> str:
-        """Comm-model key (scaling_model.predict / ledger) this plan
+        """Comm-model key (comm_model.predict / ledger) this plan
         prices as — the single mapping shared with the ledger."""
-        from gtopkssgd_tpu.obs.ledger import wire_mode_for
         return wire_mode_for(self.mode, self.schedule,
                              bucketing=self.bucketing)
 
@@ -150,19 +152,18 @@ def planner_inputs(probe_dir: Optional[str] = None) -> Dict[str, Any]:
     """The alpha-beta constants the planner scores with, plus where they
     came from: the newest fit artifact (dcn_probe / calib_fit) when one
     exists, else documented fallback defaults (PLANNER_DEFAULT_ALPHA_MS
-    + the scaling model's DCN bandwidth).
+    + the comm model's DCN bandwidth).
 
     An artifact carrying a per-axis ``axes`` section prices each hop
     from its OWN measured fit: the "dcn" entry overrides the blended
     slow-link alpha/beta, and the "ici" entry's bandwidth replaces the
     DEFAULT_ICI_GBPS guess — so a hierarchical plan's two hops are
     scored from two measured links, with no caller change needed."""
-    from gtopkssgd_tpu.obs import ledger
-    fit = ledger.load_alpha_beta(search_dir=probe_dir)
+    fit = load_alpha_beta(search_dir=probe_dir)
     if fit is not None:
         out = {"alpha_ms": fit["alpha_ms"],
                "beta_gbps": fit["beta_gbps"],
-               "ici_gbps": ledger.DEFAULT_ICI_GBPS,
+               "ici_gbps": DEFAULT_ICI_GBPS,
                "fit_source": fit["source"]}
         # Theil-Sen residual noise floor, when the artifact records one
         # (calib_fit does; probe-era artifacts don't). The forecast
@@ -185,8 +186,8 @@ def planner_inputs(probe_dir: Optional[str] = None) -> Dict[str, Any]:
                            for name, ax in sorted(axes.items())}
         return out
     return {"alpha_ms": PLANNER_DEFAULT_ALPHA_MS,
-            "beta_gbps": ledger.DEFAULT_DCN_GBPS,
-            "ici_gbps": ledger.DEFAULT_ICI_GBPS,
+            "beta_gbps": DEFAULT_DCN_GBPS,
+            "ici_gbps": DEFAULT_ICI_GBPS,
             "fit_source": "fallback-defaults"}
 
 
@@ -194,16 +195,14 @@ def score_plan(plan: CommPlan, p: int, *, n: int, k: int,
                alpha_ms: float, beta_gbps: float, ici_gbps: float,
                buckets: Optional[Tuple[Tuple[int, int], ...]] = None
                ) -> float:
-    """Predicted comm_ms of one candidate — scaling_model.predict when
-    benchmarks/ is present, the ledger's pure alpha-beta model
-    otherwise. The same number the ledger later audits against measured
-    T_comm, so a plan decision is always reconcilable post-hoc.
-    ``buckets`` (the BucketPlan's ((n_b, k_b), ...) pairs) prices the
-    bucketed wire as B independent merges."""
-    from gtopkssgd_tpu.obs.ledger import predict_comm_ms
-    return predict_comm_ms(
-        plan.wire_mode, p, n=n, k=k, alpha_ms=alpha_ms,
-        beta_gbps=beta_gbps, ici_gbps=ici_gbps,
+    """Predicted comm_ms of one candidate (comm_model.predict). The
+    same number the ledger later audits against measured T_comm, so a
+    plan decision is always reconcilable post-hoc. ``buckets`` (the
+    BucketPlan's ((n_b, k_b), ...) pairs) prices the bucketed wire as B
+    independent merges."""
+    return predict(
+        plan.wire_mode, p, n=n, k=k, dcn_alpha_ms=alpha_ms,
+        dcn_gbps=beta_gbps, ici_gbps=ici_gbps,
         ici_size=plan.ici_size, codec=plan.codec, buckets=buckets)
 
 
@@ -282,7 +281,6 @@ def build_decision(mode: Optional[str], *, p: int, n: int, k: int,
     # one bucket of the full (n, k) — both execution orders then expose
     # the same span (a B=1 pipeline has nothing to overlap), which is
     # exactly the honest answer for that wire.
-    from gtopkssgd_tpu.parallel import bucketing as _bucketing
     span_pairs = buckets if buckets else ((n, k),)
     span_plan = _bucketing.BucketPlan(
         boundaries=tuple(range(len(span_pairs) + 1)),
@@ -328,21 +326,13 @@ def build_decision(mode: Optional[str], *, p: int, n: int, k: int,
                         inputs=inputs, pin=pin)
 
 
-@functools.lru_cache(maxsize=None)
-def resolve_plan(mode: Optional[str], p: int, n: int, k: int,
-                 codec: str = "fp32", ici_size: int = 1,
-                 pin: Optional[str] = "auto",
-                 probe_dir: Optional[str] = None,
-                 bucketing: str = "concat",
-                 buckets: Optional[Tuple[Tuple[int, int], ...]] = None,
-                 pipeline: str = "serial") -> CommPlan:
-    """The optimizer's trace-time entry point: (mode, mesh, n, k, codec,
-    pin) -> CommPlan, memoized — the decision is made once per distinct
-    shape, never per step, and retracing costs a dict lookup. The
-    bucketing key, (n_b, k_b) pairs, and resolved pipeline are part of
-    the memo key, so a bucketed and an unbucketed run of the same shape
-    resolve independently."""
-    return build_decision(mode, p=p, n=n, k=k, codec=codec,
-                          ici_size=ici_size, pin=pin,
-                          probe_dir=probe_dir, bucketing=bucketing,
-                          buckets=buckets, pipeline=pipeline).plan
+def resolve_plan(mode: Optional[str], name: Optional[str] = "auto", *,
+                 codec: str = "fp32", ici_size: int = 1) -> CommPlan:
+    """The optimizer's trace-time entry point: the candidate of ``mode``
+    called ``name``. ``Trainer`` passes the name its one
+    :func:`build_decision` chose; 'auto' (an optimizer built with no
+    ``Trainer`` above it) is the historical default, the first
+    candidate. Nothing is scored and no file is read here."""
+    name = validate_pin(name, mode, ici_size=ici_size)
+    cands = candidate_plans(mode, codec=codec, ici_size=ici_size)
+    return next((c for c in cands if c.name == name), cands[0])

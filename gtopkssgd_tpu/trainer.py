@@ -682,26 +682,19 @@ class Trainer:
         # Explicit comm-model fit (--comm-model-fit): loaded once here —
         # a malformed artifact fails at startup, not mid-run. It prices
         # the plan decision below and its filename is stamped as fit
-        # provenance; _comm_plan_pin later pins the optimizer's
-        # trace-time resolve_plan to the decision it produced.
+        # provenance.
         self._comm_fit = None
-        self._comm_plan_pin = None
         if cfg.comm_model_fit:
             from gtopkssgd_tpu.obs.calib import load_fit_file
             self._comm_fit = load_fit_file(cfg.comm_model_fit)
-        self.tx = self._make_tx()
-        self.state, self.carry = self._init_state()
-        # Layer-name column for "layers" records: index i of every
-        # telemetry [L] array is leaf i of the params pytree in jax.tree
-        # flatten order — the same order the optimizer's segment map uses.
-        self._layer_names = (
-            layer_names(self.state.params) if cfg.obs_layers else ())
-        # Wire-plan decision (parallel.planner): resolved once here with
-        # the same inputs the optimizer's trace-time resolve_plan sees,
-        # logged as the "plan" record (chosen plan + every candidate's
-        # score) and stamped into the manifest so the ledger prices the
-        # schedule that actually ran. Dense / single-device runs have no
-        # sparse wire to plan.
+        params, batch_stats = self._init_params()
+        # Wire-plan decision (parallel.planner): made once, here, from
+        # the parameter count, before the optimizer exists; _make_tx
+        # hands the chosen plan's name to gtopk_sgd, whose traced step
+        # looks it up. Logged as the "plan" record (chosen plan + every
+        # candidate's score) and stamped into the manifest so the ledger
+        # prices the schedule that ran. Dense / single-device runs have
+        # no sparse wire to plan.
         self._plan_decision = None
         # Bucket plan (parallel.bucketing): resolved host-side from the
         # SAME leaf sizes the optimizer's trace-time plan_buckets sees
@@ -713,7 +706,7 @@ class Trainer:
             if parse_buckets(cfg.buckets) != "concat":
                 leaf_sizes = tuple(
                     int(leaf.size)
-                    for leaf in jax.tree_util.tree_leaves(self.state.params))
+                    for leaf in jax.tree_util.tree_leaves(params))
                 self._bucket_plan = plan_buckets(
                     leaf_sizes, cfg.density, buckets=cfg.buckets,
                     p=self.p, codec=cfg.wire_codec,
@@ -738,15 +731,13 @@ class Trainer:
                 pipeline=(bplan.pipeline if bplan is not None
                           else "serial"),
                 **fit_kw)
-        if (self._comm_fit is not None and self._plan_decision is not None
-                and self._plan_decision.pin == "auto"):
-            # The optimizer's trace-time resolve_plan only sees the
-            # default probe dir; pin it to the decision the explicit fit
-            # priced, or the wire that runs could disagree with the plan
-            # that was recorded. Same state treedef — comm_plan never
-            # shapes opt state — so the rebuilt tx drops in.
-            self._comm_plan_pin = self._plan_decision.plan.name
-            self.tx = self._make_tx()
+        self.tx = self._make_tx()
+        self.state, self.carry = self._init_state(params, batch_stats)
+        # Layer-name column for "layers" records: index i of every
+        # telemetry [L] array is leaf i of the params pytree in jax.tree
+        # flatten order — the same order the optimizer's segment map uses.
+        self._layer_names = (
+            layer_names(self.state.params) if cfg.obs_layers else ())
         plan_extra = {}
         if self._plan_decision is not None:
             d = self._plan_decision
@@ -1107,7 +1098,9 @@ class Trainer:
             density=cfg.density,
             topk_method=cfg.topk_method,
             wire_codec=cfg.wire_codec,
-            comm_plan=self._comm_plan_pin or cfg.comm_plan,
+            comm_plan=(self._plan_decision.plan.name
+                       if self._plan_decision is not None
+                       else cfg.comm_plan),
             buckets=cfg.buckets,
             pipeline=cfg.pipeline,
             clip_grad_norm=cfg.clip_grad_norm,
@@ -1342,7 +1335,9 @@ class Trainer:
         return base
 
     # ---------------------------------------------------------------- state
-    def _init_state(self) -> Tuple[TrainState, Any]:
+    def _init_params(self):
+        """(params, batch_stats) of the freshly initialised model; sets
+        ``num_params``, which the wire plan is decided from."""
         cfg = self.cfg
         rng = jax.random.PRNGKey(cfg.seed)
         batch = self._peek_batch()
@@ -1354,7 +1349,16 @@ class Trainer:
             lambda key, x: self.model.init({"params": key, "dropout": key}, x)
         )(rng, x)
         params = variables["params"]
-        batch_stats = variables.get("batch_stats", {})
+        n = sum(x.size for x in jax.tree.leaves(params))
+        self.num_params = n
+        self.logger.info(
+            "model=%s dataset=%s params=%.3fM workers=%d compression=%s density=%g",
+            cfg.dnn, cfg.dataset, n / 1e6, cfg.nworkers,
+            cfg.compression, cfg.density,
+        )
+        return params, variables.get("batch_stats", {})
+
+    def _init_state(self, params, batch_stats) -> Tuple[TrainState, Any]:
         opt_state = jax.jit(self.tx.init)(params)
         if self.p > 1:
             # The error-feedback residual is genuinely PER-DEVICE state (it
@@ -1364,13 +1368,6 @@ class Trainer:
             # Checkpointing then captures every device's residual, not just
             # device 0's.
             opt_state = expand_residual_per_device(opt_state, self.p, self.mesh)
-        n = sum(x.size for x in jax.tree.leaves(params))
-        self.num_params = n
-        self.logger.info(
-            "model=%s dataset=%s params=%.3fM workers=%d compression=%s density=%g",
-            cfg.dnn, cfg.dataset, n / 1e6, cfg.nworkers,
-            cfg.compression, cfg.density,
-        )
         state = TrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,

@@ -7,11 +7,11 @@ paper's own analysis axis. Here the same split, plus `jax.block_until_ready`
 fencing so the async dispatch queue doesn't fold every phase into the last.
 
 For phases fused inside one jitted step (the production path — XLA overlaps
-comm and compute, so a host-side timer *cannot* see them separately), use
-the benchmark harness's segmented mode which jits each phase apart; this
-timer then reports whole-step time under 'step'.
+comm and compute, so a host-side timer *cannot* see them separately), read
+the stages off a device trace by their named scopes (PERF.md section 3);
+this timer then reports whole-step time under 'step'.
 
-Instrumentation call sites (trainer/benchmark phase timing) live in
+Instrumentation call sites (the trainer's phase timing) live in
 ``gtopkssgd_tpu.obs.tracing.Tracer``, which builds on TimingStats and adds
 nested span paths plus ``jax.profiler.TraceAnnotation`` scopes; StepTimer
 stays as the minimal primitive for harness-internal timing.

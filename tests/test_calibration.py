@@ -22,7 +22,8 @@ from gtopkssgd_tpu.obs.calib import (
     message_count,
 )
 from gtopkssgd_tpu.obs.events import AnomalyHalt, AnomalyMonitor, Thresholds
-from gtopkssgd_tpu.obs.ledger import _tree_rounds_fallback, load_alpha_beta
+from gtopkssgd_tpu.obs.ledger import load_alpha_beta
+from gtopkssgd_tpu.parallel import tree_rounds
 from gtopkssgd_tpu.obs import registry as obs_registry
 from gtopkssgd_tpu.obs import report as obs_report
 from gtopkssgd_tpu.utils.metrics import MetricsLogger
@@ -55,9 +56,9 @@ def test_message_count_matches_ledger_decomposition():
     assert message_count("dense", 4) == 6            # 2(p-1)
     assert message_count("gtopk_balanced", 4) == 6   # 2(p-1)
     assert message_count("allgather", 4) == 3        # p-1
-    assert message_count("gtopk", 8) == _tree_rounds_fallback(8)
+    assert message_count("gtopk", 8) == tree_rounds(8)
     assert message_count("gtopk_hier", 8, ici_size=4) == \
-        _tree_rounds_fallback(2)
+        tree_rounds(2)
     assert message_count("gtopk", 1) == 0            # nothing on the wire
 
 
@@ -262,11 +263,10 @@ def test_calib_artifact_flips_planner_schedule(tmp_path):
     assert calibrated.inputs["fit_source"] == "calib_fit_32proc.json"
     assert calibrated.inputs["alpha_ms"] == pytest.approx(0.1, rel=0.05)
     assert calibrated.plan.name == "balanced"
-    # the optimizer's memoized trace-time entry point flips identically
-    # (fresh tmp dirs -> distinct lru_cache keys)
-    plan = resolve_plan("gtopk", shape["p"], shape["n"], shape["k"],
-                        "fp32", 1, "auto", d)
-    assert plan.name == "balanced"
+    # the optimizer is handed the decision's name, and its trace-time
+    # lookup returns the schedule that was decided
+    assert resolve_plan("gtopk", calibrated.plan.name).schedule == "balanced"
+    assert resolve_plan("gtopk", committed.plan.name).schedule == "tree"
 
 
 def test_load_alpha_beta_numeric_proc_sort(tmp_path):
@@ -545,8 +545,8 @@ def test_trainer_calibrates_and_writes_artifact(tmp_path):
 
 def test_trainer_comm_model_fit_flag(tmp_path):
     """--comm-model-fit: an explicit artifact prices the plan decision,
-    its filename lands in manifest + plan record, and the decided
-    schedule is pinned through to the optimizer."""
+    and its filename lands in manifest + plan record (that the step
+    runs the decided plan: test_planner's decides_once test)."""
     from gtopkssgd_tpu.trainer import TrainConfig, Trainer
 
     fit_path = str(tmp_path / "calib_fit_2proc.json")
@@ -563,7 +563,6 @@ def test_trainer_comm_model_fit_flag(tmp_path):
         d = t._plan_decision
         assert d.inputs["fit_source"] == "calib_fit_2proc.json"
         assert d.inputs["alpha_ms"] == pytest.approx(7.25)
-        assert t._comm_plan_pin == d.plan.name
     recs = [json.loads(l) for l in open(os.path.join(out, "metrics.jsonl"))]
     man = next(r for r in recs if r["kind"] == "manifest")
     assert man["comm_fit_source"] == "calib_fit_2proc.json"

@@ -285,31 +285,3 @@ def test_layerwise_trainer_checkpoint_roundtrip(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     t2.train(2)
     assert int(t2.state.step) == 7
-
-
-def test_layerwise_breakdown_has_phase_evidence():
-    """Round-2 verdict weak #7: measure_breakdown used to REFUSE layerwise
-    modes, leaving the perf-thesis mode with no phase-level evidence path.
-    It now returns the per-phase split (fwd+bwd / per-leaf compress / comm
-    / apply) with the layerwise wire K; structural check on the CI mesh —
-    the committed artifact comes from the chip."""
-    from gtopkssgd_tpu.benchmark import BenchConfig, measure_breakdown
-    from gtopkssgd_tpu.ops import k_for_density
-
-    cfg = BenchConfig(dnn="resnet20", batch_size=4, steps=2,
-                      dtype="float32", nworkers=8)
-    res = measure_breakdown(cfg, "gtopk_layerwise", 0.01)
-    for phase in ("forward_backward", "compress_per_leaf", "comm", "apply"):
-        assert res[phase] > 0.0, res
-    assert res["sum"] >= max(res["forward_backward"], res["comm"])
-    import jax
-    import jax.numpy as jnp
-
-    from gtopkssgd_tpu.models import get_model
-
-    model, _ = get_model("resnet20", dtype=jnp.float32)
-    params = model.init({"params": jax.random.PRNGKey(0)},
-                        jnp.zeros((1, 32, 32, 3)))["params"]
-    expect_k = sum(k_for_density(int(a.size), 0.01)
-                   for a in jax.tree.leaves(params))
-    assert res["k_total"] == expect_k

@@ -51,7 +51,7 @@ def _records(out_dir):
 def test_extraction_roundtrip_on_jitted_step():
     """cost/memory summaries off a real compiled executable: identifier-
     safe keys, the peak-HBM decomposition identity, and compiled_flops
-    as the one flop path (benchmark.py aliases it for MFU)."""
+    as the one flop path."""
     x = jnp.arange(16, dtype=jnp.float32)
     compiled = jax.jit(lambda v: (v * 2.0 + 1.0).sum()).lower(x).compile()
     cost = cost_summary(compiled)

@@ -30,7 +30,6 @@ from gtopkssgd_tpu.obs.trace_attr import (
     find_trace_file,
     format_attr,
     host_span_means,
-    op_ranking,
     overlap_fraction,
     self_durations_us,
 )
@@ -143,16 +142,6 @@ def test_find_trace_file_resolution(tmp_path):
     assert find_trace_file(str(tmp_path)) == str(target)
     with pytest.raises(FileNotFoundError):
         find_trace_file(str(tmp_path / "empty"))
-
-
-def test_op_ranking_shared_parser(tmp_path):
-    rank = op_ranking(os.path.dirname(FIXTURE))
-    for key in ("trace_file", "steps_lane", "attributed_op_us_total",
-                "hlo_category_us", "top_ops"):
-        assert key in rank
-    assert rank["steps_lane"]["executions"] >= 0
-    with pytest.raises(SystemExit):
-        op_ranking(str(tmp_path))            # no trace -> usage error
 
 
 # ------------------------------------------------- synthetic source choice

@@ -432,39 +432,76 @@ def test_ledger_without_manifest_returns_empty():
     assert ledger.ledger_rows([{"kind": "attr", "t_comm_us": 10.0}]) == []
 
 
-def test_ledger_uses_scaling_model_when_present():
-    """In this repo benchmarks/scaling_model.py exists, so predict_comm_ms
-    must agree with scaling_model.predict exactly (the fallback is for
-    installed-package use only)."""
-    import math
+# Hand-counted comm model at n=10000, k=100 (fp32: one k-set is 800 B,
+# the dense buffer 40000 B), 2-chip slices, alpha 0.3 ms a DCN message.
+# DCN at 12 Gbit/s moves a byte in 1/1.5e6 ms, ICI at 800 in 1e-8 ms.
+_DCN, _ICI, _A = 1 / 1.5e6, 1e-8, 0.3
+_PREDICT_BY_HAND = {
+    # p=1: no wire but the modes that hand their own set around on ICI.
+    # p=4 is 2 slices, p=6 is 3 (ragged: fold + unfold ride DCN).
+    "dense": {1: 0.0,
+              # ring in the slice, ring over 2 slices, 2 DCN messages
+              4: 40000 * _ICI + 40000 * _DCN + 2 * _A,
+              # ring over 3 slices moves 2*(2/3) of the buffer, 4 messages
+              6: 40000 * _ICI + (4 / 3) * 40000 * _DCN + 4 * _A},
+    "gtopk": {1: 0.0,
+              # 2 rounds: 1 inside the slice, 1 across
+              4: 800 * _ICI + (800 * _DCN + _A),
+              # 4 rounds (2 + fold + unfold): 1 inside, 3 across
+              6: 800 * _ICI + 3 * (800 * _DCN + _A)},
+    "gtopk_balanced": {
+        1: 800 * _ICI,                       # cap = k, its own share
+        # cap = ceil(1.5 * 100 / 4) = 38 -> 304 B; 3 ICI hops, 4 DCN
+        4: 3 * 304 * _ICI + 4 * (304 * _DCN + _A),
+        # cap = ceil(1.5 * 100 / 6) = 25 -> 200 B; 3 ICI hops, 8 DCN
+        6: 3 * 200 * _ICI + 8 * (200 * _DCN + _A)},
+    "allgather": {1: 800 * _ICI,
+                  4: 2 * 800 * _ICI + 2 * 800 * _DCN + _A,
+                  6: 2 * 800 * _ICI + 4 * 800 * _DCN + 2 * _A},
+    "gtopk_hier": {1: 0.0,
+                   # dense ring in the slice, then the tree over slices:
+                   # 1 round over 2 slices, 3 over 3 (1 + fold + unfold)
+                   4: 40000 * _ICI + (800 * _DCN + _A),
+                   6: 40000 * _ICI + 3 * (800 * _DCN + _A)},
+}
 
+
+@pytest.mark.parametrize("wire_mode", sorted(_PREDICT_BY_HAND))
+def test_comm_model_predict_matches_hand_count(wire_mode):
+    """The one comm model (parallel/comm_model.predict) against values
+    counted by hand, and its two other names against it: the ledger's
+    predict_comm_ms (alpha_ms / beta_gbps) and, for the tree, the
+    scaling script's project() column."""
     from gtopkssgd_tpu.obs import ledger
+    from gtopkssgd_tpu.parallel.comm_model import predict
+    from tests.conftest import load_benchmark_module
 
-    sys.path.insert(0, REPO)
-    try:
-        from benchmarks import scaling_model
-    finally:
-        sys.path.remove(REPO)
-    for mode in ("dense", "gtopk", "allgather", "gtopk_hier",
-                 "gtopk_layerwise"):
-        for p in (1, 2, 4, 6, 8):
-            got = ledger.predict_comm_ms(mode, p, n=10000, k=100,
-                                         alpha_ms=0.3, beta_gbps=12.0,
-                                         ici_gbps=800.0, ici_size=2)
-            want = scaling_model.predict(mode, p, n=10000, k=100,
-                                         dcn_alpha_ms=0.3,
-                                         dcn_gbps=12.0, ici_gbps=800.0,
-                                         ici_size=2)
-            assert got == pytest.approx(want), (mode, p)
-    # project()'s comm_ms column is predict() rounded — no drift.
-    proj = scaling_model.project(
-        "gtopk", 8, n=10000, k=100, compute_ms=5.0, overhead_ms=1.0,
-        ici_gbps=800.0, dcn_gbps=12.0, ici_size=2, batch=4,
-        dcn_alpha_ms=0.3)
-    want = scaling_model.predict("gtopk", 8, n=10000, k=100,
-                                 ici_gbps=800.0, dcn_gbps=12.0,
-                                 ici_size=2, dcn_alpha_ms=0.3)
-    assert proj["comm_ms"] == round(want, 3)
+    for p, want in _PREDICT_BY_HAND[wire_mode].items():
+        got = predict(wire_mode, p, n=10000, k=100, dcn_alpha_ms=0.3,
+                      dcn_gbps=12.0, ici_gbps=800.0, ici_size=2)
+        assert got == pytest.approx(want, rel=1e-9, abs=1e-15), p
+        assert ledger.predict_comm_ms(
+            wire_mode, p, n=10000, k=100, alpha_ms=0.3, beta_gbps=12.0,
+            ici_gbps=800.0, ici_size=2) == got
+    if wire_mode == "gtopk":
+        # the layerwise mode ships the flat tree's wire ...
+        assert predict("gtopk_layerwise", 6, n=10000, k=100,
+                       dcn_alpha_ms=0.3, dcn_gbps=12.0, ici_gbps=800.0,
+                       ici_size=2) == got
+        # ... B buckets are B merges, summed ...
+        assert ledger.predict_comm_ms(
+            "gtopk", 6, n=20000, k=200, alpha_ms=0.3, beta_gbps=12.0,
+            ici_gbps=800.0, ici_size=2,
+            buckets=((10000, 100), (10000, 100))
+        ) == pytest.approx(2 * got)
+        # ... and project()'s comm_ms column is predict() rounded.
+        scaling_model = load_benchmark_module("scaling_model")
+        assert scaling_model.predict is predict
+        proj = scaling_model.project(
+            "gtopk", 6, n=10000, k=100, compute_ms=5.0, overhead_ms=1.0,
+            ici_gbps=800.0, dcn_gbps=12.0, ici_size=2, batch=4,
+            dcn_alpha_ms=0.3)
+        assert proj["comm_ms"] == round(got, 3)
 
 
 def test_report_ledger_cli(tmp_path, capsys):

@@ -239,25 +239,3 @@ def test_dense_warmup_hier_matches_dense_scale():
     pd, _ = _spmd_step(tx_d, mesh)(params, sd, grads)
     np.testing.assert_allclose(np.asarray(ph["w"]), np.asarray(pd["w"]),
                                rtol=1e-5, atol=1e-6)
-
-
-def test_effective_density_layerwise_counts_per_leaf_ceil():
-    """effective_density must report the COMMUNICATED density: for
-    layerwise modes per-leaf ceil rounding (k_l = ceil(rho*n_l) >= 1)
-    pushes it well above rho whenever small leaves exist, and calling
-    without leaf sizes raises instead of silently underestimating."""
-    import pytest
-
-    from gtopkssgd_tpu.optimizer import effective_density
-
-    assert effective_density("dense", 0.001) == 1.0
-    assert effective_density("gtopk", 0.001) == 0.001
-    # 3 leaves of 10 elements at rho=0.001: k_l = 1 each -> 3/30 = 0.1,
-    # a 100x blow-up over the flat rho.
-    d = effective_density("gtopk_layerwise", 0.001, leaf_sizes=(10, 10, 10))
-    np.testing.assert_allclose(d, 0.1)
-    # one big leaf dominates: sum(ceil) ~ rho*N and the blow-up vanishes
-    d = effective_density("gtopk_layerwise", 0.001, leaf_sizes=(100_000,))
-    np.testing.assert_allclose(d, 0.001)
-    with pytest.raises(ValueError, match="leaf_sizes"):
-        effective_density("gtopk_layerwise", 0.001)

@@ -290,24 +290,84 @@ def test_planner_carries_pipeline_and_span_columns():
     assert dense.pipeline == "serial"
 
 
-def test_resolve_plan_memo_keys_on_pipeline():
-    buckets = ((5_000, 50), (5_000, 50))
-    a = resolve_plan("gtopk_layerwise", 8, 10_000, 100, "fp32", 1,
-                     "auto", None, "b2", buckets, "serial")
-    b = resolve_plan("gtopk_layerwise", 8, 10_000, 100, "fp32", 1,
-                     "auto", None, "b2", buckets, "overlap")
-    assert a is not b
-    assert a.pipeline == "serial" and b.pipeline == "overlap"
-    assert a.schedule == b.schedule            # order, not wire choice
-    assert resolve_plan("gtopk_layerwise", 8, 10_000, 100, "fp32", 1,
-                        "auto", None, "b2", buckets, "serial") is a
+def test_resolve_plan_looks_the_name_up():
+    """The optimizer's trace-time entry point is a lookup among the
+    mode's candidates: the name Trainer's one decision chose comes back
+    as that candidate, labelled with the codec and ici width asked for."""
+    for name in ("tree", "balanced"):
+        plan = resolve_plan("gtopk_layerwise", name, codec="int8:64")
+        assert (plan.name, plan.schedule, plan.codec) == (
+            name, name, "int8:64")
+    hier = resolve_plan("gtopk_hier", "hier", ici_size=4)
+    assert (hier.schedule, hier.intra, hier.ici_size) == ("tree", "psum", 4)
+    with pytest.raises(ValueError, match="does not realize"):
+        resolve_plan("allgather", "balanced")
 
 
-def test_resolve_plan_memoizes():
-    a = resolve_plan("gtopk", 8, 10_000, 100)
-    b = resolve_plan("gtopk", 8, 10_000, 100)
-    assert a is b
-    assert a.schedule == "tree"
+def test_resolve_plan_auto_is_the_historical_schedule(tmp_path,
+                                                      monkeypatch):
+    """'auto' reaching the optimizer with no Trainer above it is the
+    mode's hand-picked schedule whatever any fit would price: it scores
+    nothing, so a fast-fabric fit in the default directory that flips
+    build_decision to 'balanced' leaves it alone."""
+    from gtopkssgd_tpu.parallel import comm_model
+
+    with open(tmp_path / "calib_fit_32proc.json", "w") as fh:
+        fh.write('{"alpha_beta_fit": {"alpha_ms": 0.0, "beta_gbps": 0.6}}')
+    monkeypatch.setattr(comm_model, "FIT_DIR", str(tmp_path))
+    shape = dict(p=32, n=25_557_032, k=255_571)
+    assert build_decision("gtopk", **shape).plan.name == "balanced"
+    for mode in ("gtopk", "gtopk_layerwise", "gtopk_hier", "allgather",
+                 "dense"):
+        assert (resolve_plan(mode, "auto").schedule
+                == resolve_plan(mode, None).schedule
+                == default_schedule(mode))
+
+
+@pytest.mark.parametrize("fit", ["no_fit", "fast_fit"])
+@pytest.mark.parametrize("pin", ["auto", "tree", "balanced"])
+def test_trainer_decides_once_and_the_step_runs_that_plan(
+        tmp_path, monkeypatch, pin, fit):
+    """One decision, one optimizer: Trainer.__init__ scores the
+    candidates host-side, builds tx once with the chosen plan's name,
+    and the schedule in the lowered step is the one the manifest says
+    ran — also when an explicit fast-fabric fit flips 'auto' away from
+    the historical tree (8 ranks, no per-message latency: the balanced
+    schedule's O(k) volume beats the tree's O(k log p))."""
+    import json
+    import os
+
+    from gtopkssgd_tpu.trainer import TrainConfig, Trainer
+
+    flags = {}
+    if fit == "fast_fit":
+        flags["comm_model_fit"] = str(tmp_path / "calib_fit_8proc.json")
+        with open(flags["comm_model_fit"], "w") as fh:
+            json.dump({"alpha_beta_fit":
+                       {"alpha_ms": 0.0, "beta_gbps": 0.6}}, fh)
+    built = []
+    make_tx = Trainer._make_tx
+    monkeypatch.setattr(
+        Trainer, "_make_tx",
+        lambda self, *a, **kw: built.append(1) or make_tx(self, *a, **kw))
+    out = str(tmp_path / "run")
+    with Trainer(TrainConfig(
+            dnn="resnet20", batch_size=2, nworkers=8, compression="gtopk",
+            density=0.01, comm_plan=pin, log_interval=5, eval_batches=1,
+            max_epochs=1, prefetch=0, out_dir=out, **flags)) as t:
+        assert len(built) == 1
+        batch = t._device_batch(t._shard_batches(t._iters)[0])
+        text = t._train_step.lower(t.state, t.carry, batch).as_text()
+    with open(os.path.join(out, "metrics.jsonl")) as fh:
+        man = next(r for r in map(json.loads, fh)
+                   if r["kind"] == "manifest")
+    want = pin if pin != "auto" else (
+        "balanced" if fit == "fast_fit" else "tree")
+    assert man["comm_plan"] == man["comm_plan_schedule"] == want
+    assert man["comm_plan_pin"] == pin
+    # only the balanced schedule gathers; the tree only permutes
+    lowered = "balanced" if "stablehlo.all_gather" in text else "tree"
+    assert lowered == man["comm_plan_schedule"]
 
 
 def test_sparse_allreduce_rejects_unknown_schedule():
