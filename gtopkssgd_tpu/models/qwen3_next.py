@@ -25,7 +25,12 @@ here is how they are computed:
     block. What the absent experts would add is left out; nothing stands
     in for the other chips or their all-to-all;
   * the head and the loss a sequence at a time; every layer under
-    ``jax.checkpoint``.
+    ``jax.checkpoint``, which keeps nothing of a layer but, by name, the
+    outputs of the two checkpoints nested inside it (``KEPT_BYTES``,
+    ``kept_across_remat``): the chunks' algebra (``prepare``) and the
+    attention's query blocks (``one``) then run twice a step, forward and
+    for their own backward, and not a third time when the layer's forward
+    is replayed.
 
 Precision is the reference's: float32 parameters, residual stream, norms,
 router, softmax, recurrence state and loss; matrix products in ``dtype``.
@@ -50,6 +55,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
 
 F32 = jnp.float32
@@ -92,6 +98,29 @@ PRESETS = {
 # chip: 2.7 GB a sequence of 4,096 tokens at the published widths).
 GDN_SEQUENCES = 1
 
+# What a layer's remat keeps from its forward to its backward pass, by
+# ``checkpoint_name``: the stacked outputs of ``GatedDeltaNet``'s ``prepare``
+# (u, w, q_in, k_out [n, B, H, C, 128] and attn [n, B, H, C, C] float32:
+# 4 x 268.4 + 134.2 MB = 1.208 GB a layer at 4 x 4,096 tokens) and the
+# attention's output before its gate ([B, S, 16, 256] float32, 268 MB).
+# ``prepare`` and ``one`` keep their own ``jax.checkpoint``: that is what
+# holds one sequence's 2.7 GB (``GDN_SEQUENCES``) and one query block's
+# scores to the moment they are used; only their outputs live on. Without
+# the names the layer's replay would run both a second time just to hand
+# their own backward passes the same values.
+KEPT_CHUNKS, KEPT_ATTENTION = "gdn_chunks", "attn_out"
+# Bytes the layers of one step may keep so, together: what a v5e (15.75
+# GiB, ``bytes_limit`` 16.909 GB) has left after a GiB of margin and the
+# step itself. With nothing kept XLA's analysis gives the benchmark's step
+# 10.632 GB at 4 x 4,096 tokens and 16.118 GB at 8 x 4,096: 5.146 GB of
+# state and flat vectors (16 B a parameter) and 334,844 B a token. At the
+# cell's 16,384 tokens that leaves 5.20 GB, the four layers keep 3 x 1.208 +
+# 0.268 = 3.89 GB and the step reads 14.306 GB (PERF.md section 6, PR 30);
+# at 8 sequences nothing is left and every layer is rematerialised whole,
+# as all were before (the published depth of 48 would keep 43 GB).
+KEPT_BYTES = 16_909_000_000 - 2 ** 30 - 5_146_000_000
+STEP_BYTES_A_TOKEN = 334_844
+
 
 def chunk_of(seq_len: int) -> int:
     """Tokens in a chunk of the delta rule: 64, less for a short sequence."""
@@ -100,6 +129,34 @@ def chunk_of(seq_len: int) -> int:
 
 def query_block_of(seq_len: int) -> int:
     return min(512, max(1, seq_len // 2))
+
+
+def is_attention(sizes, i):
+    return (i + 1) % sizes["full_attention_interval"] == 0
+
+
+def kept_budget(batch, length):
+    return max(0, KEPT_BYTES - STEP_BYTES_A_TOKEN * batch * length)
+
+
+def kept_across_remat(sizes, batch, length, budget):
+    """[(bytes layer i's remat would keep by name, whether it does)]
+    for a step of ``batch`` sequences of ``length`` tokens: in layer order,
+    a layer keeps its outputs if they fit in what is left of ``budget``."""
+    chunk = chunk_of(sizes["seq_len"])
+    chunks = -(-length // chunk)
+    d_k, d_v = sizes["linear_key_head_dim"], sizes["linear_value_head_dim"]
+    delta = 4 * chunks * batch * sizes["linear_num_value_heads"] * (
+        chunk * (d_v + 3 * d_k + chunk) + 1)   # u; w, q_in, k_out; attn; decay
+    attention = 4 * batch * length * sizes["num_attention_heads"] \
+        * sizes["head_dim"]
+    out, total = [], 0
+    for i in range(sizes["num_hidden_layers"]):
+        size = attention if is_attention(sizes, i) else delta
+        keep = total + size <= budget
+        total += size * keep
+        out.append((size, keep))
+    return out
 
 
 # ------------------------------------------------------------------ pieces
@@ -444,7 +501,8 @@ class GatedDeltaNet(nn.Module):
             # crosses the chunks of every sequence in one scan.
             whole = lambda a: jnp.moveaxis(a, 0, 1).reshape(
                 (a.shape[1], batch) + a.shape[3:])
-            o = scan_chunks(*map(whole, prepared))[:, :length]
+            o = scan_chunks(*(checkpoint_name(whole(a), KEPT_CHUNKS)
+                              for a in prepared))[:, :length]
         with jax.named_scope("layer/gdn_proj"):
             z = qkvz[..., 2 * key_w + val_w:].astype(F32).reshape(
                 batch, length, h_v, d_v)
@@ -481,8 +539,8 @@ class GatedAttention(nn.Module):
                 batch, length, kv_heads, dim).astype(F32)
             q = rotary(rms_norm0(q, w_qn, eps), s["rope_theta"], rotary_dims)
             k = rotary(rms_norm0(k, w_kn, eps), s["rope_theta"], rotary_dims)
-            out = blocked_causal_attention(
-                q, k, v, dtype, query_block_of(s["seq_len"]))
+            out = checkpoint_name(blocked_causal_attention(
+                q, k, v, dtype, query_block_of(s["seq_len"])), KEPT_ATTENTION)
             out = out * jax.nn.sigmoid(gate)
             return dense(out.reshape(batch, length, heads * dim), w_o, dtype)
 
@@ -592,10 +650,13 @@ class Qwen3Next(nn.Module):
             table = self.param("embed", _normal(), (rows, d), F32)
             x = table[tokens]
         counts = []
-        for i in range(s["num_hidden_layers"]):
-            attention = (i + 1) % s["full_attention_interval"] == 0
-            x, count = nn.remat(Layer)(
-                s, self.dtype, attention, name=f"layer_{i}")(x)
+        by_name = jax.checkpoint_policies.save_only_these_names(
+            KEPT_CHUNKS, KEPT_ATTENTION)
+        for i, (_, keep) in enumerate(
+                kept_across_remat(s, *tokens.shape,
+                                  kept_budget(*tokens.shape))):
+            x, count = nn.remat(Layer, policy=by_name if keep else None)(
+                s, self.dtype, is_attention(s, i), name=f"layer_{i}")(x)
             counts.append(count)
         with jax.named_scope("layer/head"):
             w_final = self.param("final_norm", nn.initializers.zeros, (d,), F32)

@@ -4,6 +4,7 @@ qwen3_next.py), the chunked delta rule against the per-token recurrence,
 the expert shares against the uncut layer, and the rule that no token-slot
 is dropped."""
 
+import collections
 import os
 import sys
 
@@ -292,3 +293,116 @@ def test_published_preset_counts_its_parameters():
         jax.random.PRNGKey(0))["params"]
     assert sum(v.size for v in jax.tree.leaves(shapes)) == 323_677_248
     assert all(v.dtype == jnp.float32 for v in jax.tree.leaves(shapes))
+
+
+# ------------------------------------------- what a layer's remat keeps
+def primitives(jaxpr, into=None):
+    """How often each primitive occurs in a jaxpr, nested jaxprs included."""
+    into = collections.Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    primitives(inner, into)
+    return into
+
+
+def gradient_and_primitives(params, batch):
+    """(((loss, counts), gradient), the gradient's primitives) on ``tiny``
+    with bfloat16 products, traced anew: the budget is read at trace time."""
+    module = prog.Qwen3Next("tiny", jnp.bfloat16)
+    grad = jax.value_and_grad(lambda p: module.apply(
+        {"params": p}, batch["tokens"], batch["targets"], train=True),
+        has_aux=True)
+    return (jax.jit(grad)(params),
+            primitives(jax.make_jaxpr(grad)(params).jaxpr))
+
+
+@pytest.fixture(scope="module")
+def kept_and_not(seeded):
+    """{"kept": by name, "not": with a budget of no bytes, which is the
+    remat with no policy} -> ``gradient_and_primitives``."""
+    out = {"kept": gradient_and_primitives(*seeded)}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(prog, "kept_budget", lambda batch, length: 0)
+        out["not"] = gradient_and_primitives(*seeded)
+    return out
+
+
+@pytest.mark.parametrize("part", ["loss", "moe_load", "moe_dropped", "gradient"])
+def test_keeping_by_name_changes_no_value(kept_and_not, part):
+    """Same operations on the same values, one execution fewer: every leaf
+    bit for bit."""
+    pick = lambda out: {"loss": out[0][0], "gradient": out[1], **out[0][1]}[part]
+    kept, bare = (pick(kept_and_not[k][0]) for k in ("kept", "not"))
+    for (name, a), (_, b) in zip(leaves(kept), leaves(bare)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    assert all(np.isfinite(np.asarray(a, np.float32)).all()
+               for _, a in leaves(kept))
+
+
+@pytest.mark.parametrize("primitive,kept,bare", [
+    # A DeltaNet layer's solve: forward, once more for ``prepare``'s own
+    # backward, two transposed in the solve's VJP; not kept, the layer's
+    # replay adds a fifth.
+    ("triangular_solve", 4 * 3, 5 * 3),
+    # ``lax.map(prepare)`` is a scan: one fewer a DeltaNet layer.
+    ("scan", 17, 17 + 3),
+    # The softmax's row maximum of an attention query block (two blocks
+    # on ``tiny``), beside the routers' and the loss's.
+    ("reduce_max", 14, 14 + 2),
+])
+def test_a_layers_inner_checkpoints_run_twice_not_three_times(
+        kept_and_not, primitive, kept, bare):
+    assert kept_and_not["kept"][1][primitive] == kept
+    assert kept_and_not["not"][1][primitive] == bare
+
+
+@pytest.mark.parametrize("preset,batch,budget,want", [
+    # The benchmark's cell: every layer keeps its outputs.
+    ("80b_a3b_ep64", 4, None,
+     [(1_207_992_320, True)] * 3 + [(268_435_456, True)]),
+    # Half as many tokens again, and the step's own share of the chip with
+    # them: the budget holds one DeltaNet layer and the attention.
+    ("80b_a3b_ep64", 6, None,
+     [(1_811_988_480, True)] + [(1_811_988_480, False)] * 2
+     + [(402_653_184, True)]),
+    # Twice the batch fills the chip as it is (16.1 of 16.9 GB): nothing kept.
+    ("80b_a3b_ep64", 8, None,
+     [(2_415_984_640, False)] * 3 + [(536_870_912, False)]),
+    ("tiny", 2, None, [(393_344, True)] * 3 + [(65_536, True)]),
+    ("tiny", 2, 393_344 * 2,
+     [(393_344, True)] * 2 + [(393_344, False), (65_536, False)]),
+    ("tiny", 2, 0, [(393_344, False)] * 3 + [(65_536, False)]),
+])
+def test_which_layers_keep_their_outputs_follows_from_the_shapes(
+        preset, batch, budget, want):
+    sizes = prog.PRESETS[preset]
+    if budget is None:
+        budget = prog.kept_budget(batch, sizes["seq_len"])
+    got = prog.kept_across_remat(sizes, batch, sizes["seq_len"], budget)
+    assert got == want
+    assert sum(size for size, keep in got if keep) <= budget
+
+
+def test_published_depth_keeps_what_the_budget_holds_and_no_more():
+    """48 layers at the cell's batch would keep 43 GB: the first five do
+    (5.10 of the 5.20 GB left), the rest are rematerialised whole."""
+    sizes = dict(prog.PRESETS["80b_a3b_ep64"], num_hidden_layers=48)
+    got = prog.kept_across_remat(sizes, 4, 4096, prog.kept_budget(4, 4096))
+    assert sum(size for size, _ in got) == 36 * 1_207_992_320 + 12 * 268_435_456
+    assert [i for i, (_, keep) in enumerate(got) if keep] == [0, 1, 2, 3, 4]
+
+
+def test_layers_past_the_budget_still_differentiate(seeded, kept_and_not,
+                                                    monkeypatch):
+    """A budget that holds the first two layers' outputs only: the other
+    two fall back to the plain remat, and the gradient is the same."""
+    monkeypatch.setattr(prog, "kept_budget", lambda batch, length: 393_344 * 2)
+    out, count = gradient_and_primitives(*seeded)
+    assert count["triangular_solve"] == 4 * 2 + 5
+    assert count["reduce_max"] == 14 + 2
+    for (name, a), (_, b) in zip(leaves(out), leaves(kept_and_not["kept"][0])):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
