@@ -488,8 +488,10 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16"])
     p.add_argument("--model-preset", default=None,
-                   help="qwen3_next: which size to build "
-                        "(models.qwen3_next.PRESETS: 80b_a3b_ep64, tiny)")
+                   help="the decoders' sizes: qwen3_next takes 80b_a3b_ep64 "
+                        "or tiny, keye_vl2 takes 30b_a3b_ep16 or tiny (each "
+                        "model's PRESETS); another name, or a model that "
+                        "has no presets, is an error")
     p.add_argument("--s2d", action="store_true",
                    help="resnet50: space-to-depth stem (4x4x12 conv on 2x2 "
                         "pixel blocks; a superset of the 7x7x3 map — exact "

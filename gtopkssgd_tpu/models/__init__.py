@@ -18,7 +18,9 @@ from typing import Any, Callable, Dict, Tuple
 
 import flax.linen as nn
 
+from gtopkssgd_tpu.models import keye_vl2, qwen3_next
 from gtopkssgd_tpu.models.alexnet import AlexNet
+from gtopkssgd_tpu.models.keye_vl2 import KeyeVL2
 from gtopkssgd_tpu.models.lstm import PTBLSTM
 from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
 from gtopkssgd_tpu.models.qwen3_next import Qwen3Next
@@ -34,7 +36,9 @@ class ModelSpec:
     how its loss comes about (``loss``: ``classify`` logits against
     ``label``, ``tokens`` logits against ``targets``, ``ctc``, or ``own``
     for a model that takes the targets and returns its loss and counts
-    itself) and whether it threads a ``carry`` from window to window."""
+    itself) and whether it threads a ``carry`` from window to window.
+    ``presets`` names the sizes a model is built at by ``--model-preset``
+    (its constructor's ``preset``); most models have one size and none."""
 
     name: str
     build: Callable[..., nn.Module]
@@ -45,6 +49,7 @@ class ModelSpec:
     input_key: str = "image"
     loss: str = "classify"
     carry: bool = False
+    presets: Tuple[str, ...] = ()
 
 
 _ZOO: Dict[str, ModelSpec] = {}
@@ -121,6 +126,19 @@ _register(
         has_batchnorm=False,
         input_key="tokens",
         loss="own",
+        presets=tuple(qwen3_next.PRESETS),
+    )
+)
+_register(
+    ModelSpec(
+        "keye_vl2",
+        KeyeVL2,
+        "tokens",
+        (16384,),  # one sequence of token ids
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
+        presets=tuple(keye_vl2.PRESETS),
     )
 )
 
@@ -147,10 +165,12 @@ def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
             "does not take it")
     if kwargs.get("preset") is None:
         kwargs.pop("preset", None)     # the model's own default
-    elif dnn != "qwen3_next":
+    elif kwargs["preset"] not in spec.presets:
         raise ValueError(
-            f"--model-preset names a size of qwen3_next; --dnn {dnn} has "
-            "none")
+            f"--model-preset {kwargs['preset']!r}: --dnn {dnn} has "
+            + (f"the presets {list(spec.presets)}" if spec.presets else
+               "none (models with presets: "
+               f"{sorted(n for n, m in _ZOO.items() if m.presets)})"))
     return spec.build(**kwargs), spec
 
 
@@ -169,4 +189,5 @@ __all__ = [
     "PTBLSTM",
     "DeepSpeechAN4",
     "Qwen3Next",
+    "KeyeVL2",
 ]

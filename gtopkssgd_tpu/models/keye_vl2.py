@@ -1,0 +1,475 @@
+"""Keye-VL-2.0-30B-A3B's decoder: grouped-query attention under a learned
+sparse-attention indexer (each query attends to the ``topk`` keys its
+indexer scores highest), each layer followed by a sparse mixture of experts
+with no shared expert
+(https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B, ``KeyeVL2``; the
+language model's text-only step: no vision tower is built).
+
+The layer equations are written out in ``perfbench/refmodels/keye_vl2.py``
+(the frozen plain reference; parameter names and shapes are equal leaf for
+leaf, ``tests/test_keye_vl2.py`` holds the two together). What differs here
+is how they are computed:
+
+  * queries in blocks of ``q_chunk_size``, ``BUCKET`` blocks of equal key
+    extent at a time (one ``lax.map``: a block sees the keys up to its
+    bucket's end, the rest of the triangle masked), so the [S, S] index
+    scores and the [H, S, S] attention scores never exist at once;
+  * the selection, once a layer and outside every gradient
+    (``select_thresholds``): a query's threshold tau_t, the ``topk``-th
+    largest of its index scores, by 32 counting passes over the scores' bit
+    patterns (``kth_largest``: exact, no sort). A layer's remat keeps the
+    thresholds by name (``KEPT_SELECTION``, 4 bytes a query): the backward
+    pass compares the recomputed scores with them and does not select
+    again;
+  * the attention in its **masked form**: every key up to the bucket's end
+    is multiplied, the keys outside S_t = {s <= t: I_ts >= tau_t} masked out
+    of the softmax, one key-value head's query heads at a time, the softmax's
+    exponent taken against a bound from the norms (``LOGIT_CAP``), and the
+    probabilities' sum over the heads carried along for the indexer's loss;
+  * the expert layer, the head and the loss are ``models/decoder.py``'s, as
+    the other decoder's are; every layer under ``jax.checkpoint``, which
+    keeps by name the thresholds and what the query blocks' own checkpoint
+    gives out (``KEPT_ATTENTION``), so that a block runs twice a step, not
+    three times.
+
+Precision is the reference's: float32 parameters, residual stream, norms,
+rotary, the sum over the indexer's heads, thresholds, both softmaxes,
+router and both losses; projections, q.k, a.v and the index products
+qI.kI in ``dtype`` with float32 accumulation.
+
+Stages are named for the device trace (``layer/dsa_index``: the indexer's
+projections, norm, rotary, index scores and loss; ``layer/dsa_select``:
+thresholds and masks; ``layer/attn``; ``layer/moe_router``;
+``layer/moe_experts``; ``layer/head``), forward and backward alike. With
+the loss go the held experts' loads and dropped slots (always 0) and, for
+``obs.counters.dsa_counters``, each layer's number of keys kept and its
+indexer loss.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
+
+from gtopkssgd_tpu.models.decoder import (
+    F32, SparseMoE, _normal, dense, rms_norm0, rotary, token_losses)
+
+# The published sizes (config.json of Keye-VL-2.0-30B-A3B; ``sa_config``'s
+# keys flat) with the three cuts of
+# perfbench/configs/keye_vl2_30b_a3b_ep16.json, whose ``sizes`` a test holds
+# equal to this preset key for key; and the size every CPU test runs.
+PRESETS = {
+    "30b_a3b_ep16": dict(
+        hidden_size=2048, num_hidden_layers=4,
+        num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+        rope_theta=10000000, rms_norm_eps=1e-6,
+        indexer_num_heads=16, indexer_head_dim=64, indexer_num_kv_heads=1,
+        topk=2048, q_chunk_size=512, kv_chunk_size=512,
+        num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768,
+        norm_topk_prob=True,
+        experts_held=8, expert_offset=0, expert_parallel=16,
+        vocab_size=151936, vocab_rows=18992, seq_len=16384),
+    "tiny": dict(
+        hidden_size=64, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        rope_theta=10000000, rms_norm_eps=1e-6,
+        indexer_num_heads=4, indexer_head_dim=16, indexer_num_kv_heads=1,
+        topk=8, q_chunk_size=8, kv_chunk_size=8,
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+        norm_topk_prob=True,
+        experts_held=4, expert_offset=0, expert_parallel=4,
+        vocab_size=1024, vocab_rows=128, seq_len=48),
+}
+
+# Query blocks that share a key extent and one compiled body: the keys up
+# to the end of the bucket's last block. At 4 a masked pair in eleven is
+# beyond its block's end (8 bodies a layer at 16,384 tokens, not 32).
+BUCKET = 4
+# What a layer's remat keeps from its forward to its backward pass, by
+# ``checkpoint_name``: the thresholds tau [B, S] float32 (64 KB a layer at
+# 16,384 tokens; not differentiated), and the outputs of the query blocks'
+# own checkpoint: the attention's output in ``dtype`` before its output
+# projection ([B, S, H, D], 134 MB a layer), the indexer loss and the count
+# of keys of every query. With them the layer's replay runs neither the
+# selection nor a block's forward a second time: a block runs twice a step
+# (forward, and once more for its own backward), not three times. A layer
+# always keeps them: 0.54 GB over the four layers, no byte budget decides.
+KEPT_SELECTION, KEPT_ATTENTION = "dsa_tau", "dsa_attn_out"
+
+
+# ---------------------------------------------------------------- selection
+def index_scores(qi, ki, w, dtype):
+    """I_ts = sum_j w_tj ReLU(qI_tj . kI_s): qi [B, Q, J, D], ki [B, K, D],
+    w [B, Q, J] float32 -> [B, Q, K] float32."""
+    dots = jnp.einsum("bqjd,bkd->bjqk", qi.astype(dtype), ki.astype(dtype),
+                      preferred_element_type=F32)
+    return jnp.sum(jnp.moveaxis(w, 2, 1)[..., None] * jax.nn.relu(dots), axis=1)
+
+
+def ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def from_ordered_bits(u):
+    """The inverse, but that 0 (under every float's pattern) gives -inf."""
+    bits = jnp.where(u >> 31 == 1, u ^ jnp.uint32(1 << 31), ~u)
+    return jnp.where(u == 0, -jnp.inf, lax.bitcast_convert_type(bits, F32))
+
+
+def kth_largest(keys, k):
+    """The ``k``-th largest of each row of uint32 ``keys`` [..., n], exactly
+    (0 where a row has fewer than ``k`` non-zero keys): the answer's bits
+    from the top, a bit staying set when ``k`` keys still reach it."""
+    def body(i, prefix):
+        reach = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(keys >= reach[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, reach, prefix)
+
+    return lax.fori_loop(0, 32, body, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def buckets(length, block):
+    """[(first query, queries, key extent)] of a sequence padded to whole
+    blocks: ``BUCKET`` blocks a bucket, fewer in the last."""
+    padded = -(-length // block) * block
+    step = BUCKET * block
+    return [(start, min(step, padded - start), min(start + step, padded))
+            for start in range(0, padded, step)]
+
+
+def _blocks(a, block, axis=1):
+    """[B, n * block, ...] -> [n, B, block, ...] for ``lax.map``: the
+    sequence ``axis`` cut into blocks, their count in front."""
+    a = a.reshape(a.shape[:axis] + (-1, block) + a.shape[axis + 1:])
+    return jnp.moveaxis(a, axis, 0)
+
+
+def _unblocks(a):
+    """[n, B, block, ...] -> [B, n * block, ...]."""
+    a = jnp.moveaxis(a, 0, 1)
+    return a.reshape((a.shape[0], -1) + a.shape[3:])
+
+
+def select_thresholds(qi, ki, w, topk, dtype, block):
+    """tau [B, S] float32: the ``topk``-th largest index score of each query
+    among the keys up to itself, -inf for a query with fewer than ``topk``
+    of them. qi [B, S, J, D], ki [B, S, D], w [B, S, J], S a multiple of
+    ``block``; no gradient passes."""
+    qi, ki, w = map(lax.stop_gradient, (qi, ki, w))
+    out = []
+    for start, queries, extent in buckets(qi.shape[1], block):
+        keys = ki[:, :extent]
+
+        def one(args, keys=keys, extent=extent):
+            qi_b, w_b, rows = args
+            with jax.named_scope("layer/dsa_index"):
+                scores = index_scores(qi_b, keys, w_b, dtype)
+            with jax.named_scope("layer/dsa_select"):
+                valid = rows[:, None] >= jnp.arange(extent)[None, :]
+                ranked = jnp.where(valid, ordered_bits(scores), 0)
+                return from_ordered_bits(kth_largest(ranked, topk))
+
+        part = slice(start, start + queries)
+        rows = jnp.arange(start, start + queries).reshape(-1, block)
+        out.append(_unblocks(lax.map(
+            one, (_blocks(qi[:, part], block), _blocks(w[:, part], block),
+                  rows))))
+    return jnp.concatenate(out, 1)
+
+
+# ---------------------------------------------------------------- attention
+# The softmax's exponent is taken against ``top``, a bound on a row's logits
+# from the norms of its query and of the longest key before it
+# (|q.k| <= |q| |k|), and not against the row's own maximum, which would cost
+# a pass over the [H, block, keys] float32 logits in memory: exp, mask and
+# the rounding to ``dtype`` then ride on the product that makes the logits,
+# and the weights' sum divides the output. The softmax is the same whatever
+# is subtracted; in float32 a row keeps its precision while its largest
+# logit lies within some 40 of ``top``, which RMS-normed q and k hold to
+# (|logit| <= |q| |k| / sqrt(D) = sqrt(D) (1 + w_q)(1 + w_k), 11.3 at
+# w = 0); the cap keeps a bound beyond that from pushing every weight under
+# the smallest float.
+LOGIT_CAP = 60.0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def attend_group(q, k, v, keep, top, dtype):
+    """One key-value head's query heads over the kept keys of a query block:
+    q [B, Q, R, D], k, v [B, K, D] in ``dtype``, keep [B, Q, K] bool, top
+    [B, R, Q, 1] float32 -> (o [B, R, Q, D] float32, the R heads'
+    probabilities summed [B, Q, K] float32, which takes no gradient).
+
+    The weights exp(logit - top) are rounded to ``dtype`` where the product
+    that makes the logits ends, and their sum divides the output. The
+    backward pass is written out so that the softmax's difference
+    dE - sum_s p_s dE_s is taken in float32 where the product dE = do v^T
+    ends, and only its product with the weights is rounded: left to
+    autodiff, the two halves would each be rounded to ``dtype`` first."""
+    return _attend_group(q, k, v, keep, top, dtype)[0]
+
+
+def _attend_group(q, k, v, keep, top, dtype):
+    logits = jnp.einsum("bqrd,bkd->brqk", q, k, preferred_element_type=F32) \
+        / math.sqrt(q.shape[-1])
+    weights = jnp.exp(jnp.where(keep[:, None], logits - top, -jnp.inf)
+                      ).astype(dtype)
+    total = jnp.sum(weights.astype(F32), -1, keepdims=True)
+    out = jnp.einsum("brqk,bkd->brqd", weights, v,
+                     preferred_element_type=F32) / total
+    share = jnp.sum(weights.astype(F32) / total, axis=1)
+    return (out, share), (q, k, v, top, weights, total, out)
+
+
+def _attend_group_bwd(dtype, kept, cotangents):
+    q, k, v, top, weights, total, out = kept
+    d_out, _ = cotangents
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    mean = jnp.sum(d_out * out, -1, keepdims=True)       # sum_s p_s dE_s
+    d_logits = (weights.astype(F32) / total * (jnp.einsum(
+        "brqd,bkd->brqk", d_out.astype(dtype), v, preferred_element_type=F32)
+        - mean)).astype(dtype)
+    d_v = jnp.einsum("brqk,brqd->bkd", weights, (d_out / total).astype(dtype),
+                     preferred_element_type=F32)
+    d_q = jnp.moveaxis(jnp.einsum("brqk,bkd->brqd", d_logits, k,
+                                  preferred_element_type=F32), 1, 2) * scale
+    d_k = jnp.einsum("brqk,bqrd->bkd", d_logits, q,
+                     preferred_element_type=F32) * scale
+    return (d_q.astype(q.dtype), d_k.astype(k.dtype), d_v.astype(v.dtype),
+            None, jnp.zeros_like(top))
+
+
+attend_group.defvjp(_attend_group, _attend_group_bwd)
+
+
+def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
+    """Softmax attention of every query over its key set S_t = {s <= t:
+    I_ts >= tau_t}, and the indexer's loss against it.
+
+    q [B, S, H, D], k, v [B, S, H_kv, D], qi [B, S, J, D_I], ki [B, S, D_I],
+    w [B, S, J], tau [B, S], float32, S a multiple of ``block`` ->
+    (o [B, S, H, D] float32, KL(p_t || softmax_{S_t} I_t) [B, S], |S_t|
+    [B, S] int32). Gradients reach q, k, v through o alone and qi, ki, w
+    through the loss alone; tau takes none."""
+    batch, length, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    # One key-value head with its query heads at a time: [G, B, S, ...].
+    q = jnp.moveaxis(q.reshape(batch, length, kv_heads, heads // kv_heads,
+                               dim), 2, 0).astype(dtype)
+    k, v = (jnp.moveaxis(a, 2, 0).astype(dtype) for a in (k, v))
+    norm = lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(F32)), -1))
+    q_norm, k_norm = lax.stop_gradient((norm(q), norm(k)))
+    outs, losses, kept = [], [], []
+    for start, queries, extent in buckets(length, block):
+        k_e, v_e, ki_e = k[:, :, :extent], v[:, :, :extent], ki[:, :extent]
+        # What no logit of a query's row passes (``LOGIT_CAP``): [G, B, Q, R].
+        top = jnp.minimum(q_norm[:, :, start:start + queries] * jnp.max(
+            k_norm[:, :, :extent], -1)[..., None, None] / math.sqrt(dim),
+            LOGIT_CAP)
+
+        @jax.checkpoint
+        def one(args, k_e=k_e, v_e=v_e, ki_e=ki_e, extent=extent):
+            q_b, top_b, qi_b, w_b, tau_b, rows = args
+            with jax.named_scope("layer/dsa_index"):
+                scores = index_scores(qi_b, ki_e, w_b, dtype)
+            with jax.named_scope("layer/dsa_select"):
+                keep = (rows[:, None] >= jnp.arange(extent)[None, :]) \
+                    & (lax.stop_gradient(scores) >= tau_b[..., None])
+
+            def group(mass, args):
+                q_g, k_g, v_g, top_g = args
+                out, share = attend_group(q_g, k_g, v_g, keep, top_g, dtype)
+                return mass + share, out
+
+            with jax.named_scope("layer/attn"):
+                mass, out = lax.scan(
+                    group, jnp.zeros(scores.shape, F32), (q_b, k_e, v_e, top_b))
+            with jax.named_scope("layer/dsa_index"):
+                p = mass / heads
+                log_q = jax.nn.log_softmax(
+                    jnp.where(keep, scores, -jnp.inf), axis=-1)
+                seen = keep & (p > 0)
+                kl = jnp.sum(jnp.where(
+                    seen, p * (_ln(jnp.where(seen, p, 1.0))
+                               - jnp.where(seen, log_q, 0.0)), 0.0), -1)
+            return out, kl, keep.sum(-1, dtype=jnp.int32)
+
+        part = slice(start, start + queries)
+        rows = jnp.arange(start, start + queries).reshape(-1, block)
+        # q's blocks [n, G, B, block, R, D] and their bounds [n, G, B, R,
+        # block, 1]; the scan inside runs over G.
+        out, kl, count = lax.map(
+            one, (_blocks(q[:, :, part], block, 2),
+                  jnp.swapaxes(_blocks(top, block, 2), -1, -2)[..., None],
+                  _blocks(qi[:, part], block), _blocks(w[:, part], block),
+                  _blocks(tau[:, part], block), rows))
+        # [n, G, B, R, block, D] -> [B, n * block, G * R, D]
+        outs.append(out.transpose(2, 0, 4, 1, 3, 5).reshape(
+            batch, queries, heads, dim))
+        losses.append(_unblocks(kl))
+        kept.append(_unblocks(count))
+    return (jnp.concatenate(outs, 1), jnp.concatenate(losses, 1),
+            jnp.concatenate(kept, 1))
+
+
+# The layers are alike: under ``jit`` the two are traced once for the first
+# layer, and the others (their derivatives and transposes too) take the same
+# jaxpr from jax's caches: the step's trace, paid at every start, is that
+# much shorter. XLA inlines the calls.
+_select_thresholds = jax.jit(select_thresholds, static_argnums=(3, 4, 5))
+_sparse_attention = jax.jit(sparse_attention, static_argnums=(7, 8))
+
+
+def layer_norm(x, scale, bias, eps):
+    x = x.astype(F32)
+    centred = x - jnp.mean(x, -1, keepdims=True)
+    return centred * lax.rsqrt(
+        jnp.mean(centred * centred, -1, keepdims=True) + eps) * scale + bias
+
+
+# ------------------------------------------------------------------ modules
+class SparseAttention(nn.Module):
+    """(y [B, S, d], this layer's indexer loss, its sum of |S_t|)."""
+    sizes: dict
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        s, dtype = self.sizes, self.dtype
+        d, dim = s["hidden_size"], s["head_dim"]
+        heads, kv_heads = s["num_attention_heads"], s["num_key_value_heads"]
+        j, d_i = s["indexer_num_heads"], s["indexer_head_dim"]
+        w_q = self.param("q_proj", _normal(), (d, heads * dim), F32)
+        w_kv = self.param("kv_proj", _normal(), (d, 2 * kv_heads * dim), F32)
+        w_qn = self.param("q_norm", nn.initializers.zeros, (dim,), F32)
+        w_kn = self.param("k_norm", nn.initializers.zeros, (dim,), F32)
+        w_o = self.param("o_proj", _normal(), (heads * dim, d), F32)
+        w_i = self.param("index_proj", _normal(), (d, j * d_i + d_i + j), F32)
+        a_ki = self.param("index_k_norm_scale", nn.initializers.ones, (d_i,),
+                          F32)
+        b_ki = self.param("index_k_norm_bias", nn.initializers.zeros, (d_i,),
+                          F32)
+
+        batch, length = h.shape[:2]
+        if self.is_initializing():
+            # Every parameter is made; tracing the rest at 16,384 tokens
+            # would be seconds of each start for shapes alone.
+            return jnp.zeros(h.shape, dtype), jnp.zeros((), F32), \
+                jnp.zeros((), jnp.int32)
+        eps, theta = s["rms_norm_eps"], s["rope_theta"]
+        block = min(s["q_chunk_size"], length)
+        # Whole blocks: a padded query sees what the last one sees and is
+        # cut off again; no real query sees a padded key (they come later).
+        pad = lambda a: jnp.pad(
+            a, ((0, 0), (0, -length % block)) + ((0, 0),) * (a.ndim - 2))
+        with jax.named_scope("layer/attn"):
+            q = dense(h, w_q, dtype).reshape(batch, length, heads, dim)
+            kv = dense(h, w_kv, dtype).reshape(batch, length, 2, kv_heads, dim)
+            k, v = kv[:, :, 0], kv[:, :, 1].astype(F32)
+            q = rotary(rms_norm0(q, w_qn, eps), theta, dim)
+            k = rotary(rms_norm0(k, w_kn, eps), theta, dim)
+        with jax.named_scope("layer/dsa_index"):
+            index = dense(lax.stop_gradient(h), w_i, dtype).astype(F32)
+            qi = rotary(index[..., :j * d_i].reshape(batch, length, j, d_i),
+                        theta, d_i)
+            ki = rotary(layer_norm(index[..., j * d_i:j * d_i + d_i], a_ki,
+                                   b_ki, eps)[:, :, None], theta, d_i)[:, :, 0]
+            w = index[..., j * d_i + d_i:] / math.sqrt(j * d_i)
+        q, k, v, qi, ki, w = map(pad, (q, k, v, qi, ki, w))
+        tau = checkpoint_name(
+            _select_thresholds(qi, ki, w, s["topk"], dtype, block),
+            KEPT_SELECTION)
+        out, kl, kept = _sparse_attention(q, k, v, qi, ki, w, tau, dtype,
+                                          block)
+        # ``dense`` would round ``out`` to ``dtype`` anyway: kept so.
+        out, kl, kept = (checkpoint_name(a, KEPT_ATTENTION)
+                         for a in (out.astype(dtype), kl, kept))
+        with jax.named_scope("layer/attn"):
+            y = dense(out[:, :length].reshape(batch, length, heads * dim),
+                      w_o, dtype)
+        with jax.named_scope("layer/dsa_index"):
+            return y, jnp.mean(kl[:, :length]), jnp.sum(kept[:, :length])
+
+
+class Layer(nn.Module):
+    sizes: dict
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        s = self.sizes
+        d, eps = s["hidden_size"], s["rms_norm_eps"]
+        w_in = self.param("input_norm", nn.initializers.zeros, (d,), F32)
+        w_post = self.param("post_norm", nn.initializers.zeros, (d,), F32)
+        # The layer's own norms and residual adds count for the kind they
+        # feed; scopes inside the mixer and the expert layer are innermost.
+        with jax.named_scope("layer/attn"):
+            y, index_loss, kept = SparseAttention(s, self.dtype, name="mixer")(
+                rms_norm0(x, w_in, eps))
+            x = x + y.astype(F32)
+        with jax.named_scope("layer/moe_router"):
+            y, load, dropped = SparseMoE(s, self.dtype, name="moe")(
+                rms_norm0(x, w_post, eps))
+            return x + y, (load, dropped, kept, index_loss)
+
+
+def keys_due(length, topk):
+    """sum over t < length of min(t + 1, topk): what the layers keep of one
+    sequence when no score ties at a threshold."""
+    topk = min(topk, length)
+    return topk * (topk + 1) // 2 + (length - topk) * topk
+
+
+class KeyeVL2(nn.Module):
+    """``__call__(tokens, targets)`` gives the objective, mean
+    cross-entropy + the indexer loss L_I, and the layers' counts
+    ``{"moe_load": [layers, held], "moe_dropped": [layers], "dsa_kept":
+    [layers], "dsa_due": [], "dsa_index_loss": [layers]}``; without targets,
+    the logits [B, S, vocab_rows]."""
+    preset: str = "30b_a3b_ep16"
+    dtype: Any = jnp.float32
+
+    @property
+    def sizes(self):
+        return PRESETS[self.preset]
+
+    @nn.compact
+    def __call__(self, tokens, targets=None, *, train: bool = False):
+        s = self.sizes
+        d, rows = s["hidden_size"], s["vocab_rows"]
+        with jax.named_scope("layer/head"):
+            table = self.param("embed", _normal(), (rows, d), F32)
+            x = table[tokens]
+        counts = []
+        by_name = jax.checkpoint_policies.save_only_these_names(
+            KEPT_SELECTION, KEPT_ATTENTION)
+        for i in range(s["num_hidden_layers"]):
+            x, count = nn.remat(Layer, policy=by_name)(
+                s, self.dtype, name=f"layer_{i}")(x)
+            counts.append(count)
+        with jax.named_scope("layer/head"):
+            w_final = self.param("final_norm", nn.initializers.zeros, (d,), F32)
+            head = self.param("head", _normal(), (d, rows), F32)
+            hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
+            if targets is None:
+                return jnp.dot(hidden.astype(self.dtype),
+                               head.astype(self.dtype),
+                               preferred_element_type=F32)
+            loss = token_losses(hidden, head, targets, self.dtype).mean()
+        load, dropped, kept, index_loss = (
+            jnp.stack([c[i] for c in counts]) for i in range(4))
+        batch, length = tokens.shape
+        return loss + jnp.mean(index_loss), {
+            "moe_load": load, "moe_dropped": dropped, "dsa_kept": kept,
+            "dsa_due": jnp.asarray(batch * keys_due(length, s["topk"])),
+            "dsa_index_loss": index_loss}

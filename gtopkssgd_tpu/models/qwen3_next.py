@@ -16,14 +16,10 @@ here is how they are computed:
     backward);
   * attention in blocks of queries, each block against the keys up to its
     own end, so the [B, H, S, S] scores never exist at once;
-  * the expert layer is told which experts it holds (``experts_held`` from
-    ``expert_offset`` of ``num_experts``): it routes over all of them,
-    sorts the token-slots that fall on its own experts into expert order
-    and runs them as grouped products (``lax.ragged_dot``), a block of
-    slots at a time in a loop as long as this step's routing needs: no
-    token is dropped whatever the imbalance, and a balanced step runs one
-    block. What the absent experts would add is left out; nothing stands
-    in for the other chips or their all-to-all;
+  * the expert layer, the query-block attention, the rotary embedding, the
+    norms and the head's loss are ``models/decoder.py``'s, shared with the
+    other decoder of the zoo (``keye_vl2``): a dropless share of an expert
+    group, told which experts it holds;
   * the head and the loss a sequence at a time; every layer under
     ``jax.checkpoint``, which keeps nothing of a layer but, by name, the
     outputs of the two checkpoints nested inside it (``KEPT_BYTES``,
@@ -58,8 +54,10 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
 
-F32 = jnp.float32
-HIGHEST = lax.Precision.HIGHEST
+from gtopkssgd_tpu.models.decoder import (
+    F32, HIGHEST, SparseMoE, _normal, blocked_causal_attention, dense,
+    query_block_of, rms_norm0, rotary, token_losses)
+
 # The chunked delta rule's float32 products (module docstring, Precision).
 _mm = functools.partial(jnp.einsum, precision=HIGHEST)
 
@@ -127,9 +125,6 @@ def chunk_of(seq_len: int) -> int:
     return min(64, max(1, seq_len // 4))
 
 
-def query_block_of(seq_len: int) -> int:
-    return min(512, max(1, seq_len // 2))
-
 
 def is_attention(sizes, i):
     return (i + 1) % sizes["full_attention_interval"] == 0
@@ -158,20 +153,6 @@ def kept_across_remat(sizes, batch, length, budget):
         out.append((size, keep))
     return out
 
-
-# ------------------------------------------------------------------ pieces
-def dense(x, w, dtype):
-    return jnp.dot(x.astype(dtype), w.astype(dtype))
-
-
-def rms_norm0(x, w, eps):
-    """Zero-centred RMSNorm: the stored weight is the scale minus one."""
-    x = x.astype(F32)
-    return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
-
-
-def _normal():
-    return nn.initializers.normal(0.02)
 
 
 def _a_log(key, shape, dtype=F32):
@@ -282,165 +263,6 @@ def chunked_delta_rule(q, k, v, g, beta, chunk):
     return scan_chunks(*delta_chunks(*arrays, chunk))[:, :length]
 
 
-# --------------------------------------------------------------- attention
-def rotary(x, theta, rotary_dims):
-    """Rotate-half rotary embedding on the first ``rotary_dims`` of the
-    last axis; x [B, S, H, D] float32, positions 0..S-1."""
-    length, half = x.shape[1], rotary_dims // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=F32) * 2.0 / rotary_dims))
-    angle = jnp.arange(length, dtype=F32)[:, None] * inv[None, :]
-    angle = jnp.concatenate([angle, angle], -1)[None, :, None, :]
-    rot, rest = x[..., :rotary_dims], x[..., rotary_dims:]
-    turned = jnp.concatenate([-rot[..., half:], rot[..., :half]], -1)
-    return jnp.concatenate(
-        [rot * jnp.cos(angle) + turned * jnp.sin(angle), rest], -1)
-
-
-def blocked_causal_attention(q, k, v, dtype, block):
-    """q [B, S, H, D], k, v [B, S, H_kv, D] float32 -> [B, S, H, D] float32.
-    Queries in blocks of ``block``, each against keys 0 .. its own end and
-    rematerialised in the backward pass."""
-    batch, length, heads, dim = q.shape
-    kv_heads = k.shape[2]
-    q = q.reshape(batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
-    k, v = k.astype(dtype), v.astype(dtype)
-
-    @functools.partial(jax.checkpoint, static_argnums=3)
-    def one(q_b, k_b, v_b, start):
-        scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_b, k_b,
-                            preferred_element_type=F32) / math.sqrt(dim)
-        rows = start + jnp.arange(q_b.shape[1])
-        mask = rows[:, None] >= jnp.arange(k_b.shape[1])[None, :]
-        probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-        return jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v_b,
-                          preferred_element_type=F32)
-
-    outs = []
-    for start in range(0, length, block):
-        end = min(start + block, length)
-        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
-    return jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
-
-
-# ------------------------------------------------------------ expert layer
-def route(x, router, top, normalise):
-    """(probabilities of the ``top`` experts [T, top] float32, their ids),
-    over every expert of the model, held here or not."""
-    logits = jnp.dot(x.astype(F32), router, precision=HIGHEST)
-    values, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top)
-    if normalise:
-        values = values / jnp.sum(values, -1, keepdims=True)
-    return values, ids
-
-
-def sort_held_slots(ids, probs, offset, held):
-    """Token-slots in expert order, the held experts' first.
-
-    Returns (token of each sorted slot, its routing weight, slots per held
-    expert [held]); slots of experts held elsewhere sort last with weight 0
-    and belong to no group."""
-    top = ids.shape[1]
-    local = ids.reshape(-1) - offset
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    weight = jnp.where(key[order] < held, probs.reshape(-1)[order], 0.0)
-    return (order // top).astype(jnp.int32), weight, sizes
-
-
-def blocks_run(sizes, rows, max_blocks):
-    """How many blocks of ``rows`` sorted slots are run: as many as hold
-    every slot on a held expert, unless ``max_blocks`` caps them."""
-    need = (jnp.sum(sizes) + rows - 1) // rows
-    return need if max_blocks is None else jnp.minimum(need, max_blocks)
-
-
-def slots_dropped(sizes, rows, max_blocks):
-    """Slots on held experts that no block took: 0 unless ``max_blocks``
-    cuts the loop short, which the model never does."""
-    return jnp.maximum(
-        jnp.sum(sizes) - blocks_run(sizes, rows, max_blocks) * rows, 0)
-
-
-def _expert_block(x, token, weight, sizes, gate, up, down, *, start, rows,
-                  dtype):
-    """The held experts' output for sorted slots start .. start + rows,
-    added at their tokens: [T, d] float32."""
-    ends = jnp.cumsum(sizes)
-    window = lambda a: jnp.clip(a, start, start + rows)
-    inside = (window(ends) - window(ends - sizes)).astype(jnp.int32)
-    # Rows past the block's last group belong to no expert. The grouped
-    # product leaves them unwritten on the TPU, forward and backward, so
-    # they are zeroed on the way in, after every product and (by the
-    # transposes of the same selects) on the way back.
-    valid = (jnp.arange(rows) < jnp.sum(inside))[:, None]
-    with jax.named_scope("layer/moe_router"):
-        token = lax.dynamic_slice(token, (start,), (rows,))
-        weight = lax.dynamic_slice(weight, (start,), (rows,))
-        taken = jnp.where(valid, x[token], 0.0).astype(dtype)
-    with jax.named_scope("layer/moe_experts"):
-        grouped = lambda a, w: jnp.where(
-            valid, lax.ragged_dot(a, w.astype(dtype), inside), 0).astype(F32)
-        hidden = jax.nn.silu(grouped(taken, gate)) * grouped(taken, up)
-        out = grouped(hidden.astype(dtype), down)
-    with jax.named_scope("layer/moe_router"):
-        return jnp.zeros(x.shape, F32).at[token].add(out * weight[:, None])
-
-
-def _held_experts(rows, max_blocks, dtype, x, token, weight, sizes,
-                  gate, up, down):
-    """sum over the held experts j of weight_j E_j(x), [T, d] float32.
-
-    The sorted slots are run ``rows`` at a time in a loop whose trip count
-    is this step's (``blocks_run``): every slot on a held expert is
-    computed whatever the imbalance, the buffer stays one block, and a
-    balanced step runs one block. A loop of unknown length has no
-    automatic transpose, so the backward pass is written out: the same
-    loop, each block's vjp recomputed and accumulated."""
-    def body(i, total):
-        return total + _expert_block(
-            x, token, weight, sizes, gate, up, down,
-            start=i * rows, rows=rows, dtype=dtype)
-
-    return lax.fori_loop(0, blocks_run(sizes, rows, max_blocks), body,
-                         jnp.zeros(x.shape, F32))
-
-
-held_experts = jax.custom_vjp(_held_experts, nondiff_argnums=(0, 1, 2))
-
-
-def _held_fwd(rows, max_blocks, dtype, *args):
-    return _held_experts(rows, max_blocks, dtype, *args), args
-
-
-def _held_bwd(rows, max_blocks, dtype, args, ct):
-    x, token, weight, sizes, gate, up, down = args
-
-    def body(i, grads):
-        start = i * rows
-
-        def block(x, part, gate, up, down):
-            full = lax.dynamic_update_slice(
-                jnp.zeros_like(weight), part, (start,))
-            return _expert_block(x, token, full, sizes, gate, up, down,
-                                 start=start, rows=rows, dtype=dtype)
-
-        part = lax.dynamic_slice(weight, (start,), (rows,))
-        _, pull = jax.vjp(block, x, part, gate, up, down)
-        dx, dpart, *dexperts = pull(ct)
-        gx, gweight, *gexperts = grads
-        return (gx + dx, lax.dynamic_update_slice(gweight, dpart, (start,)),
-                *(g + d for g, d in zip(gexperts, dexperts)))
-
-    zeros = tuple(jnp.zeros_like(a) for a in (x, weight, gate, up, down))
-    dx, dweight, dgate, dup, ddown = lax.fori_loop(
-        0, blocks_run(sizes, rows, max_blocks), body, zeros)
-    return dx, None, dweight, None, dgate, dup, ddown
-
-
-held_experts.defvjp(_held_fwd, _held_bwd)
-
-
 # ------------------------------------------------------------------ modules
 class GatedDeltaNet(nn.Module):
     sizes: dict
@@ -545,53 +367,6 @@ class GatedAttention(nn.Module):
             return dense(out.reshape(batch, length, heads * dim), w_o, dtype)
 
 
-class SparseMoE(nn.Module):
-    """``block_rows`` sorted slots are run at a time (None: 4096, or every
-    slot the layer can hold if that is fewer); ``max_blocks`` caps the
-    loop and so drops slots: only the test that shows ``moe_slots_dropped``
-    counting sets it."""
-    sizes: dict
-    dtype: Any
-    block_rows: Any = None
-    max_blocks: Any = None
-
-    @nn.compact
-    def __call__(self, x):
-        s, dtype = self.sizes, self.dtype
-        d, width = s["hidden_size"], s["moe_intermediate_size"]
-        shared_w = s["shared_expert_intermediate_size"]
-        held, offset = s["experts_held"], s["expert_offset"]
-        top = s["num_experts_per_tok"]
-        router = self.param("router", _normal(), (d, s["num_experts"]), F32)
-        gate = self.param("experts_gate", _normal(), (held, d, width), F32)
-        up = self.param("experts_up", _normal(), (held, d, width), F32)
-        down = self.param("experts_down", _normal(), (held, width, d), F32)
-        s_gate = self.param("shared_gate_proj", _normal(), (d, shared_w), F32)
-        s_up = self.param("shared_up_proj", _normal(), (d, shared_w), F32)
-        s_down = self.param("shared_down_proj", _normal(), (shared_w, d), F32)
-        w_s = self.param("shared_gate", _normal(), (d, 1), F32)
-
-        shape = x.shape
-        x = x.reshape(-1, d)
-        # A token's top experts are distinct: at most min(top, held) of its
-        # slots fall here.
-        rows = self.block_rows or min(4096, x.shape[0] * min(top, held))
-        with jax.named_scope("layer/moe_router"):
-            probs, ids = route(x, router, top, s["norm_topk_prob"])
-            token, weight, load = sort_held_slots(ids, probs, offset, held)
-            # The loop's last block may reach past the slots: pad them.
-            token = jnp.pad(token, (0, rows))
-            weight = jnp.pad(weight, (0, rows))
-        y = held_experts(rows, self.max_blocks, dtype, x, token, weight, load,
-                         gate, up, down)
-        with jax.named_scope("layer/shared_expert"):
-            share = jax.nn.sigmoid(dense(x, w_s, dtype).astype(F32))
-            hidden = jax.nn.silu(dense(x, s_gate, dtype).astype(F32)) \
-                * dense(x, s_up, dtype).astype(F32)
-            y = y + share * dense(hidden, s_down, dtype).astype(F32)
-        return (y.reshape(shape), load,
-                slots_dropped(load, rows, self.max_blocks))
-
 
 class Layer(nn.Module):
     sizes: dict
@@ -616,19 +391,6 @@ class Layer(nn.Module):
                 rms_norm0(x, w_post, eps))
             return x + y, (load, dropped)
 
-
-def token_losses(hidden, head, targets, dtype):
-    """Cross-entropy of every position, float32, a sequence at a time."""
-
-    @jax.checkpoint
-    def one(args):
-        h, t = args
-        logits = jnp.dot(h.astype(dtype), head.astype(dtype),
-                         preferred_element_type=F32)
-        picked = jnp.take_along_axis(logits, t[:, None], -1)[:, 0]
-        return jax.nn.logsumexp(logits, axis=-1) - picked
-
-    return lax.map(one, (hidden, targets))
 
 
 class Qwen3Next(nn.Module):

@@ -112,6 +112,8 @@ from gtopkssgd_tpu.obs.counters import (
     mass_ratio,
     selected_tau,
     sent_count,
+    dsa_counters,
+    model_counters,
     model_scalars,
     moe_counters,
     readable_counters,
@@ -180,6 +182,8 @@ __all__ = [
     "run_manifest",
     "selected_tau",
     "sent_count",
+    "dsa_counters",
+    "model_counters",
     "model_scalars",
     "moe_counters",
     "readable_counters",

@@ -522,6 +522,17 @@ MOE_FIELDS = (
     "moe_slots_dropped",
 )
 
+# What a decoder whose queries each attend to the ``topk`` keys a learned
+# indexer scores highest counts (models/keye_vl2.py), per optimizer step:
+# the keys its queries kept, sum over the step's queries of |S_t|, mean over
+# the layers; what they keep when no score ties at a threshold, sum of
+# min(t + 1, topk); and the indexer's own loss term L_I.
+DSA_FIELDS = (
+    "dsa_keys_kept",
+    "dsa_keys_due",
+    "dsa_index_loss",
+)
+
 # The model counters last read on the host (``model_scalars``): like the
 # span buffer, it outlives the trainer, so a reader can ask afterwards.
 _last_model: Dict[str, float] = {}
@@ -540,12 +551,49 @@ def moe_counters(load: Array, dropped: Array) -> Dict[str, Array]:
     }
 
 
+@jax.named_scope(SCOPE)
+def dsa_counters(kept: Array, due: Array, index_loss: Array
+                 ) -> Dict[str, Array]:
+    """``DSA_FIELDS`` as f32 scalars from the sparse-attention layers'
+    counts: ``kept`` [layers] keys kept, ``due`` [] the same for every
+    layer, ``index_loss`` [layers]. (float32 holds whole numbers to 2^24
+    and even ones to 2^25: a 16,384-token sequence's 31,458,304 is exact.)"""
+    return {
+        "dsa_keys_kept": jnp.mean(kept.astype(jnp.float32)),
+        "dsa_keys_due": due.astype(jnp.float32),
+        "dsa_index_loss": jnp.mean(index_loss),
+    }
+
+
+# The registry of model counters: each group's fields, the keys of the
+# model's counts it is computed from and its device function. A model's
+# ``aux`` holds the groups whose counts it returns; ``model_scalars`` reads
+# whichever of the fields it finds.
+MODEL_COUNTERS = {
+    "moe": (MOE_FIELDS, ("moe_load", "moe_dropped"), moe_counters),
+    "dsa": (DSA_FIELDS, ("dsa_kept", "dsa_due", "dsa_index_loss"),
+            dsa_counters),
+}
+
+
+def model_counters(counts: Dict[str, Array]) -> Dict[str, Array]:
+    """The step's ``aux`` of a model that returns its own counts: every
+    registered group whose keys are among them."""
+    out = {}
+    for _, keys, compute in MODEL_COUNTERS.values():
+        if all(key in counts for key in keys):
+            out.update(compute(*(counts[key] for key in keys)))
+    return out
+
+
 def model_scalars(aux: Dict[str, Array]) -> Dict[str, float]:
     """Host floats of the model's own counters among a step's ``aux``
-    (``MOE_FIELDS``; {} for a model that counts nothing), kept as the
-    last read. Blocks on the step like ``telemetry_scalars``: call it at
-    the same sync point."""
-    found = {key: float(aux[key]) for key in MOE_FIELDS if key in aux}
+    (the registry's fields; {} for a model that counts nothing), kept as
+    the last read. Blocks on the step like ``telemetry_scalars``: call it
+    at the same sync point."""
+    found = {key: float(aux[key])
+             for fields, _, _ in MODEL_COUNTERS.values()
+             for key in fields if key in aux}
     if found:
         _last_model.clear()
         _last_model.update(found)

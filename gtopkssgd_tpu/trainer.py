@@ -54,8 +54,8 @@ from gtopkssgd_tpu.obs import (
     TimelineRecorder,
     Tracer,
     layer_names,
+    model_counters,
     model_scalars,
-    moe_counters,
     readable_counters,
     telemetry_scalars,
 )
@@ -134,12 +134,16 @@ class TrainConfig:
     out_dir: Optional[str] = None
     seed: int = 42
     dtype: str = "float32"         # compute dtype: 'float32' | 'bfloat16'
-    model_preset: Optional[str] = None  # qwen3_next only: which of
-                                   # models.qwen3_next.PRESETS to build
-                                   # ('80b_a3b_ep64', the published sizes
-                                   # as one chip's share of a 64-chip
-                                   # expert group, or 'tiny'); None = the
-                                   # model's default
+    model_preset: Optional[str] = None  # the decoders only (qwen3_next:
+                                   # '80b_a3b_ep64', 'tiny'; keye_vl2:
+                                   # '30b_a3b_ep16', 'tiny'): which of the
+                                   # model's PRESETS to build, the
+                                   # published sizes as one chip's share
+                                   # of an expert group or the tests'
+                                   # size (ModelSpec.presets; any other
+                                   # name, or any name for a model without
+                                   # presets, fails at construction);
+                                   # None = the model's default
     space_to_depth: bool = False   # resnet50: MXU-friendly s2d stem (same
                                    # linear map as the 7x7/2 conv; see
                                    # models/resnet.py and the equivalence
@@ -1446,7 +1450,7 @@ class Trainer:
         if kind == "own":
             (loss, counts), new_bs = run(batch[self.spec.input_key],
                                          batch["targets"])
-            aux = moe_counters(counts["moe_load"], counts["moe_dropped"])
+            aux = model_counters(counts)
             return loss, (new_bs, carry, aux)
         if kind == "tokens":
             (logits, new_carry), new_bs = run(batch["tokens"], carry)

@@ -16,8 +16,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from gtopkssgd_tpu.models import get_model, qwen3_next as prog  # noqa: E402
+from gtopkssgd_tpu.models import get_model, keye_vl2 as keye_prog  # noqa: E402
+from gtopkssgd_tpu.models import qwen3_next as prog  # noqa: E402
 from gtopkssgd_tpu.obs import counters  # noqa: E402
+from perfbench.refmodels import keye_vl2 as keye_ref  # noqa: E402
 from perfbench.refmodels import qwen3_next as ref  # noqa: E402
 
 TINY = prog.PRESETS["tiny"]
@@ -147,10 +149,20 @@ def test_chunked_delta_rule_equals_the_recurrence(length, chunk):
             assert float(jnp.max(jnp.abs(mine - theirs))) < 1e-5, name
 
 
-def moe_params(seed=3):
+# The expert layer is models/decoder.py's for both decoders of the zoo: with
+# a shared expert (qwen3_next) and without one (keye_vl2).
+DECODERS = {
+    "qwen3_next": (prog, ref, TINY),
+    "keye_vl2": (keye_prog, keye_ref, keye_prog.PRESETS["tiny"]),
+}
+both_decoders = pytest.mark.parametrize("decoder", sorted(DECODERS))
+
+
+def moe_params(decoder, seed=3):
     """The parameters of one uncut expert layer: all 16 experts held."""
-    whole = dict(TINY, experts_held=TINY["num_experts"], expert_offset=0)
-    x = jax.random.normal(jax.random.PRNGKey(seed), (2, 48, TINY["hidden_size"]))
+    _, ref, tiny = DECODERS[decoder]
+    whole = dict(tiny, experts_held=tiny["num_experts"], expert_offset=0)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (2, 48, tiny["hidden_size"]))
     module = ref.SparseMoE(whole, jnp.float32)
     params = module.init({"params": jax.random.PRNGKey(seed + 1)}, x)["params"]
     # A livelier router than N(0, 0.02), so that loads differ.
@@ -164,21 +176,26 @@ def share_of(params, rank, held):
                            ("experts_gate", "experts_up", "experts_down")})
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """16 experts in 4 shares of 4: the sum of the four shares' outputs,
-    with what every chip computes alike (the shared expert) counted once,
-    is the uncut layer's, in the program and in the reference."""
-    whole, params, x = moe_params()
-    held = 4
+@both_decoders
+def test_the_shares_add_up_to_the_uncut_layer(decoder):
+    """16 experts in the preset's ``expert_parallel`` = 4 shares of 4: the
+    sum of the four shares' outputs, with what every chip computes alike (the
+    shared expert, where the model has one) counted once, is the uncut
+    layer's, in the program and in the reference."""
+    prog, ref, tiny = DECODERS[decoder]
+    whole, params, x = moe_params(decoder)
+    ranks, held = tiny["expert_parallel"], tiny["experts_held"]
+    assert ranks * held == tiny["num_experts"]
     uncut = ref.SparseMoE(whole, jnp.float32).apply({"params": params}, x)
-    only_shared = dict(TINY, experts_held=held, expert_offset=10 ** 6)
+    only_shared = dict(tiny, experts_held=held, expert_offset=10 ** 6)
     shared = ref.SparseMoE(only_shared, jnp.float32).apply(
         {"params": share_of(params, 0, held)}, x)
+    assert bool(jnp.any(shared)) == ("shared_expert_intermediate_size" in tiny)
     loads = []
     for side in ("program", "reference"):
         total = 0.0
-        for rank in range(4):
-            sizes = dict(TINY, experts_held=held, expert_offset=rank * held)
+        for rank in range(ranks):
+            sizes = dict(tiny, experts_held=held, expert_offset=rank * held)
             p = {"params": share_of(params, rank, held)}
             if side == "program":
                 y, load, dropped = prog.SparseMoE(sizes, jnp.float32).apply(p, x)
@@ -189,29 +206,33 @@ def test_the_shares_add_up_to_the_uncut_layer():
             total = total + (y - shared)
         assert float(jnp.max(jnp.abs(total + shared - uncut))) < 1e-5, side
     # Every token-slot landed on exactly one share.
-    assert int(np.sum(loads)) == x.shape[0] * x.shape[1] * TINY["num_experts_per_tok"]
+    assert int(np.sum(loads)) == x.shape[0] * x.shape[1] * tiny["num_experts_per_tok"]
 
 
-def biased_to_held_experts():
+def biased_to_held_experts(decoder="qwen3_next"):
     """An expert layer whose router sends every token to the same four
     experts, all held here: every one of the layer's slots falls here."""
-    sizes = dict(TINY, experts_held=4, expert_offset=4)
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, TINY["hidden_size"]))
+    _, ref, tiny = DECODERS[decoder]
+    sizes = dict(tiny, experts_held=4, expert_offset=4)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, tiny["hidden_size"]))
     params = ref.SparseMoE(sizes, jnp.float32).init(
         {"params": jax.random.PRNGKey(6)}, x)["params"]
     # |x.sum| grows with the input, the bias does not depend on it.
-    bias = jnp.zeros((TINY["num_experts"],)).at[4:8].set(50.0)
-    params["router"] = params["router"] * 0.0 + bias[None, :] / TINY["hidden_size"]
+    bias = jnp.zeros((tiny["num_experts"],)).at[4:8].set(50.0)
+    params["router"] = params["router"] * 0.0 + bias[None, :] / tiny["hidden_size"]
     return sizes, params, jnp.abs(x) + 1.0
 
 
+@both_decoders
 @pytest.mark.parametrize("block_rows", [None, 64])
-def test_no_slot_is_dropped_under_a_router_that_overloads_the_held(block_rows):
-    sizes, params, x = biased_to_held_experts()
+def test_no_slot_is_dropped_under_a_router_that_overloads_the_held(
+        block_rows, decoder):
+    prog, ref, tiny = DECODERS[decoder]
+    sizes, params, x = biased_to_held_experts(decoder)
     want = ref.SparseMoE(sizes, jnp.float32).apply({"params": params}, x)
     y, load, dropped = prog.SparseMoE(
         sizes, jnp.float32, block_rows=block_rows).apply({"params": params}, x)
-    slots = x.shape[0] * x.shape[1] * TINY["num_experts_per_tok"]
+    slots = x.shape[0] * x.shape[1] * tiny["num_experts_per_tok"]
     assert int(jnp.sum(load)) == slots and int(dropped) == 0
     assert float(jnp.max(jnp.abs(y - want))) < 1e-5
     got = counters.moe_counters(load[None], dropped[None])
