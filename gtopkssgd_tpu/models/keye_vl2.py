@@ -25,7 +25,11 @@ is how they are computed:
     is multiplied, the keys outside S_t = {s <= t: I_ts >= tau_t} masked out
     of the softmax, one key-value head's query heads at a time, the softmax's
     exponent taken against a bound from the norms (``LOGIT_CAP``), and the
-    probabilities' sum over the heads carried along for the indexer's loss;
+    probabilities' sum over the heads carried along for the indexer's loss.
+    On a TPU, at shapes that fill whole tiles, the same attention runs as
+    Pallas kernels (``ops/dsa_attention.py``, "attention, kernel form"
+    below) that keep the [heads, block, keys] arrays in VMEM; XLA's form
+    (``attend_group``) is every other backend's and the kernels' oracle;
   * the expert layer, the head and the loss are ``models/decoder.py``'s, as
     the other decoder's are; every layer under ``jax.checkpoint``, which
     keeps by name the thresholds and what the query blocks' own checkpoint
@@ -59,6 +63,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
 
+from gtopkssgd_tpu.ops import dsa_attention as kernels
 from gtopkssgd_tpu.models.decoder import (
     F32, SparseMoE, _normal, dense, rms_norm0, rotary, token_losses)
 
@@ -102,6 +107,7 @@ BUCKET = 4
 # selection nor a block's forward a second time: a block runs twice a step
 # (forward, and once more for its own backward), not three times. A layer
 # always keeps them: 0.54 GB over the four layers, no byte budget decides.
+# (The kernel form keeps the output in float32 and two arrays more: below.)
 KEPT_SELECTION, KEPT_ATTENTION = "dsa_tau", "dsa_attn_out"
 
 
@@ -252,6 +258,16 @@ def _attend_group_bwd(dtype, kept, cotangents):
 attend_group.defvjp(_attend_group, _attend_group_bwd)
 
 
+def index_loss(scores, keep, p):
+    """KL(p_t || softmax_{S_t} I_t) of every query: scores, p [B, Q, K]
+    float32, keep [B, Q, K] bool -> [B, Q]."""
+    log_q = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    seen = keep & (p > 0)
+    return jnp.sum(jnp.where(
+        seen, p * (_ln(jnp.where(seen, p, 1.0))
+                   - jnp.where(seen, log_q, 0.0)), 0.0), -1)
+
+
 def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
     """Softmax attention of every query over its key set S_t = {s <= t:
     I_ts >= tau_t}, and the indexer's loss against it.
@@ -295,13 +311,7 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
                 mass, out = lax.scan(
                     group, jnp.zeros(scores.shape, F32), (q_b, k_e, v_e, top_b))
             with jax.named_scope("layer/dsa_index"):
-                p = mass / heads
-                log_q = jax.nn.log_softmax(
-                    jnp.where(keep, scores, -jnp.inf), axis=-1)
-                seen = keep & (p > 0)
-                kl = jnp.sum(jnp.where(
-                    seen, p * (_ln(jnp.where(seen, p, 1.0))
-                               - jnp.where(seen, log_q, 0.0)), 0.0), -1)
+                kl = index_loss(scores, keep, mass / heads)
             return out, kl, keep.sum(-1, dtype=jnp.int32)
 
         part = slice(start, start + queries)
@@ -322,12 +332,203 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
             jnp.concatenate(kept, 1))
 
 
+# ------------------------------------------------- attention, kernel form
+# The same attention with the [R, block, keys] arrays in VMEM tiles
+# (``ops/dsa_attention.py``): where the backend is a TPU and the shapes fill
+# whole tiles, a layer's attention is one forward kernel, one kernel a bucket
+# for the head-mean probabilities, and two backward kernels. The indexer's
+# part stays what it was: a ``lax.map`` over a bucket's query blocks makes
+# the scores and ``keep`` (now for the whole layer, as int8, before any
+# attention runs), and the loss is taken from the scores and the kernel's
+# probabilities. What a layer's remat keeps by name grows by what the
+# backward pass would otherwise make again with the forward kernels and a
+# pass of index scores: the weights' sums (``total`` [B, G, R, S], 2 MB a
+# layer), the output in float32 (268 MB), the layer's mask, a bit a pair
+# (34 MB), and the probabilities (0.6 GB in float32: a bucket's rows
+# against its keys; made again they are 61 ms a step).
+KEPT_MASKS, KEPT_PROBABILITIES = "dsa_keep", "dsa_p"
+
+
+def on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def attention_form(length, dim, block):
+    """``kernel`` where ``sparse_attention`` runs as the Pallas kernels,
+    ``masked`` where as XLA's masked products: the kernels need a TPU, a
+    head of whole 128-lane rows, and blocks, buckets and a (padded) length
+    of whole tiles."""
+    padded = -(-length // block) * block
+    whole = (dim % 128 == 0 and block % kernels.TILE_Q == 0
+             and min(BUCKET * block, padded) % kernels.TILE_K == 0)
+    return "kernel" if on_tpu() and whole else "masked"
+
+
+def _kernel_layout(q, k, v, dtype):
+    """q [B, S, H, D] -> [B, G, R, S, D], k, v [B, S, G, D] -> [B, G, S, D],
+    in ``dtype``."""
+    batch, length, heads, dim = q.shape
+    groups = k.shape[2]
+    q = q.reshape(batch, length, groups, heads // groups, dim).transpose(
+        0, 2, 3, 1, 4).astype(dtype)
+    k, v = (a.transpose(0, 2, 1, 3).astype(dtype) for a in (k, v))
+    return q, k, v
+
+
+def _tops(q, k, block):
+    """What no logit of a query's row passes (``LOGIT_CAP``), from the norms
+    of the query and of the longest key up to its bucket's end: q
+    [B, G, R, S, D], k [B, G, S, D] -> [B, G, R, S] float32."""
+    norm = lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(F32)), -1))
+    q_norm, k_norm = norm(q), norm(k)
+    return jnp.concatenate([
+        jnp.minimum(q_norm[..., start:start + queries] * jnp.max(
+            k_norm[..., :extent], -1)[..., None, None]
+            / math.sqrt(q.shape[-1]), LOGIT_CAP)
+        for start, queries, extent in buckets(q.shape[3], block)], -1)
+
+
+def _bucket_blocks(bucket, block, *arrays):
+    """A bucket's query blocks of [B, S, ...] arrays."""
+    start, queries, _ = bucket
+    return tuple(_blocks(a[:, start:start + queries], block) for a in arrays)
+
+
+def _pack_rows(mask):
+    """[B, S, S] int8 of 0 / 1 -> [B, S // 8, S] uint8, a bit a row of
+    each eighth of the rows: whole rows stay whole, so the TPU shifts and
+    adds along no lane."""
+    batch, length, _ = mask.shape
+    eighths = mask.astype(jnp.uint8).reshape(batch, 8, length // 8, length)
+    return sum(eighths[:, bit] << bit for bit in range(8))
+
+
+def _unpack_rows(packed):
+    """The inverse."""
+    batch, rows, length = packed.shape
+    return jnp.stack([(packed >> bit) & 1 for bit in range(8)], 1).astype(
+        jnp.int8).reshape(batch, 8 * rows, length)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
+    """``sparse_attention`` with the attention in kernels: the same
+    arguments, values and gradients (tau takes none)."""
+    return _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block)[0]
+
+
+def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
+    batch, length, heads, dim = q.shape
+    with jax.named_scope("layer/attn"):
+        q_l, k_l, v_l = _kernel_layout(q, k, v, dtype)
+        top = _tops(q_l, k_l, block)
+    scores, keeps, counts = [], [], []
+    for bucket in buckets(length, block):
+        start, queries, extent = bucket
+        ki_e = ki[:, :extent]
+
+        def one(args, ki_e=ki_e, extent=extent):
+            qi_b, w_b, tau_b, rows = args
+            with jax.named_scope("layer/dsa_index"):
+                score = index_scores(qi_b, ki_e, w_b, dtype)
+            with jax.named_scope("layer/dsa_select"):
+                keep = (rows[:, None] >= jnp.arange(extent)[None, :]) \
+                    & (score >= tau_b[..., None])
+                # The layer's mask is [S, S]: a row's keys past its
+                # bucket's end are not kept.
+                return score, jnp.pad(keep.astype(jnp.int8), (
+                    (0, 0), (0, 0), (0, length - extent))), \
+                    keep.sum(-1, dtype=jnp.int32)
+
+        rows = jnp.arange(start, start + queries).reshape(-1, block)
+        score, keep, count = (_unblocks(a) for a in lax.map(
+            one, _bucket_blocks(bucket, block, qi, w, tau) + (rows,)))
+        scores.append(score), keeps.append(keep), counts.append(count)
+    with jax.named_scope("layer/dsa_select"):
+        mask = jnp.concatenate(keeps, 1)
+        packed = checkpoint_name(_pack_rows(mask), KEPT_MASKS)
+    with jax.named_scope("layer/attn"):
+        out, total = kernels.forward(q_l, k_l, v_l, mask, top, dtype=dtype,
+                                     interpret=not on_tpu())
+        out, total = (checkpoint_name(a, KEPT_ATTENTION)
+                      for a in (out, total))
+        # The heads' mean probabilities, a bucket's queries over its keys.
+        ps = tuple(checkpoint_name(kernels.probabilities(
+            q_l, k_l, mask, top, 1.0 / total, span=(start, queries),
+            dtype=dtype, interpret=not on_tpu()), KEPT_PROBABILITIES)
+            for start, queries, _ in buckets(length, block))
+    with jax.named_scope("layer/dsa_index"):
+        loss = jnp.concatenate([
+            index_loss(score, keep[..., :score.shape[-1]] > 0, p)
+            for score, keep, p in zip(scores, keeps, ps)], 1)
+    o = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
+    return (o, loss, jnp.concatenate(counts, 1)), \
+        (q_l, k_l, v_l, qi, ki, w, top, out, total, packed, ps)
+
+
+def _kernel_attention_bwd(dtype, block, kept, cotangents):
+    q_l, k_l, v_l, qi, ki, w, top, out, total, packed, ps = kept
+    d_o, d_kl, _ = cotangents
+    batch, groups, rep, length, dim = q_l.shape
+    inv_total = 1.0 / total
+    with jax.named_scope("layer/dsa_select"):
+        mask = _unpack_rows(packed)
+    # The indexer: a block's scores once more, and the loss's gradient
+    # through them into qI, kI and w. A block's rows of the mask and of p
+    # are cut where they are read, not copied out block by block before.
+    d_qi, d_w, d_ki = [], [], jnp.zeros(ki.shape, F32)
+    for bucket, p in zip(buckets(length, block), ps):
+        start, queries, extent = bucket
+        ki_e = ki[:, :extent]
+
+        def one(d_ki_e, args, ki_e=ki_e, start=start, extent=extent, p=p):
+            qi_b, w_b, d_kl_b, i = args
+            with jax.named_scope("layer/dsa_index"):
+                score, back = jax.vjp(
+                    lambda a, b, c: index_scores(a, b, c, dtype),
+                    qi_b, ki_e, w_b)
+                keep_b = lax.dynamic_slice(
+                    mask, (0, start + i * block, 0), (batch, block, extent))
+                p_b = lax.dynamic_slice_in_dim(p, i * block, block, 1)
+                d_score, = jax.vjp(lambda s: index_loss(
+                    s, keep_b > 0, p_b), score)[1](d_kl_b)
+                d_qi_b, d_ki_b, d_w_b = back(d_score)
+            return d_ki_e + d_ki_b, (d_qi_b, d_w_b)
+
+        d_ki_e, (d_qi_b, d_w_b) = lax.scan(
+            one, jnp.zeros(ki_e.shape, F32),
+            _bucket_blocks(bucket, block, qi, w, d_kl)
+            + (jnp.arange(queries // block),))
+        d_ki = d_ki.at[:, :extent].add(d_ki_e)
+        d_qi.append(_unblocks(d_qi_b)), d_w.append(_unblocks(d_w_b))
+    with jax.named_scope("layer/attn"):
+        d_out = d_o.reshape(batch, length, groups, rep, dim).transpose(
+            0, 2, 3, 1, 4)
+        mean = jnp.sum(d_out * out, -1)                  # sum_s p_s dE_s
+        d_q = kernels.backward_q(
+            q_l, k_l, v_l, mask, top, inv_total, mean, d_out.astype(dtype),
+            dtype=dtype, interpret=not on_tpu())
+        d_k, d_v = kernels.backward_kv(
+            q_l, k_l, v_l, jnp.swapaxes(mask, 1, 2), top, inv_total, mean,
+            d_out.astype(dtype), (d_out / total[..., None]).astype(dtype),
+            dtype=dtype, interpret=not on_tpu())
+        d_q = d_q.transpose(0, 3, 1, 2, 4).reshape(
+            batch, length, groups * rep, dim)
+        d_k, d_v = (a.transpose(0, 2, 1, 3) for a in (d_k, d_v))
+    return (d_q, d_k, d_v, jnp.concatenate(d_qi, 1), d_ki,
+            jnp.concatenate(d_w, 1), jnp.zeros((batch, length), F32))
+
+
+kernel_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
+
+
 # The layers are alike: under ``jit`` the two are traced once for the first
 # layer, and the others (their derivatives and transposes too) take the same
 # jaxpr from jax's caches: the step's trace, paid at every start, is that
 # much shorter. XLA inlines the calls.
 _select_thresholds = jax.jit(select_thresholds, static_argnums=(3, 4, 5))
 _sparse_attention = jax.jit(sparse_attention, static_argnums=(7, 8))
+_kernel_attention_once = jax.jit(kernel_attention, static_argnums=(7, 8))
 
 
 def layer_norm(x, scale, bias, eps):
@@ -389,11 +590,17 @@ class SparseAttention(nn.Module):
         tau = checkpoint_name(
             _select_thresholds(qi, ki, w, s["topk"], dtype, block),
             KEPT_SELECTION)
-        out, kl, kept = _sparse_attention(q, k, v, qi, ki, w, tau, dtype,
-                                          block)
-        # ``dense`` would round ``out`` to ``dtype`` anyway: kept so.
-        out, kl, kept = (checkpoint_name(a, KEPT_ATTENTION)
-                         for a in (out.astype(dtype), kl, kept))
+        if attention_form(length, dim, block) == "kernel":
+            # Keeps its own output (float32, the kernels' layout) by name.
+            out, kl, kept = _kernel_attention_once(q, k, v, qi, ki, w, tau,
+                                                   dtype, block)
+            out = out.astype(dtype)
+        else:
+            out, kl, kept = _sparse_attention(q, k, v, qi, ki, w, tau, dtype,
+                                              block)
+            # ``dense`` would round ``out`` to ``dtype`` anyway: kept so.
+            out = checkpoint_name(out.astype(dtype), KEPT_ATTENTION)
+        kl, kept = (checkpoint_name(a, KEPT_ATTENTION) for a in (kl, kept))
         with jax.named_scope("layer/attn"):
             y = dense(out[:, :length].reshape(batch, length, heads * dim),
                       w_o, dtype)
@@ -443,6 +650,14 @@ class KeyeVL2(nn.Module):
     def sizes(self):
         return PRESETS[self.preset]
 
+    def forms(self, length):
+        """What the step compiles as at sequences of ``length``, for the
+        run's manifest and ``train`` records: a run on the chip that fell
+        back to the masked attention says so."""
+        s = self.sizes
+        return {"dsa_attention_form": attention_form(
+            length, s["head_dim"], min(s["q_chunk_size"], length))}
+
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
@@ -452,7 +667,7 @@ class KeyeVL2(nn.Module):
             x = table[tokens]
         counts = []
         by_name = jax.checkpoint_policies.save_only_these_names(
-            KEPT_SELECTION, KEPT_ATTENTION)
+            KEPT_SELECTION, KEPT_ATTENTION, KEPT_MASKS, KEPT_PROBABILITIES)
         for i in range(s["num_hidden_layers"]):
             x, count = nn.remat(Layer, policy=by_name)(
                 s, self.dtype, name=f"layer_{i}")(x)

@@ -661,9 +661,15 @@ class Trainer:
             data_kw["decode_workers"] = cfg.decode_workers
         if cfg.dataset == "cifar10" and cfg.synth_hard:
             data_kw["synth_hard"] = True
+        # Which of a model's forms the step compiles as (a model that
+        # chooses one from the backend and its shapes says which): in the
+        # manifest and every "train" record.
+        self._model_forms = {}
         if cfg.dataset == "tokens":
             data_kw.update(seq_len=self.model.sizes["seq_len"],
                            vocab_size=self.model.sizes["vocab_rows"])
+            if hasattr(self.model, "forms"):
+                self._model_forms = self.model.forms(data_kw["seq_len"])
         def _dataset(**kw):
             # Data-loader setup rides the shared retry/backoff helper
             # (resilience/preempt.py): a transient storage blip at
@@ -755,6 +761,7 @@ class Trainer:
                           "comm_fit_beta_gbps": d.inputs.get("beta_gbps")}
         if self._bucket_plan is not None:
             plan_extra.update(self._bucket_plan.to_manifest())
+        plan_extra.update(self._model_forms)
         # Compile-plane accounting (obs/memwatch.py, --obs-mem): build
         # the jitted step and AOT lower/compile it at the canonical
         # dispatch shape BEFORE the manifest is assembled, so the
@@ -2037,7 +2044,7 @@ class Trainer:
                         # Counter reads since the last such record.
                         obs_reads_lagged=self._obs_reads["lagged"],
                         obs_reads_sync=self._obs_reads["sync"],
-                        **last_aux,
+                        **last_aux, **self._model_forms,
                     )
                     self._host_copied_bytes = 0
                     self._obs_reads = {"lagged": 0, "sync": 0}

@@ -135,3 +135,146 @@ def test_twostage_takes_xla_stage1_when_no_group_count_fits(rng):
     real = idx[idx < n]
     assert len(set(real.tolist())) == len(real) >= 0.95 * k
     np.testing.assert_array_equal(np.asarray(vals)[idx < n], x[real])
+
+
+# ---------------------------------------------------------------------------
+# The sparse-attention decoder's attention kernels (ops/dsa_attention.py) at
+# the published shapes, and the published preset's whole step with them.
+from gtopkssgd_tpu.models import keye_vl2  # noqa: E402
+from gtopkssgd_tpu.ops import dsa_attention as dsa  # noqa: E402
+
+KEYE = keye_vl2.PRESETS["30b_a3b_ep16"]
+DSA_LENGTH, DSA_GROUPS, DSA_DIM = (
+    KEYE["seq_len"], KEYE["num_key_value_heads"], KEYE["head_dim"])
+DSA_HEADS = KEYE["num_attention_heads"] // DSA_GROUPS
+DSA_BUCKET = keye_vl2.buckets(DSA_LENGTH, KEYE["q_chunk_size"])[-1]
+
+
+def _dsa_arguments(device):
+    """Abstract q, k, keep and a row array of one layer, bfloat16."""
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=device)
+    rows = (1, DSA_GROUPS, DSA_HEADS, DSA_LENGTH)
+    return (shape(rows + (DSA_DIM,), jnp.bfloat16),
+            shape((1, DSA_GROUPS, DSA_LENGTH, DSA_DIM), jnp.bfloat16),
+            shape((1, DSA_LENGTH, DSA_LENGTH), jnp.int8),
+            shape(rows, jnp.float32))
+
+
+DSA_KERNELS = {
+    "forward": lambda q, k, keep, row: dsa.forward(
+        q, k, k, keep, row, dtype=jnp.bfloat16),
+    "probabilities": lambda q, k, keep, row: dsa.probabilities(
+        q, k, keep, row, row, span=DSA_BUCKET[:2], dtype=jnp.bfloat16),
+    "backward_q": lambda q, k, keep, row: dsa.backward_q(
+        q, k, k, keep, row, row, row, q, dtype=jnp.bfloat16),
+    "backward_kv": lambda q, k, keep, row: dsa.backward_kv(
+        q, k, k, keep, row, row, row, q, q, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(DSA_KERNELS))
+def test_dsa_attention_kernel_compiles_at_the_published_shapes(v5e, kernel):
+    """16,384 tokens, 8 query heads a key-value head, heads of 128,
+    bfloat16, the tiles the program uses: the layer's forward and backward
+    kernels over the whole sequence, the probabilities over its last
+    bucket (2,048 rows against every key)."""
+    text = jax.jit(DSA_KERNELS[kernel]).lower(*_dsa_arguments(v5e)).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"dsa_attention_{kernel}" in text
+
+
+def rqk_arrays(text, heads=(DSA_HEADS, DSA_HEADS * DSA_GROUPS)):
+    """The arrays of a compiled module that hold a number for every (query
+    head, query of a block, key of a bucket's extent): what the masked form
+    writes to HBM (``[8, 512, keys]`` in float32, ``dtype`` and pred) and
+    the kernels keep in VMEM."""
+    import collections
+    import re
+
+    extents = {extent for _, _, extent in keye_vl2.buckets(
+        DSA_LENGTH, KEYE["q_chunk_size"])}
+    found = collections.Counter()
+    for dtype, dims in re.findall(r"\b(f32|bf16|s32|s8|u8|pred)\[([0-9,]+)\]",
+                                  text):
+        big = [int(d) for d in dims.split(",") if d and int(d) > 1]
+        if len(big) >= 3 and big[-1] in extents \
+                and big[-2] == KEYE["q_chunk_size"] and big[-3] in heads:
+            found[dtype, tuple(big)] += 1
+    return found
+
+
+def test_rqk_arrays_finds_the_masked_forms_and_no_other():
+    masked = ("%f = bf16[8,512,2048]{2,1,0} fusion(f32[1,8,512,2048]{3,2,1,0} "
+              "%a), %m = pred[1,32,512,16384] compare(...)")
+    assert set(rqk_arrays(masked)) == {
+        ("bf16", (8, 512, 2048)), ("f32", (8, 512, 2048)),
+        ("pred", (32, 512, 16384))}
+    others = ("f32[1,16,512,2048] %index_dots, s8[1,16384,16384] %keep, "
+              "f32[1,2048,16384] %p, bf16[1,4,8,16384,128] %q, "
+              "f32[4096,2048] %slots, f32[8,512,128] %tile")
+    assert not rqk_arrays(others)
+
+
+@pytest.fixture(scope="module")
+def published_step(v5e):
+    """(compiled text, bytes) of the published preset's whole Trainer step
+    (the ``keye_vl2_ep16.gtopk`` cell's flags) for the described v5e, with
+    the attention in its kernel form: the backend here is the CPU, so the
+    test, not an option of the program, answers ``on_tpu``. One compile
+    (three minutes) serves the tests below."""
+    from gtopkssgd_tpu.trainer import TrainConfig, Trainer
+
+    abstract = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=v5e), tree)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(keye_vl2, "on_tpu", lambda: True)
+        jax.clear_caches()
+        with Trainer(TrainConfig(
+                dnn="keye_vl2", dataset="tokens", dtype="bfloat16", seed=42,
+                model_preset="30b_a3b_ep16", batch_size=1, nworkers=1,
+                compression="gtopk", density=0.001, lr=0.1, momentum=0.9,
+                weight_decay=0.0, clip_grad_norm=1.0, prefetch=0)) as trainer:
+            assert trainer._manifest["dsa_attention_form"] == "kernel"
+            batch = trainer._device_batch(
+                trainer._shard_batches(trainer._iters)[0])
+            compiled = trainer._train_step.lower(
+                abstract(trainer.state), abstract(trainer.carry),
+                abstract(batch)).compile()
+    jax.clear_caches()
+    memory = compiled.memory_analysis()
+    return compiled.as_text(), (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+
+
+def test_published_step_stays_under_its_memory_line(published_step):
+    """14.5 GB of the v5e's 16.9 (``bytes_limit`` 16,909,336,064), by XLA's
+    ``memory_analysis()`` (temp + argument + output - alias; equal to the
+    chip's to the byte, PERF.md section 4)."""
+    assert published_step[1] < 14.5e9, published_step[1]
+
+
+def test_published_step_runs_each_attention_kernel_once_a_layer(
+        published_step):
+    """The engagement counter, static like the mechanism: a layer holds one
+    forward kernel, a probabilities kernel a bucket and the two backward
+    kernels (the remat's replay runs none: ``o``, ``total``, p and the mask
+    are kept by name)."""
+    import re
+
+    calls = [line for line in published_step[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    layers = KEYE["num_hidden_layers"]
+    spans = len(keye_vl2.buckets(DSA_LENGTH, KEYE["q_chunk_size"]))
+    count = lambda name: sum(
+        bool(re.search(rf"dsa_attention_{name}\b", line)) for line in calls)
+    assert count("forward") == count("backward_q") == count("backward_kv") \
+        == layers
+    assert count("probabilities") == spans * layers
+
+
+def test_published_step_holds_no_array_of_heads_queries_keys(published_step):
+    assert not rqk_arrays(published_step[0])
+    # What the kernels read instead: the layer's masks, a byte a pair.
+    assert f"s8[1,{DSA_LENGTH},{DSA_LENGTH}]" in published_step[0]
