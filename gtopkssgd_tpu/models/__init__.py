@@ -18,13 +18,14 @@ from typing import Any, Callable, Dict, Tuple
 
 import flax.linen as nn
 
-from gtopkssgd_tpu.models import keye_vl2, qwen3_next
+from gtopkssgd_tpu.models import keye_vl2, qwen3_next, trinity_mini
 from gtopkssgd_tpu.models.alexnet import AlexNet
 from gtopkssgd_tpu.models.keye_vl2 import KeyeVL2
 from gtopkssgd_tpu.models.lstm import PTBLSTM
 from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
 from gtopkssgd_tpu.models.qwen3_next import Qwen3Next
 from gtopkssgd_tpu.models.resnet import ResNetCIFAR, ResNetImageNet
+from gtopkssgd_tpu.models.trinity_mini import TrinityMini
 from gtopkssgd_tpu.models.vgg import VGG16
 
 
@@ -141,6 +142,20 @@ _register(
         presets=tuple(keye_vl2.PRESETS),
     )
 )
+_register(
+    ModelSpec(
+        "trinity_mini",
+        TrinityMini,
+        "tokens",
+        (16384,),  # one sequence of token ids
+        # Its router's balancing bias rides in ``batch_stats``; there is no
+        # BatchNorm in it.
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
+        presets=tuple(trinity_mini.PRESETS),
+    )
+)
 
 
 def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
@@ -190,4 +205,5 @@ __all__ = [
     "DeepSpeechAN4",
     "Qwen3Next",
     "KeyeVL2",
+    "TrinityMini",
 ]

@@ -65,41 +65,112 @@ def rotary(x, theta, rotary_dims):
         [rot * jnp.cos(angle) + turned * jnp.sin(angle), rest], -1)
 
 
-def blocked_causal_attention(q, k, v, dtype, block):
+def blocked_causal_attention(q, k, v, dtype, block, window=None):
     """q [B, S, H, D], k, v [B, S, H_kv, D] float32 -> [B, S, H, D] float32.
     Queries in blocks of ``block``, each against keys 0 .. its own end and
-    rematerialised in the backward pass."""
+    rematerialised in the backward pass. With a ``window`` query t sees the
+    keys s with 0 <= t - s < window alone, and a block that starts past the
+    window is handed the ``window + block`` keys from ``window`` before its
+    start to its end, never the sequence: such blocks are alike and run as
+    one ``lax.map``."""
     batch, length, heads, dim = q.shape
     kv_heads = k.shape[2]
     q = q.reshape(batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
     k, v = k.astype(dtype), v.astype(dtype)
 
-    @functools.partial(jax.checkpoint, static_argnums=3)
-    def one(q_b, k_b, v_b, start):
+    def attend(q_b, k_b, v_b, seen):
+        """``seen()`` [queries, keys]: which key each query attends to."""
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_b, k_b,
                             preferred_element_type=F32) / math.sqrt(dim)
-        rows = start + jnp.arange(q_b.shape[1])
-        mask = rows[:, None] >= jnp.arange(k_b.shape[1])[None, :]
-        probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        probs = jax.nn.softmax(jnp.where(seen(), scores, -jnp.inf), axis=-1)
         return jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v_b,
                           preferred_element_type=F32)
 
+    @functools.partial(jax.checkpoint, static_argnums=3)
+    def one(q_b, k_b, v_b, start):
+        def seen():
+            rows = start + jnp.arange(q_b.shape[1])
+            return rows[:, None] >= jnp.arange(k_b.shape[1])[None, :]
+
+        return attend(q_b, k_b, v_b, seen)
+
+    @functools.partial(jax.checkpoint, static_argnums=(1, 2))
+    def windowed(start, queries, keys):
+        """``queries`` from ``start`` (static or not) against the ``keys``
+        that end with the block: cut out in here, so that the backward pass
+        is handed the sequence's q, k, v and not every block's copy."""
+        first = start + queries - keys
+        cut = lambda a, at, size: lax.dynamic_slice_in_dim(a, at, size, 1)
+
+        def seen():
+            apart = (start + jnp.arange(queries))[:, None] \
+                - (first + jnp.arange(keys))[None, :]
+            return (apart >= 0) & (apart < window)
+
+        return attend(cut(q, start, queries), cut(k, first, keys),
+                      cut(v, first, keys), seen)
+
+    # Blocks that end inside the first window see every key before them;
+    # the whole blocks from ``alike`` on each see window + block keys.
+    plain = length if window is None else min(length, window) // block * block
     outs = []
-    for start in range(0, length, block):
+    for start in range(0, plain, block):
         end = min(start + block, length)
         outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+    if plain < length:
+        alike = min(length, -(-window // block) * block)
+        count = (length - alike) // block
+        for start in range(plain, alike, block):
+            end = min(start + block, length)
+            outs.append(windowed(start, end - start,
+                                 min(end, window + end - start)))
+        if count:
+            out = lax.map(lambda start: windowed(start, block, window + block),
+                          alike + block * jnp.arange(count))
+            outs.append(jnp.moveaxis(out, 0, 1).reshape(
+                (batch, count * block) + out.shape[3:]))
+        start = alike + count * block
+        if start < length:
+            outs.append(windowed(start, length - start,
+                                 window + length - start))
     return jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
 
 
 # ------------------------------------------------------------ expert layer
-def route(x, router, top, normalise):
-    """(probabilities of the ``top`` experts [T, top] float32, their ids),
-    over every expert of the model, held here or not."""
+def route(x, router, top, normalise, score_func="softmax", bias=None,
+          scale=1.0):
+    """(weights of the ``top`` experts [T, top] float32, their ids), over
+    every expert of the model, held here or not. ``score_func`` scores the
+    logits (``softmax`` over the experts, or ``sigmoid`` of each); a
+    ``bias`` [experts] is added to choose the experts and to nothing else:
+    the weights are the unbiased scores, renormalised over the chosen if
+    ``normalise``, times ``scale``."""
     logits = jnp.dot(x.astype(F32), router, precision=HIGHEST)
-    values, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top)
+    if score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score_func {score_func!r}: softmax or sigmoid")
+    if bias is None:
+        values, ids = lax.top_k(scores, top)
+    else:
+        _, ids = lax.top_k(lax.stop_gradient(scores) + bias, top)
+        values = jnp.take_along_axis(scores, ids, -1)
     if normalise:
-        values = values / jnp.sum(values, -1, keepdims=True)
-    return values, ids
+        total = jnp.sum(values, -1, keepdims=True)
+        # Sigmoid scores can all underflow; softmax's largest cannot.
+        values = values / (total if score_func == "softmax" else total + 1e-20)
+    return values if scale == 1.0 else values * scale, ids
+
+
+def balanced_bias(bias, counts, rate):
+    """The aux-loss-free balancing step: an expert that took fewer than the
+    mean of ``counts`` [experts] rises by ``rate``, one that took more
+    falls, and the bias stays centred."""
+    counts = counts.astype(F32)
+    delta = rate * jnp.sign(jnp.mean(counts) - counts)
+    return bias + delta - jnp.mean(delta)
 
 
 def sort_held_slots(ids, probs, offset, held):
@@ -214,7 +285,20 @@ class SparseMoE(nn.Module):
     """``block_rows`` sorted slots are run at a time (None: 4096, or every
     slot the layer can hold if that is fewer); ``max_blocks`` caps the
     loop and so drops slots: only the test that shows ``moe_slots_dropped``
-    counting sets it."""
+    counting sets it.
+
+    What a model's router is comes from ``sizes``: ``score_func``
+    (``softmax`` if left out), ``norm_topk_prob``, ``route_scale`` and, with
+    ``load_balance_coeff``, a balancing bias [experts] that is no parameter:
+    it lives in the ``batch_stats`` collection, takes no gradient, moves the
+    choice of experts and never their weights, and where that collection
+    is mutable (a training step) is moved by this step's own counts
+    (``balanced_bias``). A shared expert has its sigmoid gate unless
+    ``shared_expert_gate`` is False.
+
+    Returns (y, slots per held expert [held], slots dropped, and with a
+    balancing bias (tokens chosen per expert [experts], the bias the
+    choice was made with), else None)."""
     sizes: dict
     dtype: Any
     block_rows: Any = None
@@ -225,9 +309,11 @@ class SparseMoE(nn.Module):
         s, dtype = self.sizes, self.dtype
         d, width = s["hidden_size"], s["moe_intermediate_size"]
         shared_w = s.get("shared_expert_intermediate_size", 0)
+        gated = s.get("shared_expert_gate", True)
         held, offset = s["experts_held"], s["expert_offset"]
-        top = s["num_experts_per_tok"]
-        router = self.param("router", _normal(), (d, s["num_experts"]), F32)
+        top, experts = s["num_experts_per_tok"], s["num_experts"]
+        rate = s.get("load_balance_coeff")
+        router = self.param("router", _normal(), (d, experts), F32)
         gate = self.param("experts_gate", _normal(), (held, d, width), F32)
         up = self.param("experts_up", _normal(), (held, d, width), F32)
         down = self.param("experts_down", _normal(), (held, width, d), F32)
@@ -237,15 +323,29 @@ class SparseMoE(nn.Module):
             s_up = self.param("shared_up_proj", _normal(), (d, shared_w), F32)
             s_down = self.param("shared_down_proj", _normal(), (shared_w, d),
                                 F32)
-            w_s = self.param("shared_gate", _normal(), (d, 1), F32)
+            if gated:
+                w_s = self.param("shared_gate", _normal(), (d, 1), F32)
+        bias = None if rate is None else self.variable(
+            "batch_stats", "router_bias", jnp.zeros, (experts,), F32)
 
         shape = x.shape
         x = x.reshape(-1, d)
         # A token's top experts are distinct: at most min(top, held) of its
         # slots fall here.
         rows = self.block_rows or min(4096, x.shape[0] * min(top, held))
+        balance = None
         with jax.named_scope("layer/moe_router"):
-            probs, ids = route(x, router, top, s["norm_topk_prob"])
+            probs, ids = route(
+                x, router, top, s["norm_topk_prob"],
+                s.get("score_func", "softmax"),
+                None if bias is None else bias.value,
+                s.get("route_scale", 1.0))
+            if bias is not None:
+                chosen = jnp.bincount(ids.reshape(-1), length=experts)
+                balance = (chosen, bias.value)
+                if not self.is_initializing() \
+                        and self.is_mutable_collection("batch_stats"):
+                    bias.value = balanced_bias(bias.value, chosen, rate)
             token, weight, load = sort_held_slots(ids, probs, offset, held)
             # The loop's last block may reach past the slots: pad them.
             token = jnp.pad(token, (0, rows))
@@ -254,12 +354,14 @@ class SparseMoE(nn.Module):
                          gate, up, down)
         if shared_w:
             with jax.named_scope("layer/shared_expert"):
-                share = jax.nn.sigmoid(dense(x, w_s, dtype).astype(F32))
+                share = jax.nn.sigmoid(dense(x, w_s, dtype).astype(F32)) \
+                    if gated else None
                 hidden = jax.nn.silu(dense(x, s_gate, dtype).astype(F32)) \
                     * dense(x, s_up, dtype).astype(F32)
-                y = y + share * dense(hidden, s_down, dtype).astype(F32)
+                out = dense(hidden, s_down, dtype).astype(F32)
+                y = y + (out if share is None else share * out)
         return (y.reshape(shape), load,
-                slots_dropped(load, rows, self.max_blocks))
+                slots_dropped(load, rows, self.max_blocks), balance)
 
 
 # Tokens whose logits over the vocabulary's rows exist at once in the loss:

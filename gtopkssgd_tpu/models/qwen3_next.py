@@ -387,7 +387,7 @@ class Layer(nn.Module):
                              else "layer/gdn_proj"):
             x = x + mixer(rms_norm0(x, w_in, eps)).astype(F32)
         with jax.named_scope("layer/moe_router"):
-            y, load, dropped = SparseMoE(s, self.dtype, name="moe")(
+            y, load, dropped, _ = SparseMoE(s, self.dtype, name="moe")(
                 rms_norm0(x, w_post, eps))
             return x + y, (load, dropped)
 

@@ -115,6 +115,7 @@ from gtopkssgd_tpu.obs.counters import (
     dsa_counters,
     model_counters,
     model_scalars,
+    moe_balance_counters,
     moe_counters,
     readable_counters,
     telemetry_scalars,
@@ -185,6 +186,7 @@ __all__ = [
     "dsa_counters",
     "model_counters",
     "model_scalars",
+    "moe_balance_counters",
     "moe_counters",
     "readable_counters",
     "telemetry_scalars",

@@ -533,6 +533,17 @@ DSA_FIELDS = (
     "dsa_index_loss",
 )
 
+# What an expert layer whose router carries a balancing bias counts
+# (models/trinity_mini.py), per optimizer step, over every expert of the
+# model, held here or not: the tokens the fullest expert was chosen by and
+# the mean (layers x experts), which the bias acts on, and the largest
+# |bias| the step chose with.
+MOE_BALANCE_FIELDS = (
+    "moe_count_max",
+    "moe_count_mean",
+    "moe_bias_absmax",
+)
+
 # The model counters last read on the host (``model_scalars``): like the
 # span buffer, it outlives the trainer, so a reader can ask afterwards.
 _last_model: Dict[str, float] = {}
@@ -565,6 +576,18 @@ def dsa_counters(kept: Array, due: Array, index_loss: Array
     }
 
 
+@jax.named_scope(SCOPE)
+def moe_balance_counters(count: Array, bias: Array) -> Dict[str, Array]:
+    """``MOE_BALANCE_FIELDS`` as f32 scalars from the expert layers'
+    selection counts and balancing biases, both [layers, experts]."""
+    count = count.astype(jnp.float32)
+    return {
+        "moe_count_max": jnp.max(count),
+        "moe_count_mean": jnp.mean(count),
+        "moe_bias_absmax": jnp.max(jnp.abs(bias)),
+    }
+
+
 # The registry of model counters: each group's fields, the keys of the
 # model's counts it is computed from and its device function. A model's
 # ``aux`` holds the groups whose counts it returns; ``model_scalars`` reads
@@ -573,6 +596,8 @@ MODEL_COUNTERS = {
     "moe": (MOE_FIELDS, ("moe_load", "moe_dropped"), moe_counters),
     "dsa": (DSA_FIELDS, ("dsa_kept", "dsa_due", "dsa_index_loss"),
             dsa_counters),
+    "moe_balance": (MOE_BALANCE_FIELDS, ("moe_count", "moe_bias"),
+                    moe_balance_counters),
 }
 
 

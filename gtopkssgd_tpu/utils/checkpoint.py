@@ -97,7 +97,10 @@ class CheckpointManager:
             # knows the old shape without guessing.
             rec["meta"] = dict(meta)
         path = self._integrity_path(step)
-        tmp = path + ".tmp"
+        # A temporary of this process's own: the processes of a run share
+        # the directory and write the same record, and with one name for
+        # all the slower one's rename found the file already moved.
+        tmp = f"{path}.{os.getpid()}.tmp"
         with open(tmp, "w") as fh:
             json.dump(rec, fh, sort_keys=True)
             fh.write("\n")

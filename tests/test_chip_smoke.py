@@ -144,6 +144,52 @@ def test_cache_dir_has_one_setter():
     assert hits == ["gtopkssgd_tpu/utils/settings.py"]
 
 
+TRIM_PROBE = (
+    "import jax, jax.numpy as jnp\n"
+    "from jax._src import monitoring\n"
+    "from gtopkssgd_tpu.utils import settings\n"
+    "calls = []\n"
+    "settings.trim_heap = lambda: calls.append(1)\n"
+    "before = len(monitoring.get_event_duration_listeners())\n"
+    "settings.enable_compilation_cache()\n"
+    "settings.enable_compilation_cache()\n"
+    "assert len(monitoring.get_event_duration_listeners()) == before + 1\n"
+    "settings.TRIM_AFTER_COMPILE_S = 1e9\n"
+    "jax.jit(lambda x: x + 1)(jnp.ones(3)).block_until_ready()\n"
+    "assert not calls, calls\n"
+    "settings.TRIM_AFTER_COMPILE_S = 0.0\n"
+    "jax.jit(lambda x: x * 2)(jnp.ones(3)).block_until_ready()\n"
+    "assert calls\n"
+    "print(len(calls) > 0)\n")
+
+
+def test_a_long_compile_is_followed_by_a_trim_of_the_heap():
+    """Registered once however often the cache is enabled; a compile
+    under ``TRIM_AFTER_COMPILE_S`` trims nothing, one over it does, and
+    that holds for a compile nobody of this package asked for."""
+    proc = _run(["-c", TRIM_PROBE])
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == ["True"]
+
+
+def test_trim_heap_hands_freed_pages_back():
+    """On glibc ``trim_heap`` is True and the process's resident memory
+    falls once a fragmented heap has been freed."""
+    from gtopkssgd_tpu.utils import settings
+
+    def resident():
+        with open("/proc/self/status") as fh:
+            return next(int(line.split()[1]) for line in fh
+                        if line.startswith("VmRSS"))
+
+    junk = [bytes(4000) for _ in range(100_000)]
+    keep = junk[::50]        # pins the arena's top: free() alone trims little
+    del junk
+    before = resident()
+    assert settings.trim_heap() is True
+    assert resident() < before - 100_000, (before, resident(), len(keep))
+
+
 def test_manifest_does_not_guard_the_backend(monkeypatch):
     """A backend that cannot be described is an error on the training
     path, not a manifest with backend=None."""

@@ -18,9 +18,11 @@ sys.path.insert(0, REPO)
 
 from gtopkssgd_tpu.models import get_model, keye_vl2 as keye_prog  # noqa: E402
 from gtopkssgd_tpu.models import qwen3_next as prog  # noqa: E402
+from gtopkssgd_tpu.models import trinity_mini as trinity_prog  # noqa: E402
 from gtopkssgd_tpu.obs import counters  # noqa: E402
 from perfbench.refmodels import keye_vl2 as keye_ref  # noqa: E402
 from perfbench.refmodels import qwen3_next as ref  # noqa: E402
+from perfbench.refmodels import trinity_mini as trinity_ref  # noqa: E402
 
 TINY = prog.PRESETS["tiny"]
 
@@ -149,13 +151,41 @@ def test_chunked_delta_rule_equals_the_recurrence(length, chunk):
             assert float(jnp.max(jnp.abs(mine - theirs))) < 1e-5, name
 
 
-# The expert layer is models/decoder.py's for both decoders of the zoo: with
-# a shared expert (qwen3_next) and without one (keye_vl2).
+# The expert layer is models/decoder.py's for all three decoders of the zoo:
+# with a gated shared expert (qwen3_next), without one (keye_vl2), and with
+# an ungated one under a sigmoid router with a balancing bias (trinity_mini,
+# whose preset is read through ``moe_sizes`` and whose bias rides in
+# ``batch_stats``: zero here).
 DECODERS = {
     "qwen3_next": (prog, ref, TINY),
     "keye_vl2": (keye_prog, keye_ref, keye_prog.PRESETS["tiny"]),
+    "trinity_mini": (trinity_prog, trinity_ref,
+                     trinity_prog.PRESETS["tiny"]),
 }
-both_decoders = pytest.mark.parametrize("decoder", sorted(DECODERS))
+all_decoders = pytest.mark.parametrize("decoder", sorted(DECODERS))
+
+
+def variables(decoder, params):
+    if decoder != "trinity_mini":
+        return {"params": params}
+    experts = DECODERS[decoder][2]["num_experts"]
+    return {"params": params,
+            "batch_stats": {"router_bias": jnp.zeros((experts,))}}
+
+
+def program_layer(decoder, sizes, params, x, **kw):
+    """(y, slots per held expert, slots dropped) of the program's layer."""
+    prog = DECODERS[decoder][0]
+    if decoder == "trinity_mini":
+        sizes = prog.moe_sizes(sizes)
+    return prog.SparseMoE(sizes, jnp.float32, **kw).apply(
+        variables(decoder, params), x)[:3]
+
+
+def reference_layer(decoder, sizes, params, x):
+    y = DECODERS[decoder][1].SparseMoE(sizes, jnp.float32).apply(
+        variables(decoder, params), x)
+    return y[0] if decoder == "trinity_mini" else y
 
 
 def moe_params(decoder, seed=3):
@@ -176,33 +206,34 @@ def share_of(params, rank, held):
                            ("experts_gate", "experts_up", "experts_down")})
 
 
-@both_decoders
+@all_decoders
 def test_the_shares_add_up_to_the_uncut_layer(decoder):
     """16 experts in the preset's ``expert_parallel`` = 4 shares of 4: the
     sum of the four shares' outputs, with what every chip computes alike (the
     shared expert, where the model has one) counted once, is the uncut
     layer's, in the program and in the reference."""
-    prog, ref, tiny = DECODERS[decoder]
+    _, _, tiny = DECODERS[decoder]
     whole, params, x = moe_params(decoder)
     ranks, held = tiny["expert_parallel"], tiny["experts_held"]
     assert ranks * held == tiny["num_experts"]
-    uncut = ref.SparseMoE(whole, jnp.float32).apply({"params": params}, x)
+    uncut = reference_layer(decoder, whole, params, x)
     only_shared = dict(tiny, experts_held=held, expert_offset=10 ** 6)
-    shared = ref.SparseMoE(only_shared, jnp.float32).apply(
-        {"params": share_of(params, 0, held)}, x)
-    assert bool(jnp.any(shared)) == ("shared_expert_intermediate_size" in tiny)
+    shared = reference_layer(decoder, only_shared, share_of(params, 0, held), x)
+    assert bool(jnp.any(shared)) == bool(
+        "shared_expert_intermediate_size" in tiny
+        or tiny.get("num_shared_experts"))
     loads = []
     for side in ("program", "reference"):
         total = 0.0
         for rank in range(ranks):
             sizes = dict(tiny, experts_held=held, expert_offset=rank * held)
-            p = {"params": share_of(params, rank, held)}
+            p = share_of(params, rank, held)
             if side == "program":
-                y, load, dropped = prog.SparseMoE(sizes, jnp.float32).apply(p, x)
+                y, load, dropped = program_layer(decoder, sizes, p, x)
                 loads.append(np.asarray(load))
                 assert int(dropped) == 0
             else:
-                y = ref.SparseMoE(sizes, jnp.float32).apply(p, x)
+                y = reference_layer(decoder, sizes, p, x)
             total = total + (y - shared)
         assert float(jnp.max(jnp.abs(total + shared - uncut))) < 1e-5, side
     # Every token-slot landed on exactly one share.
@@ -223,15 +254,15 @@ def biased_to_held_experts(decoder="qwen3_next"):
     return sizes, params, jnp.abs(x) + 1.0
 
 
-@both_decoders
+@all_decoders
 @pytest.mark.parametrize("block_rows", [None, 64])
 def test_no_slot_is_dropped_under_a_router_that_overloads_the_held(
         block_rows, decoder):
-    prog, ref, tiny = DECODERS[decoder]
+    _, _, tiny = DECODERS[decoder]
     sizes, params, x = biased_to_held_experts(decoder)
-    want = ref.SparseMoE(sizes, jnp.float32).apply({"params": params}, x)
-    y, load, dropped = prog.SparseMoE(
-        sizes, jnp.float32, block_rows=block_rows).apply({"params": params}, x)
+    want = reference_layer(decoder, sizes, params, x)
+    y, load, dropped = program_layer(decoder, sizes, params, x,
+                                     block_rows=block_rows)
     slots = x.shape[0] * x.shape[1] * tiny["num_experts_per_tok"]
     assert int(jnp.sum(load)) == slots and int(dropped) == 0
     assert float(jnp.max(jnp.abs(y - want))) < 1e-5
@@ -247,7 +278,7 @@ def test_a_capacity_limited_layer_fails_the_no_drop_test():
     reference's."""
     sizes, params, x = biased_to_held_experts()
     want = ref.SparseMoE(sizes, jnp.float32).apply({"params": params}, x)
-    y, load, dropped = prog.SparseMoE(
+    y, load, dropped, _ = prog.SparseMoE(
         sizes, jnp.float32, block_rows=64, max_blocks=1).apply(
             {"params": params}, x)
     assert int(dropped) == int(jnp.sum(load)) - 64 > 0

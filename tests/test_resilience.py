@@ -261,6 +261,34 @@ def test_checkpoint_integrity_roundtrip_and_mismatch(tmp_path):
     strict.close()
 
 
+def test_two_processes_write_one_steps_sidecar(tmp_path, monkeypatch):
+    """The processes of a run share the checkpoint directory and write the
+    same sidecar. One is overtaken between its write and its rename: with
+    one temporary name for both, its rename found the file gone (PR 35:
+    ``test_multihost.py::test_two_process_distributed_gtopk`` failed so in
+    two whole runs of three)."""
+    from gtopkssgd_tpu.utils import checkpoint
+
+    d = str(tmp_path / "ckpt")
+    slow, fast = (CheckpointManager(d, config_hash="aaaa") for _ in range(2))
+    replace, overtaken = os.replace, []
+
+    def overtaking(src, dst):
+        if not overtaken:
+            overtaken.append(src)
+            with monkeypatch.context() as other:
+                other.setattr(checkpoint.os, "getpid", lambda: os.getppid())
+                fast._write_integrity(2, _tiny_state(2.0))
+        replace(src, dst)
+
+    monkeypatch.setattr(checkpoint.os, "replace", overtaking)
+    slow._write_integrity(2, _tiny_state(2.0))
+    assert overtaken and slow._read_integrity(2)["step"] == 2
+    assert sorted(os.listdir(d)) == ["integrity-2.json"]
+    slow.close()
+    fast.close()
+
+
 def test_corrupt_latest_falls_back_to_previous_step(tmp_path):
     d = str(tmp_path / "ckpt")
     mgr = CheckpointManager(d, config_hash="aaaa")
