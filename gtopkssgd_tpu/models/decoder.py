@@ -1,9 +1,17 @@
-"""What the decoders of this zoo share (``qwen3_next``, ``keye_vl2``):
-the dropless expert layer of one chip's share of an expert group, the
-rotary embedding, the zero-centred RMSNorm, the query-block causal
-attention, and the head's loss a sequence at a time. Each model file
-states its own layer equations and imports these; nothing here knows a
-model's sizes beyond the ``sizes`` dict it is handed.
+"""What the decoders of this zoo share (``qwen3_next``, ``keye_vl2``,
+``trinity_mini``): the dropless expert layer of one chip's share of an
+expert group, the rotary embedding, the zero-centred RMSNorm, the causal
+and sliding-window attention, and the head's loss a sequence at a time.
+Each model file states its own layer equations and imports these; nothing
+here knows a model's sizes beyond the ``sizes`` dict it is handed.
+
+The attention has two forms, chosen by ``attention_form`` from what it
+observes (no flag): where the backend is a TPU and head and length fill
+whole tiles, the fused flash kernels of ``ops/flash_attention.py``, one
+call a layer forward and two backward, whose ``[heads, queries, keys]``
+scores, weights and ``d_logits`` stay in VMEM and which skip the key tiles
+that the diagonal and the window cut away; everywhere else XLA's products
+in blocks of queries, the float32 oracle of the kernels' tests.
 
 The expert layer is told which experts it holds (``experts_held`` from
 ``expert_offset`` of ``num_experts``): it routes over all of them, sorts
@@ -27,6 +35,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from gtopkssgd_tpu.ops import flash_attention as flash
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
@@ -65,14 +76,83 @@ def rotary(x, theta, rotary_dims):
         [rot * jnp.cos(angle) + turned * jnp.sin(angle), rest], -1)
 
 
-def blocked_causal_attention(q, k, v, dtype, block, window=None):
-    """q [B, S, H, D], k, v [B, S, H_kv, D] float32 -> [B, S, H, D] float32.
-    Queries in blocks of ``block``, each against keys 0 .. its own end and
-    rematerialised in the backward pass. With a ``window`` query t sees the
-    keys s with 0 <= t - s < window alone, and a block that starts past the
-    window is handed the ``window + block`` keys from ``window`` before its
-    start to its end, never the sequence: such blocks are alike and run as
-    one ``lax.map``."""
+def on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def attention_form(length, dim):
+    """``kernel`` where ``blocked_causal_attention`` runs as the Pallas
+    kernels, ``blocked`` where as XLA's products a block of queries at a
+    time: the kernels need a TPU, a head of whole 128-lane rows and a
+    length of whole tiles."""
+    whole = dim % 128 == 0 and length % flash.TILE_Q == 0 \
+        and length % flash.TILE_K == 0
+    return "kernel" if on_tpu() and whole else "blocked"
+
+
+def kernel_layout(q, k, v, dtype):
+    """q [B, S, H, D] -> [B, G, R, S, D], k, v [B, S, G, D] -> [B, G, S, D],
+    in ``dtype``: what the attention kernels read."""
+    batch, length, heads, dim = q.shape
+    groups = k.shape[2]
+    q = q.reshape(batch, length, groups, heads // groups, dim).transpose(
+        0, 2, 3, 1, 4).astype(dtype)
+    k, v = (a.transpose(0, 2, 1, 3).astype(dtype) for a in (k, v))
+    return q, k, v
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def kernel_causal_attention(q, k, v, dtype, window, kept):
+    """``blocked_causal_attention`` with the attention in kernels
+    (interpret mode off the TPU): the same arguments but the block, the
+    same values and gradients."""
+    return _kernel_attention(q, k, v, dtype, window, kept)[0]
+
+
+def _kernel_attention(q, k, v, dtype, window, kept):
+    batch, length, heads, dim = q.shape
+    q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
+    out, lse = flash.forward(q_l, k_l, v_l, window=window,
+                             interpret=not on_tpu())
+    out = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
+    if kept is not None:
+        # Named here, where the backward pass takes them from: a remat that
+        # keeps the name runs the forward kernel once a step.
+        out, lse = (checkpoint_name(a, kept) for a in (out, lse))
+    return out, (q_l, k_l, v_l, out, lse)
+
+
+def _kernel_attention_bwd(dtype, window, kept, residuals, d_out):
+    q_l, k_l, v_l, out, lse = residuals
+    batch, groups, rep, length, dim = q_l.shape
+    rows = lambda a: jnp.moveaxis(
+        a.reshape((batch, length, groups, rep) + a.shape[3:]), 1, 3)
+    delta = rows(jnp.sum(d_out * out, -1))             # sum_s p_s dP_s
+    d_out = rows(d_out).astype(dtype)
+    run = dict(window=window, interpret=not on_tpu())
+    d_q = flash.backward_q(q_l, k_l, v_l, lse, delta, d_out, **run)
+    d_k, d_v = flash.backward_kv(q_l, k_l, v_l, lse, delta, d_out, **run)
+    return (jnp.moveaxis(d_q, 3, 1).reshape(batch, length, groups * rep, dim),
+            d_k.transpose(0, 2, 1, 3), d_v.transpose(0, 2, 1, 3))
+
+
+kernel_causal_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
+
+
+def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
+    """q [B, S, H, D], k, v [B, S, H_kv, D] float32 -> [B, S, H, D] float32:
+    query t sees the keys s with 0 <= t - s (< ``window``, if given). The
+    output is named ``kept`` for a remat's policy (``checkpoint_name``).
+
+    In the kernel form (``attention_form``) one call covers the sequence
+    and ``kept`` names the rows' log-sum-exp too. In the blocked form
+    queries run in blocks of ``block``, each against keys 0 .. its own end
+    and rematerialised in the backward pass; with a ``window`` a block that
+    starts past it is handed the ``window + block`` keys from ``window``
+    before its start to its end, never the sequence: such blocks are alike
+    and run as one ``lax.map``."""
+    if attention_form(q.shape[1], q.shape[3]) == "kernel":
+        return kernel_causal_attention(q, k, v, dtype, window, kept)
     batch, length, heads, dim = q.shape
     kv_heads = k.shape[2]
     q = q.reshape(batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
@@ -133,7 +213,8 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None):
         if start < length:
             outs.append(windowed(start, length - start,
                                  window + length - start))
-    return jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
+    out = jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
+    return out if kept is None else checkpoint_name(out, kept)
 
 
 # ------------------------------------------------------------ expert layer

@@ -65,7 +65,8 @@ from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics
 
 from gtopkssgd_tpu.ops import dsa_attention as kernels
 from gtopkssgd_tpu.models.decoder import (
-    F32, SparseMoE, _normal, dense, rms_norm0, rotary, token_losses)
+    F32, SparseMoE, _normal, dense, kernel_layout, on_tpu, rms_norm0, rotary,
+    token_losses)
 
 # The published sizes (config.json of Keye-VL-2.0-30B-A3B; ``sa_config``'s
 # keys flat) with the three cuts of
@@ -349,10 +350,6 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
 KEPT_MASKS, KEPT_PROBABILITIES = "dsa_keep", "dsa_p"
 
 
-def on_tpu():
-    return jax.default_backend() == "tpu"
-
-
 def attention_form(length, dim, block):
     """``kernel`` where ``sparse_attention`` runs as the Pallas kernels,
     ``masked`` where as XLA's masked products: the kernels need a TPU, a
@@ -362,17 +359,6 @@ def attention_form(length, dim, block):
     whole = (dim % 128 == 0 and block % kernels.TILE_Q == 0
              and min(BUCKET * block, padded) % kernels.TILE_K == 0)
     return "kernel" if on_tpu() and whole else "masked"
-
-
-def _kernel_layout(q, k, v, dtype):
-    """q [B, S, H, D] -> [B, G, R, S, D], k, v [B, S, G, D] -> [B, G, S, D],
-    in ``dtype``."""
-    batch, length, heads, dim = q.shape
-    groups = k.shape[2]
-    q = q.reshape(batch, length, groups, heads // groups, dim).transpose(
-        0, 2, 3, 1, 4).astype(dtype)
-    k, v = (a.transpose(0, 2, 1, 3).astype(dtype) for a in (k, v))
-    return q, k, v
 
 
 def _tops(q, k, block):
@@ -420,7 +406,7 @@ def kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
 def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
     batch, length, heads, dim = q.shape
     with jax.named_scope("layer/attn"):
-        q_l, k_l, v_l = _kernel_layout(q, k, v, dtype)
+        q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
         top = _tops(q_l, k_l, block)
     scores, keeps, counts = [], [], []
     for bucket in buckets(length, block):

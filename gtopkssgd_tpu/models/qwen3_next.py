@@ -14,8 +14,11 @@ here is how they are computed:
     crosses chunks, in one ``lax.scan`` for the whole batch
     (``scan_chunks``; forward and, by autodiff through the same scan,
     backward);
-  * attention in blocks of queries, each block against the keys up to its
-    own end, so the [B, H, S, S] scores never exist at once;
+  * attention without the [B, H, S, S] scores ever in HBM at once
+    (``models/decoder.py``'s ``blocked_causal_attention``): on a TPU at
+    whole tiles the fused flash kernels of ``ops/flash_attention.py``,
+    elsewhere blocks of queries, each against the keys up to its own end
+    (``forms`` says which compiled: ``attention_form``);
   * the expert layer, the query-block attention, the rotary embedding, the
     norms and the head's loss are ``models/decoder.py``'s, shared with the
     other decoder of the zoo (``keye_vl2``): a dropless share of an expert
@@ -26,7 +29,8 @@ here is how they are computed:
     ``kept_across_remat``): the chunks' algebra (``prepare``) and the
     attention's query blocks (``one``) then run twice a step, forward and
     for their own backward, and not a third time when the layer's forward
-    is replayed.
+    is replayed (the attention's forward kernel once: its backward takes
+    the output and the rows' log-sum-exp, kept under the same name).
 
 Precision is the reference's: float32 parameters, residual stream, norms,
 router, softmax, recurrence state and loss; matrix products in ``dtype``.
@@ -55,8 +59,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
 
 from gtopkssgd_tpu.models.decoder import (
-    F32, HIGHEST, SparseMoE, _normal, blocked_causal_attention, dense,
-    query_block_of, rms_norm0, rotary, token_losses)
+    F32, HIGHEST, SparseMoE, _normal, attention_form,
+    blocked_causal_attention, dense, query_block_of, rms_norm0, rotary,
+    token_losses)
 
 # The chunked delta rule's float32 products (module docstring, Precision).
 _mm = functools.partial(jnp.einsum, precision=HIGHEST)
@@ -100,7 +105,8 @@ GDN_SEQUENCES = 1
 # ``checkpoint_name``: the stacked outputs of ``GatedDeltaNet``'s ``prepare``
 # (u, w, q_in, k_out [n, B, H, C, 128] and attn [n, B, H, C, C] float32:
 # 4 x 268.4 + 134.2 MB = 1.208 GB a layer at 4 x 4,096 tokens) and the
-# attention's output before its gate ([B, S, 16, 256] float32, 268 MB).
+# attention's output before its gate ([B, S, 16, 256] float32, 268 MB; in
+# the kernel form its rows' log-sum-exp too, 1 MB that the budget leaves out).
 # ``prepare`` and ``one`` keep their own ``jax.checkpoint``: that is what
 # holds one sequence's 2.7 GB (``GDN_SEQUENCES``) and one query block's
 # scores to the moment they are used; only their outputs live on. Without
@@ -361,8 +367,9 @@ class GatedAttention(nn.Module):
                 batch, length, kv_heads, dim).astype(F32)
             q = rotary(rms_norm0(q, w_qn, eps), s["rope_theta"], rotary_dims)
             k = rotary(rms_norm0(k, w_kn, eps), s["rope_theta"], rotary_dims)
-            out = checkpoint_name(blocked_causal_attention(
-                q, k, v, dtype, query_block_of(s["seq_len"])), KEPT_ATTENTION)
+            out = blocked_causal_attention(
+                q, k, v, dtype, query_block_of(s["seq_len"]),
+                kept=KEPT_ATTENTION)
             out = out * jax.nn.sigmoid(gate)
             return dense(out.reshape(batch, length, heads * dim), w_o, dtype)
 
@@ -403,6 +410,13 @@ class Qwen3Next(nn.Module):
     @property
     def sizes(self):
         return PRESETS[self.preset]
+
+    def forms(self, length):
+        """What the step compiles as at sequences of ``length``, for the
+        run's manifest and ``train`` records: a run on the chip that fell
+        back to the blocked attention says so."""
+        return {"attention_form": attention_form(
+            length, self.sizes["head_dim"])}
 
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):

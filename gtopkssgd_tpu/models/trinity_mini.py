@@ -13,12 +13,18 @@ parameter names and shapes are equal leaf for leaf,
 ``tests/test_trinity_mini.py`` holds the two together). What differs here is
 how they are computed:
 
-  * attention in blocks of queries (``models/decoder.py``'s
-    ``blocked_causal_attention``): a full layer's block against the keys up
-    to its own end, a sliding layer's against the ``sliding_window`` +
-    block keys that end with it, so that four layers of five never touch
-    the pairs the window cuts away; the blocks past the first window are
-    alike and one ``lax.map``;
+  * the attention is ``models/decoder.py``'s ``blocked_causal_attention``,
+    told each layer's window (``sliding_window`` or None). On a TPU at
+    whole tiles it runs as the fused flash kernels of
+    ``ops/flash_attention.py``, one call a layer for the whole sequence
+    forward and two backward: the scores stay in VMEM, and a query tile of
+    512 visits the 5 key tiles of 32 that hold its window (every tile up to
+    the diagonal in the full layer), so four layers of five never touch
+    the pairs the window cuts away. Everywhere else XLA's products in
+    blocks of queries: a full layer's block against the keys up to its own
+    end, a sliding layer's against the ``sliding_window`` + block keys that
+    end with it, the blocks past the first window alike and one
+    ``lax.map``. ``forms`` says which compiled (``attention_form``);
   * the expert layer is the zoo's dropless share of an expert group
     (``SparseMoE``), told by ``sizes`` that its router scores with a
     sigmoid, chooses on score + bias, weighs by the score alone and scales
@@ -29,9 +35,11 @@ how they are computed:
     residual and no top-k, and a training step moves it from its own
     routing counts;
   * the head and the loss a sequence (``LOSS_ROWS`` tokens of it) at a
-    time; every layer under ``jax.checkpoint``, which keeps by name what
-    the query blocks' own checkpoint gives out (``KEPT_ATTENTION``): a
-    block runs twice a step, forward and for its own backward.
+    time; every layer under ``jax.checkpoint``, which keeps by name
+    (``KEPT_ATTENTION``) the attention's output and, in the kernel form,
+    its rows' log-sum-exp: the forward kernel runs once a step and the
+    backward kernels take both from there; in the blocked form a block
+    runs twice a step, forward and for its own backward.
 
 Precision is the reference's: float32 parameters, residual stream, norms,
 rotary, router, softmax, gates and loss; matrix products in ``dtype`` with
@@ -53,11 +61,10 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from gtopkssgd_tpu.models.decoder import (
-    F32, SparseMoE, _normal, blocked_causal_attention, dense, rms_norm0,
-    rotary, token_losses)
+    F32, SparseMoE, _normal, attention_form, blocked_causal_attention, dense,
+    rms_norm0, rotary, token_losses)
 
 # The published sizes (config.json of Trinity-Mini) with the four cuts of
 # perfbench/configs/trinity_mini_26b_a3b_ep16.json, whose ``sizes`` a test
@@ -93,7 +100,8 @@ PRESETS = {
 
 # What a layer's remat keeps from its forward to its backward pass, by
 # ``checkpoint_name``: the attention's output before its gate ([B, S, H, D]
-# float32, 268 MB a layer at 16,384 tokens, 1.34 GB over the five).
+# float32, 268 MB a layer at 16,384 tokens, 1.34 GB over the five) and, in
+# the kernel form, its rows' log-sum-exp ([B, G, R, S] float32, 2 MB).
 KEPT_ATTENTION = "afmoe_attn_out"
 
 
@@ -147,9 +155,9 @@ class GatedAttention(nn.Module):
         k, v = rms_norm0(kv[:, :, 0], w_kn, eps), kv[:, :, 1].astype(F32)
         if self.sliding:
             q, k = (rotary(a, s["rope_theta"], dim) for a in (q, k))
-        out = checkpoint_name(blocked_causal_attention(
+        out = blocked_causal_attention(
             q, k, v, dtype, query_block_of(s["seq_len"]),
-            s["sliding_window"] if self.sliding else None), KEPT_ATTENTION)
+            s["sliding_window"] if self.sliding else None, KEPT_ATTENTION)
         out = out.reshape(batch, length, heads * dim) * jax.nn.sigmoid(gate)
         return dense(out, w_o, dtype)
 
@@ -216,6 +224,13 @@ class TrinityMini(nn.Module):
     @property
     def sizes(self):
         return PRESETS[self.preset]
+
+    def forms(self, length):
+        """What the step compiles as at sequences of ``length``, for the
+        run's manifest and ``train`` records: a run on the chip that fell
+        back to the blocked attention says so."""
+        return {"attention_form": attention_form(
+            length, self.sizes["head_dim"])}
 
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):

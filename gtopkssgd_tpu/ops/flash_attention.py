@@ -1,0 +1,367 @@
+"""Pallas TPU kernels for the decoders' causal and sliding-window softmax
+attention (``models/decoder.py::blocked_causal_attention``): the
+``[heads, queries, keys]`` scores, weights and ``d_logits`` live in VMEM
+tiles and never reach HBM.
+
+Query t attends to the keys s with 0 <= t - s < ``window`` (``window``
+None: 0 <= t - s). Both bounds are arithmetic on a tile's two indices: no
+mask array exists anywhere, only a band's edge tiles make the comparison
+(by ``iota``), and **a key tile wholly outside the band is neither visited
+nor fetched**: the grid's last axis is as long as the widest query tile's
+band (5 key tiles of 32 at a window of 2,048 and tiles of 512; every tile
+up to the diagonal without a window, where the steps past it repeat the
+last tile's index, so nothing is fetched for them).
+
+The precision is the XLA form's: q.k^T in the arrays' dtype with float32
+accumulation, scale, maximum, ``exp`` and row sums in float32, the weights
+rounded to that dtype for the a.v product, float32 accumulation and a
+float32 output. The softmax is the online one (a running maximum m and sum
+l, rescaled where the maximum rises), so what is rounded is the weight
+before its normalisation, with the same relative rounding. The backward
+pass makes p = exp(logits - lse) again from the rows' log-sum-exp
+``lse`` = m + log l and rounds p and ``d_logits`` = p * (dP - delta) to the
+dtype for their products; d_q, d_k, d_v are float32.
+
+One call covers a layer's whole sequence, one key-value head's R query
+heads folded into the rows of each product (a q tile is ``[R * tq, D]``: a
+key tile is fetched once for its R heads). Layouts, as
+``ops/dsa_attention.py``'s:
+
+  q, d_out, o, d_q   [B, G, R, S, D]       k, v, d_k, d_v   [B, G, S, D]
+  lse, delta         [B, G, R, S]          float32, a number a row, the
+                                            queries along the lanes (a last
+                                            axis of 1 would cost 128 lanes
+                                            a number)
+
+Three kernels: ``forward`` (o, lse), ``backward_q`` (q tiles outside, the
+band's key tiles swept) and ``backward_kv`` (key tiles outside, the query
+tiles that see them swept). ``forward`` and ``backward_kv`` make the logits
+transposed, [tk, R * tq], so that a row's numbers (m, l, lse, delta) lie
+along the lanes as the arrays hold them: as columns [R * tq, 1] they cost a
+register for every 8 rows, and the forward kernel, which touches m and l at
+every step, ran at 48% of the MXU's peak so and runs at 64% transposed
+(24.0 -> 18.0 ms a causal layer of 16,384 tokens; PERF.md section 6, PR 36).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
+
+from gtopkssgd_tpu.ops.dsa_attention import (
+    _NT, _SWEEP, _column, _lanes, _params, _tiles as _whole_tiles)
+
+F32 = jnp.float32
+# Queries and keys a tile. A step multiplies R * TILE_Q rows by TILE_K keys.
+TILE_Q, TILE_K = 512, 512
+# What a key outside the band scores: finite, so that a row whose first
+# visited tile holds none of its keys has a maximum to subtract (its weights
+# there are wiped when a real key raises the maximum; every row sees itself).
+MASKED = -0.7 * float(jnp.finfo(F32).max)
+_TN = (((0,), (0,)), ((), ()))          # a [n, d] x [n, m] -> [d, m] product
+
+
+def _tiles(length, tq, tk):
+    """The tile sizes of a call (``TILE_Q`` x ``TILE_K`` unless given)."""
+    return _whole_tiles(length, tq or TILE_Q, tk or TILE_K)
+
+
+def _at_least_0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _at_most(x, top):
+    return min(x, top) if isinstance(x, int) else jnp.minimum(x, top)
+
+
+# The band, in tiles; ``i`` and ``j`` are ints or traced grid indices.
+def first_key_tile(i, tq, tk, window):
+    """The key tile of the oldest key query tile i's first row sees."""
+    return 0 * i if window is None \
+        else _at_least_0(i * tq - window + 1) // tk
+
+
+def last_key_tile(i, tq, tk):
+    """The key tile of query tile i's last row."""
+    return ((i + 1) * tq - 1) // tk
+
+
+def first_query_tile(j, tq, tk):
+    """The query tile of key tile j's first key."""
+    return j * tk // tq
+
+
+def last_query_tile(j, tq, tk, window, length):
+    """The query tile of the last row that sees key tile j's last key."""
+    last = length // tq - 1
+    return last + 0 * j if window is None \
+        else _at_most(((j + 1) * tk + window - 2) // tq, last)
+
+
+def key_tiles(length, tq, tk, window):
+    """[(first, last) key tile of each query tile]."""
+    return [(first_key_tile(i, tq, tk, window), last_key_tile(i, tq, tk))
+            for i in range(length // tq)]
+
+
+def query_tiles(length, tq, tk, window):
+    """[(first, last) query tile of each key tile]."""
+    return [(first_query_tile(j, tq, tk),
+             last_query_tile(j, tq, tk, window, length))
+            for j in range(length // tk)]
+
+
+def _sweep(spans):
+    """The grid's last axis: the longest of ``spans``."""
+    return max(last - first + 1 for first, last in spans)
+
+
+def _window(window, length):
+    """A window that cuts no pair is none."""
+    return None if window is None or window >= length else window
+
+
+def _inside(i, j, tq, tk, window):
+    """Whether every pair of query tile i and key tile j is in the band."""
+    seen = i * tq >= (j + 1) * tk - 1
+    return seen if window is None \
+        else seen & ((i + 1) * tq - 1 - j * tk < window)
+
+
+def _bias(i, j, tq, tk, window, transposed=False):
+    """The band inside a tile, as what is added to a logit: 0 in it,
+    ``MASKED`` outside. [tq, tk], or [tk, tq] for the transposed logits."""
+    shape = (tk, tq) if transposed else (tq, tk)
+    rows = i * tq + lax.broadcasted_iota(jnp.int32, shape, int(transposed))
+    keys = j * tk + lax.broadcasted_iota(jnp.int32, shape,
+                                         int(not transposed))
+    apart = rows - keys
+    seen = apart >= 0 if window is None \
+        else (apart >= 0) & (apart < window)
+    return jnp.where(seen, 0.0, MASKED)
+
+
+def _edges(visited, inside, step):
+    """``step(masked)`` for a visited tile: without the comparison where
+    the whole tile is inside the band."""
+    pl.when(visited & inside)(lambda: step(False))
+    pl.when(visited & jnp.logical_not(inside))(lambda: step(True))
+
+
+def _row_specs(heads, tq, dim, tk, window):
+    """Block specs for a grid (b, g, i, step) that sweeps query tile i's
+    key tiles: of a [B, G, R, S, D] array (``rows(dim)``) or a
+    [B, G, R, S] one (``rows()``), and of k / v. A step past the tile's
+    last key tile repeats its index, so nothing is fetched for it."""
+    def key_tile(i, step):
+        return jnp.minimum(first_key_tile(i, tq, tk, window) + step,
+                           last_key_tile(i, tq, tk))
+
+    rows = lambda *width: pl.BlockSpec(
+        (None, None, heads, tq) + width,
+        lambda b, g, i, step: (b, g, 0, i) + (0,) * len(width))
+    keys = pl.BlockSpec((None, None, tk, dim),
+                        lambda b, g, i, step: (b, g, key_tile(i, step), 0))
+    return rows, keys
+
+
+# ------------------------------------------------------------------ forward
+def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                    acc_ref, *, scale, window):
+    """The logits transposed, [tk, R * tq], as in ``_backward_kv_kernel``:
+    a row's maximum and sum are reductions over sublanes and lie along the
+    lanes ([1, R * tq]: 32 registers, where a column [R * tq, 1] takes 512),
+    and so does what rescales the accumulator, [D, R * tq]."""
+    heads, tq, dim = q_ref.shape
+    rows, tk = heads * tq, k_ref.shape[0]
+    i, at = pl.program_id(2), pl.program_id(3)
+    j = first_key_tile(i, tq, tk, window) + at
+
+    @pl.when(at == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(masked):
+        v = v_ref[...]
+        logits = lax.dot_general(k_ref[...], q_ref[...].reshape(rows, dim),
+                                 _NT, preferred_element_type=F32) * scale
+        if masked:
+            logits = logits + jnp.concatenate(
+                [_bias(i, j, tq, tk, window, transposed=True)] * heads, axis=1)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(logits, axis=0, keepdims=True))
+        fade = jnp.exp(m - m_new)
+        w = jnp.exp(logits - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = fade * l_ref[...] + jnp.sum(w, axis=0, keepdims=True)
+        acc_ref[...] = fade * acc_ref[...] + lax.dot_general(
+            v, w.astype(v.dtype), _TN, preferred_element_type=F32)
+
+    _edges(j <= last_key_tile(i, tq, tk), _inside(i, j, tq, tk, window), step)
+
+    @pl.when(at == pl.num_programs(3) - 1)
+    def _():
+        total = l_ref[...]
+        o_ref[...] = (acc_ref[...] / total).T.reshape(heads, tq, dim)
+        lse = m_ref[...] + _ln(total)
+        lse_ref[...] = jnp.concatenate(
+            [lse[:, r * tq:(r + 1) * tq] for r in range(heads)], axis=0)
+
+
+def forward(q, k, v, *, window=None, tile_q=None, tile_k=None,
+            interpret=False):
+    """(o [B, G, R, S, D] float32, lse [B, G, R, S] float32)."""
+    batch, groups, heads, length, dim = q.shape
+    tq, tk = _tiles(length, tile_q, tile_k)
+    window = _window(window, length)
+    rows, keys = _row_specs(heads, tq, dim, tk, window)
+    return pl.pallas_call(
+        functools.partial(_forward_kernel, scale=1.0 / math.sqrt(dim),
+                          window=window),
+        grid=(batch, groups, length // tq,
+              _sweep(key_tiles(length, tq, tk, window))),
+        in_specs=[rows(dim), keys, keys],
+        out_specs=[rows(dim), rows()],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, F32),
+                   jax.ShapeDtypeStruct(q.shape[:-1], F32)],
+        scratch_shapes=[pltpu.VMEM((1, heads * tq), F32),
+                        pltpu.VMEM((1, heads * tq), F32),
+                        pltpu.VMEM((dim, heads * tq), F32)],
+        compiler_params=_params(_SWEEP),
+        name="flash_attention_forward", interpret=interpret,
+    )(q, k, v)
+
+
+# ----------------------------------------------------------------- backward
+def _backward_q_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
+                       dq_ref, acc_ref, *, scale, window):
+    heads, tq, dim = q_ref.shape
+    rows, tk = heads * tq, k_ref.shape[0]
+    i, at = pl.program_id(2), pl.program_id(3)
+    j = first_key_tile(i, tq, tk, window) + at
+
+    @pl.when(at == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(masked):
+        k = k_ref[...]
+        logits = lax.dot_general(q_ref[...].reshape(rows, dim), k, _NT,
+                                 preferred_element_type=F32) * scale
+        if masked:
+            logits = (logits.reshape(heads, tq, tk)
+                      + _bias(i, j, tq, tk, window)[None]).reshape(rows, tk)
+        p = jnp.exp(logits - _column(lse_ref[...]))
+        d_p = lax.dot_general(do_ref[...].reshape(rows, dim), v_ref[...], _NT,
+                              preferred_element_type=F32)
+        d_logits = (p * (d_p - _column(delta_ref[...]))).astype(k.dtype)
+        acc_ref[...] += jnp.dot(d_logits, k, preferred_element_type=F32)
+
+    _edges(j <= last_key_tile(i, tq, tk), _inside(i, j, tq, tk, window), step)
+
+    @pl.when(at == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[...] = (acc_ref[...] * scale).reshape(heads, tq, dim)
+
+
+def backward_q(q, k, v, lse, delta, d_out, *, window=None, tile_q=None,
+               tile_k=None, interpret=False):
+    """d_q [B, G, R, S, D] float32. ``d_out`` in the dtype of q, k, v;
+    ``delta`` = sum_d d_out * o, a number a row, float32."""
+    batch, groups, heads, length, dim = q.shape
+    tq, tk = _tiles(length, tile_q, tile_k)
+    window = _window(window, length)
+    rows, keys = _row_specs(heads, tq, dim, tk, window)
+    return pl.pallas_call(
+        functools.partial(_backward_q_kernel, scale=1.0 / math.sqrt(dim),
+                          window=window),
+        grid=(batch, groups, length // tq,
+              _sweep(key_tiles(length, tq, tk, window))),
+        in_specs=[rows(dim), keys, keys, rows(), rows(), rows(dim)],
+        out_specs=rows(dim),
+        out_shape=jax.ShapeDtypeStruct(q.shape, F32),
+        scratch_shapes=[pltpu.VMEM((heads * tq, dim), F32)],
+        compiler_params=_params(_SWEEP),
+        name="flash_attention_backward_q", interpret=interpret,
+    )(q, k, v, lse, delta, d_out)
+
+
+def _backward_kv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
+                        dk_ref, dv_ref, dk_acc, dv_acc, *, scale, window,
+                        length):
+    """The logits transposed, [tk, R * tq]: a row's ``lse`` and ``delta``
+    lie along the lanes as the arrays hold them, and the sums over queries
+    are plain products."""
+    heads, tq, dim = q_ref.shape
+    rows, tk = heads * tq, k_ref.shape[0]
+    j, at = pl.program_id(2), pl.program_id(3)
+    i = first_query_tile(j, tq, tk) + at
+
+    @pl.when(at == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(masked):
+        q, d_o = q_ref[...].reshape(rows, dim), do_ref[...].reshape(rows, dim)
+        logits = lax.dot_general(k_ref[...], q, _NT,
+                                 preferred_element_type=F32) * scale
+        if masked:
+            logits = logits + jnp.concatenate(
+                [_bias(i, j, tq, tk, window, transposed=True)] * heads, axis=1)
+        p = jnp.exp(logits - _lanes(lse_ref[...]))
+        dv_acc[...] += jnp.dot(p.astype(q.dtype), d_o,
+                               preferred_element_type=F32)
+        d_p = lax.dot_general(v_ref[...], d_o, _NT,
+                              preferred_element_type=F32)
+        d_logits = (p * (d_p - _lanes(delta_ref[...]))).astype(q.dtype)
+        dk_acc[...] += jnp.dot(d_logits, q, preferred_element_type=F32)
+
+    _edges(i <= last_query_tile(j, tq, tk, window, length),
+           _inside(i, j, tq, tk, window), step)
+
+    @pl.when(at == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...] * scale
+        dv_ref[...] = dv_acc[...]
+
+
+def backward_kv(q, k, v, lse, delta, d_out, *, window=None, tile_q=None,
+                tile_k=None, interpret=False):
+    """(d_k, d_v) [B, G, S, D] float32."""
+    batch, groups, heads, length, dim = q.shape
+    tq, tk = _tiles(length, tile_q, tile_k)
+    window = _window(window, length)
+
+    def query_tile(j, step):
+        return jnp.minimum(first_query_tile(j, tq, tk) + step,
+                           last_query_tile(j, tq, tk, window, length))
+
+    rows = pl.BlockSpec(
+        (None, None, heads, tq, dim),
+        lambda b, g, j, step: (b, g, 0, query_tile(j, step), 0))
+    lanes = pl.BlockSpec((None, None, heads, tq),
+                         lambda b, g, j, step: (b, g, 0, query_tile(j, step)))
+    keys = pl.BlockSpec((None, None, tk, dim),
+                        lambda b, g, j, step: (b, g, j, 0))
+    return pl.pallas_call(
+        functools.partial(_backward_kv_kernel, scale=1.0 / math.sqrt(dim),
+                          window=window, length=length),
+        grid=(batch, groups, length // tk,
+              _sweep(query_tiles(length, tq, tk, window))),
+        in_specs=[rows, keys, keys, lanes, lanes, rows],
+        out_specs=[keys, keys],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, F32)] * 2,
+        scratch_shapes=[pltpu.VMEM((tk, dim), F32)] * 2,
+        compiler_params=_params(_SWEEP),
+        name="flash_attention_backward_kv", interpret=interpret,
+    )(q, k, v, lse, delta, d_out)

@@ -34,6 +34,31 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device, for the files that ask the chip's compiler
+    without the chip (``test_pallas_compile.py``, ``test_flash_compile.py``:
+    a file each, so that each has a worker of its own). Nothing but a test
+    that asks for it describes the topology. The persistent cache is off
+    around the module (a compile for a described device is written to it
+    but cannot be read back without a chip, and the next one warns)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
 def load_benchmark_module(name: str):
     """Import benchmarks/<name>.py by path (benchmarks/ is not a package
     on sys.path for the test run). Shared by the tests that pin the

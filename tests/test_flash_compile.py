@@ -1,0 +1,150 @@
+"""The decoders' attention kernels (ops/flash_attention.py), asked of the
+chip's compiler without the chip: each kernel at both cells' published
+shapes, and the sliding-window decoder's whole ``Trainer`` step in its
+kernel form. Nothing executes; a passing compile is not a chip run. Skipped,
+not failed, where the topology cannot be described (``conftest.py``'s
+``v5e``). A file of its own beside ``test_pallas_compile.py``, so that the
+whole-step compiles of the two run on two workers.
+"""
+
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gtopkssgd_tpu.models import decoder, qwen3_next, trinity_mini
+from gtopkssgd_tpu.ops import flash_attention as flash
+
+TRINITY = trinity_mini.PRESETS["26b_a3b_ep16"]
+QWEN = qwen3_next.PRESETS["80b_a3b_ep64"]
+# (sequences a step in the cell, the preset, the window)
+LAYERS = {"sliding": (1, TRINITY, TRINITY["sliding_window"]),
+          "full": (1, TRINITY, None),
+          "hybrid": (4, QWEN, None)}
+KERNELS = {
+    "forward": lambda q, k, row, **kw: flash.forward(q, k, k, **kw),
+    "backward_q": lambda q, k, row, **kw: flash.backward_q(
+        q, k, k, row, row, q, **kw),
+    "backward_kv": lambda q, k, row, **kw: flash.backward_kv(
+        q, k, k, row, row, q, **kw),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+@pytest.mark.parametrize("layer", sorted(LAYERS))
+def test_flash_attention_kernel_compiles_at_the_published_shapes(
+        v5e, layer, kernel):
+    """16,384 tokens x 32 / 4 heads of 128 under the window of 2,048 and
+    under none, and 4 x 4,096 tokens x 16 / 2 heads of 256: bfloat16, the
+    tiles the program uses, one custom call each."""
+    batch, sizes, window = LAYERS[layer]
+    length, dim = sizes["seq_len"], sizes["head_dim"]
+    groups = sizes["num_key_value_heads"]
+    rows = (batch, groups, sizes["num_attention_heads"] // groups, length)
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
+    text = jax.jit(lambda *a: KERNELS[kernel](*a, window=window)).lower(
+        shape(rows + (dim,), jnp.bfloat16),
+        shape((batch, groups, length, dim), jnp.bfloat16),
+        shape(rows, jnp.float32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"flash_attention_{kernel}" in text
+
+
+def score_arrays(text):
+    """The arrays of a compiled module that hold a number for every (query
+    head, query of a block of 512, key): what the blocked form writes to
+    HBM (``[4, 8, 512, keys]`` in float32, ``dtype`` and pred, ``keys`` 512
+    to 16,384 in the full layer and 2,560 past the window) and the kernels
+    keep in VMEM."""
+    found = collections.Counter()
+    for dtype, dims in re.findall(r"\b(f32|bf16|pred)\[([0-9,]+)\]", text):
+        big = [int(d) for d in dims.split(",") if d and int(d) > 1]
+        if len(big) >= 3 and big[-2] == 512 and big[-1] % 512 == 0 \
+                and big[-3] in (8, 32):
+            found[dtype, tuple(big)] += 1
+    return found
+
+
+def test_score_arrays_finds_the_blocked_forms_and_no_other():
+    blocked = ("%f = bf16[1,4,8,512,2560]{4,3,2,1,0} fusion(f32[1,4,8,512,2560] "
+               "%a), %m = pred[4,8,512,16384] compare(...), f32[4,8,512,512]")
+    assert set(score_arrays(blocked)) == {
+        ("bf16", (4, 8, 512, 2560)), ("f32", (4, 8, 512, 2560)),
+        ("pred", (4, 8, 512, 16384)), ("f32", (4, 8, 512, 512))}
+    others = ("bf16[1,4,8,16384,128] %q, f32[1,4,8,16384] %lse, "
+              "f32[8,2048,1024] %experts, f32[4096,25024] %logits, "
+              "f32[16384,128] %router, f32[8,512,128] %tile, "
+              "f32[1,16384,32,128] %out, f32[4096,2048] %slots")
+    assert not score_arrays(others)
+
+
+@pytest.fixture(scope="module")
+def published_step(v5e):
+    """(compiled text, bytes) of the sliding-window decoder's whole Trainer
+    step (the ``trinity_mini_ep16.gtopk`` cell's flags) for the described
+    v5e, with the attention in its kernel form: the backend here is the
+    CPU, so the test, not an option of the program, answers ``on_tpu``. One
+    compile (two minutes) serves the tests below."""
+    from gtopkssgd_tpu.trainer import TrainConfig, Trainer
+
+    abstract = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=v5e), tree)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(decoder, "on_tpu", lambda: True)
+        jax.clear_caches()
+        with Trainer(TrainConfig(
+                dnn="trinity_mini", dataset="tokens", dtype="bfloat16",
+                seed=42, model_preset="26b_a3b_ep16", batch_size=1,
+                nworkers=1, compression="gtopk", density=0.001, lr=0.1,
+                momentum=0.9, weight_decay=0.0, clip_grad_norm=1.0,
+                prefetch=0)) as trainer:
+            assert trainer._manifest["attention_form"] == "kernel"
+            batch = trainer._device_batch(
+                trainer._shard_batches(trainer._iters)[0])
+            compiled = trainer._train_step.lower(
+                abstract(trainer.state), abstract(trainer.carry),
+                abstract(batch)).compile()
+    jax.clear_caches()
+    memory = compiled.memory_analysis()
+    return compiled.as_text(), (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+
+
+def test_published_step_stays_under_its_memory_line(published_step):
+    """12.3 GB of the v5e's 16.9 by XLA's ``memory_analysis()`` (temp +
+    argument + output - alias; equal to the chip's to the byte, PERF.md
+    section 4): the blocked form's step read 12.19, and the kernel form
+    may only take less (the score arrays go, 2 MB a layer come)."""
+    assert published_step[1] < 12.3e9, published_step[1]
+
+
+def test_published_step_runs_each_attention_kernel_once_a_layer(
+        published_step):
+    """The engagement counter, static like the mechanism: a layer holds one
+    forward and the two backward kernels (the remat's replay runs none: the
+    output and the rows' log-sum-exp are kept by name), and each call
+    carries its layer kind's scope, backward too, so that the device trace
+    counts it where it runs (``swa_attn_ms``, ``full_attn_ms``)."""
+    calls = [line for line in published_step[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kinds = TRINITY["layer_kinds"].split(",")
+    for name in KERNELS:
+        mine = [line for line in calls
+                if re.search(rf"flash_attention_{name}\b", line)]
+        assert len(mine) == len(kinds), (name, len(mine))
+        scopes = collections.Counter(
+            re.search(r'op_name="[^"]*layer/(attn_\w+)', line).group(1)
+            for line in mine)
+        assert scopes == {"attn_window": kinds.count("sliding"),
+                          "attn_full": kinds.count("full")}, (name, scopes)
+
+
+def test_published_step_holds_no_array_of_heads_queries_keys(published_step):
+    assert not score_arrays(published_step[0])
+    # What the kernels read and write instead, in their own layout.
+    length = TRINITY["seq_len"]
+    assert f"bf16[1,4,8,{length},128]" in published_step[0]
+    assert f"f32[1,4,8,{length}]" in published_step[0]

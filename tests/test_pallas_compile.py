@@ -5,7 +5,8 @@ whose sublane dimension is below 8) or cannot fit (VMEM). The TPU compiler
 is installed here and compiles for a *described* v5e topology, so these
 AOT compiles guard the main path's kernels at real widths on every PR at no
 chip time. Nothing executes; a passing compile is not a chip run. Skipped,
-not failed, where the topology cannot be described.
+not failed, where the topology cannot be described (the ``v5e`` fixture is
+``conftest.py``'s, shared with ``test_flash_compile.py``).
 """
 
 import os
@@ -15,7 +16,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from gtopkssgd_tpu.ops import pallas_topk as pk
 from gtopkssgd_tpu.ops.topk import (
@@ -27,26 +27,6 @@ from gtopkssgd_tpu.ops.topk import (
 
 RESNET20_N = 272_474
 RESNET50_N = 25_557_032
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    """One described v5e device; the persistent cache is off around the
-    module (a compile for a described device is written to it but cannot
-    be read back without a chip, and the next one warns)."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"cannot describe a v5e topology: {e}")
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
 
 
 def _compile(fn, *shapes, device):

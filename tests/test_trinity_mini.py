@@ -347,7 +347,7 @@ def test_remat_keeps_the_attention_by_name_and_changes_no_value(seeded):
     params, biases, batch = seeded
     kept = program_side(params, biases, batch)
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(prog, "checkpoint_name", lambda x, name: x)
+        patch.setattr(decoder, "checkpoint_name", lambda x, name: x)
         jax.clear_caches()
         bare = program_side(params, biases, batch)
     jax.clear_caches()
