@@ -11,7 +11,13 @@ whole tiles, the fused flash kernels of ``ops/flash_attention.py``, one
 call a layer forward and two backward, whose ``[heads, queries, keys]``
 scores, weights and ``d_logits`` stay in VMEM and which skip the key tiles
 that the diagonal and the window cut away; everywhere else XLA's products
-in blocks of queries, the float32 oracle of the kernels' tests.
+in blocks of queries, the float32 oracle of the kernels' tests. Either way
+its operations are named for the device trace, within the caller's
+``layer/<kind>``: ``part/layout`` (to and from the kernels' layout, the
+blocks' cutting and joining) and ``part/kernel`` (the Pallas calls; in the
+blocked form the products and softmax they stand for), forward and in the
+written-out backward rule alike; the mixers name their own ``part/proj`` and
+``part/pointwise`` (the three levels of names: ``trainer._build_train_step``).
 
 The expert layer is told which experts it holds (``experts_held`` from
 ``expert_offset`` of ``num_experts``): it routes over all of them, sorts
@@ -95,9 +101,10 @@ def kernel_layout(q, k, v, dtype):
     in ``dtype``: what the attention kernels read."""
     batch, length, heads, dim = q.shape
     groups = k.shape[2]
-    q = q.reshape(batch, length, groups, heads // groups, dim).transpose(
-        0, 2, 3, 1, 4).astype(dtype)
-    k, v = (a.transpose(0, 2, 1, 3).astype(dtype) for a in (k, v))
+    with jax.named_scope("part/layout"):
+        q = q.reshape(batch, length, groups, heads // groups, dim).transpose(
+            0, 2, 3, 1, 4).astype(dtype)
+        k, v = (a.transpose(0, 2, 1, 3).astype(dtype) for a in (k, v))
     return q, k, v
 
 
@@ -112,13 +119,15 @@ def kernel_causal_attention(q, k, v, dtype, window, kept):
 def _kernel_attention(q, k, v, dtype, window, kept):
     batch, length, heads, dim = q.shape
     q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
-    out, lse = flash.forward(q_l, k_l, v_l, window=window,
-                             interpret=not on_tpu())
-    out = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
-    if kept is not None:
-        # Named here, where the backward pass takes them from: a remat that
-        # keeps the name runs the forward kernel once a step.
-        out, lse = (checkpoint_name(a, kept) for a in (out, lse))
+    with jax.named_scope("part/kernel"):
+        out, lse = flash.forward(q_l, k_l, v_l, window=window,
+                                 interpret=not on_tpu())
+    with jax.named_scope("part/layout"):
+        out = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
+        if kept is not None:
+            # Named here, where the backward pass takes them from: a remat
+            # that keeps the name runs the forward kernel once a step.
+            out, lse = (checkpoint_name(a, kept) for a in (out, lse))
     return out, (q_l, k_l, v_l, out, lse)
 
 
@@ -127,13 +136,17 @@ def _kernel_attention_bwd(dtype, window, kept, residuals, d_out):
     batch, groups, rep, length, dim = q_l.shape
     rows = lambda a: jnp.moveaxis(
         a.reshape((batch, length, groups, rep) + a.shape[3:]), 1, 3)
-    delta = rows(jnp.sum(d_out * out, -1))             # sum_s p_s dP_s
-    d_out = rows(d_out).astype(dtype)
+    with jax.named_scope("part/layout"):
+        delta = rows(jnp.sum(d_out * out, -1))         # sum_s p_s dP_s
+        d_out = rows(d_out).astype(dtype)
     run = dict(window=window, interpret=not on_tpu())
-    d_q = flash.backward_q(q_l, k_l, v_l, lse, delta, d_out, **run)
-    d_k, d_v = flash.backward_kv(q_l, k_l, v_l, lse, delta, d_out, **run)
-    return (jnp.moveaxis(d_q, 3, 1).reshape(batch, length, groups * rep, dim),
-            d_k.transpose(0, 2, 1, 3), d_v.transpose(0, 2, 1, 3))
+    with jax.named_scope("part/kernel"):
+        d_q = flash.backward_q(q_l, k_l, v_l, lse, delta, d_out, **run)
+        d_k, d_v = flash.backward_kv(q_l, k_l, v_l, lse, delta, d_out, **run)
+    with jax.named_scope("part/layout"):
+        return (jnp.moveaxis(d_q, 3, 1).reshape(
+                    batch, length, groups * rep, dim),
+                d_k.transpose(0, 2, 1, 3), d_v.transpose(0, 2, 1, 3))
 
 
 kernel_causal_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
@@ -155,9 +168,12 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
         return kernel_causal_attention(q, k, v, dtype, window, kept)
     batch, length, heads, dim = q.shape
     kv_heads = k.shape[2]
-    q = q.reshape(batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
-    k, v = k.astype(dtype), v.astype(dtype)
+    with jax.named_scope("part/layout"):
+        q = q.reshape(
+            batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
+        k, v = k.astype(dtype), v.astype(dtype)
 
+    @jax.named_scope("part/kernel")
     def attend(q_b, k_b, v_b, seen):
         """``seen()`` [queries, keys]: which key each query attends to."""
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_b, k_b,
@@ -187,8 +203,10 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
                 - (first + jnp.arange(keys))[None, :]
             return (apart >= 0) & (apart < window)
 
-        return attend(cut(q, start, queries), cut(k, first, keys),
-                      cut(v, first, keys), seen)
+        with jax.named_scope("part/layout"):
+            cuts = (cut(q, start, queries), cut(k, first, keys),
+                    cut(v, first, keys))
+        return attend(*cuts, seen)
 
     # Blocks that end inside the first window see every key before them;
     # the whole blocks from ``alike`` on each see window + block keys.
@@ -196,7 +214,9 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
     outs = []
     for start in range(0, plain, block):
         end = min(start + block, length)
-        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+        with jax.named_scope("part/layout"):
+            cuts = q[:, start:end], k[:, :end], v[:, :end]
+        outs.append(one(*cuts, start))
     if plain < length:
         alike = min(length, -(-window // block) * block)
         count = (length - alike) // block
@@ -207,14 +227,16 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
         if count:
             out = lax.map(lambda start: windowed(start, block, window + block),
                           alike + block * jnp.arange(count))
-            outs.append(jnp.moveaxis(out, 0, 1).reshape(
-                (batch, count * block) + out.shape[3:]))
+            with jax.named_scope("part/layout"):
+                outs.append(jnp.moveaxis(out, 0, 1).reshape(
+                    (batch, count * block) + out.shape[3:]))
         start = alike + count * block
         if start < length:
             outs.append(windowed(start, length - start,
                                  window + length - start))
-    out = jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
-    return out if kept is None else checkpoint_name(out, kept)
+    with jax.named_scope("part/layout"):
+        out = jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
+        return out if kept is None else checkpoint_name(out, kept)
 
 
 # ------------------------------------------------------------ expert layer

@@ -176,7 +176,8 @@ def select_thresholds(qi, ki, w, topk, dtype, block):
     qi, ki, w = map(lax.stop_gradient, (qi, ki, w))
     out = []
     for start, queries, extent in buckets(qi.shape[1], block):
-        keys = ki[:, :extent]
+        with jax.named_scope("part/layout"):
+            keys = ki[:, :extent]
 
         def one(args, keys=keys, extent=extent):
             qi_b, w_b, rows = args
@@ -188,11 +189,20 @@ def select_thresholds(qi, ki, w, topk, dtype, block):
                 return from_ordered_bits(kth_largest(ranked, topk))
 
         part = slice(start, start + queries)
-        rows = jnp.arange(start, start + queries).reshape(-1, block)
-        out.append(_unblocks(lax.map(
-            one, (_blocks(qi[:, part], block), _blocks(w[:, part], block),
-                  rows))))
-    return jnp.concatenate(out, 1)
+        # The blocks' cutting and joining are the mixer's part/layout;
+        # ``one``'s operations have kinds of their own, and the loop itself
+        # stands outside every part: the compiler files what it fuses into
+        # the loop's own slices under the loop's name, and that is the
+        # indexer's work, not a layout's.
+        with jax.named_scope("part/layout"):
+            rows = jnp.arange(start, start + queries).reshape(-1, block)
+            blocks = (_blocks(qi[:, part], block), _blocks(w[:, part], block),
+                      rows)
+        taus = lax.map(one, blocks)
+        with jax.named_scope("part/layout"):
+            out.append(_unblocks(taus))
+    with jax.named_scope("part/layout"):
+        return jnp.concatenate(out, 1)
 
 
 # ---------------------------------------------------------------- attention
@@ -281,18 +291,22 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
     batch, length, heads, dim = q.shape
     kv_heads = k.shape[2]
     # One key-value head with its query heads at a time: [G, B, S, ...].
-    q = jnp.moveaxis(q.reshape(batch, length, kv_heads, heads // kv_heads,
-                               dim), 2, 0).astype(dtype)
-    k, v = (jnp.moveaxis(a, 2, 0).astype(dtype) for a in (k, v))
+    with jax.named_scope("part/layout"):
+        q = jnp.moveaxis(q.reshape(batch, length, kv_heads, heads // kv_heads,
+                                   dim), 2, 0).astype(dtype)
+        k, v = (jnp.moveaxis(a, 2, 0).astype(dtype) for a in (k, v))
     norm = lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(F32)), -1))
-    q_norm, k_norm = lax.stop_gradient((norm(q), norm(k)))
+    with jax.named_scope("part/pointwise"):
+        q_norm, k_norm = lax.stop_gradient((norm(q), norm(k)))
     outs, losses, kept = [], [], []
     for start, queries, extent in buckets(length, block):
-        k_e, v_e, ki_e = k[:, :, :extent], v[:, :, :extent], ki[:, :extent]
+        with jax.named_scope("part/layout"):
+            k_e, v_e, ki_e = k[:, :, :extent], v[:, :, :extent], ki[:, :extent]
         # What no logit of a query's row passes (``LOGIT_CAP``): [G, B, Q, R].
-        top = jnp.minimum(q_norm[:, :, start:start + queries] * jnp.max(
-            k_norm[:, :, :extent], -1)[..., None, None] / math.sqrt(dim),
-            LOGIT_CAP)
+        with jax.named_scope("part/pointwise"):
+            top = jnp.minimum(q_norm[:, :, start:start + queries] * jnp.max(
+                k_norm[:, :, :extent], -1)[..., None, None] / math.sqrt(dim),
+                LOGIT_CAP)
 
         @jax.checkpoint
         def one(args, k_e=k_e, v_e=v_e, ki_e=ki_e, extent=extent):
@@ -308,7 +322,9 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
                 out, share = attend_group(q_g, k_g, v_g, keep, top_g, dtype)
                 return mass + share, out
 
-            with jax.named_scope("layer/attn"):
+            # ``attend_group``'s products and softmax, forward and in its
+            # written-out backward pass.
+            with jax.named_scope("layer/attn"), jax.named_scope("part/kernel"):
                 mass, out = lax.scan(
                     group, jnp.zeros(scores.shape, F32), (q_b, k_e, v_e, top_b))
             with jax.named_scope("layer/dsa_index"):
@@ -316,21 +332,24 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
             return out, kl, keep.sum(-1, dtype=jnp.int32)
 
         part = slice(start, start + queries)
-        rows = jnp.arange(start, start + queries).reshape(-1, block)
         # q's blocks [n, G, B, block, R, D] and their bounds [n, G, B, R,
         # block, 1]; the scan inside runs over G.
-        out, kl, count = lax.map(
-            one, (_blocks(q[:, :, part], block, 2),
-                  jnp.swapaxes(_blocks(top, block, 2), -1, -2)[..., None],
-                  _blocks(qi[:, part], block), _blocks(w[:, part], block),
-                  _blocks(tau[:, part], block), rows))
+        with jax.named_scope("part/layout"):
+            rows = jnp.arange(start, start + queries).reshape(-1, block)
+            blocks = (_blocks(q[:, :, part], block, 2),
+                      jnp.swapaxes(_blocks(top, block, 2), -1, -2)[..., None],
+                      _blocks(qi[:, part], block), _blocks(w[:, part], block),
+                      _blocks(tau[:, part], block), rows)
+        out, kl, count = lax.map(one, blocks)
         # [n, G, B, R, block, D] -> [B, n * block, G * R, D]
-        outs.append(out.transpose(2, 0, 4, 1, 3, 5).reshape(
-            batch, queries, heads, dim))
-        losses.append(_unblocks(kl))
-        kept.append(_unblocks(count))
-    return (jnp.concatenate(outs, 1), jnp.concatenate(losses, 1),
-            jnp.concatenate(kept, 1))
+        with jax.named_scope("part/layout"):
+            outs.append(out.transpose(2, 0, 4, 1, 3, 5).reshape(
+                batch, queries, heads, dim))
+            losses.append(_unblocks(kl))
+            kept.append(_unblocks(count))
+    with jax.named_scope("part/layout"):
+        return (jnp.concatenate(outs, 1), jnp.concatenate(losses, 1),
+                jnp.concatenate(kept, 1))
 
 
 # ------------------------------------------------- attention, kernel form
@@ -407,11 +426,13 @@ def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
     batch, length, heads, dim = q.shape
     with jax.named_scope("layer/attn"):
         q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
-        top = _tops(q_l, k_l, block)
+        with jax.named_scope("part/pointwise"):
+            top = _tops(q_l, k_l, block)
     scores, keeps, counts = [], [], []
     for bucket in buckets(length, block):
         start, queries, extent = bucket
-        ki_e = ki[:, :extent]
+        with jax.named_scope("part/layout"):
+            ki_e = ki[:, :extent]
 
         def one(args, ki_e=ki_e, extent=extent):
             qi_b, w_b, tau_b, rows = args
@@ -426,19 +447,24 @@ def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
                     (0, 0), (0, 0), (0, length - extent))), \
                     keep.sum(-1, dtype=jnp.int32)
 
-        rows = jnp.arange(start, start + queries).reshape(-1, block)
-        score, keep, count = (_unblocks(a) for a in lax.map(
-            one, _bucket_blocks(bucket, block, qi, w, tau) + (rows,)))
+        # The loop outside every part, as in ``select_thresholds``.
+        with jax.named_scope("part/layout"):
+            rows = jnp.arange(start, start + queries).reshape(-1, block)
+            blocks = _bucket_blocks(bucket, block, qi, w, tau) + (rows,)
+        made = lax.map(one, blocks)
+        with jax.named_scope("part/layout"):
+            score, keep, count = (_unblocks(a) for a in made)
         scores.append(score), keeps.append(keep), counts.append(count)
     with jax.named_scope("layer/dsa_select"):
         mask = jnp.concatenate(keeps, 1)
         packed = checkpoint_name(_pack_rows(mask), KEPT_MASKS)
-    with jax.named_scope("layer/attn"):
+    with jax.named_scope("layer/attn"), jax.named_scope("part/kernel"):
         out, total = kernels.forward(q_l, k_l, v_l, mask, top, dtype=dtype,
                                      interpret=not on_tpu())
         out, total = (checkpoint_name(a, KEPT_ATTENTION)
                       for a in (out, total))
-        # The heads' mean probabilities, a bucket's queries over its keys.
+        # The heads' mean probabilities, a bucket's queries over its keys
+        # (with the reciprocal of the rows' sums they are handed).
         ps = tuple(checkpoint_name(kernels.probabilities(
             q_l, k_l, mask, top, 1.0 / total, span=(start, queries),
             dtype=dtype, interpret=not on_tpu()), KEPT_PROBABILITIES)
@@ -447,8 +473,10 @@ def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
         loss = jnp.concatenate([
             index_loss(score, keep[..., :score.shape[-1]] > 0, p)
             for score, keep, p in zip(scores, keeps, ps)], 1)
-    o = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
-    return (o, loss, jnp.concatenate(counts, 1)), \
+    with jax.named_scope("part/layout"):
+        o = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
+        counts = jnp.concatenate(counts, 1)
+    return (o, loss, counts), \
         (q_l, k_l, v_l, qi, ki, w, top, out, total, packed, ps)
 
 
@@ -456,16 +484,19 @@ def _kernel_attention_bwd(dtype, block, kept, cotangents):
     q_l, k_l, v_l, qi, ki, w, top, out, total, packed, ps = kept
     d_o, d_kl, _ = cotangents
     batch, groups, rep, length, dim = q_l.shape
-    inv_total = 1.0 / total
+    with jax.named_scope("part/pointwise"):
+        inv_total = 1.0 / total
     with jax.named_scope("layer/dsa_select"):
         mask = _unpack_rows(packed)
     # The indexer: a block's scores once more, and the loss's gradient
     # through them into qI, kI and w. A block's rows of the mask and of p
     # are cut where they are read, not copied out block by block before.
-    d_qi, d_w, d_ki = [], [], jnp.zeros(ki.shape, F32)
+    with jax.named_scope("part/layout"):
+        d_qi, d_w, d_ki = [], [], jnp.zeros(ki.shape, F32)
     for bucket, p in zip(buckets(length, block), ps):
         start, queries, extent = bucket
-        ki_e = ki[:, :extent]
+        with jax.named_scope("part/layout"):
+            ki_e = ki[:, :extent]
 
         def one(d_ki_e, args, ki_e=ki_e, start=start, extent=extent, p=p):
             qi_b, w_b, d_kl_b, i = args
@@ -481,28 +512,40 @@ def _kernel_attention_bwd(dtype, block, kept, cotangents):
                 d_qi_b, d_ki_b, d_w_b = back(d_score)
             return d_ki_e + d_ki_b, (d_qi_b, d_w_b)
 
-        d_ki_e, (d_qi_b, d_w_b) = lax.scan(
-            one, jnp.zeros(ki_e.shape, F32),
-            _bucket_blocks(bucket, block, qi, w, d_kl)
-            + (jnp.arange(queries // block),))
-        d_ki = d_ki.at[:, :extent].add(d_ki_e)
-        d_qi.append(_unblocks(d_qi_b)), d_w.append(_unblocks(d_w_b))
+        # The loop outside every part, as in ``select_thresholds``.
+        with jax.named_scope("part/layout"):
+            zeros = jnp.zeros(ki_e.shape, F32)
+            blocks = _bucket_blocks(bucket, block, qi, w, d_kl) \
+                + (jnp.arange(queries // block),)
+        d_ki_e, (d_qi_b, d_w_b) = lax.scan(one, zeros, blocks)
+        with jax.named_scope("part/layout"):
+            d_ki = d_ki.at[:, :extent].add(d_ki_e)
+            d_qi.append(_unblocks(d_qi_b)), d_w.append(_unblocks(d_w_b))
     with jax.named_scope("layer/attn"):
-        d_out = d_o.reshape(batch, length, groups, rep, dim).transpose(
-            0, 2, 3, 1, 4)
-        mean = jnp.sum(d_out * out, -1)                  # sum_s p_s dE_s
-        d_q = kernels.backward_q(
-            q_l, k_l, v_l, mask, top, inv_total, mean, d_out.astype(dtype),
-            dtype=dtype, interpret=not on_tpu())
-        d_k, d_v = kernels.backward_kv(
-            q_l, k_l, v_l, jnp.swapaxes(mask, 1, 2), top, inv_total, mean,
-            d_out.astype(dtype), (d_out / total[..., None]).astype(dtype),
-            dtype=dtype, interpret=not on_tpu())
-        d_q = d_q.transpose(0, 3, 1, 2, 4).reshape(
-            batch, length, groups * rep, dim)
-        d_k, d_v = (a.transpose(0, 2, 1, 3) for a in (d_k, d_v))
-    return (d_q, d_k, d_v, jnp.concatenate(d_qi, 1), d_ki,
-            jnp.concatenate(d_w, 1), jnp.zeros((batch, length), F32))
+        with jax.named_scope("part/layout"):
+            d_out = d_o.reshape(batch, length, groups, rep, dim).transpose(
+                0, 2, 3, 1, 4)
+            mean = jnp.sum(d_out * out, -1)              # sum_s p_s dE_s
+            d_low = d_out.astype(dtype)
+        with jax.named_scope("part/kernel"):
+            d_q = kernels.backward_q(
+                q_l, k_l, v_l, mask, top, inv_total, mean, d_low,
+                dtype=dtype, interpret=not on_tpu())
+        with jax.named_scope("part/layout"):
+            mask_t = jnp.swapaxes(mask, 1, 2)
+            d_low_kv = d_out.astype(dtype)
+            d_scaled = (d_out / total[..., None]).astype(dtype)
+        with jax.named_scope("part/kernel"):
+            d_k, d_v = kernels.backward_kv(
+                q_l, k_l, v_l, mask_t, top, inv_total, mean, d_low_kv,
+                d_scaled, dtype=dtype, interpret=not on_tpu())
+        with jax.named_scope("part/layout"):
+            d_q = d_q.transpose(0, 3, 1, 2, 4).reshape(
+                batch, length, groups * rep, dim)
+            d_k, d_v = (a.transpose(0, 2, 1, 3) for a in (d_k, d_v))
+    with jax.named_scope("part/layout"):
+        return (d_q, d_k, d_v, jnp.concatenate(d_qi, 1), d_ki,
+                jnp.concatenate(d_w, 1), jnp.zeros((batch, length), F32))
 
 
 kernel_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
@@ -560,11 +603,14 @@ class SparseAttention(nn.Module):
         pad = lambda a: jnp.pad(
             a, ((0, 0), (0, -length % block)) + ((0, 0),) * (a.ndim - 2))
         with jax.named_scope("layer/attn"):
-            q = dense(h, w_q, dtype).reshape(batch, length, heads, dim)
-            kv = dense(h, w_kv, dtype).reshape(batch, length, 2, kv_heads, dim)
-            k, v = kv[:, :, 0], kv[:, :, 1].astype(F32)
-            q = rotary(rms_norm0(q, w_qn, eps), theta, dim)
-            k = rotary(rms_norm0(k, w_kn, eps), theta, dim)
+            with jax.named_scope("part/proj"):
+                q = dense(h, w_q, dtype).reshape(batch, length, heads, dim)
+                kv = dense(h, w_kv, dtype).reshape(
+                    batch, length, 2, kv_heads, dim)
+            with jax.named_scope("part/pointwise"):
+                k, v = kv[:, :, 0], kv[:, :, 1].astype(F32)
+                q = rotary(rms_norm0(q, w_qn, eps), theta, dim)
+                k = rotary(rms_norm0(k, w_kn, eps), theta, dim)
         with jax.named_scope("layer/dsa_index"):
             index = dense(lax.stop_gradient(h), w_i, dtype).astype(F32)
             qi = rotary(index[..., :j * d_i].reshape(batch, length, j, d_i),
@@ -572,7 +618,11 @@ class SparseAttention(nn.Module):
             ki = rotary(layer_norm(index[..., j * d_i:j * d_i + d_i], a_ki,
                                    b_ki, eps)[:, :, None], theta, d_i)[:, :, 0]
             w = index[..., j * d_i + d_i:] / math.sqrt(j * d_i)
-        q, k, v, qi, ki, w = map(pad, (q, k, v, qi, ki, w))
+        # From here to the output projection every operation under no
+        # ``layer/`` scope of its own has the kind of ``Layer``'s: the
+        # blocks' cutting and joining are its part/layout.
+        with jax.named_scope("part/layout"):
+            q, k, v, qi, ki, w = map(pad, (q, k, v, qi, ki, w))
         tau = checkpoint_name(
             _select_thresholds(qi, ki, w, s["topk"], dtype, block),
             KEPT_SELECTION)
@@ -580,14 +630,16 @@ class SparseAttention(nn.Module):
             # Keeps its own output (float32, the kernels' layout) by name.
             out, kl, kept = _kernel_attention_once(q, k, v, qi, ki, w, tau,
                                                    dtype, block)
-            out = out.astype(dtype)
+            with jax.named_scope("part/layout"):
+                out = out.astype(dtype)
         else:
             out, kl, kept = _sparse_attention(q, k, v, qi, ki, w, tau, dtype,
                                               block)
             # ``dense`` would round ``out`` to ``dtype`` anyway: kept so.
-            out = checkpoint_name(out.astype(dtype), KEPT_ATTENTION)
+            with jax.named_scope("part/layout"):
+                out = checkpoint_name(out.astype(dtype), KEPT_ATTENTION)
         kl, kept = (checkpoint_name(a, KEPT_ATTENTION) for a in (kl, kept))
-        with jax.named_scope("layer/attn"):
+        with jax.named_scope("layer/attn"), jax.named_scope("part/proj"):
             y = dense(out[:, :length].reshape(batch, length, heads * dim),
                       w_o, dtype)
         with jax.named_scope("layer/dsa_index"):
@@ -605,11 +657,15 @@ class Layer(nn.Module):
         w_in = self.param("input_norm", nn.initializers.zeros, (d,), F32)
         w_post = self.param("post_norm", nn.initializers.zeros, (d,), F32)
         # The layer's own norms and residual adds count for the kind they
-        # feed; scopes inside the mixer and the expert layer are innermost.
+        # feed and for its part/pointwise; scopes inside the mixer and the
+        # expert layer are innermost.
         with jax.named_scope("layer/attn"):
-            y, index_loss, kept = SparseAttention(s, self.dtype, name="mixer")(
-                rms_norm0(x, w_in, eps))
-            x = x + y.astype(F32)
+            with jax.named_scope("part/pointwise"):
+                h = rms_norm0(x, w_in, eps)
+            y, index_loss, kept = SparseAttention(
+                s, self.dtype, name="mixer")(h)
+            with jax.named_scope("part/pointwise"):
+                x = x + y.astype(F32)
         with jax.named_scope("layer/moe_router"):
             y, load, dropped, _ = SparseMoE(s, self.dtype, name="moe")(
                 rms_norm0(x, w_post, eps))

@@ -360,18 +360,27 @@ class GatedAttention(nn.Module):
         eps = s["rms_norm_eps"]
         rotary_dims = int(dim * s["partial_rotary_factor"])
         with jax.named_scope("layer/attn"):
-            qg = dense(x, w_q, dtype).reshape(batch, length, heads, 2 * dim)
-            q, gate = qg[..., :dim], qg[..., dim:].astype(F32)
-            k = dense(x, w_k, dtype).reshape(batch, length, kv_heads, dim)
-            v = dense(x, w_v, dtype).reshape(
-                batch, length, kv_heads, dim).astype(F32)
-            q = rotary(rms_norm0(q, w_qn, eps), s["rope_theta"], rotary_dims)
-            k = rotary(rms_norm0(k, w_kn, eps), s["rope_theta"], rotary_dims)
+            with jax.named_scope("part/proj"):
+                qg = dense(x, w_q, dtype).reshape(
+                    batch, length, heads, 2 * dim)
+                q, gate = qg[..., :dim], qg[..., dim:].astype(F32)
+                k = dense(x, w_k, dtype).reshape(batch, length, kv_heads, dim)
+                v = dense(x, w_v, dtype).reshape(
+                    batch, length, kv_heads, dim).astype(F32)
+            with jax.named_scope("part/pointwise"):
+                q = rotary(rms_norm0(q, w_qn, eps), s["rope_theta"],
+                           rotary_dims)
+                k = rotary(rms_norm0(k, w_kn, eps), s["rope_theta"],
+                           rotary_dims)
+            # Its own parts inside: part/layout and part/kernel.
             out = blocked_causal_attention(
                 q, k, v, dtype, query_block_of(s["seq_len"]),
                 kept=KEPT_ATTENTION)
-            out = out * jax.nn.sigmoid(gate)
-            return dense(out.reshape(batch, length, heads * dim), w_o, dtype)
+            with jax.named_scope("part/pointwise"):
+                out = out * jax.nn.sigmoid(gate)
+            with jax.named_scope("part/proj"):
+                return dense(
+                    out.reshape(batch, length, heads * dim), w_o, dtype)
 
 
 
@@ -389,10 +398,15 @@ class Layer(nn.Module):
         mixer = (GatedAttention if self.attention else GatedDeltaNet)(
             s, self.dtype, name="mixer")
         # The layer's own norms and residual adds count for the kind they
-        # feed; scopes inside the mixer and the expert layer are innermost.
+        # feed (part/pointwise: read inside layer/attn alone); scopes inside
+        # the mixer and the expert layer are innermost.
         with jax.named_scope("layer/attn" if self.attention
                              else "layer/gdn_proj"):
-            x = x + mixer(rms_norm0(x, w_in, eps)).astype(F32)
+            with jax.named_scope("part/pointwise"):
+                h = rms_norm0(x, w_in, eps)
+            y = mixer(h)
+            with jax.named_scope("part/pointwise"):
+                x = x + y.astype(F32)
         with jax.named_scope("layer/moe_router"):
             y, load, dropped, _ = SparseMoE(s, self.dtype, name="moe")(
                 rms_norm0(x, w_post, eps))

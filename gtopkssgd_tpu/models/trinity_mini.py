@@ -48,9 +48,11 @@ float32 accumulation.
 Stages are named for the device trace (``layer/attn_window``,
 ``layer/attn_full``: a layer's whole mixer with its two norms and residual
 add; ``layer/dense_mlp``; ``layer/moe_router``, ``layer/moe_experts``,
-``layer/shared_expert``; ``layer/head``), forward and backward alike. With
-the loss go the held experts' loads and dropped slots (always 0) and every
-expert's selection count and bias, for ``obs.counters``.
+``layer/shared_expert``; ``layer/head``), forward and backward alike, and
+within the two attention kinds by part (``part/proj``, ``part/pointwise``,
+``part/layout``, ``part/kernel``). With the loss go the held experts' loads
+and dropped slots (always 0) and every expert's selection count and bias,
+for ``obs.counters``.
 """
 
 from __future__ import annotations
@@ -148,18 +150,24 @@ class GatedAttention(nn.Module):
             # alone at every start.
             return jnp.zeros(h.shape, dtype)
         eps = s["rms_norm_eps"]
-        q = dense(h, w_q, dtype).reshape(batch, length, heads, dim)
-        kv = dense(h, w_kv, dtype).reshape(batch, length, 2, kv_heads, dim)
-        gate = dense(h, w_g, dtype).astype(F32)
-        q = rms_norm0(q, w_qn, eps)
-        k, v = rms_norm0(kv[:, :, 0], w_kn, eps), kv[:, :, 1].astype(F32)
-        if self.sliding:
-            q, k = (rotary(a, s["rope_theta"], dim) for a in (q, k))
+        with jax.named_scope("part/proj"):
+            q = dense(h, w_q, dtype).reshape(batch, length, heads, dim)
+            kv = dense(h, w_kv, dtype).reshape(batch, length, 2, kv_heads, dim)
+            gate = dense(h, w_g, dtype).astype(F32)
+        with jax.named_scope("part/pointwise"):
+            q = rms_norm0(q, w_qn, eps)
+            k, v = rms_norm0(kv[:, :, 0], w_kn, eps), kv[:, :, 1].astype(F32)
+            if self.sliding:
+                q, k = (rotary(a, s["rope_theta"], dim) for a in (q, k))
+        # Its own parts inside: part/layout and part/kernel.
         out = blocked_causal_attention(
             q, k, v, dtype, query_block_of(s["seq_len"]),
             s["sliding_window"] if self.sliding else None, KEPT_ATTENTION)
-        out = out.reshape(batch, length, heads * dim) * jax.nn.sigmoid(gate)
-        return dense(out, w_o, dtype)
+        with jax.named_scope("part/pointwise"):
+            out = out.reshape(batch, length, heads * dim) \
+                * jax.nn.sigmoid(gate)
+        with jax.named_scope("part/proj"):
+            return dense(out, w_o, dtype)
 
 
 class DenseMLP(nn.Module):
@@ -193,12 +201,16 @@ class Layer(nn.Module):
             for name in ("input_norm", "post_attn_norm", "pre_mlp_norm",
                          "post_mlp_norm"))
         # The layer's own norms and residual adds count for the kind they
-        # feed; scopes inside the expert layer are innermost.
+        # feed and, in an attention kind, for its part/pointwise; scopes
+        # inside the mixer and the expert layer are innermost (the three
+        # levels of names: trainer._build_train_step).
         with jax.named_scope("layer/attn_window" if self.sliding
                              else "layer/attn_full"):
-            y = GatedAttention(s, self.dtype, self.sliding, name="mixer")(
-                rms_norm0(x, w_in, eps))
-            x = x + rms_norm0(y, w_post_attn, eps)
+            with jax.named_scope("part/pointwise"):
+                h = rms_norm0(x, w_in, eps)
+            y = GatedAttention(s, self.dtype, self.sliding, name="mixer")(h)
+            with jax.named_scope("part/pointwise"):
+                x = x + rms_norm0(y, w_post_attn, eps)
         if self.dense_mlp:
             with jax.named_scope("layer/dense_mlp"):
                 y = DenseMLP(s, self.dtype, name="mlp")(

@@ -492,13 +492,15 @@ def gtopk_sgd(
             off += s
         n = off
         kk_total = sum(ks)
-        flats = [leaf.reshape(-1) for leaf in leaves]
+        with jax.named_scope("gtopk/flatten"):
+            flats = [leaf.reshape(-1) for leaf in leaves]
         if clip_grad_norm is not None:
             # Same clip-BEFORE-compress order as the flat path; the global
             # norm is a sum of per-leaf sums — no concatenation needed.
-            gnorm = jnp.sqrt(sum(jnp.sum(f * f) for f in flats))
-            scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
-            flats = [f * scale for f in flats]
+            with jax.named_scope("gtopk/clip"):
+                gnorm = jnp.sqrt(sum(jnp.sum(f * f) for f in flats))
+                scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
+                flats = [f * scale for f in flats]
         p = bound_axis_size()
         # Bucket partition for this (leaf_sizes, density, p, codec) —
         # the alpha-beta DP of parallel.bucketing; None under the
@@ -935,9 +937,10 @@ def gtopk_sgd(
         if correction:
             residual = {"v": residual, "u": u_new}
 
-        avg_grads = treedef.unflatten([
-            d.reshape(leaf.shape) for d, leaf in zip(dense_fl, leaves)
-        ])
+        with jax.named_scope("gtopk/unflatten"):
+            avg_grads = treedef.unflatten([
+                d.reshape(leaf.shape) for d, leaf in zip(dense_fl, leaves)
+            ])
         with jax.named_scope("gtopk/apply"):
             updates, inner_state = inner.update(
                 avg_grads, state.inner, params)
@@ -984,7 +987,10 @@ def gtopk_sgd(
     def update_fn(grads, state: GTopKSGDState, params=None):
         if layerwise:
             return layerwise_update(grads, state, params)
-        flat, unravel = ravel_pytree(grads)
+        # The flat [N] form's own passes are stages like the others
+        # (trainer._build_train_step lists them): flatten, clip, unflatten.
+        with jax.named_scope("gtopk/flatten"):
+            flat, unravel = ravel_pytree(grads)
         n = flat.shape[0]
         if telemetry_layers:
             # Static trace-time layer structure: ravel_pytree flattens in
@@ -996,9 +1002,10 @@ def gtopk_sgd(
         if clip_grad_norm is not None:
             # Reference LSTM path: clip the raw local gradient BEFORE the
             # residual accumulate/compress (order matters for convergence).
-            gnorm = jnp.sqrt(jnp.sum(flat * flat))
-            scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
-            flat = flat * scale
+            with jax.named_scope("gtopk/clip"):
+                gnorm = jnp.sqrt(jnp.sum(flat * flat))
+                scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
+                flat = flat * scale
 
         p = bound_axis_size()
         if hier and p > 1:
@@ -1224,7 +1231,8 @@ def gtopk_sgd(
             if correction:
                 residual = {"v": residual, "u": u_new}
 
-        avg_grads = unravel(dense)
+        with jax.named_scope("gtopk/unflatten"):
+            avg_grads = unravel(dense)
         with jax.named_scope("gtopk/apply"):
             updates, inner_state = inner.update(
                 avg_grads, state.inner, params)

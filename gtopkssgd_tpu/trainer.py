@@ -1534,11 +1534,26 @@ class Trainer:
                 grads_sum = jax.tree.map(jnp.add, grads_sum, grads)
                 return (grads_sum, bs, cr), (loss, aux)
 
-            # The named scopes (here and in the optimizer, compression,
-            # collectives and counters) are metadata on the compiled
-            # operations: a device trace names each stage of the step by
-            # them (perfbench/metrics/scoped.py); the arithmetic and the
-            # fusion are what they were.
+            # The named scopes are metadata on the compiled operations: a
+            # device trace prices the step by them; the arithmetic and the
+            # fusion are what they were. Three levels, each read from an
+            # operation's path by its own rule (perfbench/metrics/):
+            #   gtopk/<stage>, the OUTERMOST counts (scoped.py): fwd_bwd and
+            #     apply here; flatten, clip, unflatten and apply in the
+            #     optimizer; accumulate, select, mask, repair in
+            #     compression and ops/topk; allreduce[/round<i>] in the
+            #     collectives; telemetry in the counters;
+            #   layer/<kind>, the INNERMOST counts (layer_ms.py): the
+            #     decoders' layer kinds, in models/*, inside gtopk/fwd_bwd;
+            #   part/<name>, the INNERMOST within a kind (part_ms.py): proj,
+            #     pointwise, layout, kernel through the attention kinds
+            #     (layer/attn, layer/attn_window, layer/attn_full), in the
+            #     mixers and models/decoder.py, forward and in the written-
+            #     out backward passes alike. A loop whose body has kinds of
+            #     its own (Keye's indexer) stands outside every part: what
+            #     the compiler files under the loop's name is the body's.
+            # An operation's pass is read from the same path: forward,
+            # replay (a remat's second forward) or backward.
             with jax.named_scope("gtopk/fwd_bwd"):
                 zero_grads = jax.tree.map(jnp.zeros_like, state.params)
                 (grads, new_bs, new_carry), (losses, auxes) = lax.scan(

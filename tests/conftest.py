@@ -154,9 +154,33 @@ STALE = tuple(
 LAST_NO_MORE = ("test_perfbench_cell_keye_vl2.py::"
                 "test_entries_keep_the_contracts_letter")
 
+# ``perfbench/test_perfbench_entries_by_name.py`` ends its letter test with
+# "the cell is in no other metric's list": true while every decoder metric
+# belonged to one cell, false since PR 37's six (the attention's parts, their
+# share and the replay) list all three decoder cells, as ISSUE 37 asks. The
+# file is the benchmark's, so its two cases are marked here, strictly: a
+# ``benchmark`` PR that lets a shared metric list the cell makes them pass,
+# these marks fail, and they go. The marks hide that one assertion only:
+# ``perfbench/test_perfbench_parts.py::
+# test_entries_keep_the_contracts_letter_beside_the_shared_metrics`` is the
+# test's whole body, assertion for assertion (entry keys and characters, no
+# width under ``reduced``, the entry against its file, the cell, the metrics'
+# units, directions, ``moves``, ``layer`` and lists, the nine limits), with
+# that clause naming the six.
+SHARED_SINCE = tuple(
+    "test_perfbench_entries_by_name.py::"
+    f"test_entries_keep_the_contracts_letter[{config}]"
+    for config in ("keye_vl2_30b_a3b_ep16", "trinity_mini_26b_a3b_ep16"))
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(SHARED_SINCE):
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="asserts its cell is in no other metric's list; six "
+                       "metrics list the three decoder cells since PR 37"))
+            continue
         if item.nodeid.endswith(STALE):
             item.add_marker(pytest.mark.xfail(
                 strict=True,

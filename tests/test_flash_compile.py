@@ -140,6 +140,11 @@ def test_published_step_runs_each_attention_kernel_once_a_layer(
             for line in mine)
         assert scopes == {"attn_window": kinds.count("sliding"),
                           "attn_full": kinds.count("full")}, (name, scopes)
+        # ... and, within the kind, the part the device trace prices the
+        # kernels alone by (``attn_kernel_ms``).
+        assert all(re.search(
+            rf'op_name="[^"]*layer/attn_\w+/mixer/part/kernel/'
+            rf'flash_attention_{name}/pallas_call"', line) for line in mine)
 
 
 def test_published_step_holds_no_array_of_heads_queries_keys(published_step):

@@ -252,6 +252,12 @@ def test_published_step_runs_each_attention_kernel_once_a_layer(
     assert count("forward") == count("backward_q") == count("backward_kv") \
         == layers
     assert count("probabilities") == spans * layers
+    # Each carries its layer kind's scope and, within it, the part the
+    # device trace prices the kernels alone by (``attn_kernel_ms``).
+    mine = [line for line in calls if "dsa_attention_" in line]
+    assert len(mine) == (3 + spans) * layers and all(re.search(
+        r'op_name="[^"]*layer/attn/[^"]*part/kernel/dsa_attention_\w+/'
+        r'pallas_call"', line) for line in mine)
 
 
 def test_published_step_holds_no_array_of_heads_queries_keys(published_step):
