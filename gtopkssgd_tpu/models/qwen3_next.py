@@ -13,7 +13,13 @@ here is how they are computed:
     ``delta_chunks``, a sequence at a time), and only the d_k x d_v state
     crosses chunks, in one ``lax.scan`` for the whole batch
     (``scan_chunks``; forward and, by autodiff through the same scan,
-    backward);
+    backward). The chunks' algebra has two forms, chosen by ``delta_form``
+    from what it observes (no flag): on a TPU at chunks of 64, heads of
+    whole lane rows and whole blocks of chunks the fused kernels of
+    ``ops/delta_chunks.py``, forward and backward, in which a chunk's
+    blocks stay in VMEM and the triangular system is inverted by products;
+    elsewhere XLA's products and ``triangular_solve``, the oracle of the
+    kernels' tests (``forms`` says which compiled: ``delta_form``);
   * attention without the [B, H, S, S] scores ever in HBM at once
     (``models/decoder.py``'s ``blocked_causal_attention``): on a TPU at
     whole tiles the fused flash kernels of ``ops/flash_attention.py``,
@@ -30,7 +36,9 @@ here is how they are computed:
     attention's query blocks (``one``) then run twice a step, forward and
     for their own backward, and not a third time when the layer's forward
     is replayed (the attention's forward kernel once: its backward takes
-    the output and the rows' log-sum-exp, kept under the same name).
+    the output and the rows' log-sum-exp, kept under the same name; the
+    chunks' forward kernel once: its backward takes the kernel's inputs
+    and makes a chunk's blocks itself).
 
 Precision is the reference's: float32 parameters, residual stream, norms,
 router, softmax, recurrence state and loss; matrix products in ``dtype``.
@@ -58,10 +66,12 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
 
+from gtopkssgd_tpu.models import decoder
 from gtopkssgd_tpu.models.decoder import (
     F32, HIGHEST, SparseMoE, _normal, attention_form,
     blocked_causal_attention, dense, query_block_of, rms_norm0, rotary,
     token_losses)
+from gtopkssgd_tpu.ops import delta_chunks as delta_kernels
 
 # The chunked delta rule's float32 products (module docstring, Precision).
 _mm = functools.partial(jnp.einsum, precision=HIGHEST)
@@ -97,8 +107,12 @@ PRESETS = {
 
 
 # Sequences whose convolution and chunk algebra are live at once in a
-# Gated DeltaNet layer (their float32 intermediates are what fills the
-# chip: 2.7 GB a sequence of 4,096 tokens at the published widths).
+# Gated DeltaNet layer. In the XLA form their float32 intermediates are what
+# fills the chip: 2.7 GB a sequence of 4,096 tokens at the published widths.
+# In the kernel form the chunks' blocks never leave VMEM and a sequence
+# costs the convolution's [4096, 8192] float32 passes, 0.42 GB: the cell's
+# step reads 13.62 GB at 1, 14.04 at 2 and 14.93 at all 4 (compiled for a
+# described v5e, PR 38), against a line of 14.5; the loop stays.
 GDN_SEQUENCES = 1
 
 # What a layer's remat keeps from its forward to its backward pass, by
@@ -121,7 +135,11 @@ KEPT_CHUNKS, KEPT_ATTENTION = "gdn_chunks", "attn_out"
 # cell's 16,384 tokens that leaves 5.20 GB, the four layers keep 3 x 1.208 +
 # 0.268 = 3.89 GB and the step reads 14.306 GB (PERF.md section 6, PR 30);
 # at 8 sequences nothing is left and every layer is rematerialised whole,
-# as all were before (the published depth of 48 would keep 43 GB).
+# as all were before (the published depth of 48 would keep 43 GB). Both
+# constants are PR 30's readings of the XLA form; since the attention's and
+# the chunks' kernels (PRs 36, 38) the cell's step reads 13.62 GB with the
+# four layers kept and 11.98 with none, the XLA form of the chunks 13.81
+# and 11.99 (compiled for a described v5e, PR 38).
 KEPT_BYTES = 16_909_000_000 - 2 ** 30 - 5_146_000_000
 STEP_BYTES_A_TOKEN = 334_844
 
@@ -227,6 +245,55 @@ def delta_chunks(q, k, v, g, beta, chunk):
             jnp.exp(gamma[..., -1])[..., None, None])
 
 
+def delta_form(length, chunk, d_k, d_v):
+    """``kernel`` where the chunks' algebra runs as the Pallas kernels of
+    ``ops/delta_chunks.py``, ``xla`` where as ``delta_chunks``' products and
+    triangular solve: the kernels need a TPU, chunks of 64, heads of whole
+    128-lane rows and a padded length of whole blocks of chunks."""
+    whole = chunk == 64 and d_k % 128 == 0 and d_v % 128 == 0 \
+        and delta_kernels.block_of(-(-length // chunk)) is not None
+    return "kernel" if decoder.on_tpu() and whole else "xla"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunk_kernels(q, k, v, gamma, beta, key_heads):
+    return delta_kernels.forward(q, k, v, gamma, beta, key_heads=key_heads,
+                                 interpret=not decoder.on_tpu())
+
+
+def _chunk_kernels_fwd(q, k, v, gamma, beta, key_heads):
+    # The residuals are the inputs: under ``prepare``'s own checkpoint
+    # nothing of the forward kernel is computed again for the backward one,
+    # which makes a chunk's blocks itself.
+    return (_chunk_kernels(q, k, v, gamma, beta, key_heads),
+            (q, k, v, gamma, beta))
+
+
+def _chunk_kernels_bwd(key_heads, residuals, cotangents):
+    return delta_kernels.backward(*residuals, *cotangents,
+                                  key_heads=key_heads,
+                                  interpret=not decoder.on_tpu())
+
+
+_chunk_kernels.defvjp(_chunk_kernels_fwd, _chunk_kernels_bwd)
+
+
+def kernel_delta_chunks(q, k, v, g, beta, chunk):
+    """``delta_chunks`` with the algebra in kernels (interpret mode off the
+    TPU): the same values and gradients, but q and k come by *key* head,
+    [B, S, H_k, d_k] with H a multiple of H_k, and value head h reads key
+    head h // (H / H_k): the repeat to H heads is never made."""
+    batch, length, heads = g.shape
+    n = length // chunk
+    # [B, S, H] -> [B, H, n, C]: a chunk's numbers along the lanes.
+    numbers = lambda a: a.reshape(batch, n, chunk, heads).transpose(0, 3, 1, 2)
+    flat = lambda a: a.reshape(batch, length, -1)
+    gamma = jnp.cumsum(numbers(g), axis=-1)
+    out = _chunk_kernels(flat(q), flat(k), flat(v), gamma, numbers(beta),
+                         q.shape[2])
+    return out + (jnp.exp(gamma[..., -1]).transpose(2, 0, 1)[..., None, None],)
+
+
 def scan_chunks(u, w, attn, q_in, k_out, decay):
     """The state's pass over the chunks ``delta_chunks`` prepared, S_0 = 0:
 
@@ -264,9 +331,12 @@ def pad_to_chunks(arrays, chunk):
 def chunked_delta_rule(q, k, v, g, beta, chunk):
     """The gated delta rule over whole sequences, chunk by chunk: q, k
     [B, S, H, d_k], v [B, S, H, d_v], g and beta [B, S, H], float32, any
-    S, to o [B, S, H, d_v]."""
+    S, to o [B, S, H, d_v]. The chunks' algebra in the form ``delta_form``
+    finds."""
     arrays, length = pad_to_chunks((q, k, v, g, beta), chunk)
-    return scan_chunks(*delta_chunks(*arrays, chunk))[:, :length]
+    chunks = kernel_delta_chunks if delta_form(
+        length, chunk, q.shape[-1], v.shape[-1]) == "kernel" else delta_chunks
+    return scan_chunks(*chunks(*arrays, chunk))[:, :length]
 
 
 # ------------------------------------------------------------------ modules
@@ -294,6 +364,10 @@ class GatedDeltaNet(nn.Module):
         batch, length = x.shape[:2]
         group = math.gcd(batch, GDN_SEQUENCES)
         chunk = chunk_of(s["seq_len"])
+        kernels = delta_form(length, chunk, d_k, d_v) == "kernel"
+        # The kernels read q and k by key head; the XLA form by value head.
+        repeat = (lambda a: a) if kernels else (
+            lambda a: jnp.repeat(a, h_v // h_k, axis=2))
 
         @jax.checkpoint
         def prepare(args):
@@ -310,13 +384,14 @@ class GatedDeltaNet(nn.Module):
                 v = heads(qkv[..., 2 * key_w:], h_v, d_v)
                 unit = lambda a: a * lax.rsqrt(
                     jnp.sum(a * a, -1, keepdims=True) + 1e-6)
-                q = jnp.repeat(unit(q) / math.sqrt(d_k), h_v // h_k, axis=2)
-                k = jnp.repeat(unit(k), h_v // h_k, axis=2)
+                q = repeat(unit(q) / math.sqrt(d_k))
+                k = repeat(unit(k))
                 beta = jax.nn.sigmoid(ba[..., :h_v])
                 g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., h_v:] + dt_bias)
             with jax.named_scope("layer/gdn_scan"):
                 arrays, _ = pad_to_chunks((q, k, v, g, beta), chunk)
-                return delta_chunks(*arrays, chunk)
+                return (kernel_delta_chunks if kernels else delta_chunks)(
+                    *arrays, chunk)
 
         grouped = lambda a: a.reshape((batch // group, group) + a.shape[1:])
         with jax.named_scope("layer/gdn_proj"):
@@ -429,8 +504,11 @@ class Qwen3Next(nn.Module):
         """What the step compiles as at sequences of ``length``, for the
         run's manifest and ``train`` records: a run on the chip that fell
         back to the blocked attention says so."""
-        return {"attention_form": attention_form(
-            length, self.sizes["head_dim"])}
+        s = self.sizes
+        return {"attention_form": attention_form(length, s["head_dim"]),
+                "delta_form": delta_form(
+                    length, chunk_of(s["seq_len"]), s["linear_key_head_dim"],
+                    s["linear_value_head_dim"])}
 
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):

@@ -230,10 +230,12 @@ def test_the_form_follows_the_backend_and_the_shapes(tpu, length, dim, form,
     (qwen3_next.Qwen3Next, "tiny", 128, 16)])
 def test_a_models_forms_are_the_rules(module, preset, length, dim,
                                       monkeypatch):
-    assert module(preset).forms(length) == {"attention_form": "blocked"}
+    # (The hybrid decoder names its chunk algebra's form too:
+    # tests/test_delta_chunks_kernel.py.)
+    assert module(preset).forms(length)["attention_form"] == "blocked"
     monkeypatch.setattr(decoder, "on_tpu", lambda: True)
-    assert module(preset).forms(length) == {
-        "attention_form": decoder.attention_form(length, dim)}
+    assert module(preset).forms(length)["attention_form"] \
+        == decoder.attention_form(length, dim)
     assert (module(preset).forms(length)["attention_form"] == "kernel") \
         == (preset != "tiny")
 
@@ -308,7 +310,7 @@ def test_the_runs_records_name_the_form_that_compiled(dnn, batch, length,
                              batch_size=batch, compression="gtopk",
                              density=0.01, log_interval=1,
                              out_dir=str(tmp_path))) as t:
-        assert t._model_forms == {"attention_form": "blocked"}
+        assert t._model_forms["attention_form"] == "blocked"
         t.train(2)
     rows = [json.loads(line) for line in open(tmp_path / "metrics.jsonl")]
     named = [r for r in rows if r["kind"] in ("manifest", "train")]

@@ -1,7 +1,8 @@
-"""The decoders' attention kernels (ops/flash_attention.py), asked of the
-chip's compiler without the chip: each kernel at both cells' published
-shapes, and the sliding-window decoder's whole ``Trainer`` step in its
-kernel form. Nothing executes; a passing compile is not a chip run. Skipped,
+"""The decoders' attention kernels (ops/flash_attention.py) and the Gated
+DeltaNet's chunk-algebra kernels (ops/delta_chunks.py), asked of the chip's
+compiler without the chip: each kernel at its cells' published shapes, and
+the sliding-window and the hybrid decoder's whole ``Trainer`` steps in
+their kernel forms. Nothing executes; a passing compile is not a chip run. Skipped,
 not failed, where the topology cannot be described (``conftest.py``'s
 ``v5e``). A file of its own beside ``test_pallas_compile.py``, so that the
 whole-step compiles of the two run on two workers.
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from gtopkssgd_tpu.models import decoder, qwen3_next, trinity_mini
+from gtopkssgd_tpu.ops import delta_chunks
 from gtopkssgd_tpu.ops import flash_attention as flash
 
 TRINITY = trinity_mini.PRESETS["26b_a3b_ep16"]
@@ -80,13 +82,10 @@ def test_score_arrays_finds_the_blocked_forms_and_no_other():
     assert not score_arrays(others)
 
 
-@pytest.fixture(scope="module")
-def published_step(v5e):
-    """(compiled text, bytes) of the sliding-window decoder's whole Trainer
-    step (the ``trinity_mini_ep16.gtopk`` cell's flags) for the described
-    v5e, with the attention in its kernel form: the backend here is the
-    CPU, so the test, not an option of the program, answers ``on_tpu``. One
-    compile (two minutes) serves the tests below."""
+def compiled_step(v5e, forms, **flags):
+    """(compiled text, bytes) of a decoder's whole Trainer step (a cell's
+    flags) for the described v5e, in its kernel forms: the backend here is
+    the CPU, so the test, not an option of the program, answers ``on_tpu``."""
     from gtopkssgd_tpu.trainer import TrainConfig, Trainer
 
     abstract = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -95,12 +94,11 @@ def published_step(v5e):
         patch.setattr(decoder, "on_tpu", lambda: True)
         jax.clear_caches()
         with Trainer(TrainConfig(
-                dnn="trinity_mini", dataset="tokens", dtype="bfloat16",
-                seed=42, model_preset="26b_a3b_ep16", batch_size=1,
-                nworkers=1, compression="gtopk", density=0.001, lr=0.1,
-                momentum=0.9, weight_decay=0.0, clip_grad_norm=1.0,
-                prefetch=0)) as trainer:
-            assert trainer._manifest["attention_form"] == "kernel"
+                dataset="tokens", dtype="bfloat16", seed=42, nworkers=1,
+                compression="gtopk", density=0.001, momentum=0.9,
+                weight_decay=0.0, clip_grad_norm=1.0, prefetch=0,
+                **flags)) as trainer:
+            assert all(trainer._manifest[form] == "kernel" for form in forms)
             batch = trainer._device_batch(
                 trainer._shard_batches(trainer._iters)[0])
             compiled = trainer._train_step.lower(
@@ -111,6 +109,14 @@ def published_step(v5e):
     return compiled.as_text(), (
         memory.temp_size_in_bytes + memory.argument_size_in_bytes
         + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def published_step(v5e):
+    """The sliding-window decoder's step (the ``trinity_mini_ep16.gtopk``
+    cell's flags): one compile (two minutes) serves the tests below."""
+    return compiled_step(v5e, ["attention_form"], dnn="trinity_mini",
+                         model_preset="26b_a3b_ep16", batch_size=1, lr=0.1)
 
 
 def test_published_step_stays_under_its_memory_line(published_step):
@@ -153,3 +159,82 @@ def test_published_step_holds_no_array_of_heads_queries_keys(published_step):
     length = TRINITY["seq_len"]
     assert f"bf16[1,4,8,{length},128]" in published_step[0]
     assert f"f32[1,4,8,{length}]" in published_step[0]
+
+
+# ------------------------------------------- the Gated DeltaNet's kernels
+DELTA_KERNELS = {
+    "forward": lambda q, v, row, wide, square, **kw: delta_chunks.forward(
+        q, q, v, row, row, **kw),
+    "backward": lambda q, v, row, wide, square, **kw: delta_chunks.backward(
+        q, q, v, row, row, wide, wide, square, wide, wide, **kw),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(DELTA_KERNELS))
+def test_delta_chunks_kernel_compiles_at_the_published_shapes(v5e, kernel):
+    """A sequence of 4,096 tokens (what ``lax.map(prepare)`` hands over at
+    a time), 16 key and 32 value heads of 128, chunks of 64: float32, the
+    blocks the program uses, one custom call each."""
+    length, chunk = QWEN["seq_len"], qwen3_next.chunk_of(QWEN["seq_len"])
+    keys, heads = QWEN["linear_num_key_heads"], QWEN["linear_num_value_heads"]
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=v5e)
+    out = lambda width: shape(length // chunk, 1, heads, chunk, width)
+    text = jax.jit(lambda *a: DELTA_KERNELS[kernel](
+        *a, key_heads=keys)).lower(
+            shape(1, length, keys * QWEN["linear_key_head_dim"]),
+            shape(1, length, heads * QWEN["linear_value_head_dim"]),
+            shape(1, heads, length // chunk, chunk),
+            out(128), out(chunk)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"delta_chunks_{kernel}" in text
+
+
+@pytest.fixture(scope="module")
+def published_hybrid_step(v5e):
+    """The hybrid decoder's step (the ``qwen3_next_ep64.gtopk`` cell's
+    flags), the attention and the chunks' algebra in their kernel forms:
+    one compile (a minute and a half) serves the tests below."""
+    return compiled_step(v5e, ["attention_form", "delta_form"],
+                         dnn="qwen3_next", model_preset="80b_a3b_ep64",
+                         batch_size=4, lr=0.5)
+
+
+def test_published_hybrid_step_stays_under_its_memory_line(
+        published_hybrid_step):
+    """13.62 GB of the v5e's 16.9 by XLA's ``memory_analysis()``; the XLA
+    form's step read 13.81 and the line is 14.5 (ISSUE 38): a sequence's
+    2.7 GB of float32 chunk blocks are gone, what a layer keeps is not."""
+    assert published_hybrid_step[1] < 14.5e9, published_hybrid_step[1]
+
+
+def test_published_hybrid_step_runs_each_delta_kernel_once_a_layer_and_pass(
+        published_hybrid_step):
+    """The engagement counter, static like the mechanism: a DeltaNet layer
+    holds one forward and one backward kernel (in its ``lax.map`` over the
+    sequences; neither ``prepare``'s own checkpoint nor the layer's replay
+    runs the forward kernel again), each under ``layer/gdn_scan`` so that
+    the device trace counts it there (``gdn_scan_ms``), backward too; and
+    the attention layer its three."""
+    calls = [line for line in published_hybrid_step[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    layers = QWEN["num_hidden_layers"]
+    deltanet = layers - layers // QWEN["full_attention_interval"]
+    for name in DELTA_KERNELS:
+        mine = [line for line in calls
+                if re.search(rf"delta_chunks_{name}\b", line)]
+        assert len(mine) == deltanet, (name, len(mine))
+        assert all(re.search(
+            rf'op_name="[^"]*layer/gdn_scan/[^"]*delta_chunks_{name}/'
+            rf'pallas_call"', line) for line in mine), name
+    for name in KERNELS:
+        assert len([line for line in calls if re.search(
+            rf"flash_attention_{name}\b", line)]) == layers - deltanet
+
+
+def test_published_hybrid_step_holds_no_triangular_solve(
+        published_hybrid_step):
+    """The XLA form's solve is the TPU's ``InvertDiagBlocksLowerTriangular``
+    custom call under the name ``triangular_solve`` (65.7 ms a step, PERF.md
+    section 6, PR 38): the kernels invert by products."""
+    assert "InvertDiagBlocks" not in published_hybrid_step[0]
+    assert "triangular_solve" not in published_hybrid_step[0]
