@@ -18,8 +18,9 @@ from typing import Any, Callable, Dict, Tuple
 
 import flax.linen as nn
 
-from gtopkssgd_tpu.models import keye_vl2, qwen3_next, trinity_mini
+from gtopkssgd_tpu.models import kanana2, keye_vl2, qwen3_next, trinity_mini
 from gtopkssgd_tpu.models.alexnet import AlexNet
+from gtopkssgd_tpu.models.kanana2 import Kanana2
 from gtopkssgd_tpu.models.keye_vl2 import KeyeVL2
 from gtopkssgd_tpu.models.lstm import PTBLSTM
 from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
@@ -156,6 +157,19 @@ _register(
         presets=tuple(trinity_mini.PRESETS),
     )
 )
+_register(
+    ModelSpec(
+        "kanana2",
+        Kanana2,
+        "tokens",
+        (8192,),  # one sequence of token ids
+        # As trinity_mini: the balancing bias rides in ``batch_stats``.
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
+        presets=tuple(kanana2.PRESETS),
+    )
+)
 
 
 def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
@@ -206,4 +220,5 @@ __all__ = [
     "Qwen3Next",
     "KeyeVL2",
     "TrinityMini",
+    "Kanana2",
 ]

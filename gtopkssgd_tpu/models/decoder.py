@@ -1,7 +1,9 @@
 """What the decoders of this zoo share (``qwen3_next``, ``keye_vl2``,
-``trinity_mini``): the dropless expert layer of one chip's share of an
-expert group, the rotary embedding, the zero-centred RMSNorm, the causal
-and sliding-window attention, and the head's loss a sequence at a time.
+``trinity_mini``, ``kanana2``): the dropless expert layer of one chip's
+share of an expert group, a dense layer's feed-forward, the rotary
+embedding, the zero-centred RMSNorm, the causal and sliding-window
+attention (keys as wide as values or wider), and the head's loss a sequence
+at a time.
 Each model file states its own layer equations and imports these; nothing
 here knows a model's sizes beyond the ``sizes`` dict it is handed.
 
@@ -86,19 +88,23 @@ def on_tpu():
     return jax.default_backend() == "tpu"
 
 
-def attention_form(length, dim):
+def attention_form(length, dim, value_dim=None):
     """``kernel`` where ``blocked_causal_attention`` runs as the Pallas
     kernels, ``blocked`` where as XLA's products a block of queries at a
-    time: the kernels need a TPU, a head of whole 128-lane rows and a
-    length of whole tiles."""
-    whole = dim % 128 == 0 and length % flash.TILE_Q == 0 \
-        and length % flash.TILE_K == 0
+    time: the kernels need a TPU, a value head (``value_dim``; the key
+    head's ``dim`` if None) of whole 128-lane rows, a key head of whole
+    half rows (latent attention: 192 beside 128) and a length of whole
+    tiles."""
+    value_dim = dim if value_dim is None else value_dim
+    whole = value_dim % 128 == 0 and dim % 64 == 0 \
+        and length % flash.TILE_Q == 0 and length % flash.TILE_K == 0
     return "kernel" if on_tpu() and whole else "blocked"
 
 
 def kernel_layout(q, k, v, dtype):
-    """q [B, S, H, D] -> [B, G, R, S, D], k, v [B, S, G, D] -> [B, G, S, D],
-    in ``dtype``: what the attention kernels read."""
+    """q [B, S, H, D] -> [B, G, R, S, D], k [B, S, G, D] -> [B, G, S, D],
+    v [B, S, G, D_v] -> [B, G, S, D_v], in ``dtype``: what the attention
+    kernels read."""
     batch, length, heads, dim = q.shape
     groups = k.shape[2]
     with jax.named_scope("part/layout"):
@@ -117,13 +123,14 @@ def kernel_causal_attention(q, k, v, dtype, window, kept):
 
 
 def _kernel_attention(q, k, v, dtype, window, kept):
-    batch, length, heads, dim = q.shape
+    batch, length, heads, _ = q.shape
     q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
     with jax.named_scope("part/kernel"):
         out, lse = flash.forward(q_l, k_l, v_l, window=window,
                                  interpret=not on_tpu())
     with jax.named_scope("part/layout"):
-        out = out.transpose(0, 3, 1, 2, 4).reshape(batch, length, heads, dim)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(
+            batch, length, heads, v.shape[3])
         if kept is not None:
             # Named here, where the backward pass takes them from: a remat
             # that keeps the name runs the forward kernel once a step.
@@ -153,9 +160,10 @@ kernel_causal_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
 
 
 def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
-    """q [B, S, H, D], k, v [B, S, H_kv, D] float32 -> [B, S, H, D] float32:
-    query t sees the keys s with 0 <= t - s (< ``window``, if given). The
-    output is named ``kept`` for a remat's policy (``checkpoint_name``).
+    """q [B, S, H, D], k [B, S, H_kv, D], v [B, S, H_kv, D_v] float32 ->
+    [B, S, H, D_v] float32: query t sees the keys s with 0 <= t - s
+    (< ``window``, if given), scores scaled by 1 / sqrt(D). The output is
+    named ``kept`` for a remat's policy (``checkpoint_name``).
 
     In the kernel form (``attention_form``) one call covers the sequence
     and ``kept`` names the rows' log-sum-exp too. In the blocked form
@@ -164,7 +172,7 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
     starts past it is handed the ``window + block`` keys from ``window``
     before its start to its end, never the sequence: such blocks are alike
     and run as one ``lax.map``."""
-    if attention_form(q.shape[1], q.shape[3]) == "kernel":
+    if attention_form(q.shape[1], q.shape[3], v.shape[3]) == "kernel":
         return kernel_causal_attention(q, k, v, dtype, window, kept)
     batch, length, heads, dim = q.shape
     kv_heads = k.shape[2]
@@ -235,7 +243,8 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
             outs.append(windowed(start, length - start,
                                  window + length - start))
     with jax.named_scope("part/layout"):
-        out = jnp.concatenate(outs, 1).reshape(batch, length, heads, dim)
+        out = jnp.concatenate(outs, 1).reshape(
+            batch, length, heads, v.shape[3])
         return out if kept is None else checkpoint_name(out, kept)
 
 
@@ -467,6 +476,22 @@ class SparseMoE(nn.Module):
                 slots_dropped(load, rows, self.max_blocks), balance)
 
 
+class DenseMLP(nn.Module):
+    """A dense layer's SwiGLU feed-forward of ``intermediate_size``."""
+    sizes: dict
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        d, width = self.sizes["hidden_size"], self.sizes["intermediate_size"]
+        w_gate = self.param("gate_proj", _normal(), (d, width), F32)
+        w_up = self.param("up_proj", _normal(), (d, width), F32)
+        w_down = self.param("down_proj", _normal(), (width, d), F32)
+        hidden = jax.nn.silu(dense(x, w_gate, self.dtype).astype(F32)) \
+            * dense(x, w_up, self.dtype).astype(F32)
+        return dense(hidden, w_down, self.dtype).astype(F32)
+
+
 # Tokens whose logits over the vocabulary's rows exist at once in the loss:
 # a sequence, or this many tokens of a longer one (16,384 x 18,992 float32
 # would be 1.2 GB, and as much again for their gradient).
@@ -490,3 +515,44 @@ def token_losses(hidden, head, targets, dtype):
         return jax.nn.logsumexp(logits, axis=-1) - picked
 
     return lax.map(one, (hidden, targets))
+
+
+# What an expert layer counts (``SparseMoE``'s second and third results)
+# and, with a balancing bias, its fourth: the names ``obs.counters`` reads.
+MOE_COUNTS = ("moe_load", "moe_dropped")
+BALANCE_COUNTS = MOE_COUNTS + ("moe_count", "moe_bias")
+
+
+def decoder_shell(module, tokens, targets, layer, depth, counts,
+                  embed_scale=None):
+    """What the decoders' ``__call__`` share round their layers, run inside
+    the model's own ``nn.compact`` method (``embed``, ``final_norm`` and
+    ``head`` are ``module``'s parameters; ``module.sizes`` gives
+    ``hidden_size``, ``vocab_rows`` and ``rms_norm_eps``): the embedding's
+    rows (times ``embed_scale``, if given), ``layer(i)(x)`` -> (x, the
+    layer's counts or None) for i < ``depth``, then the final norm and the
+    untied head, embedding and head under ``layer/head``. Without targets
+    the logits [B, S, vocab_rows]; with them (the mean cross-entropy,
+    {name: the layers' count of that place, stacked over the layers that
+    gave any} for the names ``counts``)."""
+    s, dtype = module.sizes, module.dtype
+    d, rows = s["hidden_size"], s["vocab_rows"]
+    with jax.named_scope("layer/head"):
+        table = module.param("embed", _normal(), (rows, d), F32)
+        x = table[tokens]
+        if embed_scale is not None:
+            x = x * embed_scale
+    found = []
+    for i in range(depth):
+        x, count = layer(i)(x)
+        if count is not None:
+            found.append(count)
+    with jax.named_scope("layer/head"):
+        w_final = module.param("final_norm", nn.initializers.zeros, (d,), F32)
+        head = module.param("head", _normal(), (d, rows), F32)
+        hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
+        if targets is None:
+            return jnp.dot(hidden.astype(dtype), head.astype(dtype),
+                           preferred_element_type=F32)
+        loss = token_losses(hidden, head, targets, dtype).mean()
+    return loss, {name: jnp.stack(c) for name, c in zip(counts, zip(*found))}

@@ -65,8 +65,8 @@ from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics
 
 from gtopkssgd_tpu.ops import dsa_attention as kernels
 from gtopkssgd_tpu.models.decoder import (
-    F32, SparseMoE, _normal, dense, kernel_layout, on_tpu, rms_norm0, rotary,
-    token_losses)
+    F32, MOE_COUNTS, SparseMoE, _normal, decoder_shell, dense, kernel_layout,
+    on_tpu, rms_norm0, rotary)
 
 # The published sizes (config.json of Keye-VL-2.0-30B-A3B; ``sa_config``'s
 # keys flat) with the three cuts of
@@ -703,30 +703,18 @@ class KeyeVL2(nn.Module):
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
-        d, rows = s["hidden_size"], s["vocab_rows"]
-        with jax.named_scope("layer/head"):
-            table = self.param("embed", _normal(), (rows, d), F32)
-            x = table[tokens]
-        counts = []
         by_name = jax.checkpoint_policies.save_only_these_names(
             KEPT_SELECTION, KEPT_ATTENTION, KEPT_MASKS, KEPT_PROBABILITIES)
-        for i in range(s["num_hidden_layers"]):
-            x, count = nn.remat(Layer, policy=by_name)(
-                s, self.dtype, name=f"layer_{i}")(x)
-            counts.append(count)
-        with jax.named_scope("layer/head"):
-            w_final = self.param("final_norm", nn.initializers.zeros, (d,), F32)
-            head = self.param("head", _normal(), (d, rows), F32)
-            hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
-            if targets is None:
-                return jnp.dot(hidden.astype(self.dtype),
-                               head.astype(self.dtype),
-                               preferred_element_type=F32)
-            loss = token_losses(hidden, head, targets, self.dtype).mean()
-        load, dropped, kept, index_loss = (
-            jnp.stack([c[i] for c in counts]) for i in range(4))
+        out = decoder_shell(
+            self, tokens, targets,
+            lambda i: nn.remat(Layer, policy=by_name)(
+                s, self.dtype, name=f"layer_{i}"),
+            s["num_hidden_layers"],
+            MOE_COUNTS + ("dsa_kept", "dsa_index_loss"))
+        if targets is None:
+            return out
+        loss, counts = out
         batch, length = tokens.shape
-        return loss + jnp.mean(index_loss), {
-            "moe_load": load, "moe_dropped": dropped, "dsa_kept": kept,
-            "dsa_due": jnp.asarray(batch * keys_due(length, s["topk"])),
-            "dsa_index_loss": index_loss}
+        return loss + jnp.mean(counts["dsa_index_loss"]), dict(
+            counts,
+            dsa_due=jnp.asarray(batch * keys_due(length, s["topk"])))

@@ -68,9 +68,9 @@ from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics
 
 from gtopkssgd_tpu.models import decoder
 from gtopkssgd_tpu.models.decoder import (
-    F32, HIGHEST, SparseMoE, _normal, attention_form,
-    blocked_causal_attention, dense, query_block_of, rms_norm0, rotary,
-    token_losses)
+    F32, HIGHEST, MOE_COUNTS, SparseMoE, _normal, attention_form,
+    blocked_causal_attention, decoder_shell, dense, query_block_of,
+    rms_norm0, rotary)
 from gtopkssgd_tpu.ops import delta_chunks as delta_kernels
 
 # The chunked delta rule's float32 products (module docstring, Precision).
@@ -513,27 +513,12 @@ class Qwen3Next(nn.Module):
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
-        d, rows = s["hidden_size"], s["vocab_rows"]
-        with jax.named_scope("layer/head"):
-            table = self.param("embed", _normal(), (rows, d), F32)
-            x = table[tokens]
-        counts = []
         by_name = jax.checkpoint_policies.save_only_these_names(
             KEPT_CHUNKS, KEPT_ATTENTION)
-        for i, (_, keep) in enumerate(
-                kept_across_remat(s, *tokens.shape,
-                                  kept_budget(*tokens.shape))):
-            x, count = nn.remat(Layer, policy=by_name if keep else None)(
-                s, self.dtype, is_attention(s, i), name=f"layer_{i}")(x)
-            counts.append(count)
-        with jax.named_scope("layer/head"):
-            w_final = self.param("final_norm", nn.initializers.zeros, (d,), F32)
-            head = self.param("head", _normal(), (d, rows), F32)
-            hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
-            if targets is None:
-                return jnp.dot(hidden.astype(self.dtype),
-                               head.astype(self.dtype),
-                               preferred_element_type=F32)
-            loss = token_losses(hidden, head, targets, self.dtype).mean()
-        return loss, {"moe_load": jnp.stack([c[0] for c in counts]),
-                      "moe_dropped": jnp.stack([c[1] for c in counts])}
+        keeps = [keep for _, keep in kept_across_remat(
+            s, *tokens.shape, kept_budget(*tokens.shape))]
+        return decoder_shell(
+            self, tokens, targets,
+            lambda i: nn.remat(Layer, policy=by_name if keeps[i] else None)(
+                s, self.dtype, is_attention(s, i), name=f"layer_{i}"),
+            len(keeps), MOE_COUNTS)

@@ -65,8 +65,8 @@ import jax
 import jax.numpy as jnp
 
 from gtopkssgd_tpu.models.decoder import (
-    F32, SparseMoE, _normal, attention_form, blocked_causal_attention, dense,
-    rms_norm0, rotary, token_losses)
+    BALANCE_COUNTS, F32, DenseMLP, SparseMoE, _normal, attention_form,
+    blocked_causal_attention, decoder_shell, dense, rms_norm0, rotary)
 
 # The published sizes (config.json of Trinity-Mini) with the four cuts of
 # perfbench/configs/trinity_mini_26b_a3b_ep16.json, whose ``sizes`` a test
@@ -170,21 +170,6 @@ class GatedAttention(nn.Module):
             return dense(out, w_o, dtype)
 
 
-class DenseMLP(nn.Module):
-    sizes: dict
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        d, width = self.sizes["hidden_size"], self.sizes["intermediate_size"]
-        w_gate = self.param("gate_proj", _normal(), (d, width), F32)
-        w_up = self.param("up_proj", _normal(), (d, width), F32)
-        w_down = self.param("down_proj", _normal(), (width, d), F32)
-        hidden = jax.nn.silu(dense(x, w_gate, self.dtype).astype(F32)) \
-            * dense(x, w_up, self.dtype).astype(F32)
-        return dense(hidden, w_down, self.dtype).astype(F32)
-
-
 class Layer(nn.Module):
     """(x, the expert layer's counts or None for a dense layer)."""
     sizes: dict
@@ -247,30 +232,11 @@ class TrinityMini(nn.Module):
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
-        d, rows = s["hidden_size"], s["vocab_rows"]
-        with jax.named_scope("layer/head"):
-            table = self.param("embed", _normal(), (rows, d), F32)
-            x = table[tokens]
-            if s["mup_enabled"]:
-                x = x * math.sqrt(d)
-        counts = []
         by_name = jax.checkpoint_policies.save_only_these_names(KEPT_ATTENTION)
-        for i in range(s["num_hidden_layers"]):
-            x, count = nn.remat(Layer, policy=by_name)(
+        return decoder_shell(
+            self, tokens, targets,
+            lambda i: nn.remat(Layer, policy=by_name)(
                 s, self.dtype, is_sliding(s, i), is_dense(s, i),
-                name=f"layer_{i}")(x)
-            if count is not None:
-                counts.append(count)
-        with jax.named_scope("layer/head"):
-            w_final = self.param("final_norm", nn.initializers.zeros, (d,), F32)
-            head = self.param("head", _normal(), (d, rows), F32)
-            hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
-            if targets is None:
-                return jnp.dot(hidden.astype(self.dtype),
-                               head.astype(self.dtype),
-                               preferred_element_type=F32)
-            loss = token_losses(hidden, head, targets, self.dtype).mean()
-        load, dropped, chosen, bias = (
-            jnp.stack([c[i] for c in counts]) for i in range(4))
-        return loss, {"moe_load": load, "moe_dropped": dropped,
-                      "moe_count": chosen, "moe_bias": bias}
+                name=f"layer_{i}"),
+            s["num_hidden_layers"], BALANCE_COUNTS,
+            math.sqrt(s["hidden_size"]) if s["mup_enabled"] else None)

@@ -24,14 +24,16 @@ dtype for their products; d_q, d_k, d_v are float32.
 
 One call covers a layer's whole sequence, one key-value head's R query
 heads folded into the rows of each product (a q tile is ``[R * tq, D]``: a
-key tile is fetched once for its R heads). Layouts, as
-``ops/dsa_attention.py``'s:
+key tile is fetched once for its R heads). Keys may be wider than values
+(latent attention: D = 192 beside D_v = 128): q and k have the key width
+D, which also sets the scale 1 / sqrt(D), v and o the value width D_v, and
+a block's last axis is its array's. Layouts, as ``ops/dsa_attention.py``'s:
 
-  q, d_out, o, d_q   [B, G, R, S, D]       k, v, d_k, d_v   [B, G, S, D]
-  lse, delta         [B, G, R, S]          float32, a number a row, the
-                                            queries along the lanes (a last
-                                            axis of 1 would cost 128 lanes
-                                            a number)
+  q, d_q       [B, G, R, S, D]        k, d_k   [B, G, S, D]
+  d_out, o     [B, G, R, S, D_v]      v, d_v   [B, G, S, D_v]
+  lse, delta   [B, G, R, S]           float32, a number a row, the queries
+                                      along the lanes (a last axis of 1
+                                      would cost 128 lanes a number)
 
 Three kernels: ``forward`` (o, lse), ``backward_q`` (q tiles outside, the
 band's key tiles swept) and ``backward_kv`` (key tiles outside, the query
@@ -155,11 +157,12 @@ def _edges(visited, inside, step):
     pl.when(visited & jnp.logical_not(inside))(lambda: step(True))
 
 
-def _row_specs(heads, tq, dim, tk, window):
+def _row_specs(heads, tq, tk, window):
     """Block specs for a grid (b, g, i, step) that sweeps query tile i's
     key tiles: of a [B, G, R, S, D] array (``rows(dim)``) or a
-    [B, G, R, S] one (``rows()``), and of k / v. A step past the tile's
-    last key tile repeats its index, so nothing is fetched for it."""
+    [B, G, R, S] one (``rows()``), and of k / v (``keys(dim)``). A step
+    past the tile's last key tile repeats its index, so nothing is fetched
+    for it."""
     def key_tile(i, step):
         return jnp.minimum(first_key_tile(i, tq, tk, window) + step,
                            last_key_tile(i, tq, tk))
@@ -167,8 +170,9 @@ def _row_specs(heads, tq, dim, tk, window):
     rows = lambda *width: pl.BlockSpec(
         (None, None, heads, tq) + width,
         lambda b, g, i, step: (b, g, 0, i) + (0,) * len(width))
-    keys = pl.BlockSpec((None, None, tk, dim),
-                        lambda b, g, i, step: (b, g, key_tile(i, step), 0))
+    keys = lambda width: pl.BlockSpec(
+        (None, None, tk, width),
+        lambda b, g, i, step: (b, g, key_tile(i, step), 0))
     return rows, keys
 
 
@@ -211,7 +215,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     @pl.when(at == pl.num_programs(3) - 1)
     def _():
         total = l_ref[...]
-        o_ref[...] = (acc_ref[...] / total).T.reshape(heads, tq, dim)
+        o_ref[...] = (acc_ref[...] / total).T.reshape(o_ref.shape)
         lse = m_ref[...] + _ln(total)
         lse_ref[...] = jnp.concatenate(
             [lse[:, r * tq:(r + 1) * tq] for r in range(heads)], axis=0)
@@ -219,23 +223,24 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 def forward(q, k, v, *, window=None, tile_q=None, tile_k=None,
             interpret=False):
-    """(o [B, G, R, S, D] float32, lse [B, G, R, S] float32)."""
+    """(o [B, G, R, S, D_v] float32, lse [B, G, R, S] float32)."""
     batch, groups, heads, length, dim = q.shape
+    value_dim = v.shape[-1]
     tq, tk = _tiles(length, tile_q, tile_k)
     window = _window(window, length)
-    rows, keys = _row_specs(heads, tq, dim, tk, window)
+    rows, keys = _row_specs(heads, tq, tk, window)
     return pl.pallas_call(
         functools.partial(_forward_kernel, scale=1.0 / math.sqrt(dim),
                           window=window),
         grid=(batch, groups, length // tq,
               _sweep(key_tiles(length, tq, tk, window))),
-        in_specs=[rows(dim), keys, keys],
-        out_specs=[rows(dim), rows()],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, F32),
+        in_specs=[rows(dim), keys(dim), keys(value_dim)],
+        out_specs=[rows(value_dim), rows()],
+        out_shape=[jax.ShapeDtypeStruct(q.shape[:-1] + (value_dim,), F32),
                    jax.ShapeDtypeStruct(q.shape[:-1], F32)],
         scratch_shapes=[pltpu.VMEM((1, heads * tq), F32),
                         pltpu.VMEM((1, heads * tq), F32),
-                        pltpu.VMEM((dim, heads * tq), F32)],
+                        pltpu.VMEM((value_dim, heads * tq), F32)],
         compiler_params=_params(_SWEEP),
         name="flash_attention_forward", interpret=interpret,
     )(q, k, v)
@@ -261,7 +266,7 @@ def _backward_q_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
             logits = (logits.reshape(heads, tq, tk)
                       + _bias(i, j, tq, tk, window)[None]).reshape(rows, tk)
         p = jnp.exp(logits - _column(lse_ref[...]))
-        d_p = lax.dot_general(do_ref[...].reshape(rows, dim), v_ref[...], _NT,
+        d_p = lax.dot_general(do_ref[...].reshape(rows, -1), v_ref[...], _NT,
                               preferred_element_type=F32)
         d_logits = (p * (d_p - _column(delta_ref[...]))).astype(k.dtype)
         acc_ref[...] += jnp.dot(d_logits, k, preferred_element_type=F32)
@@ -275,18 +280,20 @@ def _backward_q_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
 
 def backward_q(q, k, v, lse, delta, d_out, *, window=None, tile_q=None,
                tile_k=None, interpret=False):
-    """d_q [B, G, R, S, D] float32. ``d_out`` in the dtype of q, k, v;
-    ``delta`` = sum_d d_out * o, a number a row, float32."""
+    """d_q [B, G, R, S, D] float32. ``d_out`` [B, G, R, S, D_v] in the
+    dtype of q, k, v; ``delta`` = sum_d d_out * o, a number a row, float32."""
     batch, groups, heads, length, dim = q.shape
+    value_dim = v.shape[-1]
     tq, tk = _tiles(length, tile_q, tile_k)
     window = _window(window, length)
-    rows, keys = _row_specs(heads, tq, dim, tk, window)
+    rows, keys = _row_specs(heads, tq, tk, window)
     return pl.pallas_call(
         functools.partial(_backward_q_kernel, scale=1.0 / math.sqrt(dim),
                           window=window),
         grid=(batch, groups, length // tq,
               _sweep(key_tiles(length, tq, tk, window))),
-        in_specs=[rows(dim), keys, keys, rows(), rows(), rows(dim)],
+        in_specs=[rows(dim), keys(dim), keys(value_dim), rows(), rows(),
+                  rows(value_dim)],
         out_specs=rows(dim),
         out_shape=jax.ShapeDtypeStruct(q.shape, F32),
         scratch_shapes=[pltpu.VMEM((heads * tq, dim), F32)],
@@ -312,7 +319,7 @@ def _backward_kv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def step(masked):
-        q, d_o = q_ref[...].reshape(rows, dim), do_ref[...].reshape(rows, dim)
+        q, d_o = q_ref[...].reshape(rows, dim), do_ref[...].reshape(rows, -1)
         logits = lax.dot_general(k_ref[...], q, _NT,
                                  preferred_element_type=F32) * scale
         if masked:
@@ -337,8 +344,9 @@ def _backward_kv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
 
 def backward_kv(q, k, v, lse, delta, d_out, *, window=None, tile_q=None,
                 tile_k=None, interpret=False):
-    """(d_k, d_v) [B, G, S, D] float32."""
+    """(d_k [B, G, S, D], d_v [B, G, S, D_v]) float32."""
     batch, groups, heads, length, dim = q.shape
+    value_dim = v.shape[-1]
     tq, tk = _tiles(length, tile_q, tile_k)
     window = _window(window, length)
 
@@ -346,22 +354,25 @@ def backward_kv(q, k, v, lse, delta, d_out, *, window=None, tile_q=None,
         return jnp.minimum(first_query_tile(j, tq, tk) + step,
                            last_query_tile(j, tq, tk, window, length))
 
-    rows = pl.BlockSpec(
-        (None, None, heads, tq, dim),
+    rows = lambda width: pl.BlockSpec(
+        (None, None, heads, tq, width),
         lambda b, g, j, step: (b, g, 0, query_tile(j, step), 0))
     lanes = pl.BlockSpec((None, None, heads, tq),
                          lambda b, g, j, step: (b, g, 0, query_tile(j, step)))
-    keys = pl.BlockSpec((None, None, tk, dim),
-                        lambda b, g, j, step: (b, g, j, 0))
+    keys = lambda width: pl.BlockSpec((None, None, tk, width),
+                                      lambda b, g, j, step: (b, g, j, 0))
     return pl.pallas_call(
         functools.partial(_backward_kv_kernel, scale=1.0 / math.sqrt(dim),
                           window=window, length=length),
         grid=(batch, groups, length // tk,
               _sweep(query_tiles(length, tq, tk, window))),
-        in_specs=[rows, keys, keys, lanes, lanes, rows],
-        out_specs=[keys, keys],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, F32)] * 2,
-        scratch_shapes=[pltpu.VMEM((tk, dim), F32)] * 2,
+        in_specs=[rows(dim), keys(dim), keys(value_dim), lanes, lanes,
+                  rows(value_dim)],
+        out_specs=[keys(dim), keys(value_dim)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, F32),
+                   jax.ShapeDtypeStruct(v.shape, F32)],
+        scratch_shapes=[pltpu.VMEM((tk, dim), F32),
+                        pltpu.VMEM((tk, value_dim), F32)],
         compiler_params=_params(_SWEEP),
         name="flash_attention_backward_kv", interpret=interpret,
     )(q, k, v, lse, delta, d_out)

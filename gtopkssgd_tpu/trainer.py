@@ -137,7 +137,8 @@ class TrainConfig:
     model_preset: Optional[str] = None  # the decoders only (qwen3_next:
                                    # '80b_a3b_ep64', 'tiny'; keye_vl2:
                                    # '30b_a3b_ep16', 'tiny'; trinity_mini:
-                                   # '26b_a3b_ep16', 'tiny'): which of the
+                                   # '26b_a3b_ep16', 'tiny'; kanana2:
+                                   # '30b_a3b_ep16', 'tiny'): which of the
                                    # model's PRESETS to build, the
                                    # published sizes as one chip's share
                                    # of an expert group or the tests'

@@ -24,6 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from gtopkssgd_tpu.models import decoder  # noqa: E402
 from gtopkssgd_tpu.models import qwen3_next, trinity_mini  # noqa: E402
 from gtopkssgd_tpu.ops import flash_attention as flash  # noqa: E402
+from perfbench.refmodels import kanana2 as latent_ref  # noqa: E402
 from perfbench.refmodels import trinity_mini as ref  # noqa: E402
 
 F32 = jnp.float32
@@ -102,6 +103,46 @@ def test_kernel_form_equals_the_blocked_form_and_the_reference(
             *a, F32, 32, window), q, k, v, d_out)
         for a, b, c in zip(mine, blocked, exact):
             assert rel(a, c) < 1.25 * rel(b, c)
+
+
+# Keys wider than values (latent attention): the published 192 beside 128,
+# a head with keys and values of its own (R = 1) and two query heads a
+# key-value head; and ``tiny``'s 24 beside 16, which is no multiple of it.
+WIDTHS = [(w, rep, key, value, d)
+          for (rep, key, value), w, d in itertools.product(
+              [(1, 192, 128), (2, 192, 128), (1, 24, 16)], (None, 50),
+              (jnp.float32, jnp.bfloat16))]
+
+
+@pytest.mark.parametrize(
+    "window,rep,key,value,dtype", WIDTHS,
+    ids=[f"w{w}-r{r}-k{k}-v{v}-{d.__name__}" for w, r, k, v, d in WIDTHS])
+def test_kernel_form_takes_a_key_head_wider_than_the_value_head(
+        window, rep, key, value, dtype, monkeypatch):
+    """q, k [.., D] beside v, o [.., D_v]: output and d_v of the value
+    width, d_q and d_k of the key width, the scale 1 / sqrt(D), against the
+    blocked form and (without a window) the latent-attention reference."""
+    monkeypatch.setattr(flash, "TILE_Q", TILE)
+    monkeypatch.setattr(flash, "TILE_K", TILE)
+    keys = jax.random.split(jax.random.PRNGKey(1), 4)
+    q = jax.random.normal(keys[0], (2, LENGTH, 2 * rep, key))
+    k = jax.random.normal(keys[1], (2, LENGTH, 2, key))
+    v = jax.random.normal(keys[2], (2, LENGTH, 2, value))
+    d_out = jax.random.normal(keys[3], (2, LENGTH, 2 * rep, value))
+    mine = value_and_grads(lambda *a: decoder.kernel_causal_attention(
+        *a, dtype, window, None), q, k, v, d_out)
+    assert [a.shape[-1] for a in mine] == [value, key, key, value]
+    others = [value_and_grads(lambda *a: decoder.blocked_causal_attention(
+        *a, dtype, 32, window), q, k, v, d_out)]
+    if window is None and rep == 1:
+        others.append(value_and_grads(lambda q, k, v: jnp.stack([
+            latent_ref.attention(q[b], k[b], v[b], 0, dtype)
+            for b in range(2)]), q, k, v, d_out))
+    for theirs in others:
+        for name, a, b, close in zip(("o", "d_q", "d_k", "d_v"), mine, theirs,
+                                     (CLOSE[dtype][0],) + (CLOSE[dtype][1],) * 3):
+            assert a.dtype == F32 and a.shape == b.shape
+            assert rel(a, b) < close, (name, rel(a, b))
 
 
 def test_unequal_tiles_and_the_windows_edges(monkeypatch):
@@ -208,7 +249,7 @@ QWEN = qwen3_next.PRESETS["80b_a3b_ep64"]
     (True, 4096, 256, "kernel"),        # the hybrid decoder's cell
     (True, 512, 128, "kernel"),
     (True, 16384, 64, "blocked"),       # a head of half a lane row
-    (True, 16384, 192, "blocked"),
+    (True, 16384, 192, "blocked"),      # values of 1.5 lane rows
     (True, 16000, 128, "blocked"),      # no whole tile
     (True, 64, 128, "blocked"),
     (True, 64, 16, "blocked"),          # ``tiny``
@@ -220,6 +261,24 @@ def test_the_form_follows_the_backend_and_the_shapes(tpu, length, dim, form,
     assert jax.default_backend() == "cpu" and not decoder.on_tpu()
     monkeypatch.setattr(decoder, "on_tpu", lambda: tpu)
     assert decoder.attention_form(length, dim) == form
+    assert decoder.attention_form(length, dim, dim) == form
+
+
+@pytest.mark.parametrize("tpu,length,key,value,form", [
+    (False, 8192, 192, 128, "blocked"),
+    (True, 8192, 192, 128, "kernel"),   # the latent-attention decoder's cell
+    (True, 8192, 128, 192, "blocked"),  # values of 1.5 lane rows
+    (True, 8192, 160, 128, "blocked"),  # keys of no whole half row
+    (True, 8192, 64, 128, "kernel"),
+    (True, 64, 24, 16, "blocked"),      # ``tiny``
+])
+def test_the_form_reads_both_widths(tpu, length, key, value, form,
+                                    monkeypatch):
+    """The values of whole 128-lane rows (the accumulator's sublanes, the
+    output's lanes), the keys of whole half rows: each such pair compiles
+    for a v5e (tests/test_flash_compile.py has the published one)."""
+    monkeypatch.setattr(decoder, "on_tpu", lambda: tpu)
+    assert decoder.attention_form(length, key, value) == form
 
 
 @pytest.mark.parametrize("module,preset,length,dim", [

@@ -15,23 +15,39 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from gtopkssgd_tpu.models import decoder, qwen3_next, trinity_mini
+from gtopkssgd_tpu.models import decoder, kanana2, qwen3_next, trinity_mini
 from gtopkssgd_tpu.ops import delta_chunks
 from gtopkssgd_tpu.ops import flash_attention as flash
 
 TRINITY = trinity_mini.PRESETS["26b_a3b_ep16"]
 QWEN = qwen3_next.PRESETS["80b_a3b_ep64"]
-# (sequences a step in the cell, the preset, the window)
-LAYERS = {"sliding": (1, TRINITY, TRINITY["sliding_window"]),
-          "full": (1, TRINITY, None),
-          "hybrid": (4, QWEN, None)}
+KANANA = kanana2.PRESETS["30b_a3b_ep16"]
+# (sequences a step in the cell, query heads, key-value heads, key width,
+# value width, tokens, the window)
+LAYERS = {
+    "sliding": (1, 32, 4, 128, 128, 16384, TRINITY["sliding_window"]),
+    "full": (1, 32, 4, 128, 128, 16384, None),
+    "hybrid": (4, 16, 2, 256, 256, 4096, None),
+    "latent": (2, 32, 32, 192, 128, 8192, None)}
 KERNELS = {
-    "forward": lambda q, k, row, **kw: flash.forward(q, k, k, **kw),
-    "backward_q": lambda q, k, row, **kw: flash.backward_q(
-        q, k, k, row, row, q, **kw),
-    "backward_kv": lambda q, k, row, **kw: flash.backward_kv(
-        q, k, k, row, row, q, **kw),
+    "forward": lambda q, k, v, row, d_out, **kw: flash.forward(q, k, v, **kw),
+    "backward_q": lambda q, k, v, row, d_out, **kw: flash.backward_q(
+        q, k, v, row, row, d_out, **kw),
+    "backward_kv": lambda q, k, v, row, d_out, **kw: flash.backward_kv(
+        q, k, v, row, row, d_out, **kw),
 }
+
+
+def test_the_layers_shapes_are_the_published_presets():
+    for heads, groups, key, value, length, sizes in (
+            LAYERS["full"][1:6] + (TRINITY,), LAYERS["hybrid"][1:6] + (QWEN,)):
+        assert (heads, groups, key, value, length) == (
+            sizes["num_attention_heads"], sizes["num_key_value_heads"],
+            sizes["head_dim"], sizes["head_dim"], sizes["seq_len"])
+    assert LAYERS["latent"][1:6] == (
+        KANANA["num_attention_heads"], KANANA["num_attention_heads"],
+        KANANA["qk_nope_head_dim"] + KANANA["qk_rope_head_dim"],
+        KANANA["v_head_dim"], KANANA["seq_len"])
 
 
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
@@ -39,17 +55,19 @@ KERNELS = {
 def test_flash_attention_kernel_compiles_at_the_published_shapes(
         v5e, layer, kernel):
     """16,384 tokens x 32 / 4 heads of 128 under the window of 2,048 and
-    under none, and 4 x 4,096 tokens x 16 / 2 heads of 256: bfloat16, the
-    tiles the program uses, one custom call each."""
-    batch, sizes, window = LAYERS[layer]
-    length, dim = sizes["seq_len"], sizes["head_dim"]
-    groups = sizes["num_key_value_heads"]
-    rows = (batch, groups, sizes["num_attention_heads"] // groups, length)
+    under none, 4 x 4,096 tokens x 16 / 2 heads of 256, and 2 x 8,192
+    tokens x 32 / 32 heads with keys of 192 beside values of 128 (1.5 rows
+    of 128 lanes: a block's last axis is its array's): bfloat16, the tiles
+    the program uses, one custom call each."""
+    batch, heads, groups, key, value, length, window = LAYERS[layer]
+    rows = (batch, groups, heads // groups, length)
     shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
     text = jax.jit(lambda *a: KERNELS[kernel](*a, window=window)).lower(
-        shape(rows + (dim,), jnp.bfloat16),
-        shape((batch, groups, length, dim), jnp.bfloat16),
-        shape(rows, jnp.float32)).compile().as_text()
+        shape(rows + (key,), jnp.bfloat16),
+        shape((batch, groups, length, key), jnp.bfloat16),
+        shape((batch, groups, length, value), jnp.bfloat16),
+        shape(rows, jnp.float32),
+        shape(rows + (value,), jnp.bfloat16)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert f"flash_attention_{kernel}" in text
 

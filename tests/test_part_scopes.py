@@ -15,11 +15,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from gtopkssgd_tpu.models import decoder, keye_vl2, qwen3_next, trinity_mini
+from gtopkssgd_tpu.models import (
+    decoder, kanana2, keye_vl2, qwen3_next, trinity_mini)
 from gtopkssgd_tpu.ops import dsa_attention, flash_attention
 from perfbench.metrics import layer_ms, part_ms, scoped
 
-ATTENTION = ("attn", "attn_window", "attn_full")
+ATTENTION = ("attn", "attn_window", "attn_full", "attn_latent")
 PARTS = {"proj", "pointwise", "layout", "kernel"}
 # What the contract asks of by name: the products, the transposes and the
 # custom calls (a Pallas call off the TPU is interpreted: its products).
@@ -81,6 +82,13 @@ def trinity(sliding):
     return layer, sizes, lambda out: jnp.sum(out[0])
 
 
+def kanana():
+    sizes = kanana2.PRESETS["tiny"]
+    layer = remat_layer(kanana2.Layer, [kanana2.KEPT_ATTENTION])(
+        sizes, jnp.float32, True)
+    return layer, sizes, lambda out: jnp.sum(out[0])
+
+
 def qwen():
     sizes = qwen3_next.PRESETS["tiny"]
     layer = remat_layer(qwen3_next.Layer, [
@@ -100,7 +108,8 @@ def keye():
 
 LAYERS = {"trinity_sliding": (lambda: trinity(True), "attn_window"),
           "trinity_full": (lambda: trinity(False), "attn_full"),
-          "qwen": (qwen, "attn"), "keye": (keye, "attn")}
+          "qwen": (qwen, "attn"), "keye": (keye, "attn"),
+          "kanana": (kanana, "attn_latent")}
 
 
 def lowered_layer(name):
