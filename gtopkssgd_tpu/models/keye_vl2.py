@@ -25,16 +25,24 @@ is how they are computed:
     is multiplied, the keys outside S_t = {s <= t: I_ts >= tau_t} masked out
     of the softmax, one key-value head's query heads at a time, the softmax's
     exponent taken against a bound from the norms (``LOGIT_CAP``), and the
-    probabilities' sum over the heads carried along for the indexer's loss.
-    On a TPU, at shapes that fill whole tiles, the same attention runs as
-    Pallas kernels (``ops/dsa_attention.py``, "attention, kernel form"
-    below) that keep the [heads, block, keys] arrays in VMEM; XLA's form
-    (``attend_group``) is every other backend's and the kernels' oracle;
+    probabilities' sum over the heads carried along for the indexer's loss;
+  * on a TPU, at shapes that fill whole tiles, the **kernel form**
+    ("attention, kernel form" below; ``attention_form`` decides, from the
+    backend and the shapes alone): the index scores and their gradient as
+    the Pallas kernels of ``ops/dsa_index.py``, which keep the
+    [J, block, keys] products in VMEM and hand out a bucket's [rows, keys]
+    scores, made once a forward pass (the thresholds, the mask and the loss
+    read that one array) and once more in the backward rule; the attention
+    as the kernels of ``ops/dsa_attention.py``, which keep the
+    [heads, block, keys] arrays in VMEM and read the layer's mask, a byte a
+    pair. XLA's form (``index_scores``, ``select_thresholds``,
+    ``attend_group``) is every other backend's and the kernels' oracle;
   * the expert layer, the head and the loss are ``models/decoder.py``'s, as
     the other decoder's are; every layer under ``jax.checkpoint``, which
     keeps by name the thresholds and what the query blocks' own checkpoint
     gives out (``KEPT_ATTENTION``), so that a block runs twice a step, not
-    three times.
+    three times (in the kernel form: the mask, the output, the weights' sums
+    and the probabilities, so that the replay runs no kernel at all).
 
 Precision is the reference's: float32 parameters, residual stream, norms,
 rotary, the sum over the indexer's heads, thresholds, both softmaxes,
@@ -64,6 +72,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics call
 
 from gtopkssgd_tpu.ops import dsa_attention as kernels
+from gtopkssgd_tpu.ops import dsa_index as index_kernels
 from gtopkssgd_tpu.models.decoder import (
     F32, MOE_COUNTS, SparseMoE, _normal, decoder_shell, dense, kernel_layout,
     on_tpu, rms_norm0, rotary)
@@ -146,6 +155,15 @@ def kth_largest(keys, k):
     return lax.fori_loop(0, 32, body, jnp.zeros(keys.shape[:-1], jnp.uint32))
 
 
+def row_thresholds(scores, rows, topk):
+    """The ``topk``-th largest of each row of ``scores`` [B, Q, K] among the
+    keys up to the row's own position ``rows`` [Q], -inf where they are
+    fewer: [B, Q] float32."""
+    valid = rows[:, None] >= jnp.arange(scores.shape[-1])[None, :]
+    ranked = jnp.where(valid, ordered_bits(scores), 0)
+    return from_ordered_bits(kth_largest(ranked, topk))
+
+
 def buckets(length, block):
     """[(first query, queries, key extent)] of a sequence padded to whole
     blocks: ``BUCKET`` blocks a bucket, fewer in the last."""
@@ -179,14 +197,12 @@ def select_thresholds(qi, ki, w, topk, dtype, block):
         with jax.named_scope("part/layout"):
             keys = ki[:, :extent]
 
-        def one(args, keys=keys, extent=extent):
+        def one(args, keys=keys):
             qi_b, w_b, rows = args
             with jax.named_scope("layer/dsa_index"):
                 scores = index_scores(qi_b, keys, w_b, dtype)
             with jax.named_scope("layer/dsa_select"):
-                valid = rows[:, None] >= jnp.arange(extent)[None, :]
-                ranked = jnp.where(valid, ordered_bits(scores), 0)
-                return from_ordered_bits(kth_largest(ranked, topk))
+                return row_thresholds(scores, rows, topk)
 
         part = slice(start, start + queries)
         # The blocks' cutting and joining are the mixer's part/layout;
@@ -353,30 +369,38 @@ def sparse_attention(q, k, v, qi, ki, w, tau, dtype, block):
 
 
 # ------------------------------------------------- attention, kernel form
-# The same attention with the [R, block, keys] arrays in VMEM tiles
-# (``ops/dsa_attention.py``): where the backend is a TPU and the shapes fill
-# whole tiles, a layer's attention is one forward kernel, one kernel a bucket
-# for the head-mean probabilities, and two backward kernels. The indexer's
-# part stays what it was: a ``lax.map`` over a bucket's query blocks makes
-# the scores and ``keep`` (now for the whole layer, as int8, before any
-# attention runs), and the loss is taken from the scores and the kernel's
-# probabilities. What a layer's remat keeps by name grows by what the
-# backward pass would otherwise make again with the forward kernels and a
-# pass of index scores: the weights' sums (``total`` [B, G, R, S], 2 MB a
-# layer), the output in float32 (268 MB), the layer's mask, a bit a pair
-# (34 MB), and the probabilities (0.6 GB in float32: a bucket's rows
-# against its keys; made again they are 61 ms a step).
+# The same attention and the same indexer with the [heads, block, keys]
+# arrays in VMEM tiles (``ops/dsa_attention.py``, ``ops/dsa_index.py``):
+# where the backend is a TPU and the shapes fill whole tiles, a layer's
+# attention is one forward kernel, one kernel a bucket for the head-mean
+# probabilities, and two backward kernels; its indexer one ``scores`` kernel
+# a bucket in the forward pass, and in the backward rule one ``scores``, one
+# ``backward_q`` and one ``backward_k`` a bucket. A bucket's scores
+# [B, rows, keys] are made **once** a forward pass: the thresholds
+# (``kth_largest`` over them, a query block at a time), the mask
+# ``score >= tau`` and the loss all read that one array, so a query keeps
+# what its threshold counted, bit for bit. What a layer's remat keeps by
+# name grows by what the backward pass would otherwise make again with the
+# forward kernels, the selection and a pass of index scores: the weights'
+# sums (``total`` [B, G, R, S], 2 MB a layer), the output in float32
+# (268 MB), the layer's mask, a bit a pair (34 MB), and the probabilities
+# (0.6 GB in float32: a bucket's rows against its keys; made again they are
+# 61 ms a step). The thresholds live between the scores and the mask alone:
+# this form keeps none.
 KEPT_MASKS, KEPT_PROBABILITIES = "dsa_keep", "dsa_p"
 
 
-def attention_form(length, dim, block):
-    """``kernel`` where ``sparse_attention`` runs as the Pallas kernels,
-    ``masked`` where as XLA's masked products: the kernels need a TPU, a
-    head of whole 128-lane rows, and blocks, buckets and a (padded) length
-    of whole tiles."""
+def attention_form(length, dim, block, index_dim):
+    """``kernel`` where ``sparse_attention`` and its index scores run as the
+    Pallas kernels, ``masked`` where as XLA's masked products: the kernels
+    need a TPU, a head of whole 128-lane rows, an indexer head of whole half
+    rows, and blocks, buckets and a (padded) length of whole tiles."""
     padded = -(-length // block) * block
-    whole = (dim % 128 == 0 and block % kernels.TILE_Q == 0
-             and min(BUCKET * block, padded) % kernels.TILE_K == 0)
+    bucket = min(BUCKET * block, padded)
+    whole = (dim % 128 == 0 and index_dim % 64 == 0
+             and block % kernels.TILE_Q == 0 and bucket % kernels.TILE_K == 0
+             and block % index_kernels.TILE_Q == 0
+             and bucket % index_kernels.TILE_K == 0)
     return "kernel" if on_tpu() and whole else "masked"
 
 
@@ -391,12 +415,6 @@ def _tops(q, k, block):
             k_norm[..., :extent], -1)[..., None, None]
             / math.sqrt(q.shape[-1]), LOGIT_CAP)
         for start, queries, extent in buckets(q.shape[3], block)], -1)
-
-
-def _bucket_blocks(bucket, block, *arrays):
-    """A bucket's query blocks of [B, S, ...] arrays."""
-    start, queries, _ = bucket
-    return tuple(_blocks(a[:, start:start + queries], block) for a in arrays)
 
 
 def _pack_rows(mask):
@@ -415,59 +433,70 @@ def _unpack_rows(packed):
         jnp.int8).reshape(batch, 8 * rows, length)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
-    """``sparse_attention`` with the attention in kernels: the same
-    arguments, values and gradients (tau takes none)."""
-    return _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block)[0]
+def _index_layout(qi, ki, dtype):
+    """The index kernels' operands: qi [B, S, J, D] -> [B, J, S, D], both in
+    ``dtype`` (what ``index_scores`` rounds them to)."""
+    return jnp.moveaxis(qi, 2, 1).astype(dtype), ki.astype(dtype)
 
 
-def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
+def _bucket_thresholds(score, start, topk, block):
+    """``select_thresholds``' counting passes over a bucket's scores
+    [B, rows, keys] as they stand: tau [B, rows] float32. A query block at a
+    time, as there: 512 rows' bit patterns (32 MB at most) stay in VMEM
+    through ``kth_largest``'s 32 passes, a bucket's 2,048 rows' would not
+    (5.5 against 10.6 ms a layer on the chip: PERF.md section 6, PR 40)."""
+    rows = jnp.arange(start, start + score.shape[1]).reshape(-1, block)
+    return _unblocks(lax.map(
+        lambda args: row_thresholds(*args, topk),
+        (_blocks(score, block), rows)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def kernel_attention(q, k, v, qi, ki, w, topk, dtype, block):
+    """``select_thresholds`` and ``sparse_attention`` with the index scores
+    and the attention in kernels: the same values and gradients, the
+    thresholds (the ``topk``-th largest score of each query) taken inside
+    from the scores the mask is made of."""
+    return _kernel_attention(q, k, v, qi, ki, w, topk, dtype, block)[0]
+
+
+def _kernel_attention(q, k, v, qi, ki, w, topk, dtype, block):
     batch, length, heads, dim = q.shape
+    interpret = not on_tpu()
     with jax.named_scope("layer/attn"):
         q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
         with jax.named_scope("part/pointwise"):
             top = _tops(q_l, k_l, block)
+    with jax.named_scope("layer/dsa_index"):
+        qi_l, ki_l = _index_layout(qi, ki, dtype)
     scores, keeps, counts = [], [], []
-    for bucket in buckets(length, block):
-        start, queries, extent = bucket
-        with jax.named_scope("part/layout"):
-            ki_e = ki[:, :extent]
-
-        def one(args, ki_e=ki_e, extent=extent):
-            qi_b, w_b, tau_b, rows = args
-            with jax.named_scope("layer/dsa_index"):
-                score = index_scores(qi_b, ki_e, w_b, dtype)
-            with jax.named_scope("layer/dsa_select"):
-                keep = (rows[:, None] >= jnp.arange(extent)[None, :]) \
-                    & (score >= tau_b[..., None])
-                # The layer's mask is [S, S]: a row's keys past its
-                # bucket's end are not kept.
-                return score, jnp.pad(keep.astype(jnp.int8), (
-                    (0, 0), (0, 0), (0, length - extent))), \
-                    keep.sum(-1, dtype=jnp.int32)
-
-        # The loop outside every part, as in ``select_thresholds``.
-        with jax.named_scope("part/layout"):
-            rows = jnp.arange(start, start + queries).reshape(-1, block)
-            blocks = _bucket_blocks(bucket, block, qi, w, tau) + (rows,)
-        made = lax.map(one, blocks)
-        with jax.named_scope("part/layout"):
-            score, keep, count = (_unblocks(a) for a in made)
-        scores.append(score), keeps.append(keep), counts.append(count)
+    for start, queries, extent in buckets(length, block):
+        with jax.named_scope("layer/dsa_index"):
+            score = index_kernels.scores(
+                qi_l, ki_l, w, span=(start, queries), interpret=interpret)
+        with jax.named_scope("layer/dsa_select"):
+            tau = _bucket_thresholds(score, start, topk, block)
+            keep = (jnp.arange(start, start + queries)[:, None]
+                    >= jnp.arange(extent)[None, :]) & (score >= tau[..., None])
+            # The layer's mask is [S, S]: a row's keys past its bucket's
+            # end are not kept.
+            keeps.append(jnp.pad(keep.astype(jnp.int8), (
+                (0, 0), (0, 0), (0, length - extent))))
+            counts.append(keep.sum(-1, dtype=jnp.int32))
+        scores.append(score)
     with jax.named_scope("layer/dsa_select"):
         mask = jnp.concatenate(keeps, 1)
         packed = checkpoint_name(_pack_rows(mask), KEPT_MASKS)
     with jax.named_scope("layer/attn"), jax.named_scope("part/kernel"):
         out, total = kernels.forward(q_l, k_l, v_l, mask, top, dtype=dtype,
-                                     interpret=not on_tpu())
+                                     interpret=interpret)
         out, total = (checkpoint_name(a, KEPT_ATTENTION)
                       for a in (out, total))
         # The heads' mean probabilities, a bucket's queries over its keys
         # (with the reciprocal of the rows' sums they are handed).
         ps = tuple(checkpoint_name(kernels.probabilities(
             q_l, k_l, mask, top, 1.0 / total, span=(start, queries),
-            dtype=dtype, interpret=not on_tpu()), KEPT_PROBABILITIES)
+            dtype=dtype, interpret=interpret), KEPT_PROBABILITIES)
             for start, queries, _ in buckets(length, block))
     with jax.named_scope("layer/dsa_index"):
         loss = jnp.concatenate([
@@ -480,47 +509,35 @@ def _kernel_attention(q, k, v, qi, ki, w, tau, dtype, block):
         (q_l, k_l, v_l, qi, ki, w, top, out, total, packed, ps)
 
 
-def _kernel_attention_bwd(dtype, block, kept, cotangents):
+def _kernel_attention_bwd(topk, dtype, block, kept, cotangents):
     q_l, k_l, v_l, qi, ki, w, top, out, total, packed, ps = kept
     d_o, d_kl, _ = cotangents
     batch, groups, rep, length, dim = q_l.shape
+    interpret = not on_tpu()
     with jax.named_scope("part/pointwise"):
         inv_total = 1.0 / total
     with jax.named_scope("layer/dsa_select"):
         mask = _unpack_rows(packed)
-    # The indexer: a block's scores once more, and the loss's gradient
-    # through them into qI, kI and w. A block's rows of the mask and of p
-    # are cut where they are read, not copied out block by block before.
-    with jax.named_scope("part/layout"):
+    # The indexer: a bucket's scores once more, the loss's gradient through
+    # them in XLA ([rows, keys] float32 passes), and that cotangent through
+    # the two backward kernels into qI and w, and into kI.
+    with jax.named_scope("layer/dsa_index"):
+        qi_l, ki_l = _index_layout(qi, ki, dtype)
+        w_rows = jnp.swapaxes(w, 1, 2)
         d_qi, d_w, d_ki = [], [], jnp.zeros(ki.shape, F32)
-    for bucket, p in zip(buckets(length, block), ps):
-        start, queries, extent = bucket
-        with jax.named_scope("part/layout"):
-            ki_e = ki[:, :extent]
-
-        def one(d_ki_e, args, ki_e=ki_e, start=start, extent=extent, p=p):
-            qi_b, w_b, d_kl_b, i = args
-            with jax.named_scope("layer/dsa_index"):
-                score, back = jax.vjp(
-                    lambda a, b, c: index_scores(a, b, c, dtype),
-                    qi_b, ki_e, w_b)
-                keep_b = lax.dynamic_slice(
-                    mask, (0, start + i * block, 0), (batch, block, extent))
-                p_b = lax.dynamic_slice_in_dim(p, i * block, block, 1)
-                d_score, = jax.vjp(lambda s: index_loss(
-                    s, keep_b > 0, p_b), score)[1](d_kl_b)
-                d_qi_b, d_ki_b, d_w_b = back(d_score)
-            return d_ki_e + d_ki_b, (d_qi_b, d_w_b)
-
-        # The loop outside every part, as in ``select_thresholds``.
-        with jax.named_scope("part/layout"):
-            zeros = jnp.zeros(ki_e.shape, F32)
-            blocks = _bucket_blocks(bucket, block, qi, w, d_kl) \
-                + (jnp.arange(queries // block),)
-        d_ki_e, (d_qi_b, d_w_b) = lax.scan(one, zeros, blocks)
-        with jax.named_scope("part/layout"):
-            d_ki = d_ki.at[:, :extent].add(d_ki_e)
-            d_qi.append(_unblocks(d_qi_b)), d_w.append(_unblocks(d_w_b))
+        for (start, queries, extent), p in zip(buckets(length, block), ps):
+            span = dict(span=(start, queries), interpret=interpret)
+            rows = slice(start, start + queries)
+            score = index_kernels.scores(qi_l, ki_l, w, **span)
+            d_score, = jax.vjp(lambda s: index_loss(
+                s, mask[:, rows, :extent] > 0, p), score)[1](d_kl[:, rows])
+            d_qi_b, d_w_b = index_kernels.backward_q(
+                qi_l, ki_l, w, d_score, dtype=dtype, **span)
+            d_ki = d_ki.at[:, :extent].add(index_kernels.backward_k(
+                qi_l, ki_l, w_rows, d_score, dtype=dtype, **span))
+            d_qi.append(d_qi_b), d_w.append(d_w_b)
+        d_qi = jnp.moveaxis(jnp.concatenate(d_qi, 2), 1, 2)
+        d_w = jnp.concatenate(d_w, 1)
     with jax.named_scope("layer/attn"):
         with jax.named_scope("part/layout"):
             d_out = d_o.reshape(batch, length, groups, rep, dim).transpose(
@@ -530,7 +547,7 @@ def _kernel_attention_bwd(dtype, block, kept, cotangents):
         with jax.named_scope("part/kernel"):
             d_q = kernels.backward_q(
                 q_l, k_l, v_l, mask, top, inv_total, mean, d_low,
-                dtype=dtype, interpret=not on_tpu())
+                dtype=dtype, interpret=interpret)
         with jax.named_scope("part/layout"):
             mask_t = jnp.swapaxes(mask, 1, 2)
             d_low_kv = d_out.astype(dtype)
@@ -538,14 +555,12 @@ def _kernel_attention_bwd(dtype, block, kept, cotangents):
         with jax.named_scope("part/kernel"):
             d_k, d_v = kernels.backward_kv(
                 q_l, k_l, v_l, mask_t, top, inv_total, mean, d_low_kv,
-                d_scaled, dtype=dtype, interpret=not on_tpu())
+                d_scaled, dtype=dtype, interpret=interpret)
         with jax.named_scope("part/layout"):
             d_q = d_q.transpose(0, 3, 1, 2, 4).reshape(
                 batch, length, groups * rep, dim)
             d_k, d_v = (a.transpose(0, 2, 1, 3) for a in (d_k, d_v))
-    with jax.named_scope("part/layout"):
-        return (d_q, d_k, d_v, jnp.concatenate(d_qi, 1), d_ki,
-                jnp.concatenate(d_w, 1), jnp.zeros((batch, length), F32))
+    return d_q, d_k, d_v, d_qi, d_ki, d_w
 
 
 kernel_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
@@ -557,7 +572,7 @@ kernel_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
 # much shorter. XLA inlines the calls.
 _select_thresholds = jax.jit(select_thresholds, static_argnums=(3, 4, 5))
 _sparse_attention = jax.jit(sparse_attention, static_argnums=(7, 8))
-_kernel_attention_once = jax.jit(kernel_attention, static_argnums=(7, 8))
+_kernel_attention_once = jax.jit(kernel_attention, static_argnums=(6, 7, 8))
 
 
 def layer_norm(x, scale, bias, eps):
@@ -623,16 +638,17 @@ class SparseAttention(nn.Module):
         # blocks' cutting and joining are its part/layout.
         with jax.named_scope("part/layout"):
             q, k, v, qi, ki, w = map(pad, (q, k, v, qi, ki, w))
-        tau = checkpoint_name(
-            _select_thresholds(qi, ki, w, s["topk"], dtype, block),
-            KEPT_SELECTION)
-        if attention_form(length, dim, block) == "kernel":
-            # Keeps its own output (float32, the kernels' layout) by name.
-            out, kl, kept = _kernel_attention_once(q, k, v, qi, ki, w, tau,
-                                                   dtype, block)
+        if attention_form(length, dim, block, d_i) == "kernel":
+            # Takes its thresholds from the scores it makes, and keeps its
+            # own output (float32, the kernels' layout) by name.
+            out, kl, kept = _kernel_attention_once(
+                q, k, v, qi, ki, w, s["topk"], dtype, block)
             with jax.named_scope("part/layout"):
                 out = out.astype(dtype)
         else:
+            tau = checkpoint_name(
+                _select_thresholds(qi, ki, w, s["topk"], dtype, block),
+                KEPT_SELECTION)
             out, kl, kept = _sparse_attention(q, k, v, qi, ki, w, tau, dtype,
                                               block)
             # ``dense`` would round ``out`` to ``dtype`` anyway: kept so.
@@ -695,10 +711,14 @@ class KeyeVL2(nn.Module):
     def forms(self, length):
         """What the step compiles as at sequences of ``length``, for the
         run's manifest and ``train`` records: a run on the chip that fell
-        back to the masked attention says so."""
+        back to the masked attention and XLA's index scores says so (one
+        rule decides both: the index kernels run inside the kernel form)."""
         s = self.sizes
-        return {"dsa_attention_form": attention_form(
-            length, s["head_dim"], min(s["q_chunk_size"], length))}
+        form = attention_form(length, s["head_dim"],
+                              min(s["q_chunk_size"], length),
+                              s["indexer_head_dim"])
+        return {"dsa_attention_form": form,
+                "dsa_index_form": "kernel" if form == "kernel" else "xla"}
 
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):

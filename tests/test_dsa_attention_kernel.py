@@ -15,6 +15,7 @@ import pytest
 
 from gtopkssgd_tpu.models import keye_vl2 as prog
 from gtopkssgd_tpu.ops import dsa_attention as kernels
+from gtopkssgd_tpu.ops import dsa_index as index_kernels
 
 F32 = jnp.float32
 DIM = 128
@@ -173,22 +174,25 @@ def test_kernel_attention_equals_sparse_attention(dtype, monkeypatch):
     512 tokens, blocks of 128 in buckets of two, top 100 keys: rows with
     fewer keys than that, ties at thresholds (the index scores are whole
     multiples of 1/4096), both losses' gradients."""
-    monkeypatch.setattr(kernels, "TILE_Q", 128)
-    monkeypatch.setattr(kernels, "TILE_K", 128)
+    for module in (kernels, index_kernels):
+        monkeypatch.setattr(module, "TILE_Q", 128)
+        monkeypatch.setattr(module, "TILE_K", 128)
     monkeypatch.setattr(prog, "BUCKET", 2)
     length, block, topk = 512, 128, 100
     *inputs, d_o, d_kl = attention_inputs(length)
     tau = prog.select_thresholds(*inputs[3:], topk, dtype, block)
 
-    def both(form):
+    def both(form, selection):
         def loss(*inputs):
-            o, kl, count = form(*inputs, tau, dtype, block)
+            o, kl, count = form(*inputs, selection, dtype, block)
             return jnp.sum(o * d_o) + jnp.sum(kl * d_kl), (o, kl, count)
         return jax.jit(jax.value_and_grad(
             loss, argnums=tuple(range(6)), has_aux=True))(*inputs)
 
-    (_, (o, kl, count)), grads = both(prog.kernel_attention)
-    (_, (o_m, kl_m, count_m)), grads_m = both(prog.sparse_attention)
+    # The kernel form takes its thresholds itself, from the scores its
+    # mask is made of; the masked form is handed ``select_thresholds``'.
+    (_, (o, kl, count)), grads = both(prog.kernel_attention, topk)
+    (_, (o_m, kl_m, count_m)), grads_m = both(prog.sparse_attention, tau)
     assert np.array_equal(np.asarray(count), np.asarray(count_m))
     assert int(count.sum()) > prog.keys_due(length, topk)       # ties
     assert rel(o, o_m) < 1e-6 and rel(kl, kl_m) < 1e-5
@@ -205,7 +209,8 @@ TINY = prog.PRESETS["tiny"]
 def form_of(sizes, length=None):
     length = length or sizes["seq_len"]
     return prog.attention_form(length, sizes["head_dim"],
-                               min(sizes["q_chunk_size"], length))
+                               min(sizes["q_chunk_size"], length),
+                               sizes["indexer_head_dim"])
 
 
 def test_the_form_follows_the_backend_and_the_shapes(monkeypatch):
@@ -222,6 +227,8 @@ def test_the_form_follows_the_backend_and_the_shapes(monkeypatch):
     assert form_of(PUBLISHED, length=300) == "masked"         # one short block
     assert form_of(dict(PUBLISHED, head_dim=64)) == "masked"
     assert form_of(dict(PUBLISHED, q_chunk_size=128)) == "masked"
+    assert form_of(dict(PUBLISHED, indexer_head_dim=32)) == "masked"
+    assert form_of(dict(PUBLISHED, indexer_head_dim=128)) == "kernel"
 
 
 def tiny_step(dtype=jnp.float32):
@@ -277,8 +284,9 @@ def test_a_layer_runs_each_kernel_once_a_step_and_the_model_is_the_same(
     names the replay runs both)."""
     grad, params = tiny_step()
     (loss_m, counts_m), grads_m = jax.jit(grad)(params)
-    monkeypatch.setattr(kernels, "TILE_Q", 8)
-    monkeypatch.setattr(kernels, "TILE_K", 8)
+    for module in (kernels, index_kernels):
+        monkeypatch.setattr(module, "TILE_Q", 8)
+        monkeypatch.setattr(module, "TILE_K", 8)
     monkeypatch.setattr(prog, "attention_form", lambda *a: "kernel")
     jax.clear_caches()          # or the second trace is the first's
     grad, _ = tiny_step()
@@ -294,12 +302,18 @@ def test_a_layer_runs_each_kernel_once_a_step_and_the_model_is_the_same(
         "dsa_attention_forward": layers,
         "dsa_attention_probabilities": layers * spans,
         "dsa_attention_backward_q": layers,
-        "dsa_attention_backward_kv": layers}
+        "dsa_attention_backward_kv": layers,
+        # The indexer's: a bucket's scores once in the forward pass and once
+        # in the backward rule, where the two backward kernels follow them.
+        "dsa_index_scores": 2 * layers * spans,
+        "dsa_index_backward_q": layers * spans,
+        "dsa_index_backward_k": layers * spans}
     monkeypatch.setattr(prog, "checkpoint_name", lambda x, name: x)
     jax.clear_caches()
     bare = kernel_calls(jax.make_jaxpr(grad)(params).jaxpr)
     assert bare["dsa_attention_forward"] == 2 * layers
     assert bare["dsa_attention_probabilities"] == 2 * layers * spans
+    assert bare["dsa_index_scores"] == 3 * layers * spans
 
 
 def test_the_runs_records_name_the_form_that_compiled(tmp_path):
@@ -310,7 +324,8 @@ def test_the_runs_records_name_the_form_that_compiled(tmp_path):
 
     from gtopkssgd_tpu.trainer import TrainConfig, Trainer
 
-    assert prog.KeyeVL2("tiny").forms(48) == {"dsa_attention_form": "masked"}
+    assert prog.KeyeVL2("tiny").forms(48) == {
+        "dsa_attention_form": "masked", "dsa_index_form": "xla"}
     with Trainer(TrainConfig(dnn="keye_vl2", model_preset="tiny",
                              batch_size=2, compression="gtopk", density=0.01,
                              log_interval=1, out_dir=str(tmp_path))) as t:
@@ -318,7 +333,8 @@ def test_the_runs_records_name_the_form_that_compiled(tmp_path):
     rows = [json.loads(line) for line in open(tmp_path / "metrics.jsonl")]
     named = [r for r in rows if r["kind"] in ("manifest", "train")]
     assert [r["kind"] for r in named] == ["manifest", "train", "train"]
-    assert all(r["dsa_attention_form"] == "masked" for r in named)
+    assert all(r["dsa_attention_form"] == "masked"
+               and r["dsa_index_form"] == "xla" for r in named)
     assert not any("dsa_attention_form" in r for r in rows
                    if r["kind"] not in ("manifest", "train"))
     with Trainer(TrainConfig(dnn="qwen3_next", model_preset="tiny",

@@ -122,12 +122,14 @@ def test_twostage_takes_xla_stage1_when_no_group_count_fits(rng):
 # the published shapes, and the published preset's whole step with them.
 from gtopkssgd_tpu.models import keye_vl2  # noqa: E402
 from gtopkssgd_tpu.ops import dsa_attention as dsa  # noqa: E402
+from gtopkssgd_tpu.ops import dsa_index  # noqa: E402
 
 KEYE = keye_vl2.PRESETS["30b_a3b_ep16"]
 DSA_LENGTH, DSA_GROUPS, DSA_DIM = (
     KEYE["seq_len"], KEYE["num_key_value_heads"], KEYE["head_dim"])
 DSA_HEADS = KEYE["num_attention_heads"] // DSA_GROUPS
 DSA_BUCKET = keye_vl2.buckets(DSA_LENGTH, KEYE["q_chunk_size"])[-1]
+INDEX_HEADS, INDEX_DIM = KEYE["indexer_num_heads"], KEYE["indexer_head_dim"]
 
 
 def _dsa_arguments(device):
@@ -164,11 +166,42 @@ def test_dsa_attention_kernel_compiles_at_the_published_shapes(v5e, kernel):
     assert f"dsa_attention_{kernel}" in text
 
 
-def rqk_arrays(text, heads=(DSA_HEADS, DSA_HEADS * DSA_GROUPS)):
+def _index_arguments(device):
+    """Abstract qi, ki, w (a column a head), w (a row a head) and the last
+    bucket's [rows, keys] float32 of one layer, bfloat16."""
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=device)
+    return (shape((1, INDEX_HEADS, DSA_LENGTH, INDEX_DIM), jnp.bfloat16),
+            shape((1, DSA_LENGTH, INDEX_DIM), jnp.bfloat16),
+            shape((1, DSA_LENGTH, INDEX_HEADS), jnp.float32),
+            shape((1, INDEX_HEADS, DSA_LENGTH), jnp.float32),
+            shape((1, DSA_BUCKET[1], DSA_BUCKET[2]), jnp.float32))
+
+
+INDEX_KERNELS = {
+    "scores": lambda qi, ki, w, w_rows, d: dsa_index.scores(
+        qi, ki, w, span=DSA_BUCKET[:2]),
+    "backward_q": lambda qi, ki, w, w_rows, d: dsa_index.backward_q(
+        qi, ki, w, d, span=DSA_BUCKET[:2], dtype=jnp.bfloat16),
+    "backward_k": lambda qi, ki, w, w_rows, d: dsa_index.backward_k(
+        qi, ki, w_rows, d, span=DSA_BUCKET[:2], dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(INDEX_KERNELS))
+def test_dsa_index_kernel_compiles_at_the_published_shapes(v5e, kernel):
+    """16,384 tokens, 16 indexer heads of 64, bfloat16, the tiles the
+    program uses: the last bucket's 2,048 rows against every key."""
+    text = jax.jit(INDEX_KERNELS[kernel]).lower(*_index_arguments(v5e)
+                                                ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"dsa_index_{kernel}" in text
+
+
+def rqk_arrays(text, heads=(DSA_HEADS, DSA_HEADS * DSA_GROUPS, INDEX_HEADS)):
     """The arrays of a compiled module that hold a number for every (query
     head, query of a block, key of a bucket's extent): what the masked form
-    writes to HBM (``[8, 512, keys]`` in float32, ``dtype`` and pred) and
-    the kernels keep in VMEM."""
+    writes to HBM (``[8, 512, keys]`` in float32, ``dtype`` and pred; the
+    indexer's ``[16, 512, keys]`` products) and the kernels keep in VMEM."""
     import collections
     import re
 
@@ -186,12 +219,14 @@ def rqk_arrays(text, heads=(DSA_HEADS, DSA_HEADS * DSA_GROUPS)):
 
 def test_rqk_arrays_finds_the_masked_forms_and_no_other():
     masked = ("%f = bf16[8,512,2048]{2,1,0} fusion(f32[1,8,512,2048]{3,2,1,0} "
-              "%a), %m = pred[1,32,512,16384] compare(...)")
+              "%a), %m = pred[1,32,512,16384] compare(...)"
+              ", f32[1,16,512,2048] %index_dots")
     assert set(rqk_arrays(masked)) == {
         ("bf16", (8, 512, 2048)), ("f32", (8, 512, 2048)),
-        ("pred", (32, 512, 16384))}
-    others = ("f32[1,16,512,2048] %index_dots, s8[1,16384,16384] %keep, "
+        ("pred", (32, 512, 16384)), ("f32", (16, 512, 2048))}
+    others = ("s8[1,16384,16384] %keep, "
               "f32[1,2048,16384] %p, bf16[1,4,8,16384,128] %q, "
+              "f32[1,16,16384,64] %d_qi, bf16[1,16,16384,64] %qi, "
               "f32[4096,2048] %slots, f32[8,512,128] %tile")
     assert not rqk_arrays(others)
 
@@ -216,6 +251,7 @@ def published_step(v5e):
                 compression="gtopk", density=0.001, lr=0.1, momentum=0.9,
                 weight_decay=0.0, clip_grad_norm=1.0, prefetch=0)) as trainer:
             assert trainer._manifest["dsa_attention_form"] == "kernel"
+            assert trainer._manifest["dsa_index_form"] == "kernel"
             batch = trainer._device_batch(
                 trainer._shard_batches(trainer._iters)[0])
             compiled = trainer._train_step.lower(
@@ -258,6 +294,30 @@ def test_published_step_runs_each_attention_kernel_once_a_layer(
     assert len(mine) == (3 + spans) * layers and all(re.search(
         r'op_name="[^"]*layer/attn/[^"]*part/kernel/dsa_attention_\w+/'
         r'pallas_call"', line) for line in mine)
+
+
+def test_published_step_runs_the_index_kernels_once_a_bucket(published_step):
+    """A layer holds, a bucket, one ``scores`` kernel in its forward pass and
+    one in the backward rule (the replay runs none: the mask and p are kept
+    by name), one ``backward_q`` and one ``backward_k``; each under the
+    indexer's kind and under no part of the attention's, so that
+    ``dsa_index_ms`` reads them and ``attn_kernel_ms`` does not."""
+    import re
+
+    calls = [line for line in published_step[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "dsa_index_" in line]
+    spans = len(keye_vl2.buckets(DSA_LENGTH, KEYE["q_chunk_size"])) \
+        * KEYE["num_hidden_layers"]
+    count = lambda name: sum(
+        bool(re.search(rf"dsa_index_{name}\b", line)) for line in calls)
+    assert count("scores") == 2 * spans
+    assert count("backward_q") == count("backward_k") == spans
+    assert len(calls) == 4 * spans
+    for line in calls:
+        path = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.search(r"layer/dsa_index/dsa_index_\w+/pallas_call", path) \
+            and "part/" not in path.split("layer/dsa_index/")[-1], path
 
 
 def test_published_step_holds_no_array_of_heads_queries_keys(published_step):

@@ -17,7 +17,7 @@ import pytest
 
 from gtopkssgd_tpu.models import (
     decoder, kanana2, keye_vl2, qwen3_next, trinity_mini)
-from gtopkssgd_tpu.ops import dsa_attention, flash_attention
+from gtopkssgd_tpu.ops import dsa_attention, dsa_index, flash_attention
 from perfbench.metrics import layer_ms, part_ms, scoped
 
 ATTENTION = ("attn", "attn_window", "attn_full", "attn_latent")
@@ -126,8 +126,9 @@ def kernel_form(monkeypatch):
     sizes fill; jax's caches hold the other form's traces."""
     monkeypatch.setattr(flash_attention, "TILE_Q", 16)
     monkeypatch.setattr(flash_attention, "TILE_K", 16)
-    monkeypatch.setattr(dsa_attention, "TILE_Q", 8)
-    monkeypatch.setattr(dsa_attention, "TILE_K", 8)
+    for module in (dsa_attention, dsa_index):
+        monkeypatch.setattr(module, "TILE_Q", 8)
+        monkeypatch.setattr(module, "TILE_K", 8)
     monkeypatch.setattr(decoder, "attention_form", lambda *a: "kernel")
     monkeypatch.setattr(keye_vl2, "attention_form", lambda *a: "kernel")
     jax.clear_caches()
@@ -186,27 +187,24 @@ def test_the_kernel_form_carries_the_same_parts(name, kernel_form):
             # The layer's replay runs no kernel: its outputs are kept.
             assert part_ms.pass_of(path) != "replay", path
     # In the form the chip runs nothing under the kind is left without a
-    # part but the indexer's three loops' own slicing and counting in Keye
-    # (``lax.map`` / ``lax.scan`` in ``select_thresholds``, the forward and
-    # the backward rule): the loops stand outside every part, because the
-    # compiler files what it fuses into those slices under the loop's name
-    # and that is the indexer's work. ``attn_parts_share``'s gap is that.
+    # part, in Keye neither since its indexer runs as kernels: the one loop
+    # left (``kth_largest``'s query blocks over a bucket's scores) stands
+    # whole under the selection's kind, its slicing included, and the
+    # backward rule has none. ``attn_parts_share``'s gap was the three
+    # loops' own slicing and counting.
     bare = [(op, path) for op, path in mine
             if not part_ms.part_of(path) and op != "constant"]
+    assert not bare, bare
     if name == "keye":
-        assert {op for op, _ in bare} <= {
-            "dynamic_slice", "dynamic_update_slice", "reshape",
-            "broadcast_in_dim", "add", "compare"}, bare
-        # In a loop's body, or the zeros a loop's outputs are written into.
-        assert all("/while/" in path or op == "broadcast_in_dim"
-                   for op, path in bare), bare
-        # And nothing of a loop is under a part: its body's operations have
-        # the indexer's kinds.
-        assert not any(part_ms.part_of(path) for _, path in operations(
-            lowered_layer(name)[0]) if "/while/" in path
-            and layer_ms.kind_of(path) in ("dsa_index", "dsa_select"))
-    else:
-        assert not bare, bare
+        # (A kernel in interpret mode lowers as a loop of its own: not
+        # the program's.)
+        loops = [path for _, path in operations(lowered_layer(name)[0])
+                 if "/while/" in path
+                 and layer_ms.kind_of(path) in ("dsa_index", "dsa_select")
+                 and not re.search(r"/dsa_(index|attention)_\w+/", path)]
+        assert loops and {layer_ms.kind_of(path) for path in loops} == {
+            "dsa_select"}
+        assert not any(part_ms.part_of(path) for path in loops)
 
 
 def test_the_other_kinds_and_the_stage_are_read_as_before():
