@@ -18,12 +18,14 @@ from typing import Any, Callable, Dict, Tuple
 
 import flax.linen as nn
 
-from gtopkssgd_tpu.models import kanana2, keye_vl2, qwen3_next, trinity_mini
+from gtopkssgd_tpu.models import (
+    kanana2, keye_vl2, ouro, qwen3_next, trinity_mini)
 from gtopkssgd_tpu.models.alexnet import AlexNet
 from gtopkssgd_tpu.models.kanana2 import Kanana2
 from gtopkssgd_tpu.models.keye_vl2 import KeyeVL2
 from gtopkssgd_tpu.models.lstm import PTBLSTM
 from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
+from gtopkssgd_tpu.models.ouro import Ouro
 from gtopkssgd_tpu.models.qwen3_next import Qwen3Next
 from gtopkssgd_tpu.models.resnet import ResNetCIFAR, ResNetImageNet
 from gtopkssgd_tpu.models.trinity_mini import TrinityMini
@@ -170,6 +172,18 @@ _register(
         presets=tuple(kanana2.PRESETS),
     )
 )
+_register(
+    ModelSpec(
+        "ouro",
+        Ouro,
+        "tokens",
+        (4096,),  # one sequence of token ids
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
+        presets=tuple(ouro.PRESETS),
+    )
+)
 
 
 def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
@@ -221,4 +235,5 @@ __all__ = [
     "KeyeVL2",
     "TrinityMini",
     "Kanana2",
+    "Ouro",
 ]

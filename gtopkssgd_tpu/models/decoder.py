@@ -1,9 +1,9 @@
 """What the decoders of this zoo share (``qwen3_next``, ``keye_vl2``,
-``trinity_mini``, ``kanana2``): the dropless expert layer of one chip's
-share of an expert group, a dense layer's feed-forward, the rotary
+``trinity_mini``, ``kanana2``, ``ouro``): the dropless expert layer of one
+chip's share of an expert group, a dense layer's feed-forward, the rotary
 embedding, the zero-centred RMSNorm, the causal and sliding-window
-attention (keys as wide as values or wider), and the head's loss a sequence
-at a time.
+attention (keys as wide as values or wider), the head's loss a sequence at
+a time, and the shell round the layers in its pieces.
 Each model file states its own layer equations and imports these; nothing
 here knows a model's sizes beyond the ``sizes`` dict it is handed.
 
@@ -246,6 +246,19 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
         out = jnp.concatenate(outs, 1).reshape(
             batch, length, heads, v.shape[3])
         return out if kept is None else checkpoint_name(out, kept)
+
+
+def normed_mixer(x, mixer, w_in, eps, w_post=None):
+    """A layer's attention half, inside the caller's ``layer/<kind>``:
+    x + ``mixer``(Norm(x; w_in)), the mixer's output through Norm(.; w_post)
+    first where the model has a norm after it (the sandwich). The norms and
+    the residual add are the kind's ``part/pointwise``; the mixer names its
+    own parts."""
+    with jax.named_scope("part/pointwise"):
+        h = rms_norm0(x, w_in, eps)
+    y = mixer(h)
+    with jax.named_scope("part/pointwise"):
+        return x + (y if w_post is None else rms_norm0(y, w_post, eps))
 
 
 # ------------------------------------------------------------ expert layer
@@ -523,36 +536,63 @@ MOE_COUNTS = ("moe_load", "moe_dropped")
 BALANCE_COUNTS = MOE_COUNTS + ("moe_count", "moe_bias")
 
 
+def embedded(module, tokens, embed_scale=None):
+    """The embedding's rows of ``tokens`` (times ``embed_scale``, if given),
+    under ``layer/head``; ``embed`` is ``module``'s parameter."""
+    s = module.sizes
+    with jax.named_scope("layer/head"):
+        table = module.param("embed", _normal(),
+                             (s["vocab_rows"], s["hidden_size"]), F32)
+        x = table[tokens]
+        return x if embed_scale is None else x * embed_scale
+
+
+def run_layers(layers, x):
+    """x through ``layers`` in order, each ``layer(x)`` -> (x, the layer's
+    counts or None): (x, the counts that were given, in order)."""
+    found = []
+    for layer in layers:
+        x, count = layer(x)
+        if count is not None:
+            found.append(count)
+    return x, found
+
+
+def logits_of(hidden, head, dtype):
+    """The head's product over every position: [B, S, vocab_rows] float32."""
+    return jnp.dot(hidden.astype(dtype), head.astype(dtype),
+                   preferred_element_type=F32)
+
+
+def head_weights(module):
+    """``module``'s final norm's weight [d] and its untied head
+    [d, vocab_rows]."""
+    s = module.sizes
+    d = s["hidden_size"]
+    return (module.param("final_norm", nn.initializers.zeros, (d,), F32),
+            module.param("head", _normal(), (d, s["vocab_rows"]), F32))
+
+
 def decoder_shell(module, tokens, targets, layer, depth, counts,
                   embed_scale=None):
     """What the decoders' ``__call__`` share round their layers, run inside
     the model's own ``nn.compact`` method (``embed``, ``final_norm`` and
     ``head`` are ``module``'s parameters; ``module.sizes`` gives
     ``hidden_size``, ``vocab_rows`` and ``rms_norm_eps``): the embedding's
-    rows (times ``embed_scale``, if given), ``layer(i)(x)`` -> (x, the
-    layer's counts or None) for i < ``depth``, then the final norm and the
-    untied head, embedding and head under ``layer/head``. Without targets
-    the logits [B, S, vocab_rows]; with them (the mean cross-entropy,
-    {name: the layers' count of that place, stacked over the layers that
-    gave any} for the names ``counts``)."""
-    s, dtype = module.sizes, module.dtype
-    d, rows = s["hidden_size"], s["vocab_rows"]
+    rows (``embedded``), ``layer(i)(x)`` -> (x, the layer's counts or None)
+    for i < ``depth`` (``run_layers``), then the final norm and the untied
+    head (``head_weights``), embedding and head under ``layer/head``.
+    Without targets the logits [B, S, vocab_rows]; with them (the mean
+    cross-entropy, {name: the layers' count of that place, stacked over the
+    layers that gave any} for the names ``counts``). A model that walks its
+    layers more than once, with a head at every pass (``models/ouro.py``),
+    calls the three pieces itself."""
+    x, found = run_layers([layer(i) for i in range(depth)],
+                          embedded(module, tokens, embed_scale))
     with jax.named_scope("layer/head"):
-        table = module.param("embed", _normal(), (rows, d), F32)
-        x = table[tokens]
-        if embed_scale is not None:
-            x = x * embed_scale
-    found = []
-    for i in range(depth):
-        x, count = layer(i)(x)
-        if count is not None:
-            found.append(count)
-    with jax.named_scope("layer/head"):
-        w_final = module.param("final_norm", nn.initializers.zeros, (d,), F32)
-        head = module.param("head", _normal(), (d, rows), F32)
-        hidden = rms_norm0(x, w_final, s["rms_norm_eps"])
+        w_final, head = head_weights(module)
+        hidden = rms_norm0(x, w_final, module.sizes["rms_norm_eps"])
         if targets is None:
-            return jnp.dot(hidden.astype(dtype), head.astype(dtype),
-                           preferred_element_type=F32)
-        loss = token_losses(hidden, head, targets, dtype).mean()
+            return logits_of(hidden, head, module.dtype)
+        loss = token_losses(hidden, head, targets, module.dtype).mean()
     return loss, {name: jnp.stack(c) for name, c in zip(counts, zip(*found))}

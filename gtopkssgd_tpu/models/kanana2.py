@@ -55,7 +55,8 @@ import jax.numpy as jnp
 
 from gtopkssgd_tpu.models.decoder import (
     BALANCE_COUNTS, F32, DenseMLP, SparseMoE, _normal, attention_form,
-    blocked_causal_attention, decoder_shell, dense, rms_norm0, rotary)
+    blocked_causal_attention, decoder_shell, dense, normed_mixer, rms_norm0,
+    rotary)
 
 # The published sizes (config.json of kanana-2-30b-a3b-instruct-2601) with
 # the three cuts of perfbench/configs/kanana2_30b_a3b_ep16.json, whose
@@ -190,11 +191,8 @@ class Layer(nn.Module):
         # kind and its part/pointwise; scopes inside the mixer and the
         # expert layer are innermost (trainer._build_train_step).
         with jax.named_scope("layer/attn_latent"):
-            with jax.named_scope("part/pointwise"):
-                h = rms_norm0(x, w_in, eps)
-            y = LatentAttention(s, self.dtype, name="mixer")(h)
-            with jax.named_scope("part/pointwise"):
-                x = x + y
+            x = normed_mixer(x, LatentAttention(s, self.dtype, name="mixer"),
+                             w_in, eps)
         if self.dense_mlp:
             with jax.named_scope("layer/dense_mlp"):
                 return x + DenseMLP(s, self.dtype, name="mlp")(

@@ -66,7 +66,8 @@ import jax.numpy as jnp
 
 from gtopkssgd_tpu.models.decoder import (
     BALANCE_COUNTS, F32, DenseMLP, SparseMoE, _normal, attention_form,
-    blocked_causal_attention, decoder_shell, dense, rms_norm0, rotary)
+    blocked_causal_attention, decoder_shell, dense, normed_mixer, rms_norm0,
+    rotary)
 
 # The published sizes (config.json of Trinity-Mini) with the four cuts of
 # perfbench/configs/trinity_mini_26b_a3b_ep16.json, whose ``sizes`` a test
@@ -191,11 +192,9 @@ class Layer(nn.Module):
         # levels of names: trainer._build_train_step).
         with jax.named_scope("layer/attn_window" if self.sliding
                              else "layer/attn_full"):
-            with jax.named_scope("part/pointwise"):
-                h = rms_norm0(x, w_in, eps)
-            y = GatedAttention(s, self.dtype, self.sliding, name="mixer")(h)
-            with jax.named_scope("part/pointwise"):
-                x = x + rms_norm0(y, w_post_attn, eps)
+            x = normed_mixer(
+                x, GatedAttention(s, self.dtype, self.sliding, name="mixer"),
+                w_in, eps, w_post_attn)
         if self.dense_mlp:
             with jax.named_scope("layer/dense_mlp"):
                 y = DenseMLP(s, self.dtype, name="mlp")(

@@ -544,6 +544,18 @@ MOE_BALANCE_FIELDS = (
     "moe_bias_absmax",
 )
 
+# What a decoder that runs its layers several times over tied weights, with
+# a head and a learned exit gate at every pass, counts (models/ouro.py), per
+# optimizer step: each pass's mean cross-entropy, the mean of the tokens'
+# exit distribution p_r (the shares add up to 1), and the mean entropy of
+# that distribution over ln(passes): 1 at an even distribution, 0 when the
+# gate has collapsed onto one pass. A model of fewer than ``LOOP_PASSES``
+# passes fills the fields of the passes it has.
+LOOP_PASSES = 4
+LOOP_FIELDS = tuple(
+    f"loop_{name}_{r}" for name in ("loss", "exit_share")
+    for r in range(1, LOOP_PASSES + 1)) + ("loop_exit_entropy",)
+
 # The model counters last read on the host (``model_scalars``): like the
 # span buffer, it outlives the trainer, so a reader can ask afterwards.
 _last_model: Dict[str, float] = {}
@@ -588,6 +600,18 @@ def moe_balance_counters(count: Array, bias: Array) -> Dict[str, Array]:
     }
 
 
+@jax.named_scope(SCOPE)
+def loop_counters(loss: Array, share: Array, entropy: Array
+                  ) -> Dict[str, Array]:
+    """``LOOP_FIELDS`` as f32 scalars from the looped decoder's counts:
+    ``loss`` and ``share`` [passes], ``entropy`` []."""
+    out = {"loop_exit_entropy": entropy}
+    for r in range(min(loss.shape[0], LOOP_PASSES)):
+        out[f"loop_loss_{r + 1}"] = loss[r]
+        out[f"loop_exit_share_{r + 1}"] = share[r]
+    return out
+
+
 # The registry of model counters: each group's fields, the keys of the
 # model's counts it is computed from and its device function. A model's
 # ``aux`` holds the groups whose counts it returns; ``model_scalars`` reads
@@ -598,6 +622,8 @@ MODEL_COUNTERS = {
             dsa_counters),
     "moe_balance": (MOE_BALANCE_FIELDS, ("moe_count", "moe_bias"),
                     moe_balance_counters),
+    "loop": (LOOP_FIELDS, ("loop_loss", "loop_exit_share",
+                           "loop_exit_entropy"), loop_counters),
 }
 
 

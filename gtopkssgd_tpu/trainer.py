@@ -138,10 +138,12 @@ class TrainConfig:
                                    # '80b_a3b_ep64', 'tiny'; keye_vl2:
                                    # '30b_a3b_ep16', 'tiny'; trinity_mini:
                                    # '26b_a3b_ep16', 'tiny'; kanana2:
-                                   # '30b_a3b_ep16', 'tiny'): which of the
+                                   # '30b_a3b_ep16', 'tiny'; ouro:
+                                   # '2p6b_l5', 'tiny'): which of the
                                    # model's PRESETS to build, the
                                    # published sizes as one chip's share
-                                   # of an expert group or the tests'
+                                   # of an expert group (ouro: the first
+                                   # layers of its stack) or the tests'
                                    # size (ModelSpec.presets; any other
                                    # name, or any name for a model without
                                    # presets, fails at construction);

@@ -15,20 +15,23 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from gtopkssgd_tpu.models import decoder, kanana2, qwen3_next, trinity_mini
+from gtopkssgd_tpu.models import (
+    decoder, kanana2, ouro, qwen3_next, trinity_mini)
 from gtopkssgd_tpu.ops import delta_chunks
 from gtopkssgd_tpu.ops import flash_attention as flash
 
 TRINITY = trinity_mini.PRESETS["26b_a3b_ep16"]
 QWEN = qwen3_next.PRESETS["80b_a3b_ep64"]
 KANANA = kanana2.PRESETS["30b_a3b_ep16"]
+OURO = ouro.PRESETS["2p6b_l5"]
 # (sequences a step in the cell, query heads, key-value heads, key width,
 # value width, tokens, the window)
 LAYERS = {
     "sliding": (1, 32, 4, 128, 128, 16384, TRINITY["sliding_window"]),
     "full": (1, 32, 4, 128, 128, 16384, None),
     "hybrid": (4, 16, 2, 256, 256, 4096, None),
-    "latent": (2, 32, 32, 192, 128, 8192, None)}
+    "latent": (2, 32, 32, 192, 128, 8192, None),
+    "looped": (1, 16, 16, 128, 128, 4096, None)}
 KERNELS = {
     "forward": lambda q, k, v, row, d_out, **kw: flash.forward(q, k, v, **kw),
     "backward_q": lambda q, k, v, row, d_out, **kw: flash.backward_q(
@@ -40,7 +43,8 @@ KERNELS = {
 
 def test_the_layers_shapes_are_the_published_presets():
     for heads, groups, key, value, length, sizes in (
-            LAYERS["full"][1:6] + (TRINITY,), LAYERS["hybrid"][1:6] + (QWEN,)):
+            LAYERS["full"][1:6] + (TRINITY,), LAYERS["hybrid"][1:6] + (QWEN,),
+            LAYERS["looped"][1:6] + (OURO,)):
         assert (heads, groups, key, value, length) == (
             sizes["num_attention_heads"], sizes["num_key_value_heads"],
             sizes["head_dim"], sizes["head_dim"], sizes["seq_len"])
@@ -55,10 +59,11 @@ def test_the_layers_shapes_are_the_published_presets():
 def test_flash_attention_kernel_compiles_at_the_published_shapes(
         v5e, layer, kernel):
     """16,384 tokens x 32 / 4 heads of 128 under the window of 2,048 and
-    under none, 4 x 4,096 tokens x 16 / 2 heads of 256, and 2 x 8,192
+    under none, 4 x 4,096 tokens x 16 / 2 heads of 256, 2 x 8,192
     tokens x 32 / 32 heads with keys of 192 beside values of 128 (1.5 rows
-    of 128 lanes: a block's last axis is its array's): bfloat16, the tiles
-    the program uses, one custom call each."""
+    of 128 lanes: a block's last axis is its array's), and 4,096 tokens x
+    16 / 16 heads of 128 (the looped decoder's): bfloat16, the tiles the
+    program uses, one custom call each."""
     batch, heads, groups, key, value, length, window = LAYERS[layer]
     rows = (batch, groups, heads // groups, length)
     shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
@@ -256,3 +261,65 @@ def test_published_hybrid_step_holds_no_triangular_solve(
     section 6, PR 38): the kernels invert by products."""
     assert "InvertDiagBlocks" not in published_hybrid_step[0]
     assert "triangular_solve" not in published_hybrid_step[0]
+
+
+# ------------------------------------------------------ the looped decoder
+@pytest.fixture(scope="module")
+def published_looped_step(v5e):
+    """The looped decoder's step (the ``ouro_l5.gtopk`` cell's flags), five
+    layers walked four times inside one device loop: one compile (half a
+    minute) serves the tests below."""
+    return compiled_step(v5e, ["attention_form"], dnn="ouro",
+                         model_preset="2p6b_l5", batch_size=1, lr=0.05)
+
+
+def test_published_looped_step_stays_under_its_memory_line(
+        published_looped_step):
+    """11.87 GB of the v5e's 16.9 by XLA's ``memory_analysis()`` (temp +
+    argument + output - alias); the line is 14.5 (ISSUE 41): 20 layer-passes
+    keep their inputs and, by name, the attention's outputs, stacked over
+    the passes by the loop (the passes unrolled read 10.79)."""
+    assert published_looped_step[1] < 12.2e9, published_looped_step[1]
+
+
+def test_published_looped_step_runs_each_kernel_once_a_layer_in_its_loop(
+        published_looped_step):
+    """The passes are one ``lax.scan``: the program holds the five layers
+    once forward (the loop over the passes) and once backward (its
+    transpose), so one forward and the two backward kernels a layer, each
+    run four times a step; the remat's replay runs none (the output and
+    the rows' log-sum-exp are kept by name). Each call is under its layer,
+    ``layer/attn`` and ``part/kernel`` inside the loop's body, backward
+    too, so that the device trace counts it where it runs
+    (``loop_attn_ms``, ``loop_attn_kernel_ms``)."""
+    calls = [line for line in published_looped_step[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    layers = OURO["num_hidden_layers"]
+    for name in KERNELS:
+        mine = [line for line in calls
+                if re.search(rf"flash_attention_{name}\b", line)]
+        assert len(mine) == layers, (name, len(mine))
+        found = collections.Counter(re.search(
+            rf'op_name="[^"]*/while/body/[^"]*(layer_\d)/[^"]*layer/attn/'
+            rf'mixer/part/kernel/flash_attention_{name}/pallas_call"',
+            line).group(1) for line in mine)
+        assert found == {f"layer_{i}": 1 for i in range(layers)}, (
+            name, found)
+    assert sum("flash_attention_" in line for line in calls) == 3 * layers
+
+
+def test_published_looped_step_holds_no_array_of_heads_queries_keys(
+        published_looped_step):
+    """No ``[.., 512, keys]`` score array of the blocked form (``[1, 16, 1,
+    512, keys]``); what the kernels read and write instead, in their own
+    layout; and no copy of the flat vector as rows of a leaf's width (PR
+    31's hazard: N is odd)."""
+    text = published_looped_step[0]
+    assert not score_arrays(text)
+    assert not re.search(r"\b(?:f32|bf16|pred)\[1,16,1,512,\d+\]", text)
+    length = OURO["seq_len"]
+    assert f"bf16[1,16,1,{length},128]" in text       # q
+    assert f"bf16[1,16,{length},128]" in text         # k, v
+    assert f"f32[1,16,1,{length}]" in text            # lse
+    assert not re.search(r"f32\[\d+,(?:2048|5632|49152)\]\{[^}]*\} "
+                         r"(?:reshape|bitcast)\(f32\[458272769\]", text)

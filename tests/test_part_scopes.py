@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from gtopkssgd_tpu.models import (
-    decoder, kanana2, keye_vl2, qwen3_next, trinity_mini)
+    decoder, kanana2, keye_vl2, ouro, qwen3_next, trinity_mini)
 from gtopkssgd_tpu.ops import dsa_attention, dsa_index, flash_attention
 from perfbench.metrics import layer_ms, part_ms, scoped
 
@@ -89,6 +89,12 @@ def kanana():
     return layer, sizes, lambda out: jnp.sum(out[0])
 
 
+def looped():
+    sizes = ouro.PRESETS["tiny"]
+    layer = remat_layer(ouro.Layer, [ouro.KEPT_ATTENTION])(sizes, jnp.float32)
+    return layer, sizes, lambda out: jnp.sum(out[0])
+
+
 def qwen():
     sizes = qwen3_next.PRESETS["tiny"]
     layer = remat_layer(qwen3_next.Layer, [
@@ -109,7 +115,7 @@ def keye():
 LAYERS = {"trinity_sliding": (lambda: trinity(True), "attn_window"),
           "trinity_full": (lambda: trinity(False), "attn_full"),
           "qwen": (qwen, "attn"), "keye": (keye, "attn"),
-          "kanana": (kanana, "attn_latent")}
+          "kanana": (kanana, "attn_latent"), "ouro": (looped, "attn")}
 
 
 def lowered_layer(name):
