@@ -7,12 +7,16 @@ zeroes the selected entries out of the residual, and after the allreduce
 calls `add_residuals(...)` to return locally-selected-but-globally-rejected
 values to the residual (the gTop-k error-feedback repair).
 
-TPU-native redesign: the residual is an explicit flat f32[N] array owned by
-the optimizer state (one pytree — so Orbax checkpoints it, fixing the
+TPU-native redesign: the residual is an explicit array owned by the
+optimizer state (one pytree — so Orbax checkpoints it, fixing the
 reference's silent residual reset on resume), and every operation below is a
 pure function traced once under `jit`. There is no mutation, no dict keyed by
-layer name (the reference flattens all layer grads into one vector per step
-anyway — we do the same with `ravel_pytree`), and no host round-trip.
+layer name, and no host round-trip. On a mesh it is one flat f32[N] vector
+(the reference flattens all layer grads into one vector per step, and the
+wire's index sets need one index space — we do the same with
+`ravel_pytree`); on one device, where nothing is sent, the same selection
+runs on the gradient's own leaves (`LeafPlan`, `compress_leaves_by_threshold`)
+and the residual is held leaf by leaf.
 
 The three-stage protocol used by the distributed optimizer:
 
@@ -25,20 +29,127 @@ The three-stage protocol used by the distributed optimizer:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from gtopkssgd_tpu import modes
 from gtopkssgd_tpu.ops import (
     k_for_density,
     membership_mask,
     select_tau,
+    select_tau_leaves,
     select_topk,
 )
 
 Array = jax.Array
+
+# A leaf the P = 1 step works on where it lies: at least this many elements
+# and a last axis of at least a row of lanes (a [2048, 1] gate is 128 times
+# its size in tiles). Every other leaf rides in the one grouped vector, whose
+# concatenate reads it the moment it is made. Two readings set the size
+# (PERF.md section 6, PR 42). Speed asks for no more: ResNet-50's optimizer
+# step alone, on the chip, takes 2.9-3.5 ms with its 10 leaves of 2**20
+# elements or more in place, 3.4-4.0 with the 29 of 2**18 or more, 3.9-4.1
+# with all 161 grouped (the parent's flat form 3.59). Memory asks for no
+# less: the readers of a leaf in place all sit at the step's end, so the
+# chip's compiler leaves the product that makes a small leaf's gradient
+# until then and holds its inputs, the layer's activations, through the rest
+# of the backward pass. Qwen's published step reads 14.61 GB by
+# `memory_analysis()` with its eighteen [2048, 512] leaves (2**20 elements)
+# in place and 13.94 with them grouped (the parent's 13.62).
+IN_PLACE_MIN_ELEMS = 1 << 21
+IN_PLACE_MIN_LAST = 128
+
+
+class LeafPlan(NamedTuple):
+    """Where each leaf of a gradient tree lives in the P = 1 step's working
+    form, the **slabs**: the leaves worked on in place, each in its own
+    shape, then one vector that holds every other leaf end to end (in the
+    tree's order; absent when there is none). Static, from the shapes."""
+
+    shapes: Tuple[Tuple[int, ...], ...]
+    in_place: Tuple[int, ...]
+    grouped: Tuple[int, ...]
+
+    @property
+    def sizes(self) -> Tuple[int, ...]:
+        return tuple(int(np.prod(s, dtype=np.int64)) for s in self.shapes)
+
+    @property
+    def n(self) -> int:
+        return sum(self.sizes)
+
+    @property
+    def slab_shapes(self) -> Tuple[Tuple[int, ...], ...]:
+        sizes = self.sizes
+        out = [self.shapes[i] for i in self.in_place]
+        if self.grouped:
+            out.append((sum(sizes[i] for i in self.grouped),))
+        return tuple(out)
+
+    def counters(self) -> dict:
+        """What the manifest says of the form the step took."""
+        sizes = self.sizes
+        return {
+            "leaves_in_place": len(self.in_place),
+            "leaves_grouped": len(self.grouped),
+            "elems_in_place_share": (
+                sum(sizes[i] for i in self.in_place) / max(1, self.n)),
+        }
+
+    def split(self, leaves: Sequence, xp=jnp) -> list:
+        """Leaves (the tree's order) -> slabs."""
+        slabs = [leaves[i] for i in self.in_place]
+        if self.grouped:
+            slabs.append(_concatenate(
+                [leaves[i].reshape(-1) for i in self.grouped], xp))
+        return slabs
+
+    def join(self, slabs: Sequence) -> list:
+        """Slabs -> leaves (the tree's order), each in its own shape."""
+        leaves = [None] * len(self.shapes)
+        for slab, i in zip(slabs, self.in_place):
+            leaves[i] = slab
+        sizes, off = self.sizes, 0
+        for i in self.grouped:
+            leaves[i] = slabs[-1][off:off + sizes[i]].reshape(self.shapes[i])
+            off += sizes[i]
+        return leaves
+
+    def from_flat(self, flat, xp=jnp) -> list:
+        """The [N] vector `ravel_pytree` makes of the tree -> slabs."""
+        sizes, leaves, off = self.sizes, [], 0
+        for shape, size in zip(self.shapes, sizes):
+            leaves.append(flat[off:off + size].reshape(shape))
+            off += size
+        return self.split(leaves, xp)
+
+    def to_flat(self, slabs: Sequence, xp=jnp):
+        """Slabs -> the [N] vector in `ravel_pytree`'s order."""
+        return _concatenate(
+            [leaf.reshape(-1) for leaf in self.join(slabs)], xp)
+
+
+def _concatenate(parts: Sequence, xp):
+    """One concatenate, as ``ravel_pytree`` makes (jnp's cuts a long list
+    into groups of 16 and those into one)."""
+    if xp is not jnp:
+        return xp.concatenate(parts)
+    return parts[0] if len(parts) == 1 else jax.lax.concatenate(parts, 0)
+
+
+def plan_leaves(shapes: Sequence[Sequence[int]]) -> LeafPlan:
+    """The rule reads a leaf's size and last axis, nothing else."""
+    shapes = tuple(tuple(int(d) for d in s) for s in shapes)
+    in_place = tuple(
+        i for i, s in enumerate(shapes)
+        if len(s) >= 2 and s[-1] >= IN_PLACE_MIN_LAST
+        and int(np.prod(s, dtype=np.int64)) >= IN_PLACE_MIN_ELEMS)
+    grouped = tuple(i for i in range(len(shapes)) if i not in in_place)
+    return LeafPlan(shapes, in_place, grouped)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +211,11 @@ class TopKCompressor:
         grad: Optional[Array] = None,
         residual: Optional[Array] = None,
     ) -> Tuple[Array, Array, Array]:
-        """Mask-form selection for paths that need no wire format.
+        """Mask-form selection for paths that need no wire format, over
+        ONE vector: a leaf or a bucket of the layerwise mode at P = 1
+        (optimizer.layerwise_update). The flat modes' one-device step no
+        longer builds the [N] vector this once read; its twin over leaves
+        is ``compress_leaves_by_threshold`` below, same rules.
 
         Returns (keep bool[N], residual f32[N], kept_tau f32[]) with
         ``keep = |acc| >= tau`` where tau is the k-th largest magnitude
@@ -154,6 +269,32 @@ class TopKCompressor:
             kept_tau = jnp.where(
                 jnp.isfinite(kept_tau), kept_tau, 0.0).astype(jnp.float32)
             return keep, jnp.where(keep, 0.0, acc), kept_tau
+
+    def compress_leaves_by_threshold(
+        self, accs: Sequence[Array],
+    ) -> Tuple[list, list, Array]:
+        """`compress_by_threshold` over an accumulator that exists only as
+        leaves (any shapes; N elements together): ONE tau, the k(N)-th
+        magnitude over all of them by the configured kernel's rule
+        (ops.select_tau_leaves), and each leaf's masks in the leaf's own
+        shape. Returns (keeps, residuals, kept_tau); ties, the tau = 0 rule
+        and kept_tau are `compress_by_threshold`'s, over the whole."""
+        k = self.k(sum(int(a.size) for a in accs))
+        with jax.named_scope("gtopk/select"):
+            tau = select_tau_leaves(accs, k, self.method)
+        with jax.named_scope("gtopk/mask"):
+            # |acc| >= tau and |acc| > 0, written on acc itself: a
+            # magnitude array with two readers (the search's candidates
+            # and this compare) is one the compiler writes out.
+            keeps = [((a >= tau) | (a <= -tau)) & (a != 0.0) for a in accs]
+            kept_tau = jnp.min(jnp.stack([
+                jnp.min(jnp.where(keep, jnp.abs(a), jnp.inf))
+                for keep, a in zip(keeps, accs)]))
+            kept_tau = jnp.where(
+                jnp.isfinite(kept_tau), kept_tau, 0.0).astype(jnp.float32)
+            return (keeps,
+                    [jnp.where(keep, 0.0, a) for keep, a in zip(keeps, accs)],
+                    kept_tau)
 
     @jax.named_scope("gtopk/repair")
     def repair(

@@ -293,45 +293,16 @@ def leaf_l2(arrs: Sequence[Array]) -> Array:
 
 
 @jax.named_scope(SCOPE)
-def selection_layer_stats(
-    acc: Array, sel_dense: Array, seg: np.ndarray, L: int
-) -> Tuple[Dict[str, Array], Array]:
-    """Per-layer selection stats for the flat [N] layout.
-
-    ``sel_dense`` is the locally-selected set densified (selected values
-    in place, 0 elsewhere — the threshold path's ``acc - residual``, or a
-    scatter of (vals, idx) for the index form). Returns
-    ({sent, tau, m_k} as f32[L], whole-model m_k). A value-0 selection
-    slot counts as not sent, matching sent_count's convention."""
-    mask = sel_dense != 0
-    sent = jax.ops.segment_sum(
-        mask.astype(jnp.float32), seg, num_segments=L,
-        indices_are_sorted=True)
-    mags = jnp.abs(sel_dense)
-    tau = jax.ops.segment_min(
-        jnp.where(mask, mags, jnp.inf), seg, num_segments=L,
-        indices_are_sorted=True)
-    tau = jnp.where(jnp.isfinite(tau), tau, 0.0).astype(jnp.float32)
-    acc32 = acc.astype(jnp.float32)
-    sel32 = sel_dense.astype(jnp.float32)
-    acc_sq = jax.ops.segment_sum(
-        acc32 * acc32, seg, num_segments=L, indices_are_sorted=True)
-    sel_sq = jax.ops.segment_sum(
-        sel32 * sel32, seg, num_segments=L, indices_are_sorted=True)
-    m_k = sel_sq / jnp.maximum(acc_sq, _MASS_EPS)
-    whole = jnp.sum(sel_sq) / jnp.maximum(jnp.sum(acc_sq), _MASS_EPS)
-    return {"sent": sent, "tau": tau, "m_k": m_k}, whole
-
-
-@jax.named_scope(SCOPE)
 def sparse_selection_layer_stats(
     acc: Array, vals: Array, idx: Array, seg: np.ndarray, L: int
 ) -> Tuple[Dict[str, Array], Array]:
-    """selection_layer_stats for the (vals, idx) wire form, without ever
-    densifying the selection: the selected coordinates' layer ids are a
-    gather ``seg[idx]``, and every per-layer stat is a k-sized segment
-    reduction (k << N), plus one [N] reduction for the per-layer acc
-    mass. A value-0 slot counts as not sent (padding convention)."""
+    """Per-layer selection stats for the flat [N] layout and the (vals,
+    idx) wire form, without ever densifying the selection: the selected
+    coordinates' layer ids are a gather ``seg[idx]``, and every per-layer
+    stat is a k-sized segment reduction (k << N), plus one [N] reduction
+    for the per-layer acc mass. Returns ({sent, tau, m_k} as f32[L],
+    whole-model m_k). A value-0 slot counts as not sent (padding
+    convention, as sent_count has it)."""
     mask = vals != 0
     seg_sel = jnp.take(jnp.asarray(seg), idx, mode="clip")
     sent = jax.ops.segment_sum(
@@ -353,9 +324,12 @@ def sparse_selection_layer_stats(
 def leafwise_selection_stats(
     accs: Sequence[Array], sel_denses: Sequence[Array]
 ) -> Tuple[Dict[str, Array], Array]:
-    """Per-leaf counterpart of selection_layer_stats for the layerwise
-    mode, where the flat [N] vector never exists: one small reduction per
-    leaf, stacked to [L]."""
+    """Per-leaf selection stats where the flat [N] vector never exists
+    (the layerwise mode; the flat modes' one-device step, whose leaves
+    keep their shapes): ``sel_denses`` are the leaves' selections
+    densified (selected values in place, 0 elsewhere). One small
+    reduction per leaf, stacked to [L]. Returns ({sent, tau, m_k} as
+    f32[L], whole-model m_k)."""
     sents, taus, sel_sqs, acc_sqs = [], [], [], []
     for a, s in zip(accs, sel_denses):
         mask = s != 0

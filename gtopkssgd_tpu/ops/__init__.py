@@ -16,6 +16,9 @@ from gtopkssgd_tpu.ops.topk import (
     bucketize_counts,
     select_topk,
     select_tau,
+    select_tau_leaves,
+    approx_bin_size,
+    bin_maxima,
     k_for_density,
     merge_sparse_sets,
     scatter_add_dense,
@@ -34,6 +37,9 @@ __all__ = [
     "bucketize_counts",
     "select_topk",
     "select_tau",
+    "select_tau_leaves",
+    "approx_bin_size",
+    "bin_maxima",
     "k_for_density",
     "merge_sparse_sets",
     "scatter_add_dense",

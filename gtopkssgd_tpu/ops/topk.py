@@ -475,6 +475,124 @@ def select_tau(
     raise ValueError(f"unknown topk method {method!r}")
 
 
+APPROX_RECALL = 0.95
+
+
+def approx_bin_size(n: int, k: int, recall_target: float = APPROX_RECALL
+                    ) -> int:
+    """2^r, the bin whose maxima XLA's `approx_max_k` keeps of an [n]
+    operand on the TPU before it sorts them (its recall formula,
+    ApproxTopKReductionOutputSize at rank 1: m = max((1 - k) / ln(recall),
+    1024) outputs are needed, r = floor(log2(n / m))): 32 at rho = 0.001.
+    1 where n is too short to reduce."""
+    m = max(int((1.0 - k) / math.log(recall_target)), 1024)
+    return 1 << max(0, (n // min(m, n)).bit_length() - 1)
+
+
+LANES = 128
+
+
+def bin_maxima(mag: Array, group: int) -> Array:
+    """Flat maxima over bins of `group` elements of a leaf's magnitudes in
+    the leaf's own shape. [..., C] is read as [rows, C] and cut into `group`
+    slabs of rows/group consecutive rows; a bin holds one element of each
+    slab, slab s's taken s whole lane tiles further along the row: the
+    elements (s * rows/group + i, (j + 128 s) mod C). So a bin's members lie
+    in `group` rows far apart and in `group` columns apart, as
+    `approx_max_k`'s windows over a flat vector do, and a gradient's hot
+    rows and hot columns (it is a sum of outer products) fall into bins of
+    their own: bins of consecutive rows of one column sent 2.5-3.8 k on
+    the chip where these, and the flat windows, send about 2 k (PERF.md
+    section 6, PR 42). The rows past the last whole slab are one more bin
+    a column. On the TPU a slab is whole tiles and a shift of whole lane
+    tiles moves no lane, so the maximum runs where the leaf lies. A vector
+    is read as rows of 128."""
+    if mag.ndim < 2:
+        mag = jnp.pad(mag.reshape(-1), (0, -mag.size % LANES))
+        mag = mag.reshape(-1, LANES)
+    cols = mag.shape[-1]
+    mag = mag.reshape(-1, cols)
+    rows = mag.shape[0]
+    per = rows // group
+    parts = []
+    if per:
+        best = mag[:per]
+        for s in range(1, group):
+            best = jnp.maximum(best, jnp.roll(
+                mag[s * per:(s + 1) * per], -(LANES * s) % cols, axis=-1))
+        parts.append(best.reshape(-1))
+    if rows > per * group:
+        parts.append(jnp.max(mag[per * group:], axis=0))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+def _kth_largest(parts, k: int) -> Array:
+    """The k-th largest of the concatenated candidate vectors."""
+    cand = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return lax.top_k(cand, k)[0][k - 1]
+
+
+def _exact_tau_leaves(mags, k: int) -> Array:
+    """The exact k-th largest magnitude over all leaves with no operand of
+    their summed size: every member of the global top k is among its own
+    leaf's top min(k, n_l)."""
+    return _kth_largest(
+        [lax.top_k(m.reshape(-1), min(k, m.size))[0] for m in mags], k)
+
+
+def _binned_tau_leaves(mags, k: int, group: int) -> Array:
+    """The k-th largest of the leaves' bin maxima: `approx_max_k`'s rule
+    (maxima of bins of `group`, then the exact k-th of those by a sort of
+    the values alone), with the bins laid along each leaf's rows and not
+    along one [N] vector."""
+    parts = [bin_maxima(m, group) for m in mags]
+    total = sum(p.size for p in parts)
+    if total < k:
+        return _exact_tau_leaves(mags, k)
+    cand = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    # Magnitudes are no less than +0, where a float32's order is its bit
+    # pattern's as an integer: the sort's comparator is one integer
+    # compare, not the total order over floats that lax.sort builds (the
+    # chip sorts 14.3M candidates in 10.9 ms so and in 20.6 ms otherwise:
+    # PERF.md section 6, PR 42).
+    bits = lax.sort(lax.bitcast_convert_type(cand, jnp.int32),
+                    is_stable=False)
+    return lax.bitcast_convert_type(bits[total - k], jnp.float32)
+
+
+def select_tau_leaves(accs, k: int, method: str = "auto") -> Array:
+    """`select_tau` for an accumulator that exists only as leaves (a list
+    of arrays of any shapes, N elements together): the same threshold rule
+    with no [N] operand for `exact` and `approx` (so for `auto`, which
+    reads N as `select_tau` does).
+
+    `exact` is the exact k-th magnitude over all leaves. `approx` keeps
+    `approx_max_k`'s recall rule on the TPU, bins of `approx_bin_size(N,
+    k)` whose maxima are the candidates and the k-th largest candidate tau,
+    and is the exact search on every other backend, where XLA lowers
+    `approx_max_k` itself to a sort: there tau is `select_tau`'s to the
+    bit. The branch is taken when the step is lowered
+    (`lax.platform_dependent`), so a step compiled for a described chip
+    holds the chip's form. Every other method concatenates the leaves and
+    is `select_tau`'s own."""
+    n = sum(int(a.size) for a in accs)
+    if method == "auto":
+        method = _resolve_auto(n)
+    mags = [jnp.abs(a) for a in accs]
+    if k >= n:
+        return functools.reduce(jnp.minimum, [jnp.min(m) for m in mags])
+    if method == "exact":
+        return _exact_tau_leaves(mags, k)
+    if method == "approx":
+        group = approx_bin_size(n, k)
+        return lax.platform_dependent(
+            *mags,
+            tpu=lambda *ms: _binned_tau_leaves(ms, k, group),
+            default=lambda *ms: _exact_tau_leaves(ms, k))
+    return select_tau(
+        jnp.concatenate([a.reshape(-1) for a in accs]), k, method)
+
+
 _METHODS = {
     "exact": lambda x, k: topk_abs(x, k),
     "blockwise": lambda x, k: blockwise_topk_abs(x, k),

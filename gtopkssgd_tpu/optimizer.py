@@ -17,8 +17,8 @@ jitted SPMD train step contains compute, compression, and the collective;
 XLA overlaps them and Orbax checkpoints the residual for free (the reference
 silently dropped residuals on resume — a sharp edge fixed here).
 
-Pipeline inside ``update`` (names match the reference call stack, SURVEY.md
-§3.1):
+Pipeline inside ``update`` on a mesh (P > 1; names match the reference call
+stack, SURVEY.md §3.1), where the wire's (vals, idx) sets index one vector:
 
     flat            = ravel_pytree(grads)                 # "flatten/merge"
     flat            = clip_by_global_norm(flat)           # LSTM path: clip
@@ -30,6 +30,23 @@ Pipeline inside ``update`` (names match the reference call stack, SURVEY.md
     res'            = repair(res, vals, idx, gidx)        # add_residuals
     dense update    = scatter(global set) / P             # average
     updates         = SGD(momentum, wd) on dense update   # inner optimizer
+
+The dense modes flatten too (one psum of one vector). On ONE device
+(``leaf_update``, every flat sparse mode at P = 1) nothing is sent, so no
+[N] vector is made: the same mathematics, one k = rho * N and one
+threshold tau over the whole gradient, runs on the gradient's own leaves,
+
+    acc_l   = clip(g_l) + r_l                 each large leaf in its own
+    tau     = k-th largest |acc| over all l   shape and layout, the small
+    keep_l  = |acc_l| >= tau, zeros never     ones together in one short
+    r_l'    = where(keep_l, 0, acc_l)         vector (compression.LeafPlan)
+    update  = SGD on where(keep_l, acc_l, 0)
+
+and the state holds r (and v, u, the age buffer) in that form. What still
+sees [N]: the mesh and dense paths above, the recall audit's taken branch,
+the threshold search of the methods other than exact / approx / auto
+(ops.select_tau_leaves), and a state made with a mesh axis named but run
+unbound, which ``leaf_update`` cuts into leaves and joins again.
 """
 
 from __future__ import annotations
@@ -42,7 +59,7 @@ import optax
 from jax import lax
 from jax.flatten_util import ravel_pytree
 
-from gtopkssgd_tpu.compression import get_compressor
+from gtopkssgd_tpu.compression import get_compressor, plan_leaves
 from gtopkssgd_tpu.obs import counters as obs_counters
 from gtopkssgd_tpu.modes import (
     ALL_MODES,
@@ -70,14 +87,18 @@ class GTopKSGDState(NamedTuple):
     """State pytree of the distributed optimizer. ``residual`` holds the
     per-device local compression state — checkpointing this state therefore
     preserves error feedback across resume. Its shape depends on the mode:
-    a flat f32[N] error-feedback buffer (empty for the dense path); a tuple
-    of per-leaf buffers for ``gtopk_layerwise``; and with
-    ``momentum_correction`` a dict ``{"v": <buffer>, "u": <velocity>}``
-    where v is the accumulated-velocity residual DGC selects from and u is
-    the local momentum buffer (same flat/per-leaf shape as v). Every
-    consumer (trainer shard_map strip/restore, per-device expansion, the
-    checkpoint template) tree-maps over the field, so all three layouts
-    ride the same plumbing.
+    a flat f32[N] error-feedback buffer (empty for the dense path); the
+    tuple of slabs of compression.LeafPlan (the large leaves in their own
+    shapes, then one vector of the small ones) for a flat sparse mode
+    built with no mesh axis named, the one-device step's own form
+    (``leaf_form_state``; ``flat_residual`` / ``slab_residual`` turn one
+    into the other); a tuple of per-leaf flat buffers for
+    ``gtopk_layerwise``; and with ``momentum_correction`` a dict
+    ``{"v": <buffer>, "u": <velocity>}`` where v is the
+    accumulated-velocity residual DGC selects from and u is the local
+    momentum buffer (same form as v). Every consumer (trainer shard_map
+    strip/restore, per-device expansion, the checkpoint template)
+    tree-maps over the field, so all layouts ride the same plumbing.
 
     ``telemetry`` (obs subsystem, default off -> an empty pytree) carries
     the on-device training-health counters of the step that PRODUCED this
@@ -87,8 +108,8 @@ class GTopKSGDState(NamedTuple):
     them without touching per-device state. With ``telemetry_layers``
     it additionally holds ``"layers"`` (obs.counters.LAYER_FIELDS as
     f32[L] arrays, leaf order = jax.tree flatten order of the grads)
-    and ``"age"`` (per-coordinate steps-since-last-shipped, residual
-    layout, replicated by construction); with
+    and ``"age"`` (per-coordinate steps-since-last-shipped, in the
+    residual's form, replicated by construction); with
     ``telemetry_audit_interval`` an ``"audit_recall"`` scalar (-1 =
     never audited)."""
 
@@ -356,6 +377,13 @@ def gtopk_sgd(
             "cold val_top1 0.250 vs 0.734/0.281; masking ablations rule "
             "out a semantics fix) — prefer one or the other",
             stacklevel=2)
+    # The residual's form. With no mesh axis named, a flat mode's step runs
+    # on one device (leaf_update) and the state itself is in that step's
+    # form, the slabs of compression.LeafPlan: known when the state is
+    # made. With an axis named and unbound, or bound at size 1, the state
+    # is the flat [N] that a P > 1 step of the same transformation reads,
+    # and leaf_update cuts it into slabs and joins it again.
+    slab_state = leaf_form_state(mode, axis_name)
     compressor = get_compressor(mode, density=density, method=topk_method)
     # Validate the codec spec at build time (bad --wire-codec fails here,
     # not inside the jitted step); the instance is reused every step.
@@ -428,11 +456,17 @@ def gtopk_sgd(
             )
         return p
 
+    def _zero_slabs(params):
+        return tuple(jnp.zeros(shape, jnp.float32)
+                     for shape in leaf_plan(params).slab_shapes)
+
     def _init_telemetry(params):
         tel = obs_counters.zero_telemetry()
         if telemetry_layers:
             tel.update(obs_counters.zero_layer_telemetry(
                 obs_counters.layer_sizes(params), per_leaf_age=layerwise))
+            if slab_state:
+                tel["age"] = _zero_slabs(params)
         if audit:
             tel["audit_recall"] = jnp.float32(-1.0)
         return tel
@@ -443,6 +477,8 @@ def gtopk_sgd(
                 jnp.zeros((int(leaf.size),), jnp.float32)
                 for leaf in jax.tree.leaves(params)
             )
+        elif slab_state:
+            residual = _zero_slabs(params)
         else:
             flat, _ = ravel_pytree(params)
             residual = compressor.init_residual(flat.shape[0])
@@ -984,10 +1020,169 @@ def gtopk_sgd(
         )
         return updates, new_state
 
+    def leaf_update(grads, state: GTopKSGDState, params=None):
+        """One device's step of the flat modes: one global k and one tau
+        as in the [N] form, on the gradient's own leaves.
+
+        No wire at P = 1, so nothing needs the (vals, idx) format, nor the
+        [N] vector it indexes: the elementwise work (accumulate, masks,
+        velocity, counters) runs on the **slabs** of compression.LeafPlan
+        (a large leaf in its own shape and layout, the small ones in one
+        short vector) and the threshold is one number over all of them
+        (TopKCompressor.compress_leaves_by_threshold). Masking u at the
+        keep mask is exact here: every local pick is delivered."""
+        leaves, treedef = jax.tree.flatten(grads)
+        lplan = leaf_plan(grads)
+        n = lplan.n
+        with jax.named_scope("gtopk/flatten"):
+            flats = lplan.split(leaves)
+            state_res = state.residual
+            if not slab_state:
+                state_res = jax.tree.map(lplan.from_flat, state_res)
+        if clip_grad_norm is not None:
+            # Clip BEFORE compress, as the [N] form does; the global norm
+            # is a sum of per-slab sums (stacked and added at once, so
+            # that no slab's sum waits for the slab before it).
+            with jax.named_scope("gtopk/clip"):
+                gnorm = jnp.sqrt(jnp.sum(jnp.stack(
+                    [jnp.sum(f * f) for f in flats])))
+                scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
+                flats = [f * scale for f in flats]
+        if correction:
+            res_in = tuple(state_res["v"])
+            us = tuple(momentum * u + f
+                       for u, f in zip(state_res["u"], flats))
+            srcs = us
+        else:
+            res_in = tuple(state_res)
+            us = ()
+            srcs = tuple(flats)
+
+        def sparse_branch(srcs, res_in, us):
+            accs = [compressor.accumulate(s, r)
+                    for s, r in zip(srcs, res_in)]
+            keeps, new_res, tau_th = (
+                compressor.compress_leaves_by_threshold(accs))
+            with jax.named_scope("gtopk/mask"):
+                dense_sl = [jnp.where(m, a, 0.0)
+                            for m, a in zip(keeps, accs)]
+                u_out = tuple(jnp.where(m, 0.0, u)
+                              for u, m in zip(us, keeps))
+            tel = ()
+            if telemetry:
+                tel = {
+                    "tau": tau_th,
+                    "sent": sum(obs_counters.kept_count(m) for m in keeps),
+                    "m_k": obs_counters.mass_ratio(accs, dense_sl),
+                }
+                if telemetry_layers:
+                    tel["lsel"], _ = obs_counters.leafwise_selection_stats(
+                        lplan.join(accs), lplan.join(dense_sl))
+                if audit:
+                    # The exact top-k and the [N] operands it indexes
+                    # exist only inside the cond's taken branch.
+                    def _do():
+                        ev, ei = topk_abs(
+                            lplan.to_flat(accs), compressor.k(n))
+                        hits = jnp.take(lplan.to_flat(keeps), ei,
+                                        mode="clip")
+                        return obs_counters.topk_recall(hits, ev)
+
+                    tel["recall"] = lax.cond(
+                        (state.count % telemetry_audit_interval) == 0,
+                        _do, lambda: jnp.float32(-1.0))
+                tel = (tel,)
+            return (tuple(dense_sl), tuple(new_res), u_out) + tel
+
+        if warmup_dense_steps > 0:
+            def dense_branch(srcs, res_in, us):
+                # Nothing to reduce at P = 1; the residual passes through
+                # and, with correction, u is NOT masked (nothing was
+                # transmitted sparsely). Dense-phase telemetry: no
+                # threshold, everything sent, full mass capture, nothing
+                # to audit.
+                tel = ()
+                if telemetry:
+                    teld = {"tau": jnp.float32(0.0),
+                            "sent": jnp.float32(n),
+                            "m_k": jnp.float32(1.0)}
+                    if telemetry_layers:
+                        teld["lsel"], _ = (
+                            obs_counters.dense_phase_selection_stats(
+                                lplan.sizes))
+                    if audit:
+                        teld["recall"] = jnp.float32(-1.0)
+                    tel = (teld,)
+                return (srcs, res_in, us) + tel
+
+            out = lax.cond(
+                state.count < warmup_dense_steps,
+                dense_branch, sparse_branch, srcs, res_in, us,
+            )
+        else:
+            out = sparse_branch(srcs, res_in, us)
+        if telemetry:
+            dense_sl, res_struct, u_new, btel = out
+        else:
+            dense_sl, res_struct, u_new = out
+        residual = ({"v": res_struct, "u": u_new} if correction
+                    else res_struct)
+        with jax.named_scope("gtopk/unflatten"):
+            avg_grads = treedef.unflatten(lplan.join(dense_sl))
+            if not slab_state:
+                residual = jax.tree.map(lplan.to_flat, residual,
+                                        is_leaf=lambda x: isinstance(x, tuple))
+        with jax.named_scope("gtopk/apply"):
+            updates, inner_state = inner.update(
+                avg_grads, state.inner, params)
+        if telemetry:
+            tel = obs_counters.make_telemetry(
+                n=n, k=compressor.k(n), p=1, mode=mode,
+                ici_size=hier_ici_size if hier else 1, codec=codec,
+                grad_norm_pre=obs_counters.tree_l2(flats),
+                grad_norm_post=obs_counters.tree_l2(dense_sl),
+                residual_norm=obs_counters.tree_l2(res_struct),
+                tau=btel["tau"], sent_elems=btel["sent"],
+                m_k=btel["m_k"],
+            )
+            if telemetry_layers:
+                # Delivered = appeared in the applied update. The age
+                # buffer keeps the residual's form.
+                age = state.telemetry["age"]
+                if not slab_state:
+                    age = tuple(lplan.from_flat(age))
+                age = obs_counters.update_age(
+                    age, tuple(d != 0 for d in dense_sl))
+                tel["layers"] = obs_counters.assemble_layer_telemetry(
+                    sel_stats=btel["lsel"], sizes=lplan.sizes,
+                    grad_norm_pre_l=obs_counters.leaf_l2(lplan.join(flats)),
+                    grad_norm_post_l=obs_counters.leaf_l2(
+                        lplan.join(dense_sl)),
+                    residual_norm_l=obs_counters.leaf_l2(
+                        lplan.join(res_struct)),
+                    age=tuple(lplan.join(age)))
+                tel["age"] = age if slab_state else lplan.to_flat(age)
+            if audit:
+                # Carry the last audited value between audits; -1 means
+                # never audited (dense warm-up included).
+                tel["audit_recall"] = jnp.where(
+                    btel["recall"] >= 0.0, btel["recall"],
+                    state.telemetry["audit_recall"])
+        else:
+            tel = state.telemetry
+        new_state = GTopKSGDState(
+            count=state.count + 1, residual=residual, inner=inner_state,
+            telemetry=tel,
+        )
+        return updates, new_state
+
     def update_fn(grads, state: GTopKSGDState, params=None):
         if layerwise:
             return layerwise_update(grads, state, params)
-        # The flat [N] form's own passes are stages like the others
+        if not dense_mode and bound_axis_size() == 1:
+            return leaf_update(grads, state, params)
+        # The flat [N] form (the dense modes, and the index form a wire
+        # needs at P > 1). Its own passes are stages like the others
         # (trainer._build_train_step lists them): flatten, clip, unflatten.
         with jax.named_scope("gtopk/flatten"):
             flat, unravel = ravel_pytree(grads)
@@ -1071,121 +1266,81 @@ def gtopk_sgd(
                         _do, lambda: jnp.float32(-1.0))
 
                 tel = ()
-                if p == 1:
-                    # No collective at p=1, so nothing ever needs the
-                    # (vals, idx) wire format — select by THRESHOLD
-                    # (compress_by_threshold): one top-k reduction for
-                    # tau, then pure elementwise where-masks for the
-                    # residual, the update, and the velocity. The
-                    # index-set form dragged a scatter (zero the
-                    # residual out) + gather (read the values) through
-                    # the flat [N] vector, and that chain is what kept
-                    # XLA from fusing selection into the backward
-                    # epilogue (fused-step overhead was ~3x the isolated
-                    # compress cost — fused_variants artifact).
-                    # Masking u at the same keep-mask is exact here:
-                    # every local pick is delivered at p=1. The tau
-                    # search reads (src, residual_in) unfused so the
-                    # twostage/pallas kernels fold the error-feedback
-                    # accumulate into their own selection pass — acc
-                    # only feeds the elementwise masks, which XLA fuses.
-                    keep, residual, tau_th = (
-                        compressor.compress_by_threshold(
-                            acc, grad=src, residual=residual_in))
-                    dense = acc - residual
-                    u_out = (jnp.where(keep, 0.0, u_in)
-                             if correction else u_in)
-                    if telemetry:
-                        tel = {
-                            "tau": tau_th,
-                            "sent": obs_counters.kept_count(keep),
-                            "m_k": obs_counters.mass_ratio(acc, dense),
-                        }
-                        if telemetry_layers:
-                            tel["lsel"], _ = (
-                                obs_counters.selection_layer_stats(
-                                    acc, dense, l_seg, n_layers))
-                        if audit:
-                            tel["recall"] = _audit_recall(
-                                lambda ei: jnp.take(
-                                    keep, ei, mode="clip"))
-                        tel = (tel,)
-                else:
-                    vals, idx, residual = compressor.compress(
-                        acc, grad=src, residual=residual_in)
-                    if codec.lossy and mode != "topk":
-                        # Fold the wire quantization error into the
-                        # error-feedback residual and ship the
-                        # requantized values: the residual repair below
-                        # then restores vq + folded error = the exact
-                        # original for rejected picks, and telemetry
-                        # (tau/sent/mass) describes what actually went on
-                        # the wire. (mode 'topk' allgathers the exact
-                        # local picks — its codec path quantizes in
-                        # topk_allgather and every pick is delivered, so
-                        # there is nothing to repair and the small
-                        # symmetric error is left to the next step's
-                        # selection, like any dense rounding.)
-                        vq = roundtrip_aligned(codec, vals, idx, n=n)
-                        residual = compressor.fold_wire_error(
-                            residual, idx, vals - vq)
-                        vals = vq
-                    if telemetry:
-                        # Selection stats describe the LOCAL selection
-                        # (what this device put on the wire); the pmean
-                        # in _finish_telemetry turns them into axis
-                        # means.
-                        tel = {
-                            "tau": obs_counters.selected_tau(vals),
-                            "sent": obs_counters.sent_count(vals),
-                            "m_k": obs_counters.mass_ratio(acc, vals),
-                        }
-                        if telemetry_layers:
-                            tel["lsel"], _ = (
-                                obs_counters.sparse_selection_layer_stats(
-                                    acc, vals, idx, l_seg, n_layers))
-                        if audit:
-                            tel["recall"] = _audit_recall(
-                                lambda ei: membership_mask(ei, idx))
-                        tel = (tel,)
-                    # Momentum factor masking: a DELIVERED coordinate's
-                    # velocity restarts (its momentum was consumed);
-                    # without this the same mass re-sends for ~1/momentum
-                    # more steps. For the allgather union every local
-                    # pick is delivered, so masking at the local
-                    # selection is exact.
-                    u_out = (u_in.at[idx].set(0.0, mode="drop")
-                             if correction else u_in)
-                    result, gidx, needs_repair = sparse_allreduce(
-                        mode, vals, idx, k=compressor.k(n), n=n,
-                        axis_name=axis_name, axis_size=p,
-                        ici_size=hier_ici_size if hier else 1,
-                        codec=codec, plan=plan,
-                    )
-                    if needs_repair:  # gtopk: sparse set + repair
-                        residual = compressor.repair(
-                            residual, vals, idx, gidx)
-                        dense = scatter_add_dense(n, gidx, result) / p
-                        # NOTE (measured design decision): under gTop-k a
-                        # local pick can be globally REJECTED; one could
-                        # argue its velocity should survive (nothing was
-                        # transmitted). Measured ablation says NO: the
-                        # repair above already preserves the rejected
-                        # VALUE in v, so also keeping u double-tracks the
-                        # same mass (v += u while u compounds) and
-                        # persistently-rejected coordinates blow up —
-                        # see restore_rejected_u_ablation in the
-                        # warmup_ab_cpu_mesh8.json artifact. The local
-                        # mask above is the stable generalization; the
-                        # branch below exists ONLY to reproduce that
-                        # ablation arm (_restore_rejected_u=True).
-                        if correction and _restore_rejected_u:
-                            rej = ~membership_mask(idx, gidx)
-                            u_out = u_out.at[idx].add(
-                                jnp.where(rej, u_in[idx], 0.0),
-                                mode="drop")
-                    else:  # allgather union: dense, every pick lands
-                        dense = result / p
+                vals, idx, residual = compressor.compress(
+                    acc, grad=src, residual=residual_in)
+                if codec.lossy and mode != "topk":
+                    # Fold the wire quantization error into the
+                    # error-feedback residual and ship the
+                    # requantized values: the residual repair below
+                    # then restores vq + folded error = the exact
+                    # original for rejected picks, and telemetry
+                    # (tau/sent/mass) describes what actually went on
+                    # the wire. (mode 'topk' allgathers the exact
+                    # local picks — its codec path quantizes in
+                    # topk_allgather and every pick is delivered, so
+                    # there is nothing to repair and the small
+                    # symmetric error is left to the next step's
+                    # selection, like any dense rounding.)
+                    vq = roundtrip_aligned(codec, vals, idx, n=n)
+                    residual = compressor.fold_wire_error(
+                        residual, idx, vals - vq)
+                    vals = vq
+                if telemetry:
+                    # Selection stats describe the LOCAL selection
+                    # (what this device put on the wire); the pmean
+                    # in _finish_telemetry turns them into axis
+                    # means.
+                    tel = {
+                        "tau": obs_counters.selected_tau(vals),
+                        "sent": obs_counters.sent_count(vals),
+                        "m_k": obs_counters.mass_ratio(acc, vals),
+                    }
+                    if telemetry_layers:
+                        tel["lsel"], _ = (
+                            obs_counters.sparse_selection_layer_stats(
+                                acc, vals, idx, l_seg, n_layers))
+                    if audit:
+                        tel["recall"] = _audit_recall(
+                            lambda ei: membership_mask(ei, idx))
+                    tel = (tel,)
+                # Momentum factor masking: a DELIVERED coordinate's
+                # velocity restarts (its momentum was consumed);
+                # without this the same mass re-sends for ~1/momentum
+                # more steps. For the allgather union every local
+                # pick is delivered, so masking at the local
+                # selection is exact.
+                u_out = (u_in.at[idx].set(0.0, mode="drop")
+                         if correction else u_in)
+                result, gidx, needs_repair = sparse_allreduce(
+                    mode, vals, idx, k=compressor.k(n), n=n,
+                    axis_name=axis_name, axis_size=p,
+                    ici_size=hier_ici_size if hier else 1,
+                    codec=codec, plan=plan,
+                )
+                if needs_repair:  # gtopk: sparse set + repair
+                    residual = compressor.repair(
+                        residual, vals, idx, gidx)
+                    dense = scatter_add_dense(n, gidx, result) / p
+                    # NOTE (measured design decision): under gTop-k a
+                    # local pick can be globally REJECTED; one could
+                    # argue its velocity should survive (nothing was
+                    # transmitted). Measured ablation says NO: the
+                    # repair above already preserves the rejected
+                    # VALUE in v, so also keeping u double-tracks the
+                    # same mass (v += u while u compounds) and
+                    # persistently-rejected coordinates blow up —
+                    # see restore_rejected_u_ablation in the
+                    # warmup_ab_cpu_mesh8.json artifact. The local
+                    # mask above is the stable generalization; the
+                    # branch below exists ONLY to reproduce that
+                    # ablation arm (_restore_rejected_u=True).
+                    if correction and _restore_rejected_u:
+                        rej = ~membership_mask(idx, gidx)
+                        u_out = u_out.at[idx].add(
+                            jnp.where(rej, u_in[idx], 0.0),
+                            mode="drop")
+                else:  # allgather union: dense, every pick lands
+                    dense = result / p
                 return (dense, residual, u_out) + tel
 
             if warmup_dense_steps > 0:
@@ -1282,6 +1437,36 @@ def gtopk_sgd(
         return updates, new_state
 
     return optax.GradientTransformation(init_fn, update_fn)
+
+
+def leaf_form_state(compression: Optional[str],
+                    axis_name: Optional[str]) -> bool:
+    """Whether ``gtopk_sgd``'s state holds its buffers as slabs (a flat
+    sparse mode with no mesh axis named) and not as [N] vectors."""
+    return (axis_name is None and compression not in DENSE_MODES
+            and compression not in LAYERWISE_MODES)
+
+
+def leaf_plan(tree):
+    """compression.LeafPlan of a parameter (or gradient) tree's shapes."""
+    return plan_leaves([leaf.shape for leaf in jax.tree.leaves(tree)])
+
+
+def flat_residual(residual, params, xp=jnp):
+    """A leaf-form state's buffers (``residual``, its ``v`` and ``u``, the
+    ``age``: slabs of compression.LeafPlan, what a flat mode's state holds
+    when no mesh axis is named) as the [N] vectors of ``ravel_pytree``'s
+    order, the form a P > 1 state and an older checkpoint hold. ``xp`` is
+    numpy for a host-side conversion."""
+    plan = leaf_plan(params)
+    return jax.tree.map(lambda slabs: plan.to_flat(slabs, xp), residual,
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
+def slab_residual(flat, params, xp=jnp):
+    """``flat_residual``'s inverse: [N] vectors -> tuples of slabs."""
+    plan = leaf_plan(params)
+    return jax.tree.map(lambda vec: tuple(plan.from_flat(vec, xp)), flat)
 
 
 def expand_residual_per_device(opt_state: GTopKSGDState, p: int, mesh):

@@ -45,7 +45,11 @@ from gtopkssgd_tpu.models import get_model
 from gtopkssgd_tpu.optimizer import (
     GTopKSGDState,
     expand_residual_per_device,
+    flat_residual,
     gtopk_sgd,
+    leaf_form_state,
+    leaf_plan,
+    slab_residual,
 )
 from gtopkssgd_tpu.obs import (
     AnomalyMonitor,
@@ -766,6 +770,12 @@ class Trainer:
         if self._bucket_plan is not None:
             plan_extra.update(self._bucket_plan.to_manifest())
         plan_extra.update(self._model_forms)
+        # Whether the one-device step took the leaf form, and how far
+        # (optimizer.leaf_update): static, from the parameters' shapes.
+        self._slab_state = leaf_form_state(
+            cfg.compression, None if self.p == 1 else "dp")
+        if self._slab_state:
+            plan_extra.update(leaf_plan(params).counters())
         # Compile-plane accounting (obs/memwatch.py, --obs-mem): build
         # the jitted step and AOT lower/compile it at the canonical
         # dispatch shape BEFORE the manifest is assembled, so the
@@ -1095,12 +1105,18 @@ class Trainer:
             self.monitor.observe_critpath(
                 step, crit_stage=cp.get("crit_stage"))
 
-    def _make_tx(self, warmup_dense_steps: Optional[int] = None):
+    def _make_tx(self, warmup_dense_steps: Optional[int] = None,
+                 slabs: Optional[bool] = None):
         """The optimizer transform; ``warmup_dense_steps`` overrides the
         config-derived value (the degrade fallback passes 2**30 to pin
         the always-dense branch — identical state treedef, so the live
-        state flows between the sparse and degraded steps unchanged)."""
+        state flows between the sparse and degraded steps unchanged).
+        ``slabs`` overrides the state's form (this run's: the leaf form
+        on one device, flat [N] buffers on a mesh) for the template of a
+        checkpoint that another form wrote."""
         cfg = self.cfg
+        if slabs is None:
+            slabs = self.p == 1
         if warmup_dense_steps is None:
             warmup_dense_steps = (
                 cfg.dense_warmup_epochs * self.steps_per_epoch)
@@ -1119,7 +1135,7 @@ class Trainer:
             buckets=cfg.buckets,
             pipeline=cfg.pipeline,
             clip_grad_norm=cfg.clip_grad_norm,
-            axis_name="dp" if self.p > 1 else None,
+            axis_name=None if slabs else "dp",
             hier_ici_size=cfg.hier_ici,
             warmup_dense_steps=warmup_dense_steps,
             momentum_correction=cfg.momentum_correction,
@@ -2334,9 +2350,19 @@ class Trainer:
             # elastic different-P resume can build the OLD-shape
             # template without guessing (utils/checkpoint.py sidecar).
             self._ckpt.save(int(self.state.step), self.state,
-                            meta={"residual_p": self.p})
+                            meta=self._ckpt_meta())
             if self.goodput is not None:
                 self.goodput.mark("ckpt")
+
+    def _ckpt_meta(self) -> dict:
+        """What a restoring run has to know of the saved state before it
+        can build a template (utils/checkpoint.py sidecar): the
+        residual's partition width, so that an elastic different-P resume
+        need not guess the old shape, and its form, slabs
+        (optimizer.leaf_update's) or the flat [N] buffers every mesh run
+        and every checkpoint older than the leaf form holds."""
+        return {"residual_p": self.p,
+                "residual_form": "slabs" if self._slab_state else "flat"}
 
     def restore(self) -> bool:
         if self._ckpt is None or self._ckpt.latest_step() is None:
@@ -2353,13 +2379,15 @@ class Trainer:
         # Elastic resumes first consult the sidecar's residual_p: a
         # checkpoint saved at a DIFFERENT fleet size takes the
         # re-partitioning path instead of the shape-identical one.
+        meta = self._ckpt.sidecar_meta()
         old_p = 0
         if self.cfg.elastic:
-            old_p = int(self._ckpt.sidecar_meta().get("residual_p") or 0)
-        if (old_p and old_p != self.p
-                and getattr(self.state.opt_state, "residual", None)
-                is not None):
-            self.state = self._restore_resized(old_p)
+            old_p = int(meta.get("residual_p") or 0)
+        old_slabs = meta.get("residual_form") == "slabs"
+        # A one-device checkpoint from before the leaf form holds flat
+        # buffers where this run holds slabs: the same path, no resize.
+        if (old_p and old_p != self.p) or old_slabs != self._slab_state:
+            self.state = self._restore_resized(old_p or self.p, old_slabs)
         else:
             self.state = self._ckpt.restore(
                 self._state_template(),
@@ -2379,56 +2407,78 @@ class Trainer:
             self.goodput.mark("ckpt")
         return True
 
-    def _restore_resized(self, old_p: int):
-        """Elastic restore across a fleet resize: the checkpoint's
-        residual is partitioned over ``old_p`` rows, this run's over
-        ``self.p``. Build a template in the SAVED shape — replicated,
-        since old_p need not divide the new mesh — so the integrity
-        digest verifies against what was actually written, then
-        re-partition the residual host-side (resilience/elastic.py:
+    def _restore_resized(self, old_p: int, old_slabs: bool):
+        """Restore a checkpoint whose per-device buffers another fleet
+        size or another form wrote: an elastic resize (the residual is
+        partitioned over ``old_p`` rows, this run's over ``self.p``), a
+        one-device run resumed on a mesh or the reverse (slabs against
+        flat [N] buffers), a one-device checkpoint older than the leaf
+        form. Build a template in the SAVED shape, from the state the
+        same configuration makes in that form — replicated, since old_p
+        need not divide the new mesh — so the integrity digest verifies
+        against what was actually written; then, host-side, bring the
+        residual to flat rows, re-partition them (resilience/elastic.py:
         grow = zero rows, shrink = masked-fold addition conserving the
-        pending gradient mass) and commit it onto the new mesh's
-        P('dp') placement. Every other leaf restores shape-identically."""
+        pending gradient mass) and commit them in this run's form and
+        placement. The age buffer of ``--obs-layers`` follows the
+        residual's form; every other leaf restores shape-identically."""
         from gtopkssgd_tpu.resilience.elastic import repartition_buffer
 
         rep = NamedSharding(self.mesh, P())
+        params = self.state.params
 
-        def leaf(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep)
+        def leaf(x, lead=()):
+            return jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
+                                        sharding=rep)
 
+        saved = jax.eval_shape(self._make_tx(slabs=old_slabs).init, params)
+        has_age = "age" in (saved.telemetry or {})
         template = jax.tree.map(leaf, self.state)
-
-        def old_leaf(r):
-            # live residual: [p, ...] rows when p > 1, bare at p == 1;
-            # the saved one followed the same convention at old_p
-            body = r.shape[1:] if self.p > 1 else r.shape
-            shape = ((old_p,) + tuple(body)) if old_p > 1 else tuple(body)
-            return jax.ShapeDtypeStruct(shape, r.dtype, sharding=rep)
-
-        template = template._replace(opt_state=template.opt_state._replace(
-            residual=jax.tree.map(old_leaf,
-                                  self.state.opt_state.residual)))
+        opt = template.opt_state._replace(residual=jax.tree.map(
+            lambda r: leaf(r, (old_p,) if old_p > 1 else ()),
+            saved.residual))
+        if has_age:
+            opt = opt._replace(telemetry=dict(
+                opt.telemetry, age=jax.tree.map(leaf, saved.telemetry["age"])))
         restored = self._ckpt.restore(
-            template, allow_mismatch=self.cfg.allow_ckpt_mismatch)
+            template._replace(opt_state=opt),
+            allow_mismatch=self.cfg.allow_ckpt_mismatch)
         dp = NamedSharding(self.mesh, P("dp"))
 
-        def repartition(saved):
-            buf = np.asarray(saved)
-            if old_p == 1:
-                buf = buf[None]
-            out = repartition_buffer(buf, max(1, self.p))
+        def reform(buffers):
+            """Saved buffers -> this run's form, through flat vectors."""
+            buffers = jax.tree.map(np.asarray, buffers)
+            if old_slabs:
+                buffers = flat_residual(buffers, params, np)
+            return buffers
+
+        def repartition(buf):
+            out = repartition_buffer(buf if old_p > 1 else buf[None],
+                                     max(1, self.p))
             if self.p == 1:
-                return jnp.asarray(out[0])
+                return out[0]
             return jax.make_array_from_callback(
                 out.shape, dp, lambda idx, o=out: o[idx])
 
-        restored = restored._replace(opt_state=restored.opt_state._replace(
-            residual=jax.tree.map(repartition,
-                                  restored.opt_state.residual)))
+        def commit(buffers):
+            if self._slab_state:
+                buffers = slab_residual(buffers, params, np)
+            return jax.tree.map(
+                lambda b: b if isinstance(b, jax.Array)
+                else jax.device_put(b, rep), buffers)
+
+        opt = restored.opt_state
+        opt = opt._replace(residual=commit(jax.tree.map(
+            repartition, reform(opt.residual))))
+        if has_age:
+            opt = opt._replace(telemetry=dict(
+                opt.telemetry, age=commit(reform(opt.telemetry["age"]))))
         self.logger.warning(
-            "elastic restore: residual re-partitioned %d -> %d rows "
-            "(pending gradient mass conserved)", old_p, self.p)
-        return restored
+            "restore: residual brought from %d row(s) of %s to %d of %s "
+            "(pending gradient mass conserved)", old_p,
+            "slabs" if old_slabs else "flat buffers", self.p,
+            "slabs" if self._slab_state else "flat buffers")
+        return restored._replace(opt_state=opt)
 
     # ---------------------------------------------------------- resilience
     def _preempt_now(self) -> None:
@@ -2441,7 +2491,7 @@ class Trainer:
         step = int(self.state.step)  # blocks: the save must be post-step
         if self._ckpt is not None:
             self._ckpt.save(step, self.state, force=True,
-                            meta={"residual_p": self.p})
+                            meta=self._ckpt_meta())
             if self.goodput is not None:
                 # The emergency save is the preempt fault's designated
                 # badput: ckpt.
@@ -2490,7 +2540,7 @@ class Trainer:
             return
         step = int(self.state.step)  # blocks: the save must be post-step
         self._ckpt.save(step, self.state, force=True,
-                        meta={"residual_p": self.p})
+                        meta=self._ckpt_meta())
         if self.goodput is not None:
             self.goodput.mark("ckpt")
         lineage = dict(self.lineage or {})

@@ -215,3 +215,84 @@ def test_compress_by_threshold_twostage_superset_of_exact(rng):
     assert np.asarray(keep)[np.asarray(exact_idx)].all()
     np.testing.assert_array_equal(
         np.where(np.asarray(keep), 0.0, np.asarray(acc)), np.asarray(res))
+
+
+# ------------------------------------------------------------------
+# The leaf form (PR 42): slabs, and one threshold over all of them.
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
+
+from gtopkssgd_tpu import compression  # noqa: E402
+
+SHAPES = [(64, 256), (4, 32, 128), (96, 1), (37,), (256,), (48, 130),
+          (8, 16)]
+
+
+def test_plan_reads_size_and_last_axis_only(monkeypatch):
+    big = compression.IN_PLACE_MIN_ELEMS
+    plan = compression.plan_leaves(
+        [(big // 128, 128), (big // 128 - 1, 128), (big,), (big, 1),
+         (2, big // 256, 128), (big // 64, 64), (7,)])
+    assert plan.in_place == (0, 4)          # large, two axes, a row of lanes
+    assert plan.grouped == (1, 2, 3, 5, 6)
+    assert plan.counters() == {
+        "leaves_in_place": 2, "leaves_grouped": 5,
+        "elems_in_place_share": 2 * big / plan.n}
+    assert plan.slab_shapes == (
+        (big // 128, 128), (2, big // 256, 128),
+        (plan.n - 2 * big,))
+    # nothing to group: no grouped slab; nothing in place: one vector
+    assert compression.plan_leaves([(big // 128, 128)]).slab_shapes == (
+        (big // 128, 128),)
+    assert compression.plan_leaves([(3, 4), (5,)]).slab_shapes == ((17,),)
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_ELEMS", 4096)
+    small = compression.plan_leaves(SHAPES)
+    assert small.in_place == (0, 1, 5) and len(small.grouped) == 4
+
+
+@pytest.mark.parametrize("xp", [jnp, np], ids=["device", "host"])
+def test_slabs_round_trip_through_leaves_and_the_flat_vector(
+        rng, monkeypatch, xp):
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_ELEMS", 4096)
+    plan = compression.plan_leaves(SHAPES)
+    leaves = [xp.asarray(rng.standard_normal(s).astype(np.float32))
+              for s in SHAPES]
+    flat = xp.concatenate([a.reshape(-1) for a in leaves])
+    slabs = plan.split(leaves, xp)
+    assert tuple(s.shape for s in slabs) == plan.slab_shapes
+    assert slabs[0] is leaves[0]                  # in place: the leaf itself
+    for got, want in zip(plan.join(slabs), leaves, strict=True):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(plan.to_flat(slabs, xp)),
+                                  np.asarray(flat))
+    for got, want in zip(plan.from_flat(flat, xp), slabs, strict=True):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("method", ["auto", "exact", "approx", "twostage"])
+@pytest.mark.parametrize("density", [0.002, 0.1, 1.0])
+def test_compress_leaves_by_threshold_is_the_vectors_partition(
+        rng, monkeypatch, method, density):
+    """keep, residual and kept_tau over slabs == compress_by_threshold over
+    their flat vector, ties and zeros included (values on a grid)."""
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_ELEMS", 4096)
+    plan = compression.plan_leaves(SHAPES)
+    comp = TopKCompressor(density=density, method=method)
+    leaves = [jnp.asarray(rng.integers(-6, 7, s) / 4.0, jnp.float32)
+              for s in SHAPES]
+    slabs = plan.split(leaves)
+    keeps, residuals, kept_tau = jax.jit(
+        comp.compress_leaves_by_threshold)(slabs)
+    want_keep, want_res, want_tau = comp.compress_by_threshold(
+        plan.to_flat(slabs))
+    np.testing.assert_array_equal(
+        np.asarray(plan.to_flat(keeps)), np.asarray(want_keep))
+    np.testing.assert_array_equal(
+        np.asarray(plan.to_flat(residuals)), np.asarray(want_res))
+    assert float(kept_tau) == float(want_tau) > 0
+    for keep, res, slab in zip(keeps, residuals, slabs, strict=True):
+        assert keep.shape == res.shape == slab.shape
+    if density == 1.0:      # every nonzero is sent, no zero is
+        assert int(sum(k.sum() for k in keeps)) == int(
+            sum(np.count_nonzero(np.asarray(a)) for a in leaves))

@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 import pytest
 
-from gtopkssgd_tpu.optimizer import gtopk_sgd
+from gtopkssgd_tpu.optimizer import flat_residual, gtopk_sgd
 from gtopkssgd_tpu.parallel import make_mesh
 
 PDEV = 8
@@ -65,9 +65,11 @@ def test_correction_p1_matches_dgc_oracle():
         got = -np.concatenate(
             [np.asarray(updates["b"]), np.asarray(updates["w"])])
         np.testing.assert_allclose(got, applied, rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(state.residual["v"]), v,
+        # no mesh axis named: the state holds slabs (optimizer.leaf_update)
+        res = flat_residual(state.residual, params)
+        np.testing.assert_allclose(np.asarray(res["v"]), v,
                                    rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(state.residual["u"]), u,
+        np.testing.assert_allclose(np.asarray(res["u"]), u,
                                    rtol=1e-5, atol=1e-6)
 
 

@@ -30,7 +30,7 @@ from gtopkssgd_tpu.obs import (
 )
 from gtopkssgd_tpu.obs import report as obs_report
 from gtopkssgd_tpu.obs import tracing
-from gtopkssgd_tpu.optimizer import gtopk_sgd
+from gtopkssgd_tpu.optimizer import flat_residual, gtopk_sgd
 from gtopkssgd_tpu.ops import k_for_density
 from gtopkssgd_tpu.utils.metrics import MetricsLogger
 
@@ -686,11 +686,14 @@ def test_residual_age_monotonic():
     tx = gtopk_sgd(0.1, compression="gtopk", density=0.05, axis_name=None,
                    telemetry=True, telemetry_layers=True)
     state = tx.init(params)
-    ages = [np.asarray(state.telemetry["age"])]
+    # no mesh axis named: the age buffer is slabs, as the residual is
+    age = lambda st: np.asarray(flat_residual(st.telemetry["age"], params))
+    assert age(state).shape == (sum(x.size for x in jax.tree.leaves(params)),)
+    ages = [age(state)]
     step = jax.jit(tx.update)
     for _ in range(3):
         _, state = step(grads, state, params)
-        ages.append(np.asarray(state.telemetry["age"]))
+        ages.append(age(state))
     for i, (prev, cur) in enumerate(zip(ages, ages[1:]), start=1):
         # every coordinate either shipped (age resets to 0) or aged by 1
         assert np.all((cur == 0) | (cur == prev + 1))

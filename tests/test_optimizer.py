@@ -239,3 +239,282 @@ def test_dense_warmup_hier_matches_dense_scale():
     pd, _ = _spmd_step(tx_d, mesh)(params, sd, grads)
     np.testing.assert_allclose(np.asarray(ph["w"]), np.asarray(pd["w"]),
                                rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------------------------------
+# The one-device step on the gradient's own leaves (leaf_update, PR 42)
+# against the flat [N] expression it replaced, written out here:
+# ravel_pytree, compress_by_threshold on the vector, unravel.
+
+import functools  # noqa: E402
+
+from jax.flatten_util import ravel_pytree  # noqa: E402
+
+import pytest  # noqa: E402
+
+from gtopkssgd_tpu import compression  # noqa: E402
+from gtopkssgd_tpu.obs import counters as obs_counters  # noqa: E402
+from gtopkssgd_tpu.optimizer import flat_residual  # noqa: E402
+
+# 2-D, stacked experts, a [n, 1] gate, vectors, a last axis that is no
+# multiple of 128, a matrix under the grouping size.
+MIXED = {"attn": (64, 256), "experts": (4, 32, 128), "gate": (96, 1),
+         "bias": (37,), "scale": (256,), "odd": (48, 130), "tiny": (8, 16)}
+LR, MOM = 0.25, 0.5
+# Counters that are float32 sums over N: equal to the bit where every
+# partial sum is exact (the data below, without clip or momentum in front
+# of the sums), to rounding where the order of summation shows (2e-4: the
+# flat expression's per-layer sums are sequential float32 segment sums,
+# off by 5e-5 over 16k elements).
+SUMS = ("grad_norm_pre", "grad_norm_post", "residual_norm", "m_k")
+
+
+@pytest.fixture
+def in_place_from_4096(monkeypatch):
+    """Leaves of `MIXED` on both sides of the rule: attn, experts and odd
+    in place, the other four in the grouped vector."""
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_ELEMS", 4096)
+    plan = compression.plan_leaves([MIXED[k] for k in sorted(MIXED)])
+    assert len(plan.in_place) == 3 and len(plan.grouped) == 4
+    return plan
+
+
+def quarters(rng, shape, bound=8):
+    """Multiples of 1/4 in [-2, 2]: squares and their sums over these
+    sizes are exact in float32 whatever the order."""
+    return jnp.asarray(rng.integers(-bound, bound + 1, shape) / 4.0,
+                       jnp.float32)
+
+
+def mixed_tree(rng, zero_share=0.0):
+    tree = {k: quarters(rng, s) for k, s in MIXED.items()}
+    if zero_share:
+        tree = {k: jnp.where(jnp.asarray(rng.random(v.shape)) < zero_share,
+                             0.0, v) for k, v in tree.items()}
+    return tree
+
+
+def flat_expression(grads_seq, params, *, density, method="auto", clip=None,
+                    correction=False, warmup=0, layers=False):
+    """The parent's P = 1 step, on the [N] vector: per step (update tree,
+    residual, counters)."""
+    comp = compression.TopKCompressor(density=density, method=method)
+    n = sum(int(np.prod(s)) for s in MIXED.values())
+    sizes = obs_counters.layer_sizes(params)
+    seg = obs_counters.segment_ids(sizes)
+    residual = jnp.zeros((n,), jnp.float32)
+    u = jnp.zeros((n,), jnp.float32)
+    age = jnp.zeros((n,), jnp.float32)
+    inner = optax.sgd(LR, momentum=None if correction else MOM)
+    inner_state = inner.init(params)
+
+    # jitted as the step is: XLA divides by a constant as it sees fit
+    @functools.partial(jax.jit, static_argnums=0)
+    def step(warm, grads, residual, u, age, inner_state):
+        flat, unravel = ravel_pytree(grads)
+        if clip is not None:
+            gnorm = jnp.sqrt(jnp.sum(flat * flat))
+            flat = flat * jnp.minimum(1.0, clip / (gnorm + 1e-6))
+        src = flat
+        if correction:
+            u = MOM * u + flat
+            src = u
+        if warm:
+            dense, tau = src, jnp.float32(0.0)
+            sent, m_k = jnp.float32(n), jnp.float32(1.0)
+            lsel = obs_counters.dense_phase_selection_stats(sizes)[0]
+        else:
+            acc = src + residual
+            keep, residual, tau = comp.compress_by_threshold(
+                acc, grad=src, residual=residual)
+            dense = acc - residual
+            if correction:
+                u = jnp.where(keep, 0.0, u)
+            sent = obs_counters.kept_count(keep)
+            m_k = obs_counters.mass_ratio(acc, dense)
+            mask = dense != 0
+            seg_sum = lambda x: jax.ops.segment_sum(
+                x, seg, num_segments=len(sizes), indices_are_sorted=True)
+            ltau = jax.ops.segment_min(
+                jnp.where(mask, jnp.abs(dense), jnp.inf), seg,
+                num_segments=len(sizes), indices_are_sorted=True)
+            lsel = {"sent": seg_sum(mask.astype(jnp.float32)),
+                    "tau": jnp.where(jnp.isfinite(ltau), ltau, 0.0),
+                    "m_k": seg_sum(dense * dense) / jnp.maximum(
+                        seg_sum(acc * acc), 1e-30)}
+        updates, inner_state = inner.update(unravel(dense), inner_state,
+                                            params)
+        tel = obs_counters.make_telemetry(
+            n=n, k=comp.k(n), p=1, mode="gtopk",
+            grad_norm_pre=obs_counters.tree_l2(flat),
+            grad_norm_post=obs_counters.tree_l2(dense),
+            residual_norm=obs_counters.tree_l2(residual),
+            tau=tau, sent_elems=sent, m_k=m_k)
+        if layers:
+            age = obs_counters.update_age(age, dense != 0)
+            tel["layers"] = obs_counters.assemble_layer_telemetry(
+                sel_stats=lsel, sizes=sizes,
+                grad_norm_pre_l=obs_counters.seg_l2(flat, seg, len(sizes)),
+                grad_norm_post_l=obs_counters.seg_l2(dense, seg, len(sizes)),
+                residual_norm_l=obs_counters.seg_l2(
+                    residual, seg, len(sizes)),
+                age=age, seg=seg)
+            tel["age"] = age
+        return updates, residual, u, age, inner_state, tel
+
+    out = []
+    for t, grads in enumerate(grads_seq):
+        updates, residual, u, age, inner_state, tel = step(
+            t < warmup, grads, residual, u, age, inner_state)
+        out.append((updates, {"v": residual, "u": u} if correction
+                    else residual, tel))
+    return out
+
+
+def leaf_steps(grads_seq, params, axis_name, *, density, method="auto",
+               clip=None, correction=False, warmup=0, layers=False):
+    tx = gtopk_sgd(LR, momentum=MOM, compression="gtopk", density=density,
+                   topk_method=method, clip_grad_norm=clip,
+                   momentum_correction=correction, warmup_dense_steps=warmup,
+                   telemetry=True, telemetry_layers=layers,
+                   axis_name=axis_name)
+    state = tx.init(params)
+    step = jax.jit(tx.update)
+    out = []
+    for grads in grads_seq:
+        updates, state = step(grads, state, params)
+        residual, tel = state.residual, dict(state.telemetry)
+        if axis_name is None:   # the state is slabs: bring it to [N]
+            assert all(isinstance(r, tuple)
+                       for r in (residual.values() if correction
+                                 else [residual]))
+            residual = flat_residual(residual, params)
+            if layers:
+                tel["age"] = flat_residual(tel["age"], params)
+        out.append((updates, residual, tel))
+    return out
+
+
+def assert_same_steps(got, want, exact_sums, fused_products=False):
+    """``fused_products``: the velocity of a clipped gradient is
+    ``m * u + g * scale``, which XLA's CPU backend contracts into a fused
+    multiply-add or not by the fusion it lands in (one rounding of 1e-8)."""
+    same = (functools.partial(np.testing.assert_allclose, rtol=1e-4,
+                              atol=1e-7)
+            if fused_products else np.testing.assert_array_equal)
+    assert len(got) == len(want)
+    for t, ((gu, gr, gt), (wu, wr, wt)) in enumerate(zip(got, want)):
+        jax.tree.map(same, gu, wu)
+        jax.tree.map(same, gr, wr)
+        assert set(gt) == set(wt)
+        for key in wt:
+            for name, g, w in ([(key, gt[key], wt[key])]
+                               if key != "layers" else
+                               [(f, gt[key][f], wt[key][f])
+                                for f in wt[key]]):
+                if not fused_products and (
+                        exact_sums or name.split("/")[-1] not in SUMS):
+                    np.testing.assert_array_equal(
+                        np.asarray(g), np.asarray(w), err_msg=f"{t} {name}")
+                else:
+                    np.testing.assert_allclose(
+                        np.asarray(g), np.asarray(w), rtol=2e-4,
+                        err_msg=f"{t} {name}")
+
+
+VARIANTS = {
+    "plain": dict(),
+    "clip": dict(clip=3.0),
+    "correction": dict(correction=True),
+    "warmup": dict(warmup=1),
+    "obs_layers": dict(layers=True),
+    "exact": dict(method="exact"),
+    "approx": dict(method="approx"),
+    "blockwise": dict(method="blockwise"),
+    "correction_warmup_layers": dict(
+        correction=True, warmup=1, layers=True),
+    "clip_correction": dict(clip=3.0, correction=True),
+}
+
+
+@pytest.mark.parametrize("axis_name", [None, "dp"],
+                         ids=["slab_state", "flat_state"])
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_leaf_form_is_the_flat_expression(in_place_from_4096, variant,
+                                          axis_name):
+    """Update, residual, tau and every counter of three steps (the residual
+    feeds back), with the state as slabs (no axis named: the trainer's one
+    device) and as the flat [N] an unbound axis leaves it."""
+    kw = VARIANTS[variant]
+    rng = np.random.default_rng(42)
+    params = mixed_tree(rng)
+    grads_seq = [mixed_tree(rng) for _ in range(3)]
+    if variant == "clip_correction":
+        # no ties for a product's last bit to break (assert_same_steps)
+        grads_seq = [jax.tree.map(
+            lambda g: jnp.asarray(rng.standard_normal(g.shape), jnp.float32),
+            grads) for grads in grads_seq]
+    want = flat_expression(grads_seq, params, density=0.01, **kw)
+    got = leaf_steps(grads_seq, params, axis_name, density=0.01, **kw)
+    # the clip's scale and the velocity's 1/2 leave the quarter grid
+    exact = not (kw.get("clip") or kw.get("correction"))
+    assert_same_steps(got, want, exact_sums=exact,
+                      fused_products=variant == "clip_correction")
+    if not kw.get("warmup"):
+        assert float(got[0][2]["sent_elems"]) >= 0.01 * in_place_from_4096.n
+
+
+def test_leaf_form_ties_at_tau_all_pass(in_place_from_4096):
+    """Magnitudes on a grid of 17 values: hundreds tie at tau, in every
+    slab, and all of them are sent (count above k), as the flat form."""
+    rng = np.random.default_rng(3)
+    params = mixed_tree(rng)
+    grads_seq = [mixed_tree(rng) for _ in range(3)]
+    want = flat_expression(grads_seq, params, density=0.05)
+    got = leaf_steps(grads_seq, params, None, density=0.05)
+    assert_same_steps(got, want, exact_sums=True)
+    n = in_place_from_4096.n
+    assert float(got[0][2]["sent_elems"]) > 1.2 * np.ceil(0.05 * n)
+
+
+def test_leaf_form_fewer_than_k_nonzeros_keeps_no_zero(in_place_from_4096):
+    rng = np.random.default_rng(4)
+    params = mixed_tree(rng)
+    grads_seq = [mixed_tree(rng, zero_share=0.97) for _ in range(3)]
+    want = flat_expression(grads_seq, params, density=0.2)
+    got = leaf_steps(grads_seq, params, None, density=0.2)
+    assert_same_steps(got, want, exact_sums=True)
+    flat0 = np.asarray(ravel_pytree(grads_seq[0])[0])
+    assert float(got[0][2]["sent_elems"]) == np.count_nonzero(flat0)
+    assert float(got[0][2]["tau"]) == np.abs(flat0[flat0 != 0]).min()
+    assert not np.asarray(got[0][1]).any()      # nothing is left behind
+
+
+@pytest.mark.parametrize("method", ["auto", "exact", "approx"])
+def test_leaf_form_k_at_least_n_sends_every_nonzero(in_place_from_4096,
+                                                    method):
+    rng = np.random.default_rng(5)
+    params = mixed_tree(rng)
+    grads_seq = [mixed_tree(rng) for _ in range(2)]
+    want = flat_expression(grads_seq, params, density=1.0, method=method)
+    got = leaf_steps(grads_seq, params, None, density=1.0, method=method)
+    assert_same_steps(got, want, exact_sums=True)
+    flat0 = np.asarray(ravel_pytree(grads_seq[0])[0])
+    assert float(got[0][2]["sent_elems"]) == np.count_nonzero(flat0)
+
+
+def test_leaf_form_state_is_slabs_only_without_an_axis():
+    params = {k: jnp.zeros(s) for k, s in MIXED.items()}
+    n = sum(x.size for x in jax.tree.leaves(params))
+    slabs = gtopk_sgd(0.1, compression="gtopk", axis_name=None).init(params)
+    assert isinstance(slabs.residual, tuple)
+    assert sum(r.size for r in slabs.residual) == n
+    for kw in (dict(compression="gtopk", axis_name="dp"),
+               dict(compression="gtopk_hier", axis_name="dp")):
+        assert gtopk_sgd(0.1, **kw).init(params).residual.shape == (n,)
+    dense = gtopk_sgd(0.1, compression="dense", axis_name=None).init(params)
+    assert dense.residual.shape == (0,)
+    lw = gtopk_sgd(0.1, compression="gtopk_layerwise",
+                   axis_name=None).init(params)
+    assert [r.shape for r in lw.residual] == [
+        (int(np.prod(MIXED[k])),) for k in sorted(MIXED)]

@@ -234,34 +234,109 @@ def stages(lowered):
     return found
 
 
-def test_the_flat_gradient_is_made_clipped_and_split_under_its_stages(
-        tmp_path):
-    """``gtopk_train_step`` at a sparse decoder cell's flags (``tiny``):
-    ``ravel_pytree``'s concatenate under ``gtopk/flatten``, the clip's
-    reduction and scaling under ``gtopk/clip``, the leaves cut out of the
-    dense update under ``gtopk/unflatten``, and no concatenate of the whole
-    gradient or slice of the update left outside them."""
+MOVES = ("concatenate", "slice", "reshape", "dynamic_update_slice",
+         "dynamic_slice", "pad")
+
+
+def largest_moves(lowered):
+    """{operation: the most elements any of its operands or results has}
+    over the lowered module's data-movement operations."""
+    largest = collections.Counter()
+    for line in lowered.as_text().splitlines():
+        op = re.search(r"stablehlo\.(\w+)", line)
+        if not op or op.group(1) not in MOVES:
+            continue
+        for dims in re.findall(r"tensor<((?:\d+x)*)\w+>", line):
+            size = 1
+            for d in dims.split("x")[:-1]:
+                size *= int(d)
+            largest[op.group(1)] = max(largest[op.group(1)], size)
+    return largest
+
+
+def one_device_step(tmp_path, **flags):
     from gtopkssgd_tpu.trainer import TrainConfig, Trainer
 
     with Trainer(TrainConfig(
-            dnn="trinity_mini", model_preset="tiny", batch_size=2, nworkers=1,
-            compression="gtopk", density=0.01, clip_grad_norm=1.0,
-            prefetch=0, out_dir=str(tmp_path))) as t:
-        leaves = len(jax.tree.leaves(t.state.params))
+            batch_size=2, nworkers=1, compression="gtopk", density=0.01,
+            prefetch=0, out_dir=str(tmp_path), **flags)) as t:
         batch = t._device_batch(t._shard_batches(t._iters)[0])
-        found = stages(t._train_step.lower(t.state, t.carry, batch))
+        return (t._train_step.lower(t.state, t.carry, batch),
+                [leaf.shape for leaf in jax.tree.leaves(t.state.params)],
+                t._manifest)
+
+
+def test_the_one_device_step_works_on_leaves_under_the_same_stages(
+        tmp_path, monkeypatch):
+    """``gtopk_train_step`` at a sparse decoder cell's flags (``tiny``,
+    with the in-place rule brought down to its sizes): no [N] vector.
+    ``gtopk/flatten`` holds the one concatenate of the grouped small
+    leaves, ``gtopk/clip`` a reduction a slab and one over those,
+    ``gtopk/unflatten`` the
+    grouped leaves' slices, and the large leaves pass through all three
+    untouched; accumulate, select, mask and the counters keep their names."""
+    from gtopkssgd_tpu import compression
+
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_ELEMS", 2048)
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_LAST", 16)
+    lowered, shapes, manifest = one_device_step(
+        tmp_path, dnn="trinity_mini", model_preset="tiny",
+        clip_grad_norm=1.0)
+    plan = compression.plan_leaves(shapes)
+    assert len(plan.in_place) > 10 and len(plan.grouped) > 10
+    found = stages(lowered)
+    slabs = len(plan.slab_shapes)
     assert found["gtopk/flatten"]["concatenate"] == 1
-    assert found["gtopk/flatten"]["reshape"] > leaves // 2   # not the 1-d
-    assert found["gtopk/clip"]["reduce"] == 1
-    assert found["gtopk/clip"]["multiply"] >= 2      # flat * flat, * scale
-    assert found["gtopk/unflatten"]["slice"] == leaves
-    assert {"gtopk/fwd_bwd", "gtopk/accumulate", "gtopk/select",
-            "gtopk/mask", "gtopk/apply", "gtopk/telemetry"} <= set(found)
+    assert found["gtopk/flatten"]["reshape"] == sum(
+        len(plan.shapes[i]) != 1 for i in plan.grouped)
+    assert found["gtopk/clip"]["reduce"] == slabs + 1   # and their sum
+    assert found["gtopk/clip"]["multiply"] == 2 * slabs   # f * f, f * scale
+    assert found["gtopk/unflatten"]["slice"] == len(plan.grouped)
+    assert found["gtopk/accumulate"]["add"] == slabs
+    assert found["gtopk/mask"]["select"] >= 2 * slabs  # residual, update
+    assert {"gtopk/fwd_bwd", "gtopk/select", "gtopk/apply",
+            "gtopk/telemetry"} <= set(found)
     # What is left under no stage is the step's bookkeeping on scalars (the
     # batch's leading axis, the loss's and the counters' means, the step's
     # count): no product and no pass over N.
     assert set(found[""]) <= {"constant", "slice", "reshape", "subtract",
                               "add", "reduce", "divide"}, found[""]
+    assert manifest["leaves_in_place"] == len(plan.in_place)
+    assert manifest["leaves_grouped"] == len(plan.grouped)
+    assert manifest["elems_in_place_share"] == pytest.approx(
+        sum(plan.sizes[i] for i in plan.in_place) / plan.n)
+
+
+@pytest.mark.parametrize("flags", [
+    dict(dnn="qwen3_next", model_preset="tiny"),
+    dict(dnn="keye_vl2", model_preset="tiny"),
+    dict(dnn="trinity_mini", model_preset="tiny"),
+    dict(dnn="kanana2", model_preset="tiny"),
+    dict(dnn="ouro", model_preset="tiny"),
+    dict(dnn="resnet20", dataset="cifar10"),
+], ids=lambda f: f["dnn"])
+def test_the_one_device_step_moves_no_whole_vector(tmp_path, monkeypatch,
+                                                   flags):
+    """The P = 1 ``gtopk`` step lowered: no concatenate, slice, reshape,
+    pad or dynamic (update) slice with an operand or result of N elements,
+    nor of half of them (the threshold's candidates and the grouped small
+    leaves are what such operations may touch). The manifest says how far
+    the form engaged: in place + grouped = the tree's leaves."""
+    from gtopkssgd_tpu import compression
+
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_ELEMS", 1024)
+    monkeypatch.setattr(compression, "IN_PLACE_MIN_LAST", 8)
+    lowered, shapes, manifest = one_device_step(tmp_path, **flags)
+    plan = compression.plan_leaves(shapes)
+    assert manifest["leaves_in_place"] + manifest["leaves_grouped"] == len(
+        shapes)
+    assert manifest["leaves_in_place"] == len(plan.in_place) > 0
+    assert manifest["elems_in_place_share"] > 0.8
+    grouped = sum(plan.sizes[i] for i in plan.grouped)
+    largest = largest_moves(lowered)
+    assert largest["concatenate"] >= grouped       # the reader reads
+    for op, size in largest.items():
+        assert size < plan.n // 2, (op, size, plan.n)
 
 
 def test_the_layerwise_form_names_the_same_three_stages():

@@ -195,3 +195,147 @@ class TestSimrecall:
         vals, idx = f(x)
         assert vals.shape == (50,) and idx.shape == (50,)
         assert idx.dtype == jnp.int32
+
+
+# ------------------------------------------------------------------
+# One tau over an accumulator that exists only as leaves (PR 42).
+
+from gtopkssgd_tpu.ops import (  # noqa: E402
+    approx_bin_size,
+    bin_maxima,
+    select_tau,
+    select_tau_leaves,
+)
+from gtopkssgd_tpu.ops import topk as topk_mod  # noqa: E402
+
+LEAF_SHAPES = [(64, 256), (4, 32, 128), (96, 1), (37,), (48, 130), (8, 16)]
+
+
+def leaves_of(rng, shapes=LEAF_SHAPES, grid=None):
+    if grid:
+        return [jnp.asarray(rng.integers(-grid, grid + 1, s) / 4.0,
+                            jnp.float32) for s in shapes]
+    return [jnp.asarray(rng.standard_normal(s), jnp.float32) for s in shapes]
+
+
+def flat_of(leaves):
+    return jnp.concatenate([a.reshape(-1) for a in leaves])
+
+
+@pytest.mark.parametrize("method", ["auto", "exact", "approx", "blockwise",
+                                    "threshold", "twostage", "simrecall"])
+@pytest.mark.parametrize("density", [0.001, 0.05])
+def test_tau_over_leaves_is_the_flat_vectors(rng, method, density):
+    """Off the chip every method's tau over the leaves is `select_tau`'s
+    over their concatenation, to the bit."""
+    leaves = leaves_of(rng)
+    k = k_for_density(sum(a.size for a in leaves), density)
+    want = select_tau(flat_of(leaves), k, method)
+    got = select_tau_leaves(leaves, k, method)
+    assert float(got) == float(want) > 0
+
+
+def test_tau_over_leaves_with_ties_zeros_and_k_past_n(rng):
+    leaves = leaves_of(rng, grid=3)                  # seven magnitudes
+    n = sum(a.size for a in leaves)
+    flat = np.abs(np.asarray(flat_of(leaves)))
+    for k in (1, 50, n // 3, n - 1):
+        want = np.sort(flat)[n - k]
+        for method in ("exact", "approx", "auto"):
+            assert float(select_tau_leaves(leaves, k, method)) == want
+    # k >= n: the smallest magnitude of all, a zero here
+    assert float(select_tau_leaves(leaves, n, "auto")) == 0.0
+    assert float(select_tau_leaves(leaves, n + 5, "exact")) == 0.0
+    # fewer nonzeros than k: tau is 0, and the masks keep no zero
+    sparse = [jnp.where(jnp.abs(a) > 0.6, a, 0.0) for a in leaves]
+    nonzero = int(sum(np.count_nonzero(np.asarray(a)) for a in sparse))
+    assert float(select_tau_leaves(sparse, nonzero + 1, "exact")) == 0.0
+
+
+def test_approx_bin_size_is_xlas_recall_formula():
+    # floor(log2(N * 0.0513 / k)) at the recall target 0.95
+    assert approx_bin_size(458_272_769, 458_273) == 32      # rho = 0.001
+    assert approx_bin_size(25_557_032, 25_558) == 32
+    assert approx_bin_size(25_557_032, 255_571) == 4        # rho = 0.01
+    assert approx_bin_size(25_557_032, 2_555_704) == 1      # rho = 0.1
+    assert approx_bin_size(1 << 20, 1049) == 32
+    assert approx_bin_size(2048, 3) == 2          # 1024 outputs at least
+    assert approx_bin_size(1000, 1) == 1
+
+
+@pytest.mark.parametrize("shape,group", [
+    ((64, 256), 32), ((4, 32, 128), 32), ((70, 130), 32), ((20, 16), 32),
+    ((5000,), 32), ((37,), 8), ((96, 1), 4), ((64, 384), 4)])
+def test_bin_maxima_partition_the_leaf(rng, shape, group):
+    """Every element lies in one bin; a bin holds one element of each of
+    `group` slabs of consecutive rows, slab s's taken s lane tiles further
+    along the row (rows far apart and columns apart: no two members share
+    a row or, in a leaf wider than the shifts, a column); the rows past the
+    last whole slab are one bin a column; the maxima are the bins' own."""
+    x = np.abs(rng.standard_normal(shape)).astype(np.float32)
+    got = np.sort(np.asarray(bin_maxima(jnp.asarray(x), group)))
+    rows = (x.reshape(-1, shape[-1]) if len(shape) > 1 else
+            np.pad(x, (0, -x.size % 128)).reshape(-1, 128))
+    n_rows, cols = rows.shape
+    per = n_rows // group
+    want, seen = [], np.zeros(rows.shape, int)
+    for i in range(per):
+        for j in range(cols):
+            members = [(s * per + i, (j + 128 * s) % cols)
+                       for s in range(group)]
+            assert len({r for r, _ in members}) == group
+            if cols >= 128 * group:
+                assert len({c for _, c in members}) == group
+            for r, c in members:
+                seen[r, c] += 1
+            want.append(max(rows[r, c] for r, c in members))
+    for j in range(cols if n_rows > per * group else 0):
+        seen[per * group:, j] += 1
+        want.append(rows[per * group:, j].max())
+    assert (seen == 1).all()
+    np.testing.assert_array_equal(got, np.sort(np.asarray(want, np.float32)))
+    assert got[-1] == x.max()
+
+
+def test_bins_keep_a_gradients_hot_rows_and_columns_apart(rng):
+    """A weight's gradient is a sum of outer products: whole rows and whole
+    columns are large. The k-th largest bin maximum still sends about k (a
+    bin of consecutive rows of one column would send twenty times k)."""
+    for shape, hot in (((640, 4096), "columns"), ((4096, 640), "rows")):
+        x = np.abs(rng.standard_normal(shape)).astype(np.float32)
+        if hot == "columns":
+            x *= np.where(rng.random(shape[1]) < 0.02, 50.0, 1.0)[None, :]
+        else:       # the first rows, one after the other
+            x *= np.where(np.arange(shape[0]) < 80, 50.0, 1.0)[:, None]
+        k = x.size // 1000
+        cand = np.asarray(bin_maxima(jnp.asarray(x), 32))
+        tau = np.sort(cand)[cand.size - k]
+        assert k <= (x >= tau).sum() <= 1.1 * k, hot
+
+
+def test_binned_tau_is_approx_max_ks_rule_on_leaves(rng):
+    """The chip's form, run here: tau is the k-th largest bin maximum, no
+    larger than the exact k-th magnitude, and the mask `>= tau` holds the
+    kernel's promise of 0.95 of the exact top k and all the candidates."""
+    leaves = leaves_of(rng, [(256, 256), (8, 64, 128), (512, 130), (4000,)])
+    n = sum(a.size for a in leaves)
+    k = k_for_density(n, 0.001)
+    group = approx_bin_size(n, k)
+    assert group == 32
+    mags = [jnp.abs(a) for a in leaves]
+    tau = float(topk_mod._binned_tau_leaves(mags, k, group))
+    cand = np.concatenate([np.asarray(bin_maxima(m, group)) for m in mags])
+    assert n / 33 < cand.size < n / 31
+    assert tau == np.sort(cand)[cand.size - k]
+    flat = np.abs(np.asarray(flat_of(leaves)))
+    exact = np.sort(flat)[n - k]
+    assert 0 < tau <= exact
+    kept = flat >= tau
+    assert kept.sum() >= k
+    top = np.argsort(-flat)[:k]
+    assert kept[top].mean() == 1.0       # a superset of the exact top k
+    assert kept.sum() <= k / 0.95 + 1    # and of no more than recall allows
+    # too few candidates for k: the exact search
+    few = [jnp.abs(a) for a in leaves_of(rng, [(64, 16), (40, 8)])]
+    assert float(topk_mod._binned_tau_leaves(few, 60, 32)) == float(
+        topk_mod._exact_tau_leaves(few, 60))

@@ -132,17 +132,109 @@ def test_checkpoint_roundtrip_preserves_residual(tmp_path):
     t = Trainer(cfg)
     t.train(5)
     t.save()
-    residual = np.asarray(t.state.opt_state.residual)
-    assert (residual != 0).any()  # error feedback accumulated something
+    # one device: the residual is the leaf form's slabs, together N long
+    slabs = [np.asarray(r) for r in t.state.opt_state.residual]
+    assert sum(r.size for r in slabs) == t.num_params
+    assert any((r != 0).any() for r in slabs)  # error feedback accumulated
     t2 = Trainer(cfg)
     assert t2.restore()
-    np.testing.assert_array_equal(
-        np.asarray(t2.state.opt_state.residual), residual
-    )
+    for got, want in zip(t2.state.opt_state.residual, slabs, strict=True):
+        np.testing.assert_array_equal(np.asarray(got), want)
     assert int(t2.state.step) == 5
     # resumed training continues without error
     t2.train(2)
     assert int(t2.state.step) == 7
+
+
+def _leaves(tree):
+    return [np.asarray(x) for x in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("flags", [
+    dict(), dict(momentum_correction=True),
+    dict(obs_counters=True, obs_layers=True)],
+    ids=["plain", "correction", "obs_layers"])
+def test_flat_checkpoint_of_one_device_restores_into_the_leaf_form(
+        tmp_path, flags):
+    """A one-device checkpoint from before the leaf form (the residual, its
+    v and u and the age buffer as [N] vectors; a sidecar that names no
+    form) restores into the slabs, one host-side reshape, and the run
+    continues bit-equal to an uninterrupted one."""
+    from gtopkssgd_tpu.optimizer import flat_residual
+
+    cfg = small_cfg(compression="gtopk", density=0.05,
+                    out_dir=str(tmp_path / "run"), **flags)
+    whole = Trainer(small_cfg(compression="gtopk", density=0.05, **flags))
+    whole.train(5)
+    t = Trainer(cfg)
+    t.train(3)
+    opt, params = t.state.opt_state, t.state.params
+    old = opt._replace(residual=flat_residual(opt.residual, params))
+    if flags.get("obs_layers"):
+        old = old._replace(telemetry=dict(
+            opt.telemetry, age=flat_residual(opt.telemetry["age"], params)))
+    n = t.num_params
+    assert all(r.shape == (n,) for r in jax.tree.leaves(old.residual))
+    t._ckpt.save(3, t.state._replace(opt_state=old),
+                 meta={"residual_p": 1})          # the older sidecar
+    t.close()
+    t2 = Trainer(cfg)
+    assert t2.restore() and int(t2.state.step) == 3
+    for got, want in zip(_leaves(t2.state.opt_state), _leaves(opt),
+                         strict=True):
+        np.testing.assert_array_equal(got, want)
+    t2.train(2)
+    for got, want in zip(_leaves(t2.state), _leaves(whole.state),
+                         strict=True):
+        np.testing.assert_array_equal(got, want)
+    # and what it saves now names its form and restores as it is
+    t2.save()
+    assert t2._ckpt.sidecar_meta() == {"residual_p": 1,
+                                       "residual_form": "slabs"}
+    t3 = Trainer(cfg)
+    assert t3.restore() and int(t3.state.step) == 5
+    for got, want in zip(_leaves(t3.state), _leaves(whole.state),
+                         strict=True):
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("old_p,new_p", [(1, 4), (4, 1)])
+def test_elastic_resume_between_one_device_and_a_mesh(tmp_path, old_p, new_p):
+    """P = 1 holds slabs and a mesh [P, N] rows: an elastic resume
+    re-partitions through the flat form both ways, and the pending
+    gradient mass is conserved (the rows' sum, coordinate by coordinate)."""
+    from gtopkssgd_tpu.optimizer import flat_residual
+
+    def cfg(p):
+        return small_cfg(nworkers=p, batch_size=4, compression="gtopk",
+                         density=0.05, elastic=True,
+                         out_dir=str(tmp_path / "run"))
+
+    def rows(t):
+        res = t.state.opt_state.residual
+        if t.p == 1:
+            assert isinstance(res, tuple)
+            return np.asarray(flat_residual(res, t.state.params))[None]
+        return np.asarray(res)
+
+    t = Trainer(cfg(old_p))
+    t.train(3)
+    t.save()
+    saved = rows(t)
+    assert saved.shape == (old_p, t.num_params) and saved.any()
+    t.close()
+    t2 = Trainer(cfg(new_p))
+    assert t2.restore() and int(t2.state.step) == 3
+    got = rows(t2)
+    assert got.shape == (new_p, t2.num_params)
+    if new_p > old_p:
+        np.testing.assert_array_equal(got[:old_p], saved)
+        assert not got[old_p:].any()
+    else:
+        np.testing.assert_allclose(got.sum(0), saved.sum(0), rtol=1e-6,
+                                   atol=1e-7)
+    t2.train(2)
+    assert int(t2.state.step) == 5
 
 
 def test_residual_sharding_multiworker_roundtrip(tmp_path):
