@@ -10,8 +10,8 @@ here is how they are computed:
   * the delta rule in its **chunked form**: inside a chunk of ``chunk``
     tokens the recurrence is a unit lower-triangular system, solved for all
     chunks at once on the MXU (the WY form: ``delta = U - W S``,
-    ``delta_chunks``, a sequence at a time), and only the d_k x d_v state
-    crosses chunks, in one ``lax.scan`` for the whole batch
+    ``delta_chunks``, ``GDN_SEQUENCES`` at a time), and only the d_k x d_v
+    state crosses chunks, in one ``lax.scan`` for the whole batch
     (``scan_chunks``; forward and, by autodiff through the same scan,
     backward). The chunks' algebra has two forms, chosen by ``delta_form``
     from what it observes (no flag): on a TPU at chunks of 64, heads of
@@ -20,6 +20,15 @@ here is how they are computed:
     blocks stay in VMEM and the triangular system is inverted by products;
     elsewhere XLA's products and ``triangular_solve``, the oracle of the
     kernels' tests (``forms`` says which compiled: ``delta_form``);
+  * a DeltaNet layer's depthwise causal convolution, SiLU and unit norms
+    in two forms as well, chosen by ``conv_form`` (no flag): on a TPU at
+    heads of whole lane rows and whole token blocks the two fused kernels
+    of ``ops/gdn_conv.py``, which read the projection's output in place
+    and write q, k and v in the layout the chunk kernels read; elsewhere
+    ``causal_conv`` and XLA's float32 passes, the oracle of the kernels'
+    tests (``forms`` says which compiled: ``conv_form``). Where both are
+    kernels a step's sequences go through them in one call each; in the
+    XLA forms a sequence at a time (``GDN_SEQUENCES``);
   * attention without the [B, H, S, S] scores ever in HBM at once
     (``models/decoder.py``'s ``blocked_causal_attention``): on a TPU at
     whole tiles the fused flash kernels of ``ops/flash_attention.py``,
@@ -72,6 +81,7 @@ from gtopkssgd_tpu.models.decoder import (
     blocked_causal_attention, decoder_shell, dense, query_block_of,
     rms_norm0, rotary)
 from gtopkssgd_tpu.ops import delta_chunks as delta_kernels
+from gtopkssgd_tpu.ops import gdn_conv as conv_kernels
 
 # The chunked delta rule's float32 products (module docstring, Precision).
 _mm = functools.partial(jnp.einsum, precision=HIGHEST)
@@ -107,13 +117,18 @@ PRESETS = {
 
 
 # Sequences whose convolution and chunk algebra are live at once in a
-# Gated DeltaNet layer. In the XLA form their float32 intermediates are what
-# fills the chip: 2.7 GB a sequence of 4,096 tokens at the published widths.
-# In the kernel form the chunks' blocks never leave VMEM and a sequence
-# costs the convolution's [4096, 8192] float32 passes, 0.42 GB: the cell's
-# step reads 13.62 GB at 1, 14.04 at 2 and 14.93 at all 4 (compiled for a
-# described v5e, PR 38), against a line of 14.5; the loop stays.
-GDN_SEQUENCES = 1
+# Gated DeltaNet layer, by the form of the two. In the XLA form their
+# float32 intermediates are what fills the chip: 2.7 GB a sequence of 4,096
+# tokens at the published widths, so one at a time, in a ``lax.map``. With
+# the chunks' blocks in VMEM (PR 38) a sequence still cost the convolution's
+# [4096, 8192] float32 passes, 0.42 GB (the cell's step read 13.62 GB at 1,
+# 14.04 at 2 and 14.93 at all 4 against a line of 14.5). With the
+# convolution, SiLU and norms in kernels too (PR 43) those passes do not
+# exist: the step reads 14.02 GB at 1, 13.75 at 2 and 13.75 at all 4
+# (compiled for a described v5e), so the cell's four sequences go through
+# both kernels in one call each and the loop, its stacking and its slices
+# are gone.
+GDN_SEQUENCES = {"xla": 1, "kernel": 4}
 
 # What a layer's remat keeps from its forward to its backward pass, by
 # ``checkpoint_name``: the stacked outputs of ``GatedDeltaNet``'s ``prepare``
@@ -199,6 +214,44 @@ def causal_conv(x, kernel):
     width, length = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
     return sum(kernel[i] * padded[:, i:i + length] for i in range(width))
+
+
+def conv_form(length, key_width, value_width, d_k):
+    """``kernel`` where a Gated DeltaNet layer's convolution, SiLU and unit
+    norms run as the two Pallas kernels of ``ops/gdn_conv.py``, ``xla`` where
+    as ``causal_conv`` and XLA's float32 passes: the kernels need a TPU,
+    q, k (``key_width`` channels each, heads of ``d_k``) and v
+    (``value_width``) of whole 128-lane heads, and a length of whole token
+    blocks."""
+    width = 2 * key_width + value_width
+    whole = conv_kernels.blocks_of(
+        length, width + value_width, width, key_width, d_k) is not None
+    return "kernel" if decoder.on_tpu() and whole else "xla"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def kernel_conv(x, taps, key_width, d_k):
+    """The convolution, SiLU, split and unit norms of ``prepare`` in kernels
+    (interpret mode off the TPU): x [B, S, >= C] in any float dtype, read in
+    place (the columns past the taps' C are the layer's output gate), to
+    float32 q, k [B, S, key_width] (q over sqrt(d_k)) and v [B, S, the
+    rest], in the layout ``kernel_delta_chunks`` reads."""
+    return conv_kernels.forward(x, taps, key_width=key_width, head=d_k,
+                                interpret=not decoder.on_tpu())
+
+
+def _kernel_conv_fwd(x, taps, key_width, d_k):
+    # The residuals are the inputs: the backward kernel makes the
+    # convolution, the SiLU and the norms again in VMEM.
+    return kernel_conv(x, taps, key_width, d_k), (x, taps)
+
+
+def _kernel_conv_bwd(key_width, d_k, residuals, cotangents):
+    return conv_kernels.backward(*residuals, *cotangents, key_width=key_width,
+                                 head=d_k, interpret=not decoder.on_tpu())
+
+
+kernel_conv.defvjp(_kernel_conv_fwd, _kernel_conv_bwd)
 
 
 # ----------------------------------------------------- chunked delta rule
@@ -362,9 +415,11 @@ class GatedDeltaNet(nn.Module):
         w_out = self.param("out_proj", _normal(), (val_w, d), F32)
 
         batch, length = x.shape[:2]
-        group = math.gcd(batch, GDN_SEQUENCES)
         chunk = chunk_of(s["seq_len"])
         kernels = delta_form(length, chunk, d_k, d_v) == "kernel"
+        fused = conv_form(length, key_w, val_w, d_k) == "kernel"
+        group = math.gcd(batch, GDN_SEQUENCES[
+            "kernel" if fused and kernels else "xla"])
         # The kernels read q and k by key head; the XLA form by value head.
         repeat = (lambda a: a) if kernels else (
             lambda a: jnp.repeat(a, h_v // h_k, axis=2))
@@ -377,15 +432,20 @@ class GatedDeltaNet(nn.Module):
             qkv, ba = args
             with jax.named_scope("layer/gdn_proj"):
                 ba = ba.astype(F32)
-                qkv = jax.nn.silu(causal_conv(qkv.astype(F32), conv))
                 heads = lambda a, n, w: a.reshape(group, length, n, w)
-                q = heads(qkv[..., :key_w], h_k, d_k)
-                k = heads(qkv[..., key_w:2 * key_w], h_k, d_k)
-                v = heads(qkv[..., 2 * key_w:], h_v, d_v)
-                unit = lambda a: a * lax.rsqrt(
-                    jnp.sum(a * a, -1, keepdims=True) + 1e-6)
-                q = repeat(unit(q) / math.sqrt(d_k))
-                k = repeat(unit(k))
+                if fused:
+                    q, k, v = kernel_conv(qkv, conv, key_w, d_k)
+                    q, k = (repeat(heads(a, h_k, d_k)) for a in (q, k))
+                    v = heads(v, h_v, d_v)
+                else:
+                    qkv = jax.nn.silu(causal_conv(qkv.astype(F32), conv))
+                    q = heads(qkv[..., :key_w], h_k, d_k)
+                    k = heads(qkv[..., key_w:2 * key_w], h_k, d_k)
+                    v = heads(qkv[..., 2 * key_w:], h_v, d_v)
+                    unit = lambda a: a * lax.rsqrt(
+                        jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+                    q = repeat(unit(q) / math.sqrt(d_k))
+                    k = repeat(unit(k))
                 beta = jax.nn.sigmoid(ba[..., :h_v])
                 g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., h_v:] + dt_bias)
             with jax.named_scope("layer/gdn_scan"):
@@ -393,17 +453,22 @@ class GatedDeltaNet(nn.Module):
                 return (kernel_delta_chunks if kernels else delta_chunks)(
                     *arrays, chunk)
 
-        grouped = lambda a: a.reshape((batch // group, group) + a.shape[1:])
         with jax.named_scope("layer/gdn_proj"):
             qkvz = dense(x, w_qkvz, dtype)
             ba = dense(x, w_ba, dtype)
-        prepared = lax.map(prepare, (grouped(qkvz[..., :2 * key_w + val_w]),
-                                     grouped(ba)))
-        with jax.named_scope("layer/gdn_scan"):
-            # [groups, n, group, H, C, ...] -> [n, B, H, C, ...]: the state
-            # crosses the chunks of every sequence in one scan.
+        # The kernels read qkvz's convolved columns in place.
+        qkv = qkvz if fused else qkvz[..., :2 * key_w + val_w]
+        if group == batch:
+            prepared, whole = prepare((qkv, ba)), lambda a: a
+        else:
+            grouped = lambda a: a.reshape(
+                (batch // group, group) + a.shape[1:])
+            prepared = lax.map(prepare, (grouped(qkv), grouped(ba)))
+            # [groups, n, group, H, C, ...] -> [n, B, H, C, ...]
             whole = lambda a: jnp.moveaxis(a, 0, 1).reshape(
                 (a.shape[1], batch) + a.shape[3:])
+        with jax.named_scope("layer/gdn_scan"):
+            # The state crosses the chunks of every sequence in one scan.
             o = scan_chunks(*(checkpoint_name(whole(a), KEPT_CHUNKS)
                               for a in prepared))[:, :length]
         with jax.named_scope("layer/gdn_proj"):
@@ -505,10 +570,13 @@ class Qwen3Next(nn.Module):
         run's manifest and ``train`` records: a run on the chip that fell
         back to the blocked attention says so."""
         s = self.sizes
+        d_k, d_v = s["linear_key_head_dim"], s["linear_value_head_dim"]
         return {"attention_form": attention_form(length, s["head_dim"]),
                 "delta_form": delta_form(
-                    length, chunk_of(s["seq_len"]), s["linear_key_head_dim"],
-                    s["linear_value_head_dim"])}
+                    length, chunk_of(s["seq_len"]), d_k, d_v),
+                "conv_form": conv_form(
+                    length, s["linear_num_key_heads"] * d_k,
+                    s["linear_num_value_heads"] * d_v, d_k)}
 
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):

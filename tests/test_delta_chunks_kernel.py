@@ -21,6 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from gtopkssgd_tpu.models import decoder, qwen3_next  # noqa: E402
 from gtopkssgd_tpu.ops import delta_chunks as kernels  # noqa: E402
+from gtopkssgd_tpu.ops import gdn_conv  # noqa: E402
 from tests.test_flash_attention_kernel import (  # noqa: E402
     pallas_calls, rel, tiny_step)
 from tests.test_qwen3_next import delta_inputs, recurrence  # noqa: E402
@@ -125,7 +126,7 @@ def test_the_inverse_by_products_is_substitutions(kind, base, monkeypatch):
     assert gap <= 2e-6 * float(jnp.max(jnp.abs(want))), gap
 
 
-@pytest.mark.parametrize("length", [256, 150])
+@pytest.mark.parametrize("length", [128, 100])
 def test_the_delta_rule_through_the_kernels_equals_the_recurrence(
         length, monkeypatch):
     """``chunked_delta_rule`` in its kernel form, forward and gradients,
@@ -141,8 +142,8 @@ def test_the_delta_rule_through_the_kernels_equals_the_recurrence(
         jax.make_jaxpr(rule)(*args).jaxpr)
     assert np.max(np.abs(np.asarray(rule(*args)) - want)) < 1e-5
     weight = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-    pull = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
-                               argnums=(0, 1, 2, 3, 4))(*args)
+    pull = lambda fn: jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2, 3, 4)))(*args)
     for mine, theirs in zip(pull(rule), pull(ref.delta_rule)):
         assert float(jnp.max(jnp.abs(mine - theirs))) < 1e-5
 
@@ -176,23 +177,35 @@ def test_the_delta_form_follows_the_backend_and_the_shapes(
 def test_the_models_forms_name_the_delta_form(preset, length, monkeypatch):
     model = qwen3_next.Qwen3Next(preset)
     assert model.forms(length) == {"attention_form": "blocked",
-                                   "delta_form": "xla"}
+                                   "delta_form": "xla", "conv_form": "xla"}
     monkeypatch.setattr(decoder, "on_tpu", lambda: True)
     on_chip = "xla" if preset == "tiny" else "kernel"
     assert model.forms(length)["delta_form"] == on_chip
+    assert model.forms(length)["conv_form"] == on_chip
 
 
 def test_tiny_through_the_kernels_is_the_same_model(monkeypatch):
-    """``tiny`` (heads of 16, chunks of 32, two value heads a key head)
-    through the kernels in interpret mode: loss and every leaf's gradient
-    are the XLA form's to float32 rounding; a step holds one forward and one
-    backward kernel a DeltaNet layer (``prepare``'s own checkpoint and the
-    layer's replay run no forward kernel again: the backward kernel takes
-    the inputs, and the outputs are kept by name) and no triangular solve."""
+    """``tiny`` (heads of 16, chunks of 32, two value heads a key head; cut
+    to one DeltaNet layer and the attention layer, which is what the counts
+    below are of) through the kernels in interpret mode, the convolution's
+    (``ops/gdn_conv.py``, its lane rule brought down to the heads of 16)
+    and the chunks': loss and every leaf's gradient are the XLA forms' to
+    float32 rounding; a step holds one forward and one backward chunk
+    kernel a DeltaNet layer (``prepare``'s own checkpoint and the layer's
+    replay run no forward kernel again: the backward kernel takes the
+    inputs, and the outputs are kept by name), the convolution's forward
+    kernel twice (``prepare``'s checkpoint makes q, k and v again for the
+    chunk kernel's backward) and its backward once, and no triangular
+    solve."""
+    monkeypatch.setitem(qwen3_next.PRESETS, "tiny", dict(
+        qwen3_next.PRESETS["tiny"], num_hidden_layers=2,
+        full_attention_interval=2))
     module = qwen3_next.Qwen3Next("tiny")
     grad, params = tiny_step(module, 128)
     (loss_x, _), grads_x = jax.jit(grad)(params)
     monkeypatch.setattr(qwen3_next, "delta_form", lambda *a: "kernel")
+    monkeypatch.setattr(qwen3_next, "conv_form", lambda *a: "kernel")
+    monkeypatch.setattr(gdn_conv, "LANES", 16)
     jax.clear_caches()          # or the second trace is the first's
     grad, _ = tiny_step(module, 128)
     (loss, _), grads = jax.jit(grad)(params)
@@ -202,27 +215,31 @@ def test_tiny_through_the_kernels_is_the_same_model(monkeypatch):
         assert rel(a, b) < 1e-4, (jax.tree_util.keystr(path), rel(a, b))
     jaxpr = jax.make_jaxpr(grad)(params)
     assert {name: len(grids) for name, grids in pallas_calls(
-        jaxpr.jaxpr).items()} == {"delta_chunks_forward": 3,
-                                  "delta_chunks_backward": 3}
+        jaxpr.jaxpr).items()} == {
+            "delta_chunks_forward": 1, "delta_chunks_backward": 1,
+            "gdn_conv_forward": 2, "gdn_conv_backward": 1}
     assert "triangular_solve" not in str(jaxpr)
     jax.clear_caches()
 
 
 def test_the_runs_records_name_the_delta_form(tmp_path):
-    """``delta_form`` in the manifest and in every ``train`` record (``xla``
-    here: the CPU), beside ``attention_form``, and in no other record."""
+    """``delta_form`` and ``conv_form`` in the manifest and in every
+    ``train`` record (``xla`` here: the CPU), beside ``attention_form``,
+    and in no other record."""
     from gtopkssgd_tpu.trainer import TrainConfig, Trainer
 
+    forms = {"attention_form": "blocked", "delta_form": "xla",
+             "conv_form": "xla"}
     with Trainer(TrainConfig(dnn="qwen3_next", dataset="tokens",
                              model_preset="tiny", batch_size=2,
                              compression="gtopk", density=0.01,
                              log_interval=1, out_dir=str(tmp_path))) as t:
-        assert t._model_forms == {"attention_form": "blocked",
-                                  "delta_form": "xla"}
+        assert t._model_forms == forms
         t.train(2)
     rows = [json.loads(line) for line in open(tmp_path / "metrics.jsonl")]
     named = [r for r in rows if r["kind"] in ("manifest", "train")]
     assert [r["kind"] for r in named] == ["manifest", "train", "train"]
-    assert all(r["delta_form"] == "xla" for r in named)
-    assert not any("delta_form" in r for r in rows
+    assert all(r[form] == "xla" for r in named
+               for form in ("delta_form", "conv_form"))
+    assert not any(form in r for r in rows for form in forms
                    if r["kind"] not in ("manifest", "train"))
