@@ -2,14 +2,12 @@
 for benchmarks/results/warmup_ab_cpu_mesh8.json.
 
 Round 2 committed that artifact without its generator; this script makes
-every arm reproducible and adds the ``restore_rejected_u_ablation`` arm the
-momentum-correction masking NOTE in optimizer.py cites: identical to
-``momentum_correction_cold_start`` except a locally-picked but
-globally-rejected coordinate's velocity u is RESTORED alongside its repaired
-residual value (``TrainConfig.restore_rejected_u`` → the optimizer's
-``_restore_rejected_u`` ablation knob). The shipped semantics mask u at the
-LOCAL selection; this arm measures the alternative so the design choice is
-backed by a committed number, not a claim.
+its arms reproducible. The artifact's two ``*restore_rejected_u_ablation``
+entries (a locally-picked but globally-rejected coordinate's velocity u
+RESTORED alongside its repaired residual value; measured worse, so the
+shipped semantics mask u at the LOCAL selection — the NOTE in optimizer.py)
+stay as the record of that decision: the option that produced them is gone
+(PR 44), so those two entries can no longer be regenerated from this tree.
 
 Protocol (unchanged from the round-2 capture): 8-way SPMD over a virtual CPU
 mesh (REAL collectives), ResNet-20 / synthetic CIFAR, rho=0.001, batch
@@ -17,7 +15,7 @@ mesh (REAL collectives), ResNet-20 / synthetic CIFAR, rho=0.001, batch
 eval at the end.
 
 Usage:
-  python benchmarks/warmup_ab.py --arms restore_rejected_u_ablation
+  python benchmarks/warmup_ab.py --arms cold_start
 Arms merge into the existing artifact (existing entries are preserved).
 
 The 8-way virtual CPU mesh is forced IN-SCRIPT (force_cpu_mesh, as
@@ -52,13 +50,6 @@ ARMS = {
     "momentum_correction_cold_start": {"momentum_correction": True},
     "layerwise_momentum_correction_cold_start": {
         "compression": "gtopk_layerwise", "momentum_correction": True},
-    "restore_rejected_u_ablation": {
-        "momentum_correction": True, "restore_rejected_u": True},
-    # Task-5 diagnostic (round-3): is the layerwise x correction deficit
-    # caused by local masking chopping tiny-leaf velocities every step?
-    "layerwise_restore_rejected_u_ablation": {
-        "compression": "gtopk_layerwise", "momentum_correction": True,
-        "restore_rejected_u": True},
 }
 
 
@@ -101,7 +92,7 @@ def run_arm(name: str, args) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arms", default="restore_rejected_u_ablation",
+    ap.add_argument("--arms", default="cold_start",
                     help=f"comma list from {sorted(ARMS)}")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--density", type=float, default=0.001)

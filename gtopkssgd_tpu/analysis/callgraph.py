@@ -59,8 +59,10 @@ DEFAULT_SEEDS: Tuple[Tuple[str, str], ...] = (
     # Module contract: every function runs inside a shard_map body.
     ("parallel/collectives.py", r".*"),
     # Installed as the GradientTransformation the jitted step calls.
-    ("optimizer.py", r"^(update_fn|layerwise_update|init_fn"
-                     r"|sparse_branch|dense_branch)$"),
+    # (update_fn reaches the three forms' functions by name; their
+    # branches are also handed over as values, so they are named here.)
+    ("optimizer.py", r"^(update_fn|init_fn|(flat|leaves|slabs)_form"
+                     r"|(bucketed_)?sparse_branch|dense_branch)$"),
     # Wire codec encode/decode run inside every exchange round.
     ("parallel/codec.py", r"^(encode|decode)$"),
 )

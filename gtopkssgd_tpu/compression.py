@@ -213,7 +213,7 @@ class TopKCompressor:
     ) -> Tuple[Array, Array, Array]:
         """Mask-form selection for paths that need no wire format, over
         ONE vector: a leaf or a bucket of the layerwise mode at P = 1
-        (optimizer.layerwise_update). The flat modes' one-device step no
+        (optimizer.py's leaves form). The flat modes' one-device step no
         longer builds the [N] vector this once read; its twin over leaves
         is ``compress_leaves_by_threshold`` below, same rules.
 

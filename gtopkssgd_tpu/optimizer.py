@@ -32,7 +32,7 @@ stack, SURVEY.md §3.1), where the wire's (vals, idx) sets index one vector:
     updates         = SGD(momentum, wd) on dense update   # inner optimizer
 
 The dense modes flatten too (one psum of one vector). On ONE device
-(``leaf_update``, every flat sparse mode at P = 1) nothing is sent, so no
+(the **slabs** form, every flat sparse mode at P = 1) nothing is sent, so no
 [N] vector is made: the same mathematics, one k = rho * N and one
 threshold tau over the whole gradient, runs on the gradient's own leaves,
 
@@ -46,12 +46,17 @@ and the state holds r (and v, u, the age buffer) in that form. What still
 sees [N]: the mesh and dense paths above, the recall audit's taken branch,
 the threshold search of the methods other than exact / approx / auto
 (ops.select_tau_leaves), and a state made with a mesh axis named but run
-unbound, which ``leaf_update`` cuts into leaves and joins again.
+unbound, which the slabs form cuts into slabs and joins again.
+
+The step itself is written once (``update_fn``): split, clip, the
+correction's velocity, dense warm-up or select-and-reduce, join, the inner
+optimizer, the counters. What the three layouts above answer differently
+(the [N] vector, ``gtopk_layerwise``'s leaves, the slabs) is a ``_Form``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional, Union
+from typing import Any, Callable, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +124,50 @@ class GTopKSGDState(NamedTuple):
     telemetry: Any = ()
 
 
+def _same(buffer):
+    return buffer
+
+
+class _Form(NamedTuple):
+    """The layout one optimizer step works the gradient in: the one thing
+    in which the step's three forms differ. ``update_fn`` picks one from
+    the mode and the bound axis size; no option names them.
+
+      flat    one [N] vector (``ravel_pytree``): the dense modes at any P,
+              the sparse modes at P > 1 (the index form a wire needs);
+      leaves  every leaf reshaped flat, per-leaf k: ``gtopk_layerwise``;
+      slabs   compression.LeafPlan's slabs, one k and one tau: the flat
+              sparse modes on one device.
+
+    **parts** is the gradient in the form's layout (one array, or a tuple
+    of arrays); a **buffer** is one of the state's (the residual, v, u,
+    the age) in the same layout."""
+
+    n: int                   # the gradient's elements
+    sizes: Sequence[int]     # of its leaves, the tree's order
+    k: int                   # what the wire model is told a step sends
+    split: Callable          # grads -> (parts, join); join(parts) -> tree
+    sq_norm: Callable        # parts -> sum of squares, in the form's own
+    #                          order of float32 additions (the clip's norm)
+    sparse_branch: Callable  # (srcs, res_in, us) -> (dense parts, residual,
+    #                          u) and, with telemetry, (counters,)
+    dense_mean: Callable     # srcs -> their mean over the mesh axis
+    layer_l2: Callable       # parts or a buffer -> f32[L] norms by leaf
+    layer_age: Callable      # the age buffer -> assemble_layer_telemetry's
+    #                          age (and seg) arguments
+    plan: Any = None         # the wire plan (parallel.planner), if a wire
+    buckets: Any = None      # ((n_b, k_b), ...) of a bucketed wire
+    state_in: Callable = _same   # a buffer as the state holds it -> the
+    state_out: Callable = _same  # form's layout, and back
+
+
+def _each_buffer(fn, residual):
+    """``fn`` over the state's buffers: the residual alone, or momentum
+    correction's v and u (a tuple of parts is one buffer)."""
+    return jax.tree.map(fn, residual,
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
 def gtopk_sgd(
     learning_rate: ScalarOrSchedule,
     *,
@@ -141,7 +190,6 @@ def gtopk_sgd(
     telemetry: bool = False,
     telemetry_layers: bool = False,
     telemetry_audit_interval: int = 0,
-    _restore_rejected_u: bool = False,
 ) -> optax.GradientTransformation:
     """Build the distributed gTop-k S-SGD gradient transformation.
 
@@ -267,13 +315,8 @@ def gtopk_sgd(
     ~1/rho steps. Under gTop-k, masking follows the LOCAL selection: a
     locally-picked but globally-rejected coordinate keeps its VALUE in
     the residual (the error-feedback repair) but its velocity u stays
-    masked. One could argue the velocity should survive too (nothing was
-    transmitted), but the measured ablation says no — restoring u
-    double-tracks the same mass (v += u while u compounds) and
-    persistently-rejected coordinates blow up; see the
-    ``restore_rejected_u_ablation`` entry of
-    benchmarks/results/warmup_ab_cpu_mesh8.json and the NOTE at the
-    repair site below. During a
+    masked (the measured decision is in the NOTE at the flat form's repair
+    below). During a
     ``warmup_dense_steps`` phase the DENSE mean of u is communicated,
     which is algebraically identical to classic momentum-SGD on the mean
     gradient (mean is linear in u) — exactly the dense baseline at
@@ -357,9 +400,6 @@ def gtopk_sgd(
             raise ValueError(
                 "momentum_correction defines its own velocity recursion; "
                 "nesterov is not expressible in it")
-    if _restore_rejected_u and not correction:
-        raise ValueError("_restore_rejected_u is a momentum_correction "
-                         "ablation knob; it needs momentum_correction=True")
     if correction and layerwise:
         import warnings
 
@@ -378,12 +418,14 @@ def gtopk_sgd(
             "out a semantics fix) — prefer one or the other",
             stacklevel=2)
     # The residual's form. With no mesh axis named, a flat mode's step runs
-    # on one device (leaf_update) and the state itself is in that step's
+    # on one device (slabs_form) and the state itself is in that step's
     # form, the slabs of compression.LeafPlan: known when the state is
     # made. With an axis named and unbound, or bound at size 1, the state
     # is the flat [N] that a P > 1 step of the same transformation reads,
-    # and leaf_update cuts it into slabs and joins it again.
+    # and slabs_form cuts it into slabs and joins it again.
     slab_state = leaf_form_state(mode, axis_name)
+    # The slice width the wire and its model are told: 1 but in hier mode.
+    ici = hier_ici_size if hier else 1
     compressor = get_compressor(mode, density=density, method=topk_method)
     # Validate the codec spec at build time (bad --wire-codec fails here,
     # not inside the jitted step); the instance is reused every step.
@@ -511,14 +553,146 @@ def gtopk_sgd(
             }
         return tel
 
-    def layerwise_update(grads, state: GTopKSGDState, params=None):
-        """Per-layer select/feedback; global reduce on the concatenated set.
+    def selection_counters(scalars, layer_stats, recall):
+        """A branch's counters, as the tail (``(dict,)`` or ``()``) of what
+        it returns: the same keys from the sparse and the dense branch, so
+        that both arms of the warm-up ``lax.cond`` have one structure. The
+        arguments are thunks (``scalars() -> (tau, sent, m_k)``): each
+        runs only where telemetry, telemetry_layers or the audit asks."""
+        if not telemetry:
+            return ()
+        tau, sent, m_k = scalars()
+        tel = {"tau": tau, "sent": sent, "m_k": m_k}
+        if telemetry_layers:
+            tel["lsel"], _ = layer_stats()
+        if audit:
+            tel["recall"] = recall()
+        return (tel,)
 
-        Mirrors the flat update_fn pipeline stage for stage; differs only
-        in WHERE selection and error feedback live (one buffer per layer,
-        never one [N] vector). Leaf order is jax.tree.flatten order of the
-        grads pytree, which init_fn used for the residual, so the two
-        always align."""
+    def dense_counters(form):
+        """The dense phase's counters (the dense modes, and a sparse
+        mode's warm-up): no threshold, everything sent, full mass capture,
+        nothing to audit."""
+        return selection_counters(
+            lambda: (jnp.float32(0.0), jnp.float32(form.n),
+                     jnp.float32(1.0)),
+            lambda: obs_counters.dense_phase_selection_stats(form.sizes),
+            lambda: jnp.float32(-1.0))
+
+    def audited(count, exact_recall):
+        """Sampled exact-vs-production recall: ``exact_recall()`` takes the
+        exact top-k of the accumulator as ground truth and compares it with
+        the production selection. It (and whatever [N] operands it builds)
+        exists only inside the cond's taken branch: a step that is not
+        audited pays nothing and reports -1."""
+        return lax.cond((count % telemetry_audit_interval) == 0,
+                        exact_recall, lambda: jnp.float32(-1.0))
+
+    def flat_form(grads, count, p) -> _Form:
+        """One [N] vector: the dense modes, and the index form the wire
+        needs at P > 1 (a sparse mode comes here only with a wire)."""
+        sizes = obs_counters.layer_sizes(grads)
+        n = sum(sizes)
+        n_layers = len(sizes)
+        # Static trace-time layer structure: ravel_pytree flattens in
+        # jax.tree order, so the segment map addresses the same leaves
+        # obs_counters.layer_names reports.
+        seg = obs_counters.segment_ids(sizes) if telemetry_layers else None
+        # The wire plan named at build time; the dense modes have no
+        # sparse wire to plan.
+        plan = (None if dense_mode else
+                resolve_plan(mode, comm_plan, codec=codec_spec, ici_size=ici))
+
+        def dense_mean(src):
+            # In hier mode the input is already the within-slice SUM
+            # (ici_dense_psum in update_fn), so a full-axis psum counts
+            # every original gradient hier_ici_size times — divide it back
+            # out or every warm-up step trains at an ici_size-inflated
+            # effective LR.
+            reduced = (dense_allreduce(src, axis_name=axis_name)
+                       if p > 1 else src)
+            return reduced / (p * ici)
+
+        def sparse_branch(src, residual_in, u_in):
+            acc = compressor.accumulate(src, residual_in)
+            vals, idx, residual = compressor.compress(
+                acc, grad=src, residual=residual_in)
+            if codec.lossy and mode != "topk":
+                # Fold the wire quantization error into the
+                # error-feedback residual and ship the requantized
+                # values: the residual repair below then restores vq +
+                # folded error = the exact original for rejected picks,
+                # and telemetry (tau/sent/mass) describes what actually
+                # went on the wire. (mode 'topk' allgathers the exact
+                # local picks — its codec path quantizes in
+                # topk_allgather and every pick is delivered, so there is
+                # nothing to repair and the small symmetric error is left
+                # to the next step's selection, like any dense rounding.)
+                vq = roundtrip_aligned(codec, vals, idx, n=n)
+                residual = compressor.fold_wire_error(
+                    residual, idx, vals - vq)
+                vals = vq
+
+            def exact_recall():
+                ev, ei = topk_abs(acc, compressor.k(n))
+                return obs_counters.topk_recall(membership_mask(ei, idx), ev)
+
+            # Selection stats describe the LOCAL selection (what this
+            # device put on the wire); the pmean in _finish_telemetry
+            # turns them into axis means.
+            tel = selection_counters(
+                lambda: (obs_counters.selected_tau(vals),
+                         obs_counters.sent_count(vals),
+                         obs_counters.mass_ratio(acc, vals)),
+                lambda: obs_counters.sparse_selection_layer_stats(
+                    acc, vals, idx, seg, n_layers),
+                lambda: audited(count, exact_recall))
+            # Momentum factor masking: a DELIVERED coordinate's velocity
+            # restarts (its momentum was consumed); without this the same
+            # mass re-sends for ~1/momentum more steps. For the allgather
+            # union every local pick is delivered, so masking at the
+            # local selection is exact.
+            u_out = (u_in.at[idx].set(0.0, mode="drop")
+                     if correction else u_in)
+            result, gidx, needs_repair = sparse_allreduce(
+                mode, vals, idx, k=compressor.k(n), n=n,
+                axis_name=axis_name, axis_size=p, ici_size=ici,
+                codec=codec, plan=plan,
+            )
+            if needs_repair:  # gtopk: sparse set + repair
+                residual = compressor.repair(residual, vals, idx, gidx)
+                dense = scatter_add_dense(n, gidx, result) / p
+                # NOTE (measured design decision): under gTop-k a local
+                # pick can be globally REJECTED; one could argue its
+                # velocity should survive (nothing was transmitted).
+                # Measured ablation says NO: the repair above already
+                # preserves the rejected VALUE in v, so also keeping u
+                # double-tracks the same mass (v += u while u compounds)
+                # and persistently-rejected coordinates blow up — see
+                # restore_rejected_u_ablation in the
+                # warmup_ab_cpu_mesh8.json artifact. The local mask above
+                # is the stable generalization, here and in the leaves
+                # form (where per-leaf ceil rounding makes tiny leaves
+                # pick, and usually get rejected, EVERY step).
+            else:  # allgather union: dense, every pick lands
+                dense = result / p
+            return (dense, residual, u_out) + tel
+
+        return _Form(
+            n=n, sizes=sizes, k=n if dense_mode else compressor.k(n),
+            plan=plan, split=ravel_pytree,
+            sq_norm=lambda flat: jnp.sum(flat * flat),
+            sparse_branch=sparse_branch, dense_mean=dense_mean,
+            layer_l2=lambda x: obs_counters.seg_l2(x, seg, n_layers),
+            layer_age=lambda age: dict(age=age, seg=seg))
+
+    def leaves_form(grads, count, p) -> _Form:
+        """Every leaf reshaped flat (``gtopk_layerwise``): per-layer
+        select and error feedback, one buffer a layer and never one [N]
+        vector; the global reduce runs on the concatenated set, or bucket
+        by bucket. Leaf order is jax.tree.flatten order of the grads
+        pytree, which init_fn used for the residual, so the two always
+        align."""
         leaves, treedef = jax.tree.flatten(grads)
         sizes = [int(leaf.size) for leaf in leaves]
         ks = [k_for_density(s, density) for s in sizes]
@@ -528,71 +702,66 @@ def gtopk_sgd(
             off += s
         n = off
         kk_total = sum(ks)
-        with jax.named_scope("gtopk/flatten"):
-            flats = [leaf.reshape(-1) for leaf in leaves]
-        if clip_grad_norm is not None:
-            # Same clip-BEFORE-compress order as the flat path; the global
-            # norm is a sum of per-leaf sums — no concatenation needed.
-            with jax.named_scope("gtopk/clip"):
-                gnorm = jnp.sqrt(sum(jnp.sum(f * f) for f in flats))
-                scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
-                flats = [f * scale for f in flats]
-        p = bound_axis_size()
-        # Bucket partition for this (leaf_sizes, density, p, codec) —
-        # the alpha-beta DP of parallel.bucketing; None under the
-        # historical 'concat' wire. Resolved host-side at trace time
-        # (the DP table is memoized), so boundaries are static
-        # structure from here on, like offsets and ks.
+        # Bucket partition for this (leaf_sizes, density, p, codec) — the
+        # alpha-beta DP of parallel.bucketing; None under the historical
+        # 'concat' wire. Resolved host-side at trace time (the DP table is
+        # memoized), so boundaries are static structure from here on, like
+        # offsets and ks.
         bplan = (plan_buckets(tuple(sizes), density, buckets=bucket_spec,
                               p=p, codec=codec_spec, mode=mode,
                               pipeline=pipeline_spec)
                  if bucket_spec != "concat" else None)
-        wire_k_total = bplan.k_total if bplan is not None else kk_total
-        # Resolved execution order for the bucketed stage loop below —
-        # plan_buckets decided an 'auto' spec against the span model;
-        # the concat wire has no stage loop and is serial by
-        # construction.
-        pipe = bplan.pipeline if bplan is not None else "serial"
         # The wire plan named at build time; None at p=1 (no wire).
         plan = (resolve_plan(mode, comm_plan, codec=codec_spec)
                 if p > 1 else None)
 
-        if correction:
-            res_in = state.residual["v"]
-            us = tuple(momentum * u + f
-                       for u, f in zip(state.residual["u"], flats))
-            srcs = list(us)
-        else:
-            res_in = state.residual
-            us = ()
-            srcs = flats
+        def split(grads):
+            def join(dense_fl):
+                return treedef.unflatten([
+                    d.reshape(leaf.shape)
+                    for d, leaf in zip(dense_fl, leaves)])
 
-        def _audit_recall(accs, hits_fn):
-            """Sampled exact-vs-production recall: exact top-kk_total of
-            the concatenated accumulator as ground truth, compared
-            against the production selection via ``hits_fn(exact_idx) ->
-            bool[k]`` membership. The concatenation and exact top-k only
-            exist inside the cond's taken branch — non-audit steps pay
-            nothing."""
-            def _do():
-                ev, ei = topk_abs(jnp.concatenate(accs), kk_total)
-                return obs_counters.topk_recall(hits_fn(ei), ev)
+            return tuple(g.reshape(-1) for g in jax.tree.leaves(grads)), join
 
-            return lax.cond(
-                (state.count % telemetry_audit_interval) == 0,
-                _do, lambda: jnp.float32(-1.0))
+        def threshold_counters(sel, keeps, accs, dense_parts, layer_parts,
+                               exact_recall):
+            """Counters of the P = 1 threshold form, over leaves or over
+            buckets: the whole-model tau from the per-part kept-taus the
+            compressor already reduced (a part with a nonempty keep set
+            always has tau > 0 — zeros never pass)."""
+            def scalars():
+                taus = jnp.stack([t for _, _, t in sel])
+                kept = taus > 0
+                tau = jnp.where(
+                    jnp.any(kept),
+                    jnp.min(jnp.where(kept, taus, jnp.inf)), 0.0)
+                return (tau, sum(obs_counters.kept_count(m) for m in keeps),
+                        obs_counters.mass_ratio(accs, dense_parts))
+
+            return selection_counters(
+                scalars,
+                lambda: obs_counters.leafwise_selection_stats(*layer_parts()),
+                lambda: audited(count, exact_recall))
 
         def sparse_branch(srcs, res_in, us):
             accs = [s + r for s, r in zip(srcs, res_in)]
-            tel = ()
+
+            def exact_recall(hits_fn):
+                """Exact top-kk_total of the concatenated accumulator as
+                ground truth; ``hits_fn(exact_idx) -> bool[k]`` is
+                membership in the production selection."""
+                def _do():
+                    ev, ei = topk_abs(jnp.concatenate(accs), kk_total)
+                    return obs_counters.topk_recall(hits_fn(ei), ev)
+                return _do
+
             if p == 1:
-                # Threshold form of the per-leaf selection (see the flat
-                # path's p=1 branch and compress_by_threshold's
-                # docstring): each leaf's top-k_l becomes one small
-                # reduction for tau_l plus elementwise masks — dropping
-                # the per-leaf scatter+gather pairs, which at ~161
-                # leaves were ~2x161 extra kernels on the step. The
-                # per-leaf k = ceil(density * n_l) is exactly
+                # Threshold form of the per-leaf selection (see
+                # compress_by_threshold's docstring): each leaf's top-k_l
+                # becomes one small reduction for tau_l plus elementwise
+                # masks — dropping the per-leaf scatter+gather pairs,
+                # which at ~161 leaves were ~2x161 extra kernels on the
+                # step. The per-leaf k = ceil(density * n_l) is exactly
                 # compressor.k(n_l), so the shared helper applies
                 # unchanged leaf by leaf.
                 sel = [compressor.compress_by_threshold(
@@ -604,38 +773,18 @@ def gtopk_sgd(
                                for u, m in zip(us, keeps))
                          if correction else us)
                 dense_fl = [a - r for a, r in zip(accs, new_res)]
-                if telemetry:
-                    # Whole-model tau from the per-leaf kept-taus the
-                    # compressor already reduced (a leaf with a nonempty
-                    # keep set always has tau > 0 — zeros never pass).
-                    taus = jnp.stack([t for _, _, t in sel])
-                    kept = taus > 0
-                    tel = {
-                        "tau": jnp.where(
-                            jnp.any(kept),
-                            jnp.min(jnp.where(kept, taus, jnp.inf)), 0.0),
-                        "sent": sum(obs_counters.kept_count(m)
-                                    for m in keeps),
-                        "m_k": obs_counters.mass_ratio(accs, dense_fl),
-                    }
-                    if telemetry_layers:
-                        tel["lsel"], _ = (
-                            obs_counters.leafwise_selection_stats(
-                                accs, dense_fl))
-                    if audit:
-                        tel["recall"] = _audit_recall(
-                            accs,
-                            lambda ei: jnp.take(
-                                jnp.concatenate(keeps), ei, mode="clip"))
-                    tel = (tel,)
-                return (dense_fl, tuple(new_res), u_out) + tel
+                tel = threshold_counters(
+                    sel, keeps, accs, dense_fl, lambda: (accs, dense_fl),
+                    exact_recall(lambda ei: jnp.take(
+                        jnp.concatenate(keeps), ei, mode="clip")))
+                return (tuple(dense_fl), tuple(new_res), u_out) + tel
             sel = [select_topk(s, kl, topk_method, residual=r)
                    for s, r, kl in zip(srcs, res_in, ks)]
             idx_l = [i for _, i in sel]
             new_res = [a.at[i].set(0.0, mode="drop")
                        for a, i in zip(accs, idx_l)]
             # Momentum factor masking, per leaf, at the LOCAL selection
-            # (see the measured-ablation note on the flat path).
+            # (see the measured-ablation note in the flat form).
             u_out = (tuple(u.at[i].set(0.0, mode="drop")
                            for u, i in zip(us, idx_l))
                      if correction else us)
@@ -644,21 +793,25 @@ def gtopk_sgd(
                 (i + o).astype(jnp.int32)
                 for i, o in zip(idx_l, offsets)
             ])
+
+            def add_per_leaf(bufs, addend):
+                """Scatter a [kk_total] vector in concatenation order back
+                into the leaves' buffers: static [pos:pos+k_l] slices
+                address each leaf's candidates."""
+                out, pos = [], 0
+                for r, i, kl in zip(bufs, idx_l, ks):
+                    out.append(r.at[i].add(addend[pos:pos + kl], mode="drop"))
+                    pos += kl
+                return out
+
             if codec.lossy:
                 # Wire-error fold, layerwise twin: requantize the
                 # concatenated set, ship vq, and scatter the error back
-                # into each leaf's residual with the same static
-                # [pos:pos+k_l] slices the repair uses — the error is in
+                # into each leaf's residual — the error is in
                 # concatenation order because roundtrip_aligned returns
                 # original slot order.
                 vq = roundtrip_aligned(codec, vals, idx, n=n)
-                err = vals - vq
-                folded, pos = [], 0
-                for r, i, kl in zip(new_res, idx_l, ks):
-                    folded.append(
-                        r.at[i].add(err[pos:pos + kl], mode="drop"))
-                    pos += kl
-                new_res = folded
+                new_res = add_per_leaf(new_res, vals - vq)
                 vals = vq
             gvals, gidx, _ = sparse_allreduce(
                 mode, vals, idx, k=kk_total, n=n,
@@ -666,52 +819,24 @@ def gtopk_sgd(
                 plan=plan,
             )
             # Error-feedback repair, split back per leaf: put_back's layout
-            # IS the concatenation order, so static [pos:pos+k_l] slices
-            # address each leaf's candidates.
+            # IS the concatenation order. u stays masked at the full LOCAL
+            # selection even for globally-rejected picks.
             rejected = ~membership_mask(idx, gidx)
-            put_back = jnp.where(rejected, vals, 0.0)
-            repaired, pos = [], 0
-            for r, i, kl in zip(new_res, idx_l, ks):
-                repaired.append(
-                    r.at[i].add(put_back[pos:pos + kl], mode="drop"))
-                pos += kl
-            # u stays masked at the full LOCAL selection even for
-            # globally-rejected picks — see the measured-ablation note on
-            # the flat path (restoring u alongside the repaired value
-            # double-tracks the same mass and diverges). Layerwise raises
-            # the stakes: per-leaf ceil rounding makes tiny leaves pick
-            # (and usually get globally rejected) EVERY step, so local
-            # masking zeroes their velocity every step — the ablation
-            # knob below measures the alternative for exactly this case
-            # (warmup_ab layerwise arms).
-            if correction and _restore_rejected_u:
-                restored, pos = [], 0
-                for u_masked, u_orig, i, kl in zip(u_out, us, idx_l, ks):
-                    restored.append(u_masked.at[i].add(
-                        jnp.where(rejected[pos:pos + kl], u_orig[i], 0.0),
-                        mode="drop"))
-                    pos += kl
-                u_out = tuple(restored)
+            repaired = add_per_leaf(new_res, jnp.where(rejected, vals, 0.0))
             dense = scatter_add_dense(n, gidx, gvals) / p
-            dense_fl = [dense[o:o + s] for o, s in zip(offsets, sizes)]
-            if telemetry:
-                # Selection stats describe the LOCAL selection (what this
-                # device put on the wire), matching sent_elems /
-                # achieved_density semantics; the pmean in
-                # _finish_telemetry turns them into axis means.
-                tel = {
-                    "tau": obs_counters.selected_tau(vals),
-                    "sent": obs_counters.sent_count(vals),
-                    "m_k": obs_counters.mass_ratio(accs, vals),
-                }
-                if telemetry_layers:
-                    tel["lsel"], _ = (
-                        obs_counters.leafwise_sparse_selection_stats(
-                            accs, [v for v, _ in sel]))
-                if audit:
-                    tel["recall"] = _audit_recall(
-                        accs, lambda ei: membership_mask(ei, idx))
-                tel = (tel,)
+            dense_fl = tuple(dense[o:o + s] for o, s in zip(offsets, sizes))
+            # Selection stats describe the LOCAL selection (what this
+            # device put on the wire), matching sent_elems /
+            # achieved_density semantics; the pmean in _finish_telemetry
+            # turns them into axis means.
+            tel = selection_counters(
+                lambda: (obs_counters.selected_tau(vals),
+                         obs_counters.sent_count(vals),
+                         obs_counters.mass_ratio(accs, vals)),
+                lambda: obs_counters.leafwise_sparse_selection_stats(
+                    accs, [v for v, _ in sel]),
+                lambda: audited(count, exact_recall(
+                    lambda ei: membership_mask(ei, idx))))
             return (dense_fl, tuple(repaired), u_out) + tel
 
         def bucketed_sparse_branch(srcs, res_in, us):
@@ -748,19 +873,18 @@ def gtopk_sgd(
                     for s in sizes[lo:hi]:
                         out.append(buf[off:off + s])
                         off += s
-                return out
+                return tuple(out)
 
             bsrcs = bconcat(srcs)
             bres = bconcat(res_in)
             bus = bconcat(us) if correction else []
             accs = [s + r for s, r in zip(bsrcs, bres)]
 
-            def _bucket_audit(hits_fn_per_bucket):
-                """Exact-vs-production recall against the bucketed
-                ground truth: per-bucket exact top-k_b (the contract the
-                bucketed selection implements), hits concatenated into
-                one recall fraction. Only exists inside the cond's
-                taken branch."""
+            def exact_recall(hits_fn_per_bucket):
+                """Recall against the bucketed ground truth: per-bucket
+                exact top-k_b (the contract the bucketed selection
+                implements), hits concatenated into one recall
+                fraction."""
                 def _do():
                     hits, evs = [], []
                     for b, (a, kb) in enumerate(zip(accs, bks)):
@@ -769,12 +893,8 @@ def gtopk_sgd(
                         evs.append(ev)
                     return obs_counters.topk_recall(
                         jnp.concatenate(hits), jnp.concatenate(evs))
+                return _do
 
-                return lax.cond(
-                    (state.count % telemetry_audit_interval) == 0,
-                    _do, lambda: jnp.float32(-1.0))
-
-            tel = ()
             if p == 1:
                 # Threshold form per bucket (see sparse_branch's p=1
                 # note): compressor.k(n_b) == k_b by construction, so
@@ -788,33 +908,17 @@ def gtopk_sgd(
                             for u, m in zip(bus, keeps)]
                            if correction else [])
                 dense_b = [a - r for a, r in zip(accs, new_res)]
-                if telemetry:
-                    taus = jnp.stack([t for _, _, t in sel])
-                    kept = taus > 0
-                    tel = {
-                        "tau": jnp.where(
-                            jnp.any(kept),
-                            jnp.min(jnp.where(kept, taus, jnp.inf)), 0.0),
-                        "sent": sum(obs_counters.kept_count(m)
-                                    for m in keeps),
-                        "m_k": obs_counters.mass_ratio(accs, dense_b),
-                    }
-                    if telemetry_layers:
-                        # Per-leaf stats from per-leaf slices of the
-                        # bucket accumulator/selection — same values the
-                        # unbucketed path reduces, just sliced out of
-                        # the concatenations.
-                        tel["lsel"], _ = (
-                            obs_counters.leafwise_selection_stats(
-                                bsplit(accs), bsplit(dense_b)))
-                    if audit:
-                        tel["recall"] = _bucket_audit(
-                            lambda b, ei: jnp.take(
-                                keeps[b], ei, mode="clip"))
-                    tel = (tel,)
+                # Per-leaf stats from per-leaf slices of the bucket
+                # accumulator/selection — same values the unbucketed
+                # path reduces, just sliced out of the concatenations.
+                tel = threshold_counters(
+                    sel, keeps, accs, dense_b,
+                    lambda: (bsplit(accs), bsplit(dense_b)),
+                    exact_recall(lambda b, ei: jnp.take(
+                        keeps[b], ei, mode="clip")))
                 dense_fl = bsplit(dense_b)
-                res_fl = tuple(bsplit(new_res))
-                u_out = tuple(bsplit(u_out_b)) if correction else us
+                res_fl = bsplit(new_res)
+                u_out = bsplit(u_out_b) if correction else us
                 return (dense_fl, res_fl, u_out) + tel
             # --- Pipelined stage loop (--pipeline) ------------------
             # Each bucket is two stages: _select (the fused two-stage
@@ -868,13 +972,12 @@ def gtopk_sgd(
                 rejected = ~membership_mask(st["i"], gidx)
                 return dict(
                     st,
-                    rej=rejected,
                     res=st["res"].at[st["i"]].add(
                         jnp.where(rejected, st["v"], 0.0), mode="drop"),
                     dense=scatter_add_dense(bns[b], gidx, gvals) / p)
 
             outs = []
-            if pipe == "overlap" and B > 1:
+            if bplan.pipeline == "overlap" and B > 1:
                 # Double-buffered stage loop: bucket b+1's selection is
                 # issued with NO data dependency on bucket b's merge —
                 # the selection compute runs while the ppermute rounds
@@ -902,125 +1005,38 @@ def gtopk_sgd(
                     outs.append(out)
             idx_b = [o["i"] for o in outs]
             vals_b = [o["v"] for o in outs]
-            rejected_b = [o["rej"] for o in outs]
-            repaired = [o["res"] for o in outs]
-            dense_bufs = [o["dense"] for o in outs]
-            u_out_b = [o["u"] for o in outs] if correction else []
-            if correction and _restore_rejected_u:
-                # Ablation arm only — see the sparse_branch note.
-                u_out_b = [
-                    u_masked.at[i].add(
-                        jnp.where(rej, u_orig[i], 0.0), mode="drop")
-                    for u_masked, u_orig, i, rej in
-                    zip(u_out_b, bus, idx_b, rejected_b)]
-            dense_fl = bsplit(dense_bufs)
-            if telemetry:
-                tel = {
-                    "tau": obs_counters.selected_tau(
-                        jnp.concatenate(vals_b)),
-                    "sent": sum(obs_counters.sent_count(v)
-                                for v in vals_b),
-                    "m_k": obs_counters.mass_ratio(accs, vals_b),
-                }
-                if telemetry_layers:
-                    tel["lsel"], _ = (
-                        obs_counters.bucketed_sparse_selection_stats(
-                            accs, vals_b, idx_b, sizes,
-                            bplan.boundaries))
-                if audit:
-                    tel["recall"] = _bucket_audit(
-                        lambda b, ei: membership_mask(ei, idx_b[b]))
-                tel = (tel,)
-            res_fl = tuple(bsplit(repaired))
-            u_out = tuple(bsplit(u_out_b)) if correction else us
+            dense_fl = bsplit([o["dense"] for o in outs])
+            tel = selection_counters(
+                lambda: (obs_counters.selected_tau(jnp.concatenate(vals_b)),
+                         sum(obs_counters.sent_count(v) for v in vals_b),
+                         obs_counters.mass_ratio(accs, vals_b)),
+                lambda: obs_counters.bucketed_sparse_selection_stats(
+                    accs, vals_b, idx_b, sizes, bplan.boundaries),
+                lambda: audited(count, exact_recall(
+                    lambda b, ei: membership_mask(ei, idx_b[b]))))
+            res_fl = bsplit([o["res"] for o in outs])
+            u_out = bsplit([o["u"] for o in outs]) if correction else us
             return (dense_fl, res_fl, u_out) + tel
 
-        if bplan is not None:
-            sparse_branch = bucketed_sparse_branch
+        def dense_mean(srcs):
+            if p == 1:
+                return srcs
+            return tuple(dense_allreduce(s, axis_name=axis_name) / p
+                         for s in srcs)
 
-        if warmup_dense_steps > 0:
-            def dense_branch(srcs, res_in, us):
-                if p > 1:
-                    srcs = [dense_allreduce(s, axis_name=axis_name) / p
-                            for s in srcs]
-                # dense phase telemetry: no threshold, everything sent,
-                # full mass capture, nothing to audit
-                tel = ()
-                if telemetry:
-                    teld = {"tau": jnp.float32(0.0),
-                            "sent": jnp.float32(n),
-                            "m_k": jnp.float32(1.0)}
-                    if telemetry_layers:
-                        teld["lsel"], _ = (
-                            obs_counters.dense_phase_selection_stats(
-                                sizes))
-                    if audit:
-                        teld["recall"] = jnp.float32(-1.0)
-                    tel = (teld,)
-                return (srcs, res_in, us) + tel
+        return _Form(
+            n=n, sizes=sizes,
+            k=bplan.k_total if bplan is not None else kk_total,
+            plan=plan, buckets=bplan.pairs() if bplan is not None else None,
+            split=split,
+            # a Python sum of per-leaf sums — no concatenation needed
+            sq_norm=lambda flats: sum(jnp.sum(f * f) for f in flats),
+            sparse_branch=(sparse_branch if bplan is None
+                           else bucketed_sparse_branch),
+            dense_mean=dense_mean, layer_l2=obs_counters.leaf_l2,
+            layer_age=lambda age: dict(age=age))
 
-            out = lax.cond(
-                state.count < warmup_dense_steps,
-                dense_branch, sparse_branch, srcs, res_in, us,
-            )
-        else:
-            out = sparse_branch(srcs, res_in, us)
-        if telemetry:
-            dense_fl, residual, u_new, btel = out
-        else:
-            dense_fl, residual, u_new = out
-        res_struct = residual
-        if correction:
-            residual = {"v": residual, "u": u_new}
-
-        with jax.named_scope("gtopk/unflatten"):
-            avg_grads = treedef.unflatten([
-                d.reshape(leaf.shape) for d, leaf in zip(dense_fl, leaves)
-            ])
-        with jax.named_scope("gtopk/apply"):
-            updates, inner_state = inner.update(
-                avg_grads, state.inner, params)
-        if telemetry:
-            tel = obs_counters.make_telemetry(
-                n=n, k=wire_k_total, p=p, mode=mode, codec=codec,
-                schedule=plan.schedule if plan is not None else None,
-                buckets=bplan.pairs() if bplan is not None else None,
-                grad_norm_pre=obs_counters.tree_l2(flats),
-                grad_norm_post=obs_counters.tree_l2(dense_fl),
-                residual_norm=obs_counters.tree_l2(res_struct),
-                tau=btel["tau"], sent_elems=btel["sent"],
-                m_k=btel["m_k"],
-            )
-            if telemetry_layers:
-                # Delivered = appeared in the globally-reduced update,
-                # which is replicated — so the age buffer stays
-                # replicated without a collective (see update_age).
-                age = obs_counters.update_age(
-                    state.telemetry["age"],
-                    tuple(d != 0 for d in dense_fl))
-                tel["layers"] = obs_counters.assemble_layer_telemetry(
-                    sel_stats=btel["lsel"], sizes=sizes,
-                    grad_norm_pre_l=obs_counters.leaf_l2(flats),
-                    grad_norm_post_l=obs_counters.leaf_l2(dense_fl),
-                    residual_norm_l=obs_counters.leaf_l2(res_struct),
-                    age=age)
-                tel["age"] = age
-            if audit:
-                # Carry the last audited value between audits; -1 means
-                # never audited (dense warm-up included).
-                tel["audit_recall"] = jnp.where(
-                    btel["recall"] >= 0.0, btel["recall"],
-                    state.telemetry["audit_recall"])
-            tel = _finish_telemetry(tel, p)
-        else:
-            tel = state.telemetry
-        new_state = GTopKSGDState(
-            count=state.count + 1, residual=residual, inner=inner_state,
-            telemetry=tel,
-        )
-        return updates, new_state
-
-    def leaf_update(grads, state: GTopKSGDState, params=None):
+    def slabs_form(grads, count) -> _Form:
         """One device's step of the flat modes: one global k and one tau
         as in the [N] form, on the gradient's own leaves.
 
@@ -1030,33 +1046,18 @@ def gtopk_sgd(
         (a large leaf in its own shape and layout, the small ones in one
         short vector) and the threshold is one number over all of them
         (TopKCompressor.compress_leaves_by_threshold). Masking u at the
-        keep mask is exact here: every local pick is delivered."""
-        leaves, treedef = jax.tree.flatten(grads)
+        keep mask is exact here: every local pick is delivered. A state
+        made with a mesh axis named holds [N] buffers: they are cut into
+        slabs on the way in and joined on the way out."""
+        treedef = jax.tree.structure(grads)
         lplan = leaf_plan(grads)
         n = lplan.n
-        with jax.named_scope("gtopk/flatten"):
-            flats = lplan.split(leaves)
-            state_res = state.residual
-            if not slab_state:
-                state_res = jax.tree.map(lplan.from_flat, state_res)
-        if clip_grad_norm is not None:
-            # Clip BEFORE compress, as the [N] form does; the global norm
-            # is a sum of per-slab sums (stacked and added at once, so
-            # that no slab's sum waits for the slab before it).
-            with jax.named_scope("gtopk/clip"):
-                gnorm = jnp.sqrt(jnp.sum(jnp.stack(
-                    [jnp.sum(f * f) for f in flats])))
-                scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
-                flats = [f * scale for f in flats]
-        if correction:
-            res_in = tuple(state_res["v"])
-            us = tuple(momentum * u + f
-                       for u, f in zip(state_res["u"], flats))
-            srcs = us
-        else:
-            res_in = tuple(state_res)
-            us = ()
-            srcs = tuple(flats)
+
+        def split(grads):
+            def join(dense_sl):
+                return treedef.unflatten(lplan.join(dense_sl))
+
+            return tuple(lplan.split(jax.tree.leaves(grads))), join
 
         def sparse_branch(srcs, res_in, us):
             accs = [compressor.accumulate(s, r)
@@ -1068,336 +1069,118 @@ def gtopk_sgd(
                             for m, a in zip(keeps, accs)]
                 u_out = tuple(jnp.where(m, 0.0, u)
                               for u, m in zip(us, keeps))
-            tel = ()
-            if telemetry:
-                tel = {
-                    "tau": tau_th,
-                    "sent": sum(obs_counters.kept_count(m) for m in keeps),
-                    "m_k": obs_counters.mass_ratio(accs, dense_sl),
-                }
-                if telemetry_layers:
-                    tel["lsel"], _ = obs_counters.leafwise_selection_stats(
-                        lplan.join(accs), lplan.join(dense_sl))
-                if audit:
-                    # The exact top-k and the [N] operands it indexes
-                    # exist only inside the cond's taken branch.
-                    def _do():
-                        ev, ei = topk_abs(
-                            lplan.to_flat(accs), compressor.k(n))
-                        hits = jnp.take(lplan.to_flat(keeps), ei,
-                                        mode="clip")
-                        return obs_counters.topk_recall(hits, ev)
 
-                    tel["recall"] = lax.cond(
-                        (state.count % telemetry_audit_interval) == 0,
-                        _do, lambda: jnp.float32(-1.0))
-                tel = (tel,)
+            def exact_recall():
+                # The exact top-k and the [N] operands it indexes.
+                ev, ei = topk_abs(lplan.to_flat(accs), compressor.k(n))
+                hits = jnp.take(lplan.to_flat(keeps), ei, mode="clip")
+                return obs_counters.topk_recall(hits, ev)
+
+            tel = selection_counters(
+                lambda: (tau_th,
+                         sum(obs_counters.kept_count(m) for m in keeps),
+                         obs_counters.mass_ratio(accs, dense_sl)),
+                lambda: obs_counters.leafwise_selection_stats(
+                    lplan.join(accs), lplan.join(dense_sl)),
+                lambda: audited(count, exact_recall))
             return (tuple(dense_sl), tuple(new_res), u_out) + tel
 
-        if warmup_dense_steps > 0:
-            def dense_branch(srcs, res_in, us):
-                # Nothing to reduce at P = 1; the residual passes through
-                # and, with correction, u is NOT masked (nothing was
-                # transmitted sparsely). Dense-phase telemetry: no
-                # threshold, everything sent, full mass capture, nothing
-                # to audit.
-                tel = ()
-                if telemetry:
-                    teld = {"tau": jnp.float32(0.0),
-                            "sent": jnp.float32(n),
-                            "m_k": jnp.float32(1.0)}
-                    if telemetry_layers:
-                        teld["lsel"], _ = (
-                            obs_counters.dense_phase_selection_stats(
-                                lplan.sizes))
-                    if audit:
-                        teld["recall"] = jnp.float32(-1.0)
-                    tel = (teld,)
-                return (srcs, res_in, us) + tel
-
-            out = lax.cond(
-                state.count < warmup_dense_steps,
-                dense_branch, sparse_branch, srcs, res_in, us,
-            )
-        else:
-            out = sparse_branch(srcs, res_in, us)
-        if telemetry:
-            dense_sl, res_struct, u_new, btel = out
-        else:
-            dense_sl, res_struct, u_new = out
-        residual = ({"v": res_struct, "u": u_new} if correction
-                    else res_struct)
-        with jax.named_scope("gtopk/unflatten"):
-            avg_grads = treedef.unflatten(lplan.join(dense_sl))
-            if not slab_state:
-                residual = jax.tree.map(lplan.to_flat, residual,
-                                        is_leaf=lambda x: isinstance(x, tuple))
-        with jax.named_scope("gtopk/apply"):
-            updates, inner_state = inner.update(
-                avg_grads, state.inner, params)
-        if telemetry:
-            tel = obs_counters.make_telemetry(
-                n=n, k=compressor.k(n), p=1, mode=mode,
-                ici_size=hier_ici_size if hier else 1, codec=codec,
-                grad_norm_pre=obs_counters.tree_l2(flats),
-                grad_norm_post=obs_counters.tree_l2(dense_sl),
-                residual_norm=obs_counters.tree_l2(res_struct),
-                tau=btel["tau"], sent_elems=btel["sent"],
-                m_k=btel["m_k"],
-            )
-            if telemetry_layers:
-                # Delivered = appeared in the applied update. The age
-                # buffer keeps the residual's form.
-                age = state.telemetry["age"]
-                if not slab_state:
-                    age = tuple(lplan.from_flat(age))
-                age = obs_counters.update_age(
-                    age, tuple(d != 0 for d in dense_sl))
-                tel["layers"] = obs_counters.assemble_layer_telemetry(
-                    sel_stats=btel["lsel"], sizes=lplan.sizes,
-                    grad_norm_pre_l=obs_counters.leaf_l2(lplan.join(flats)),
-                    grad_norm_post_l=obs_counters.leaf_l2(
-                        lplan.join(dense_sl)),
-                    residual_norm_l=obs_counters.leaf_l2(
-                        lplan.join(res_struct)),
-                    age=tuple(lplan.join(age)))
-                tel["age"] = age if slab_state else lplan.to_flat(age)
-            if audit:
-                # Carry the last audited value between audits; -1 means
-                # never audited (dense warm-up included).
-                tel["audit_recall"] = jnp.where(
-                    btel["recall"] >= 0.0, btel["recall"],
-                    state.telemetry["audit_recall"])
-        else:
-            tel = state.telemetry
-        new_state = GTopKSGDState(
-            count=state.count + 1, residual=residual, inner=inner_state,
-            telemetry=tel,
-        )
-        return updates, new_state
+        return _Form(
+            n=n, sizes=lplan.sizes, k=compressor.k(n), split=split,
+            # per-slab sums stacked and added at once, so that no slab's
+            # sum waits for the slab before it
+            sq_norm=lambda slabs: jnp.sum(jnp.stack(
+                [jnp.sum(f * f) for f in slabs])),
+            sparse_branch=sparse_branch,
+            # nothing to reduce at P = 1
+            dense_mean=lambda srcs: srcs,
+            layer_l2=lambda x: obs_counters.leaf_l2(lplan.join(x)),
+            layer_age=lambda age: dict(age=tuple(lplan.join(age))),
+            state_in=_same if slab_state else (
+                lambda buf: tuple(lplan.from_flat(buf))),
+            state_out=_same if slab_state else lplan.to_flat)
 
     def update_fn(grads, state: GTopKSGDState, params=None):
+        """One optimizer step, whatever the form: split, clip, the
+        correction's velocity, dense warm-up or select-and-reduce, join,
+        the inner optimizer, the counters."""
+        p = bound_axis_size()
         if layerwise:
-            return layerwise_update(grads, state, params)
-        if not dense_mode and bound_axis_size() == 1:
-            return leaf_update(grads, state, params)
-        # The flat [N] form (the dense modes, and the index form a wire
-        # needs at P > 1). Its own passes are stages like the others
+            form = leaves_form(grads, state.count, p)
+        elif dense_mode or p > 1:
+            form = flat_form(grads, state.count, p)
+        else:
+            form = slabs_form(grads, state.count)
+        # The form's own passes are stages like the others
         # (trainer._build_train_step lists them): flatten, clip, unflatten.
         with jax.named_scope("gtopk/flatten"):
-            flat, unravel = ravel_pytree(grads)
-        n = flat.shape[0]
-        if telemetry_layers:
-            # Static trace-time layer structure: ravel_pytree flattens in
-            # jax.tree order, so the segment map addresses the same
-            # leaves obs_counters.layer_names reports.
-            l_sizes = obs_counters.layer_sizes(grads)
-            l_seg = obs_counters.segment_ids(l_sizes)
-            n_layers = len(l_sizes)
+            parts, join = form.split(grads)
+            state_res = _each_buffer(form.state_in, state.residual)
         if clip_grad_norm is not None:
             # Reference LSTM path: clip the raw local gradient BEFORE the
             # residual accumulate/compress (order matters for convergence).
             with jax.named_scope("gtopk/clip"):
-                gnorm = jnp.sqrt(jnp.sum(flat * flat))
+                gnorm = jnp.sqrt(form.sq_norm(parts))
                 scale = jnp.minimum(1.0, clip_grad_norm / (gnorm + 1e-6))
-                flat = flat * scale
-
-        p = bound_axis_size()
+                parts = jax.tree.map(lambda f: f * scale, parts)
         if hier and p > 1:
             if p % hier_ici_size != 0:
                 raise ValueError(
                     f"axis size {p} not divisible by "
                     f"hier_ici_size={hier_ici_size}"
                 )
-            # Level 1: dense sum within the ICI slice, BEFORE error feedback
-            # — the slice acts as one logical worker from here on, and all
-            # of its devices hold identical acc/top-k/residual.
-            flat = ici_dense_psum(
-                flat, axis_name=axis_name, axis_size=p,
+            # Level 1 (the flat form: hier has a wire here): dense sum
+            # within the ICI slice, BEFORE error feedback — the slice acts
+            # as one logical worker from here on, and all of its devices
+            # hold identical acc/top-k/residual.
+            parts = ici_dense_psum(
+                parts, axis_name=axis_name, axis_size=p,
                 ici_size=hier_ici_size,
             )
-        btel = None
-        plan = None  # dense mode has no sparse wire to plan
-        if dense_mode:
-            reduced = (dense_allreduce(flat, axis_name=axis_name)
-                       if p > 1 else flat)
-            dense = reduced / p
-            residual = state.residual
-            res_struct = residual
-            if telemetry:
-                btel = {"tau": jnp.float32(0.0), "sent": jnp.float32(n),
-                        "m_k": jnp.float32(1.0)}
-                if telemetry_layers:
-                    btel["lsel"], _ = (
-                        obs_counters.dense_phase_selection_stats(l_sizes))
-                if audit:
-                    btel["recall"] = jnp.float32(-1.0)
+        if correction:
+            # DGC velocity recursion on the LOCAL (or slice-summed, in
+            # hier mode) gradient; selection reads v + u.
+            res_in = state_res["v"]
+            us = jax.tree.map(lambda u, f: momentum * u + f,
+                              state_res["u"], parts)
+            srcs = us
         else:
-            # The wire plan named at build time; None at p=1 (no wire).
-            plan = (resolve_plan(mode, comm_plan, codec=codec_spec,
-                                 ici_size=hier_ici_size if hier else 1)
-                    if p > 1 else None)
-            if correction:
-                # DGC velocity recursion on the LOCAL (or slice-summed, in
-                # hier mode) gradient; selection reads v + u below.
-                res_in = state.residual["v"]
-                u = momentum * state.residual["u"] + flat
-                src = u
-            else:
-                res_in = state.residual
-                u = jnp.zeros((0,), flat.dtype)
-                src = flat
+            res_in, us, srcs = state_res, (), parts
 
-            def sparse_branch(src, residual_in, u_in):
-                acc = compressor.accumulate(src, residual_in)
+        def dense_branch(srcs, res_in, us):
+            # The residual passes through and, with correction, the mean
+            # of u IS classic momentum on the mean gradient (mean is
+            # linear in u); u is NOT masked (nothing was transmitted
+            # sparsely).
+            return (form.dense_mean(srcs), res_in, us) + dense_counters(form)
 
-                def _audit_recall(hits_fn):
-                    """Exact-vs-production recall audit (see the
-                    layerwise twin): exact top-k of acc as ground truth,
-                    ``hits_fn(exact_idx) -> bool[k]`` membership in the
-                    production selection; the exact top-k only exists
-                    inside the cond's taken branch."""
-                    def _do():
-                        ev, ei = topk_abs(acc, compressor.k(n))
-                        return obs_counters.topk_recall(hits_fn(ei), ev)
-
-                    return lax.cond(
-                        (state.count % telemetry_audit_interval) == 0,
-                        _do, lambda: jnp.float32(-1.0))
-
-                tel = ()
-                vals, idx, residual = compressor.compress(
-                    acc, grad=src, residual=residual_in)
-                if codec.lossy and mode != "topk":
-                    # Fold the wire quantization error into the
-                    # error-feedback residual and ship the
-                    # requantized values: the residual repair below
-                    # then restores vq + folded error = the exact
-                    # original for rejected picks, and telemetry
-                    # (tau/sent/mass) describes what actually went on
-                    # the wire. (mode 'topk' allgathers the exact
-                    # local picks — its codec path quantizes in
-                    # topk_allgather and every pick is delivered, so
-                    # there is nothing to repair and the small
-                    # symmetric error is left to the next step's
-                    # selection, like any dense rounding.)
-                    vq = roundtrip_aligned(codec, vals, idx, n=n)
-                    residual = compressor.fold_wire_error(
-                        residual, idx, vals - vq)
-                    vals = vq
-                if telemetry:
-                    # Selection stats describe the LOCAL selection
-                    # (what this device put on the wire); the pmean
-                    # in _finish_telemetry turns them into axis
-                    # means.
-                    tel = {
-                        "tau": obs_counters.selected_tau(vals),
-                        "sent": obs_counters.sent_count(vals),
-                        "m_k": obs_counters.mass_ratio(acc, vals),
-                    }
-                    if telemetry_layers:
-                        tel["lsel"], _ = (
-                            obs_counters.sparse_selection_layer_stats(
-                                acc, vals, idx, l_seg, n_layers))
-                    if audit:
-                        tel["recall"] = _audit_recall(
-                            lambda ei: membership_mask(ei, idx))
-                    tel = (tel,)
-                # Momentum factor masking: a DELIVERED coordinate's
-                # velocity restarts (its momentum was consumed);
-                # without this the same mass re-sends for ~1/momentum
-                # more steps. For the allgather union every local
-                # pick is delivered, so masking at the local
-                # selection is exact.
-                u_out = (u_in.at[idx].set(0.0, mode="drop")
-                         if correction else u_in)
-                result, gidx, needs_repair = sparse_allreduce(
-                    mode, vals, idx, k=compressor.k(n), n=n,
-                    axis_name=axis_name, axis_size=p,
-                    ici_size=hier_ici_size if hier else 1,
-                    codec=codec, plan=plan,
-                )
-                if needs_repair:  # gtopk: sparse set + repair
-                    residual = compressor.repair(
-                        residual, vals, idx, gidx)
-                    dense = scatter_add_dense(n, gidx, result) / p
-                    # NOTE (measured design decision): under gTop-k a
-                    # local pick can be globally REJECTED; one could
-                    # argue its velocity should survive (nothing was
-                    # transmitted). Measured ablation says NO: the
-                    # repair above already preserves the rejected
-                    # VALUE in v, so also keeping u double-tracks the
-                    # same mass (v += u while u compounds) and
-                    # persistently-rejected coordinates blow up —
-                    # see restore_rejected_u_ablation in the
-                    # warmup_ab_cpu_mesh8.json artifact. The local
-                    # mask above is the stable generalization; the
-                    # branch below exists ONLY to reproduce that
-                    # ablation arm (_restore_rejected_u=True).
-                    if correction and _restore_rejected_u:
-                        rej = ~membership_mask(idx, gidx)
-                        u_out = u_out.at[idx].add(
-                            jnp.where(rej, u_in[idx], 0.0),
-                            mode="drop")
-                else:  # allgather union: dense, every pick lands
-                    dense = result / p
-                return (dense, residual, u_out) + tel
-
-            if warmup_dense_steps > 0:
-                def dense_branch(src, residual_in, u_in):
-                    reduced = (dense_allreduce(src, axis_name=axis_name)
-                               if p > 1 else src)
-                    # In hier mode the input is already the within-slice
-                    # SUM (ici_dense_psum above), so a full-axis psum
-                    # counts every original gradient hier_ici_size times —
-                    # divide it back out or every warm-up step trains at
-                    # an ici_size-inflated effective LR. With correction
-                    # the mean of u IS classic momentum on the mean
-                    # gradient (mean is linear in u), and u is NOT masked
-                    # (nothing was transmitted sparsely).
-                    scale = p * (hier_ici_size if (hier and p > 1) else 1)
-                    # dense phase telemetry: no threshold, everything
-                    # sent, full mass capture, nothing to audit
-                    tel = ()
-                    if telemetry:
-                        teld = {"tau": jnp.float32(0.0),
-                                "sent": jnp.float32(n),
-                                "m_k": jnp.float32(1.0)}
-                        if telemetry_layers:
-                            teld["lsel"], _ = (
-                                obs_counters.dense_phase_selection_stats(
-                                    l_sizes))
-                        if audit:
-                            teld["recall"] = jnp.float32(-1.0)
-                        tel = (teld,)
-                    return (reduced / scale, residual_in, u_in) + tel
-
-                out = lax.cond(
-                    state.count < warmup_dense_steps,
-                    dense_branch, sparse_branch, src, res_in, u,
-                )
-            else:
-                out = sparse_branch(src, res_in, u)
-            if telemetry:
-                dense, residual, u_new, btel = out
-            else:
-                dense, residual, u_new = out
-            res_struct = residual
-            if correction:
-                residual = {"v": residual, "u": u_new}
+        if dense_mode:
+            out = dense_branch(srcs, res_in, us)
+        elif warmup_dense_steps > 0:
+            out = lax.cond(
+                state.count < warmup_dense_steps,
+                dense_branch, form.sparse_branch, srcs, res_in, us,
+            )
+        else:
+            out = form.sparse_branch(srcs, res_in, us)
+        dense, res_struct, u_new, *btel = out
+        residual = {"v": res_struct, "u": u_new} if correction else res_struct
 
         with jax.named_scope("gtopk/unflatten"):
-            avg_grads = unravel(dense)
+            avg_grads = join(dense)
+            residual = _each_buffer(form.state_out, residual)
         with jax.named_scope("gtopk/apply"):
             updates, inner_state = inner.update(
                 avg_grads, state.inner, params)
+        tel = state.telemetry
         if telemetry:
+            (btel,) = btel
             tel = obs_counters.make_telemetry(
-                n=n, k=(n if dense_mode else compressor.k(n)), p=p,
-                mode=mode, ici_size=hier_ici_size if hier else 1,
-                codec=codec,
-                schedule=plan.schedule if plan is not None else None,
-                grad_norm_pre=obs_counters.tree_l2(flat),
+                n=form.n, k=form.k, p=p, mode=mode,
+                ici_size=ici, codec=codec,
+                schedule=form.plan.schedule if form.plan is not None else None,
+                buckets=form.buckets,
+                grad_norm_pre=obs_counters.tree_l2(parts),
                 grad_norm_post=obs_counters.tree_l2(dense),
                 residual_norm=obs_counters.tree_l2(res_struct),
                 tau=btel["tau"], sent_elems=btel["sent"],
@@ -1406,21 +1189,20 @@ def gtopk_sgd(
             if telemetry_layers:
                 # Delivered = appeared in the globally-reduced update,
                 # which is replicated — so the age buffer stays
-                # replicated without a collective (see update_age).
+                # replicated without a collective (see update_age), in
+                # the residual's form.
                 age = obs_counters.update_age(
-                    state.telemetry["age"], dense != 0)
+                    form.state_in(state.telemetry["age"]),
+                    jax.tree.map(lambda d: d != 0, dense))
                 tel["layers"] = obs_counters.assemble_layer_telemetry(
-                    sel_stats=btel["lsel"], sizes=l_sizes,
-                    grad_norm_pre_l=obs_counters.seg_l2(
-                        flat, l_seg, n_layers),
-                    grad_norm_post_l=obs_counters.seg_l2(
-                        dense, l_seg, n_layers),
+                    sel_stats=btel["lsel"], sizes=form.sizes,
+                    grad_norm_pre_l=form.layer_l2(parts),
+                    grad_norm_post_l=form.layer_l2(dense),
                     residual_norm_l=(
-                        jnp.zeros((n_layers,), jnp.float32)
-                        if dense_mode else
-                        obs_counters.seg_l2(res_struct, l_seg, n_layers)),
-                    age=age, seg=l_seg)
-                tel["age"] = age
+                        jnp.zeros((len(form.sizes),), jnp.float32)
+                        if dense_mode else form.layer_l2(res_struct)),
+                    **form.layer_age(age))
+                tel["age"] = form.state_out(age)
             if audit:
                 # Carry the last audited value between audits; -1 means
                 # never audited (dense warm-up / dense mode included).
@@ -1428,8 +1210,6 @@ def gtopk_sgd(
                     btel["recall"] >= 0.0, btel["recall"],
                     state.telemetry["audit_recall"])
             tel = _finish_telemetry(tel, p)
-        else:
-            tel = state.telemetry
         new_state = GTopKSGDState(
             count=state.count + 1, residual=residual, inner=inner_state,
             telemetry=tel,

@@ -128,10 +128,6 @@ class TrainConfig:
                                    # correction + factor masking (velocity
                                    # accumulates BEFORE selection;
                                    # arXiv:1712.01887 §3, TPU extension)
-    restore_rejected_u: bool = False   # ABLATION ONLY: the rejected-pick
-                                   # velocity-restore semantics measured
-                                   # (and rejected) in warmup_ab's
-                                   # restore_rejected_u_ablation entry
     max_epochs: int = 140
     nworkers: int = 1
     data_dir: Optional[str] = None
@@ -771,7 +767,7 @@ class Trainer:
             plan_extra.update(self._bucket_plan.to_manifest())
         plan_extra.update(self._model_forms)
         # Whether the one-device step took the leaf form, and how far
-        # (optimizer.leaf_update): static, from the parameters' shapes.
+        # (optimizer.py's slabs form): static, from the parameters' shapes.
         self._slab_state = leaf_form_state(
             cfg.compression, None if self.p == 1 else "dp")
         if self._slab_state:
@@ -1139,7 +1135,6 @@ class Trainer:
             hier_ici_size=cfg.hier_ici,
             warmup_dense_steps=warmup_dense_steps,
             momentum_correction=cfg.momentum_correction,
-            _restore_rejected_u=cfg.restore_rejected_u,
             telemetry=cfg.obs_counters,
             telemetry_layers=cfg.obs_layers,
             telemetry_audit_interval=cfg.obs_audit_interval,
@@ -2359,7 +2354,7 @@ class Trainer:
         can build a template (utils/checkpoint.py sidecar): the
         residual's partition width, so that an elastic different-P resume
         need not guess the old shape, and its form, slabs
-        (optimizer.leaf_update's) or the flat [N] buffers every mesh run
+        (optimizer.py's slabs form) or the flat [N] buffers every mesh run
         and every checkpoint older than the leaf form holds."""
         return {"residual_p": self.p,
                 "residual_form": "slabs" if self._slab_state else "flat"}

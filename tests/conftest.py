@@ -37,8 +37,10 @@ def rng():
 @pytest.fixture(scope="module")
 def v5e():
     """One described v5e device, for the files that ask the chip's compiler
-    without the chip (``test_pallas_compile.py``, ``test_flash_compile.py``:
-    a file each, so that each has a worker of its own). Nothing but a test
+    without the chip (``test_pallas_compile.py``, ``test_flash_compile.py``,
+    ``test_hybrid_compile.py``, ``test_looped_compile.py``,
+    ``test_mla_compile.py``: one published step a file, so that each has a
+    worker of its own). Nothing but a test
     that asks for it describes the topology. The persistent cache is off
     around the module (a compile for a described device is written to it
     but cannot be read back without a chip, and the next one warns)."""

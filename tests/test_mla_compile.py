@@ -2,7 +2,7 @@
 asked of the chip's compiler without the chip (``conftest.py``'s ``v5e``;
 the kernels alone at its shapes are tests/test_flash_compile.py's "latent"
 cases). A file of its own, so that this compile (a minute and a half) has a
-worker of its own beside the two of ``test_flash_compile.py``. Nothing
+worker of its own (one published step a file). Nothing
 executes; a passing compile is not a chip run."""
 
 import collections

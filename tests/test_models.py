@@ -100,10 +100,12 @@ def test_ptb_lstm_shapes_and_carry():
 def test_an4_shapes_and_output_length():
     model, spec = get_model("lstman4")
     rng = jax.random.PRNGKey(0)
+    # Shape-only, as test_vision_param_counts: eval_shape traces the four
+    # bidirectional layers' scans without compiling or running one.
     for t in (100, 101, 57):
-        x = jax.random.normal(rng, (2, t, 161))
-        variables = model.init({"params": rng}, x)
-        logits = model.apply(variables, x)
+        x = jax.ShapeDtypeStruct((2, t, 161), jnp.float32)
+        variables = jax.eval_shape(model.init, {"params": rng}, x)
+        logits = jax.eval_shape(model.apply, variables, x)
         assert logits.shape[0] == 2 and logits.shape[2] == 29
         assert logits.shape[1] == model.output_length(t), (
             t, logits.shape, model.output_length(t)
@@ -186,6 +188,7 @@ def test_space_to_depth_param_count():
     s2d, _ = get_model("resnet50", space_to_depth=True)
     x = jnp.zeros((1, 224, 224, 3))
     rng = jax.random.PRNGKey(0)
-    n_std = sum(a.size for a in jax.tree.leaves(std.init({"params": rng}, x)["params"]))
-    n_s2d = sum(a.size for a in jax.tree.leaves(s2d.init({"params": rng}, x)["params"]))
+    n_std, n_s2d = (
+        n_params(jax.eval_shape(m.init, {"params": rng}, x))
+        for m in (std, s2d))
     assert n_s2d - n_std == 12288 - 9408 == 2880

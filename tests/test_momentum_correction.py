@@ -65,7 +65,7 @@ def test_correction_p1_matches_dgc_oracle():
         got = -np.concatenate(
             [np.asarray(updates["b"]), np.asarray(updates["w"])])
         np.testing.assert_allclose(got, applied, rtol=1e-5, atol=1e-6)
-        # no mesh axis named: the state holds slabs (optimizer.leaf_update)
+        # no mesh axis named: the state holds slabs (optimizer.py's slabs form)
         res = flat_residual(state.residual, params)
         np.testing.assert_allclose(np.asarray(res["v"]), v,
                                    rtol=1e-5, atol=1e-6)
@@ -207,29 +207,6 @@ def test_correction_masks_at_local_selection():
     # un-picked coordinates are untouched everywhere (no stray masking):
     # device 0 never selected {14, 15} and contributed 0 mass there.
     assert v_all[0, 14] == 0.0 and u_all[0, 14] == 0.0
-
-
-def test_correction_restore_u_ablation_flag_restores_rejected_velocity():
-    """The _restore_rejected_u ablation knob (used to generate the
-    warmup_ab ablation entry) implements the OTHER semantics — velocity
-    survives for globally-rejected picks — so the A/B between the two is
-    reproducible. Also pins that the knob is correction-only."""
-    n, k_density, params, mesh, g = _mask_semantics_fixture()
-    tx = gtopk_sgd(0.1, momentum=0.9, compression="gtopk",
-                   density=k_density, axis_name="dp", axis_size=PDEV,
-                   momentum_correction=True, _restore_rejected_u=True)
-    v_all, u_all = _run_one_masked_step(params, mesh, g, tx)
-    # globally-accepted picks (device 7) are still fully consumed
-    assert u_all[7, 14] == 0.0 and u_all[7, 15] == 0.0
-    assert v_all[7, 14] == 0.0 and v_all[7, 15] == 0.0
-    # globally-rejected picks (device 0) keep BOTH value and velocity
-    np.testing.assert_allclose(v_all[0, :2], g[0, :2], rtol=1e-6)
-    np.testing.assert_allclose(u_all[0, :2], g[0, :2], rtol=1e-6)
-
-    with pytest.raises(ValueError, match="ablation"):
-        gtopk_sgd(0.1, momentum=0.9, compression="gtopk",
-                  density=k_density, axis_name=None,
-                  _restore_rejected_u=True)
 
 
 def test_correction_rejects_meaningless_combinations():

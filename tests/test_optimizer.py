@@ -242,11 +242,12 @@ def test_dense_warmup_hier_matches_dense_scale():
 
 
 # ------------------------------------------------------------------
-# The one-device step on the gradient's own leaves (leaf_update, PR 42)
+# The one-device step on the gradient's own leaves (the slabs form, PR 42)
 # against the flat [N] expression it replaced, written out here:
 # ravel_pytree, compress_by_threshold on the vector, unravel.
 
 import functools  # noqa: E402
+import warnings  # noqa: E402
 
 from jax.flatten_util import ravel_pytree  # noqa: E402
 
@@ -518,3 +519,52 @@ def test_leaf_form_state_is_slabs_only_without_an_axis():
                    axis_name=None).init(params)
     assert [r.shape for r in lw.residual] == [
         (int(np.prod(MIXED[k])),) for k in sorted(MIXED)]
+
+
+# ------------------------------------------------------------------
+# The leaves form (gtopk_layerwise) against the slabs form. On a tree of
+# ONE leaf the per-leaf k is the global k, and under the exact kernel tau
+# is the k-th largest magnitude in both, so the two forms have to take the
+# same step: what pins the third form of optimizer.update_fn to the other
+# two, as test_leaf_form_is_the_flat_expression pins slabs to flat.
+
+@pytest.mark.parametrize("correction", [False, True],
+                         ids=["momentum", "correction"])
+@pytest.mark.parametrize("clip", [None, 0.5], ids=["no_clip", "clip"])
+def test_layerwise_of_one_leaf_is_the_flat_modes_step(clip, correction):
+    rng = np.random.default_rng(11)
+    params = {"w": jnp.asarray(rng.standard_normal((24, 40)), jnp.float32)}
+    grads_seq = [
+        {"w": jnp.asarray(rng.standard_normal((24, 40)), jnp.float32)}
+        for _ in range(3)]
+    runs = {}
+    for mode in ("gtopk", "gtopk_layerwise"):
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")  # layerwise x correction
+            tx = gtopk_sgd(LR, momentum=MOM, weight_decay=1e-3,
+                           compression=mode, density=0.02,
+                           topk_method="exact", clip_grad_norm=clip,
+                           momentum_correction=correction, axis_name=None,
+                           telemetry=True)
+        state = tx.init(params)
+        update = jax.jit(tx.update)
+        steps = []
+        for grads in grads_seq:
+            updates, state = update(grads, state, params)
+            residual = state.residual
+            if mode == "gtopk":
+                residual = flat_residual(residual, params)
+            else:  # one leaf's flat buffer
+                residual = jax.tree.map(
+                    lambda r: r[0], residual,
+                    is_leaf=lambda x: isinstance(x, tuple))
+            steps.append((updates, residual, state.telemetry))
+        runs[mode] = steps
+    k = int(np.ceil(0.02 * 24 * 40))
+    for (u_f, r_f, t_f), (u_l, r_l, t_l) in zip(*runs.values()):
+        assert np.count_nonzero(np.asarray(u_f["w"])) >= k
+        np.testing.assert_array_equal(np.asarray(u_l["w"]),
+                                      np.asarray(u_f["w"]))
+        jax.tree.map(np.testing.assert_array_equal, r_l, r_f)
+        for key in ("tau", "sent_elems", "achieved_density"):
+            assert float(t_l[key]) == float(t_f[key]), key

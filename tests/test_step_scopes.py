@@ -30,6 +30,15 @@ BUILD = {
                        "gtopk/allreduce/round1"}),
     "dense_dp4": (dict(compression="dense", nworkers=4),
                   EVERY_STEP | {"gtopk/allreduce"}),
+    # The leaves form (gtopk_layerwise), which no cell runs: a leaf's
+    # accumulate is a bare add, its repair the form's own scatter, and at
+    # P > 1 the index selection has no scope of its own.
+    "layerwise_p1": (dict(compression="gtopk_layerwise", density=0.01),
+                     EVERY_STEP | {"gtopk/select", "gtopk/mask"}),
+    "layerwise_dp4": (dict(compression="gtopk_layerwise", density=0.01,
+                           nworkers=4),
+                      EVERY_STEP | {"gtopk/mask", "gtopk/allreduce/round0",
+                                    "gtopk/allreduce/round1"}),
 }
 
 
