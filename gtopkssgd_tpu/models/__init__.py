@@ -19,7 +19,7 @@ from typing import Any, Callable, Dict, Tuple
 import flax.linen as nn
 
 from gtopkssgd_tpu.models import (
-    kanana2, keye_vl2, ouro, qwen3_next, trinity_mini)
+    kanana2, keye_vl2, ouro, qwen3_next, sdar, trinity_mini)
 from gtopkssgd_tpu.models.alexnet import AlexNet
 from gtopkssgd_tpu.models.kanana2 import Kanana2
 from gtopkssgd_tpu.models.keye_vl2 import KeyeVL2
@@ -28,6 +28,7 @@ from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
 from gtopkssgd_tpu.models.ouro import Ouro
 from gtopkssgd_tpu.models.qwen3_next import Qwen3Next
 from gtopkssgd_tpu.models.resnet import ResNetCIFAR, ResNetImageNet
+from gtopkssgd_tpu.models.sdar import SDAR
 from gtopkssgd_tpu.models.trinity_mini import TrinityMini
 from gtopkssgd_tpu.models.vgg import VGG16
 
@@ -184,6 +185,18 @@ _register(
         presets=tuple(ouro.PRESETS),
     )
 )
+_register(
+    ModelSpec(
+        "sdar",
+        SDAR,
+        "tokens",
+        (8192,),  # one sequence of token ids; the model runs it as 2 x 8,192 rows
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
+        presets=tuple(sdar.PRESETS),
+    )
+)
 
 
 def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
@@ -236,4 +249,5 @@ __all__ = [
     "TrinityMini",
     "Kanana2",
     "Ouro",
+    "SDAR",
 ]

@@ -1,9 +1,11 @@
 """What the decoders of this zoo share (``qwen3_next``, ``keye_vl2``,
-``trinity_mini``, ``kanana2``, ``ouro``): the dropless expert layer of one
-chip's share of an expert group, a dense layer's feed-forward, the rotary
-embedding, the zero-centred RMSNorm, the causal and sliding-window
-attention (keys as wide as values or wider), the head's loss a sequence at
-a time, and the shell round the layers in its pieces.
+``trinity_mini``, ``kanana2``, ``ouro``, ``sdar``): the dropless expert
+layer of one chip's share of an expert group, a dense layer's feed-forward,
+the rotary embedding, the zero-centred RMSNorm, the causal and
+sliding-window attention (keys as wide as values or wider) and the same
+under block diffusion's visibility rule (``block_diffusion_attention``),
+the head's loss a sequence at a time, and the shell round the layers in
+its pieces.
 Each model file states its own layer equations and imports these; nothing
 here knows a model's sizes beyond the ``sizes`` dict it is handed.
 
@@ -119,15 +121,25 @@ def kernel_causal_attention(q, k, v, dtype, window, kept):
     """``blocked_causal_attention`` with the attention in kernels
     (interpret mode off the TPU): the same arguments but the block, the
     same values and gradients."""
-    return _kernel_attention(q, k, v, dtype, window, kept)[0]
+    return _kernel_attention(q, k, v, dtype, kept, window=window)[0]
 
 
-def _kernel_attention(q, k, v, dtype, window, kept):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def kernel_diffusion_attention(q, k, v, dtype, block_length, kept):
+    """``block_diffusion_attention`` with the attention in the same
+    kernels, told the rule by its block length."""
+    return _kernel_attention(q, k, v, dtype, kept,
+                             block_length=block_length)[0]
+
+
+def _kernel_attention(q, k, v, dtype, kept, **rule):
+    """(out, what the backward rule takes); ``rule`` is the kernels'
+    ``window`` or ``block_length``."""
     batch, length, heads, _ = q.shape
     q_l, k_l, v_l = kernel_layout(q, k, v, dtype)
     with jax.named_scope("part/kernel"):
-        out, lse = flash.forward(q_l, k_l, v_l, window=window,
-                                 interpret=not on_tpu())
+        out, lse = flash.forward(q_l, k_l, v_l, interpret=not on_tpu(),
+                                 **rule)
     with jax.named_scope("part/layout"):
         out = out.transpose(0, 3, 1, 2, 4).reshape(
             batch, length, heads, v.shape[3])
@@ -138,7 +150,7 @@ def _kernel_attention(q, k, v, dtype, window, kept):
     return out, (q_l, k_l, v_l, out, lse)
 
 
-def _kernel_attention_bwd(dtype, window, kept, residuals, d_out):
+def _kernel_attention_bwd(dtype, residuals, d_out, **rule):
     q_l, k_l, v_l, out, lse = residuals
     batch, groups, rep, length, dim = q_l.shape
     rows = lambda a: jnp.moveaxis(
@@ -146,7 +158,7 @@ def _kernel_attention_bwd(dtype, window, kept, residuals, d_out):
     with jax.named_scope("part/layout"):
         delta = rows(jnp.sum(d_out * out, -1))         # sum_s p_s dP_s
         d_out = rows(d_out).astype(dtype)
-    run = dict(window=window, interpret=not on_tpu())
+    run = dict(rule, interpret=not on_tpu())
     with jax.named_scope("part/kernel"):
         d_q = flash.backward_q(q_l, k_l, v_l, lse, delta, d_out, **run)
         d_k, d_v = flash.backward_kv(q_l, k_l, v_l, lse, delta, d_out, **run)
@@ -156,7 +168,27 @@ def _kernel_attention_bwd(dtype, window, kept, residuals, d_out):
                 d_k.transpose(0, 2, 1, 3), d_v.transpose(0, 2, 1, 3))
 
 
-kernel_causal_attention.defvjp(_kernel_attention, _kernel_attention_bwd)
+kernel_causal_attention.defvjp(
+    lambda q, k, v, dtype, window, kept: _kernel_attention(
+        q, k, v, dtype, kept, window=window),
+    lambda dtype, window, kept, residuals, d_out: _kernel_attention_bwd(
+        dtype, residuals, d_out, window=window))
+kernel_diffusion_attention.defvjp(
+    lambda q, k, v, dtype, block_length, kept: _kernel_attention(
+        q, k, v, dtype, kept, block_length=block_length),
+    lambda dtype, block_length, kept, residuals, d_out: _kernel_attention_bwd(
+        dtype, residuals, d_out, block_length=block_length))
+
+
+@jax.named_scope("part/kernel")
+def _attend(q_b, k_b, v_b, seen, dtype):
+    """A block's queries [B, Q, G, R, D] over the keys it was handed
+    [B, K, G, D]; ``seen()`` [Q, K]: which key each query attends to."""
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_b, k_b,
+                        preferred_element_type=F32) / math.sqrt(q_b.shape[-1])
+    probs = jax.nn.softmax(jnp.where(seen(), scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v_b,
+                      preferred_element_type=F32)
 
 
 def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
@@ -181,14 +213,7 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
             batch, length, kv_heads, heads // kv_heads, dim).astype(dtype)
         k, v = k.astype(dtype), v.astype(dtype)
 
-    @jax.named_scope("part/kernel")
-    def attend(q_b, k_b, v_b, seen):
-        """``seen()`` [queries, keys]: which key each query attends to."""
-        scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_b, k_b,
-                            preferred_element_type=F32) / math.sqrt(dim)
-        probs = jax.nn.softmax(jnp.where(seen(), scores, -jnp.inf), axis=-1)
-        return jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(dtype), v_b,
-                          preferred_element_type=F32)
+    attend = functools.partial(_attend, dtype=dtype)
 
     @functools.partial(jax.checkpoint, static_argnums=3)
     def one(q_b, k_b, v_b, start):
@@ -245,6 +270,77 @@ def blocked_causal_attention(q, k, v, dtype, block, window=None, kept=None):
     with jax.named_scope("part/layout"):
         out = jnp.concatenate(outs, 1).reshape(
             batch, length, heads, v.shape[3])
+        return out if kept is None else checkpoint_name(out, kept)
+
+
+def diffusion_attention_form(half, dim, block_length):
+    """``attention_form`` for ``block_diffusion_attention`` at halves of
+    ``half`` rows: the kernels besides need each half to be whole tiles and
+    a tile whole blocks of ``block_length``, a power of two."""
+    fits = flash.BlockDiffusion.fits(block_length, half, flash.TILE_Q,
+                                     flash.TILE_K)
+    return attention_form(half, dim) if fits else "blocked"
+
+
+def block_diffusion_attention(q, k, v, dtype, block, block_length, kept=None):
+    """``blocked_causal_attention`` under the block-diffusion rule: the 2L
+    rows of q [B, 2L, H, D], k, v [B, 2L, H_kv, D] are a sequence's L clean
+    tokens and then its L noised ones, and with b(t) = t // ``block_length``
+    on a row's position in its own half a clean query sees the clean keys
+    with b(s) <= b(t), a noised query the clean keys with b(s) < b(t) and
+    the noised keys with b(s) = b(t). L is whole blocks.
+
+    The kernel form (``diffusion_attention_form``) is the same three
+    kernels told the rule, one call over all 2L rows. In the blocked form a
+    block of ``block`` queries is handed the keys it can see and no others:
+    clean queries the clean keys up to the end of their last row's block,
+    noised queries the clean keys before that and the noised rows of their
+    own blocks; never [2L, 2L]."""
+    batch, rows, heads, dim = q.shape
+    half = rows // 2
+    if rows != 2 * half or half % block_length:
+        raise ValueError(f"{rows} rows: not two halves of whole blocks of "
+                         f"{block_length}")
+    if diffusion_attention_form(half, dim, block_length) == "kernel":
+        return kernel_diffusion_attention(q, k, v, dtype, block_length, kept)
+    kv_heads = k.shape[2]
+    with jax.named_scope("part/layout"):
+        q = q.reshape(batch, rows, kv_heads, heads // kv_heads,
+                      dim).astype(dtype)
+        k, v = k.astype(dtype), v.astype(dtype)
+
+    @functools.partial(jax.checkpoint, static_argnums=(3, 4, 5))
+    def one(q_b, k_b, v_b, start, clean, first):
+        """Queries at positions ``start`` .. of a half against ``clean``
+        clean keys from position 0 and then (the noised queries) the noised
+        keys from position ``first``."""
+        noised = int(clean < k_b.shape[1])
+
+        def seen():
+            qb = (start + jnp.arange(q_b.shape[1]))[:, None] // block_length
+            at = jnp.arange(k_b.shape[1])[None, :]
+            kb = jnp.where(at < clean, at, at - clean + first) // block_length
+            return jnp.where(at < clean, kb <= qb - noised, kb == qb)
+
+        return _attend(q_b, k_b, v_b, seen, dtype)
+
+    outs = []
+    for noised in (0, 1):
+        for start in range(0, half, block):
+            end = min(start + block, half)
+            # The blocks the queries lie in: positions first .. last - 1.
+            first = start // block_length * block_length
+            last = -(-end // block_length) * block_length
+            clean = last - noised * block_length
+            with jax.named_scope("part/layout"):
+                keys = lambda a: jnp.concatenate(
+                    [a[:, :clean], a[:, half + first:half + last]], 1) \
+                    if noised else a[:, :clean]
+                cuts = (q[:, noised * half + start:noised * half + end],
+                        keys(k), keys(v))
+            outs.append(one(*cuts, start, clean, first))
+    with jax.named_scope("part/layout"):
+        out = jnp.concatenate(outs, 1).reshape(batch, rows, heads, v.shape[3])
         return out if kept is None else checkpoint_name(out, kept)
 
 

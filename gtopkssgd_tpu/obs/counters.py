@@ -530,6 +530,19 @@ LOOP_FIELDS = tuple(
     f"loop_{name}_{r}" for name in ("loss", "exit_share")
     for r in range(1, LOOP_PASSES + 1)) + ("loop_exit_entropy",)
 
+# What a decoder trained by block diffusion counts of the noise it drew
+# inside the step (models/sdar.py), per optimizer step: the share of the
+# noised half's positions that were masked (about 1/2: the mean of t), the
+# mean t over the blocks, the unweighted mean cross-entropy at the masked
+# positions (the weighted sum is the loss), and the share of blocks in which
+# no position was masked (they add nothing to the loss).
+BD_FIELDS = (
+    "bd_masked_share",
+    "bd_mean_t",
+    "bd_masked_ce",
+    "bd_empty_blocks",
+)
+
 # The model counters last read on the host (``model_scalars``): like the
 # span buffer, it outlives the trainer, so a reader can ask afterwards.
 _last_model: Dict[str, float] = {}
@@ -586,6 +599,13 @@ def loop_counters(loss: Array, share: Array, entropy: Array
     return out
 
 
+@jax.named_scope(SCOPE)
+def bd_counters(*counts: Array) -> Dict[str, Array]:
+    """``BD_FIELDS`` as f32 scalars: the model gives each as a number."""
+    return {name: jnp.asarray(count, jnp.float32)
+            for name, count in zip(BD_FIELDS, counts)}
+
+
 # The registry of model counters: each group's fields, the keys of the
 # model's counts it is computed from and its device function. A model's
 # ``aux`` holds the groups whose counts it returns; ``model_scalars`` reads
@@ -598,6 +618,7 @@ MODEL_COUNTERS = {
                     moe_balance_counters),
     "loop": (LOOP_FIELDS, ("loop_loss", "loop_exit_share",
                            "loop_exit_entropy"), loop_counters),
+    "bd": (BD_FIELDS, BD_FIELDS, bd_counters),
 }
 
 

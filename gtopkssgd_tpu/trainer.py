@@ -139,7 +139,8 @@ class TrainConfig:
                                    # '30b_a3b_ep16', 'tiny'; trinity_mini:
                                    # '26b_a3b_ep16', 'tiny'; kanana2:
                                    # '30b_a3b_ep16', 'tiny'; ouro:
-                                   # '2p6b_l5', 'tiny'): which of the
+                                   # '2p6b_l5', 'tiny'; sdar:
+                                   # '30b_a3b_ep8', 'tiny'): which of the
                                    # model's PRESETS to build, the
                                    # published sizes as one chip's share
                                    # of an expert group (ouro: the first
@@ -670,8 +671,11 @@ class Trainer:
         # manifest and every "train" record.
         self._model_forms = {}
         if cfg.dataset == "tokens":
-            data_kw.update(seq_len=self.model.sizes["seq_len"],
-                           vocab_size=self.model.sizes["vocab_rows"])
+            # A model with a mask token keeps it out of the data: the ids
+            # are drawn from those below it.
+            sizes = self.model.sizes
+            data_kw.update(seq_len=sizes["seq_len"], vocab_size=sizes.get(
+                "mask_token_id", sizes["vocab_rows"]))
             if hasattr(self.model, "forms"):
                 self._model_forms = self.model.forms(data_kw["seq_len"])
         def _dataset(**kw):

@@ -132,19 +132,21 @@ def run_two_process(worker_src: str, tmp_path, ok_token: str) -> str:
 # allows up to 16 reduced keys. No file of the benchmark may be edited by
 # the PR that adds a configuration, and ``tests/perfbench/conftest.py`` (a
 # benchmark file since PR 26) names only that PR's case, so the cases of
-# PRs 31, 35, 39 and 41 are marked here, strictly and with the same wording: when
+# PRs 31, 35, 39, 41 and 45 are marked here, strictly and with the same wording: when
 # a ``benchmark`` PR relaxes the assertion the cases pass, these marks fail,
 # and these lines go. Everything else that test checks of an entry is
 # checked for Keye's and Trinity's configurations, by name, in
 # ``perfbench/test_perfbench_entries_by_name.py``, for Kanana's in
-# ``perfbench/test_perfbench_cell_kanana2.py`` and for Ouro's in
-# ``perfbench/test_perfbench_cell_ouro.py``.
+# ``perfbench/test_perfbench_cell_kanana2.py``, for Ouro's in
+# ``perfbench/test_perfbench_cell_ouro.py`` and for SDAR's in
+# ``perfbench/test_perfbench_cell_sdar.py``.
 STALE = tuple(
     "test_perfbench_contract.py::"
     "test_entry_has_just_the_contracts_keys_and_characters"
     f"[configs-{config}]"
     for config in ("keye_vl2_30b_a3b_ep16", "trinity_mini_26b_a3b_ep16",
-                   "kanana2_30b_a3b_ep16", "ouro_2p6b_l5"))
+                   "kanana2_30b_a3b_ep16", "ouro_2p6b_l5",
+                   "sdar_30b_a3b_ep8"))
 # PR 31's own letter test asserts that its configuration, its cell and its six
 # metrics are the LAST entries of their lists in BENCHMARK.json: true of the
 # PR that appended them, false as soon as the next one appends (PR 35: one

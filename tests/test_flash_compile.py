@@ -20,13 +20,14 @@ import jax.numpy as jnp
 import pytest
 
 from gtopkssgd_tpu.models import (
-    decoder, kanana2, ouro, qwen3_next, trinity_mini)
+    decoder, kanana2, ouro, qwen3_next, sdar, trinity_mini)
 from gtopkssgd_tpu.ops import flash_attention as flash
 
 TRINITY = trinity_mini.PRESETS["26b_a3b_ep16"]
 QWEN = qwen3_next.PRESETS["80b_a3b_ep64"]
 KANANA = kanana2.PRESETS["30b_a3b_ep16"]
 OURO = ouro.PRESETS["2p6b_l5"]
+SDAR = sdar.PRESETS["30b_a3b_ep8"]
 # (sequences a step in the cell, query heads, key-value heads, key width,
 # value width, tokens, the window)
 LAYERS = {
@@ -76,6 +77,29 @@ def test_flash_attention_kernel_compiles_at_the_published_shapes(
         shape((batch, groups, length, value), jnp.bfloat16),
         shape(rows, jnp.float32),
         shape(rows + (value,), jnp.bfloat16)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"flash_attention_{kernel}" in text
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_flash_attention_kernel_compiles_under_the_block_diffusion_rule(
+        v5e, kernel):
+    """2 x 8,192 rows x 32 / 4 heads of 128 in blocks of 4 tokens (the
+    block-diffusion decoder's): the same three kernels told
+    ``block_length``, a grid of 32 query tiles x 17 steps forward and 32 key
+    tiles x 32 steps in ``backward_kv``, one custom call each."""
+    heads, groups, dim = (SDAR["num_attention_heads"],
+                          SDAR["num_key_value_heads"], SDAR["head_dim"])
+    length = 2 * SDAR["seq_len"]
+    rows = (1, groups, heads // groups, length)
+    shape = lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)
+    text = jax.jit(lambda *a: KERNELS[kernel](
+        *a, block_length=SDAR["block_length"])).lower(
+        shape(rows + (dim,), jnp.bfloat16),
+        shape((1, groups, length, dim), jnp.bfloat16),
+        shape((1, groups, length, dim), jnp.bfloat16),
+        shape(rows, jnp.float32),
+        shape(rows + (dim,), jnp.bfloat16)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert f"flash_attention_{kernel}" in text
 
@@ -185,3 +209,52 @@ def test_published_step_holds_no_array_of_heads_queries_keys(published_step):
     length = TRINITY["seq_len"]
     assert f"bf16[1,4,8,{length},128]" in published_step[0]
     assert f"f32[1,4,8,{length}]" in published_step[0]
+
+
+# ------------------------------------------ the block-diffusion decoder's step
+@pytest.fixture(scope="module")
+def published_diffusion_step(v5e):
+    """The block-diffusion decoder's step (the ``sdar_ep8.gtopk`` cell's
+    flags) in its kernel form: one compile (a minute and a quarter) serves
+    the tests below, in this file's process beside Trinity's."""
+    return compiled_step(v5e, ["attention_form"], dnn="sdar",
+                         model_preset="30b_a3b_ep8", batch_size=1, lr=0.1)
+
+
+def test_published_diffusion_step_stays_under_its_memory_line(
+        published_diffusion_step):
+    """12.34 GB of the v5e's 16.9 by ``memory_analysis()``; ISSUE 45's line
+    is 14.5."""
+    assert published_diffusion_step[1] < 12.4e9, published_diffusion_step[1]
+
+
+def test_published_diffusion_step_runs_each_kernel_once_a_layer(
+        published_diffusion_step):
+    """A layer holds one forward and the two backward kernels (the remat's
+    replay runs none), each under ``layer/attn/.../part/kernel``, backward
+    too."""
+    calls = [line for line in published_diffusion_step[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in KERNELS:
+        mine = [line for line in calls
+                if re.search(rf"flash_attention_{name}\b", line)]
+        assert len(mine) == SDAR["num_hidden_layers"], (name, len(mine))
+        assert all(re.search(
+            rf'op_name="[^"]*layer/attn/mixer/part/kernel/'
+            rf'flash_attention_{name}/pallas_call"', line) for line in mine)
+    # The other custom calls are the expert layer's grouped products.
+    assert sum("flash_attention_" in line for line in calls) \
+        == 3 * SDAR["num_hidden_layers"]
+
+
+def test_published_diffusion_step_holds_no_score_or_mask_array(
+        published_diffusion_step):
+    """No ``[.., 512, keys]`` score or mask array of the blocked form and no
+    ``[.., 16384, 16384]`` one of a mask made whole; what the kernels read
+    and write instead, in their own layout, over all 2 x 8,192 rows."""
+    text = published_diffusion_step[0]
+    assert not score_arrays(text)
+    rows = 2 * SDAR["seq_len"]
+    assert not re.search(rf"\[[0-9,]*{rows},{rows}\]", text)
+    assert f"bf16[1,4,8,{rows},128]" in text
+    assert f"f32[1,4,8,{rows}]" in text

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from gtopkssgd_tpu.models import (
-    decoder, kanana2, keye_vl2, ouro, qwen3_next, trinity_mini)
+    decoder, kanana2, keye_vl2, ouro, qwen3_next, sdar, trinity_mini)
 from gtopkssgd_tpu.ops import dsa_attention, dsa_index, flash_attention
 from perfbench.metrics import layer_ms, part_ms, scoped
 
@@ -95,6 +95,13 @@ def looped():
     return layer, sizes, lambda out: jnp.sum(out[0])
 
 
+def block_diffusion():
+    # The layer's rows are a sequence's clean tokens and its noised copy.
+    sizes = dict(sdar.PRESETS["tiny"], seq_len=2 * 64)
+    layer = remat_layer(sdar.Layer, [sdar.KEPT_ATTENTION])(sizes, jnp.float32)
+    return layer, sizes, lambda out: jnp.sum(out[0])
+
+
 def qwen():
     sizes = qwen3_next.PRESETS["tiny"]
     layer = remat_layer(qwen3_next.Layer, [
@@ -115,7 +122,8 @@ def keye():
 LAYERS = {"trinity_sliding": (lambda: trinity(True), "attn_window"),
           "trinity_full": (lambda: trinity(False), "attn_full"),
           "qwen": (qwen, "attn"), "keye": (keye, "attn"),
-          "kanana": (kanana, "attn_latent"), "ouro": (looped, "attn")}
+          "kanana": (kanana, "attn_latent"), "ouro": (looped, "attn"),
+          "sdar": (block_diffusion, "attn")}
 
 
 def lowered_layer(name):
@@ -136,6 +144,8 @@ def kernel_form(monkeypatch):
         monkeypatch.setattr(module, "TILE_Q", 8)
         monkeypatch.setattr(module, "TILE_K", 8)
     monkeypatch.setattr(decoder, "attention_form", lambda *a: "kernel")
+    monkeypatch.setattr(decoder, "diffusion_attention_form",
+                        lambda *a: "kernel")
     monkeypatch.setattr(keye_vl2, "attention_form", lambda *a: "kernel")
     jax.clear_caches()
     yield
@@ -313,6 +323,7 @@ def test_the_one_device_step_works_on_leaves_under_the_same_stages(
     dict(dnn="trinity_mini", model_preset="tiny"),
     dict(dnn="kanana2", model_preset="tiny"),
     dict(dnn="ouro", model_preset="tiny"),
+    dict(dnn="sdar", model_preset="tiny"),
     dict(dnn="resnet20", dataset="cifar10"),
 ], ids=lambda f: f["dnn"])
 def test_the_one_device_step_moves_no_whole_vector(tmp_path, monkeypatch,
