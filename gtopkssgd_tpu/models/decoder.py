@@ -32,7 +32,11 @@ whatever the imbalance, and a balanced step runs one block. What the absent
 experts would add is left out; nothing stands in for the other chips or
 their all-to-all. A model with a shared expert gives its width
 (``shared_expert_intermediate_size``); one without leaves the key out and
-the layer has no such leaves.
+the layer has no such leaves. What the dispatch chose (ids and their scores,
+the sort's order, the sorted slots' tokens and weights, the held experts'
+sizes) is kept across a layer's remat by name (``KEPT_DISPATCH``): every
+model builds its layers' policy with ``kept_by_name``, and the replay makes
+no second top-k or sort.
 """
 
 from __future__ import annotations
@@ -44,6 +48,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
@@ -358,6 +363,51 @@ def normed_mixer(x, mixer, w_in, eps, w_post=None):
 
 
 # ------------------------------------------------------------ expert layer
+# What a layer's remat keeps of the expert layer's dispatch by
+# ``checkpoint_name``, so that the layer's replay makes no second top-k,
+# sort, count or gather: the chosen experts' ids and scores [T, top], the
+# sorted slots' order, token and weight [T * top], which of them fall on a
+# held expert (a bool each) and the held experts' sizes [held] (21 B a
+# token-slot: 2.8 MB a layer at 16,384 tokens and 8 experts a token, 3.4
+# MB at 10). The router's logits and scores are not kept
+# ([T, experts] float32, 33.6 MB a layer at 512 experts): their second run
+# is cheap and the softmax's backward reads the scores.
+KEPT_DISPATCH = "moe_dispatch"
+
+
+def kept_by_name(*names):
+    """The remat policy of a decoder layer: the model's own ``names`` are
+    kept across the remat and, whatever the model keeps, the expert
+    layer's dispatch."""
+    return jax.checkpoint_policies.save_only_these_names(
+        *names, KEPT_DISPATCH)
+
+
+# ``lax.top_k`` over the last axis, differentiated through ids that carry
+# the dispatch's name. top_k's own rule takes the tangent's entries at its
+# raw index output, which no name reaches, so a replay would run the top-k
+# again for them; this rule is the same gather through the named ids. Only
+# the rule names them: under a remat it is the rule that is traced. The
+# softmax routers take top_k's own values this way; ``route``'s biased path
+# cannot (it chooses by other scores than it weighs) and pays for its
+# ``take_along_axis``: a gather of 1.67 ms a layer over [16384, 512] beside
+# a top_k of 1.44 (PERF.md section 6, PR 46, call A), which is why the
+# softmax path is not written in the biased path's form.
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def kept_top_k(scores, top):
+    values, ids = lax.top_k(scores, top)
+    return values, ids
+
+
+@kept_top_k.defjvp
+def _kept_top_k_jvp(top, primals, tangents):
+    values, ids = lax.top_k(*primals, top)
+    ids = checkpoint_name(ids, KEPT_DISPATCH)
+    return (values, ids), (
+        jnp.take_along_axis(tangents[0], ids, -1),
+        np.zeros(ids.shape, jax.dtypes.float0))
+
+
 def route(x, router, top, normalise, score_func="softmax", bias=None,
           scale=1.0):
     """(weights of the ``top`` experts [T, top] float32, their ids), over
@@ -374,10 +424,12 @@ def route(x, router, top, normalise, score_func="softmax", bias=None,
     else:
         raise ValueError(f"score_func {score_func!r}: softmax or sigmoid")
     if bias is None:
-        values, ids = lax.top_k(scores, top)
+        values, ids = kept_top_k(scores, top)
     else:
         _, ids = lax.top_k(lax.stop_gradient(scores) + bias, top)
+        ids = checkpoint_name(ids, KEPT_DISPATCH)
         values = jnp.take_along_axis(scores, ids, -1)
+    values = checkpoint_name(values, KEPT_DISPATCH)
     if normalise:
         total = jnp.sum(values, -1, keepdims=True)
         # Sigmoid scores can all underflow; softmax's largest cannot.
@@ -403,10 +455,11 @@ def sort_held_slots(ids, probs, offset, held):
     top = ids.shape[1]
     local = ids.reshape(-1) - offset
     key = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(key, stable=True)
+    kept = lambda a: checkpoint_name(a, KEPT_DISPATCH)
+    order = kept(jnp.argsort(key, stable=True))
     sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    weight = jnp.where(key[order] < held, probs.reshape(-1)[order], 0.0)
-    return (order // top).astype(jnp.int32), weight, sizes
+    weight = jnp.where(kept(key[order] < held), probs.reshape(-1)[order], 0.0)
+    return kept((order // top).astype(jnp.int32)), kept(weight), kept(sizes)
 
 
 def blocks_run(sizes, rows, max_blocks):

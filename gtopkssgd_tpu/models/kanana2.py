@@ -55,8 +55,8 @@ import jax.numpy as jnp
 
 from gtopkssgd_tpu.models.decoder import (
     BALANCE_COUNTS, F32, DenseMLP, SparseMoE, _normal, attention_form,
-    blocked_causal_attention, decoder_shell, dense, normed_mixer, rms_norm0,
-    rotary)
+    blocked_causal_attention, decoder_shell, dense, kept_by_name,
+    normed_mixer, rms_norm0, rotary)
 
 # The published sizes (config.json of kanana-2-30b-a3b-instruct-2601) with
 # the three cuts of perfbench/configs/kanana2_30b_a3b_ep16.json, whose
@@ -228,7 +228,7 @@ class Kanana2(nn.Module):
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
-        by_name = jax.checkpoint_policies.save_only_these_names(KEPT_ATTENTION)
+        by_name = kept_by_name(KEPT_ATTENTION)
         return decoder_shell(
             self, tokens, targets,
             lambda i: nn.remat(Layer, policy=by_name)(

@@ -74,8 +74,8 @@ from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics
 from gtopkssgd_tpu.ops import dsa_attention as kernels
 from gtopkssgd_tpu.ops import dsa_index as index_kernels
 from gtopkssgd_tpu.models.decoder import (
-    F32, MOE_COUNTS, SparseMoE, _normal, decoder_shell, dense, kernel_layout,
-    on_tpu, rms_norm0, rotary)
+    F32, MOE_COUNTS, SparseMoE, _normal, decoder_shell, dense, kept_by_name,
+    kernel_layout, on_tpu, rms_norm0, rotary)
 
 # The published sizes (config.json of Keye-VL-2.0-30B-A3B; ``sa_config``'s
 # keys flat) with the three cuts of
@@ -723,7 +723,7 @@ class KeyeVL2(nn.Module):
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
-        by_name = jax.checkpoint_policies.save_only_these_names(
+        by_name = kept_by_name(
             KEPT_SELECTION, KEPT_ATTENTION, KEPT_MASKS, KEPT_PROBABILITIES)
         out = decoder_shell(
             self, tokens, targets,

@@ -78,8 +78,8 @@ from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics
 from gtopkssgd_tpu.models import decoder
 from gtopkssgd_tpu.models.decoder import (
     F32, HIGHEST, MOE_COUNTS, SparseMoE, _normal, attention_form,
-    blocked_causal_attention, decoder_shell, dense, query_block_of,
-    rms_norm0, rotary)
+    blocked_causal_attention, decoder_shell, dense, kept_by_name,
+    query_block_of, rms_norm0, rotary)
 from gtopkssgd_tpu.ops import delta_chunks as delta_kernels
 from gtopkssgd_tpu.ops import gdn_conv as conv_kernels
 
@@ -581,12 +581,13 @@ class Qwen3Next(nn.Module):
     @nn.compact
     def __call__(self, tokens, targets=None, *, train: bool = False):
         s = self.sizes
-        by_name = jax.checkpoint_policies.save_only_these_names(
-            KEPT_CHUNKS, KEPT_ATTENTION)
+        # A layer past the budget keeps the expert layer's dispatch alone.
+        policy = {True: kept_by_name(KEPT_CHUNKS, KEPT_ATTENTION),
+                  False: kept_by_name()}
         keeps = [keep for _, keep in kept_across_remat(
             s, *tokens.shape, kept_budget(*tokens.shape))]
         return decoder_shell(
             self, tokens, targets,
-            lambda i: nn.remat(Layer, policy=by_name if keeps[i] else None)(
+            lambda i: nn.remat(Layer, policy=policy[keeps[i]])(
                 s, self.dtype, is_attention(s, i), name=f"layer_{i}"),
             len(keeps), MOE_COUNTS)

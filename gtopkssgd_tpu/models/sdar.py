@@ -61,8 +61,8 @@ import jax.numpy as jnp
 
 from gtopkssgd_tpu.models.decoder import (
     F32, MOE_COUNTS, SparseMoE, _normal, block_diffusion_attention, dense,
-    diffusion_attention_form, embedded, head_weights, logits_of,
-    normed_mixer, rms_norm0, rotary, run_layers, token_losses)
+    diffusion_attention_form, embedded, head_weights, kept_by_name,
+    logits_of, normed_mixer, rms_norm0, rotary, run_layers, token_losses)
 
 # The published sizes (config.json of SDAR-30B-A3B-Chat) with the three cuts
 # of perfbench/configs/sdar_30b_a3b_ep8.json, whose ``sizes`` a test holds
@@ -213,7 +213,7 @@ class SDAR(nn.Module):
                 key, tokens, s["block_length"], s["mask_token_id"],
                 s["noise_eps"])
             rows = jnp.concatenate([tokens, noised], axis=1)
-        by_name = jax.checkpoint_policies.save_only_these_names(KEPT_ATTENTION)
+        by_name = kept_by_name(KEPT_ATTENTION)
         x, found = run_layers(
             [nn.remat(Layer, policy=by_name)(s, dtype, name=f"layer_{i}")
              for i in range(s["num_hidden_layers"])], embedded(self, rows))

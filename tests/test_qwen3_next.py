@@ -5,6 +5,7 @@ the expert shares against the uncut layer, and the rule that no token-slot
 is dropped."""
 
 import collections
+import contextlib
 import os
 import sys
 
@@ -16,7 +17,8 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from gtopkssgd_tpu.models import get_model, keye_vl2 as keye_prog  # noqa: E402
+from gtopkssgd_tpu.models import decoder, get_model  # noqa: E402
+from gtopkssgd_tpu.models import keye_vl2 as keye_prog  # noqa: E402
 from gtopkssgd_tpu.models import qwen3_next as prog  # noqa: E402
 from gtopkssgd_tpu.models import trinity_mini as trinity_prog  # noqa: E402
 from gtopkssgd_tpu.obs import counters  # noqa: E402
@@ -374,55 +376,96 @@ def primitives(jaxpr, into=None):
     return into
 
 
-def gradient_and_primitives(params, batch):
-    """(((loss, counts), gradient), the gradient's primitives) on ``tiny``
-    with bfloat16 products, traced anew: the budget is read at trace time."""
-    module = prog.Qwen3Next("tiny", jnp.bfloat16)
-    grad = jax.value_and_grad(lambda p: module.apply(
-        {"params": p}, batch["tokens"], batch["targets"], train=True),
-        has_aux=True)
+def gradient_and_primitives(module, variables, batch):
+    """(((loss, (counts, what the step moved)), gradient), the gradient's
+    primitives) of a decoder's training step on ``batch``, traced anew: a
+    policy and a budget are read at trace time."""
+    rest = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss(params):
+        (value, counts), moved = module.apply(
+            {"params": params, **rest}, batch["tokens"], batch["targets"],
+            train=True, mutable=list(rest),
+            rngs={"dropout": jax.random.PRNGKey(5)})
+        return value, (counts, moved)
+
+    grad = jax.value_and_grad(loss, has_aux=True)
+    params = variables["params"]
     return (jax.jit(grad)(params),
             primitives(jax.make_jaxpr(grad)(params).jaxpr))
 
 
+def tiny_gradient(params, batch):
+    """``gradient_and_primitives`` of ``tiny`` with bfloat16 products."""
+    return gradient_and_primitives(
+        prog.Qwen3Next("tiny", jnp.bfloat16), {"params": params}, batch)
+
+
+@contextlib.contextmanager
+def dispatch_unnamed():
+    """Every remat policy built inside is without the expert layer's
+    dispatch (``decoder.KEPT_DISPATCH``): what a model whose policy forgot
+    the name would compile."""
+    real = jax.checkpoint_policies.save_only_these_names
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            jax.checkpoint_policies, "save_only_these_names",
+            lambda *names: real(*(
+                name for name in names if name != decoder.KEPT_DISPATCH)))
+        yield
+
+
 @pytest.fixture(scope="module")
 def kept_and_not(seeded):
-    """{"kept": by name, "not": with a budget of no bytes, which is the
-    remat with no policy} -> ``gradient_and_primitives``."""
-    out = {"kept": gradient_and_primitives(*seeded)}
+    """{"kept": by name, "not": with a budget of no bytes, which keeps
+    the expert layers' dispatch and nothing else, "unnamed": the budget's
+    names without the dispatch's} -> ``tiny_gradient``."""
+    out = {"kept": tiny_gradient(*seeded)}
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(prog, "kept_budget", lambda batch, length: 0)
-        out["not"] = gradient_and_primitives(*seeded)
+        out["not"] = tiny_gradient(*seeded)
+    with dispatch_unnamed():
+        out["unnamed"] = tiny_gradient(*seeded)
     return out
 
 
+@pytest.mark.parametrize("other", ["not", "unnamed"])
 @pytest.mark.parametrize("part", ["loss", "moe_load", "moe_dropped", "gradient"])
-def test_keeping_by_name_changes_no_value(kept_and_not, part):
+def test_keeping_by_name_changes_no_value(kept_and_not, part, other):
     """Same operations on the same values, one execution fewer: every leaf
     bit for bit."""
-    pick = lambda out: {"loss": out[0][0], "gradient": out[1], **out[0][1]}[part]
-    kept, bare = (pick(kept_and_not[k][0]) for k in ("kept", "not"))
+    pick = lambda out: {"loss": out[0][0], "gradient": out[1], **out[0][1][0]}[part]
+    kept, bare = (pick(kept_and_not[k][0]) for k in ("kept", other))
     for (name, a), (_, b) in zip(leaves(kept), leaves(bare)):
         assert np.array_equal(np.asarray(a), np.asarray(b)), name
     assert all(np.isfinite(np.asarray(a, np.float32)).all()
                for _, a in leaves(kept))
 
 
-@pytest.mark.parametrize("primitive,kept,bare", [
+@pytest.mark.parametrize("primitive,kept,bare,unnamed", [
     # A DeltaNet layer's solve: forward, once more for ``prepare``'s own
     # backward, two transposed in the solve's VJP; not kept, the layer's
     # replay adds a fifth.
-    ("triangular_solve", 4 * 3, 5 * 3),
+    ("triangular_solve", 4 * 3, 5 * 3, 4 * 3),
     # ``lax.map(prepare)`` is a scan: one fewer a DeltaNet layer.
-    ("scan", 17, 17 + 3),
-    # The softmax's row maximum of an attention query block (two blocks
-    # on ``tiny``), beside the routers' and the loss's.
-    ("reduce_max", 14, 14 + 2),
+    ("scan", 17, 17 + 3, 17),
+    # A softmax's row maximum: the four routers' forward and again in
+    # their layer's replay, whose scores are not kept (4 * 2), the loss's
+    # and its own checkpoint's (2), and an attention query block's (two
+    # blocks on ``tiny``) forward and under the block's own checkpoint
+    # (2 * 2); not kept, the layer's replay adds the two blocks'.
+    ("reduce_max", 4 * 2 + 2 + 2 * 2, 4 * 2 + 2 + 2 * 3, 4 * 2 + 2 + 2 * 2),
+    # The dispatch: one sort and one top-k an expert layer (four on
+    # ``tiny``) in the whole gradient whatever the budget, and two where
+    # the policy lacks the dispatch's name.
+    ("sort", 4, 4, 4 * 2),
+    ("top_k", 4, 4, 4 * 2),
 ])
 def test_a_layers_inner_checkpoints_run_twice_not_three_times(
-        kept_and_not, primitive, kept, bare):
+        kept_and_not, primitive, kept, bare, unnamed):
     assert kept_and_not["kept"][1][primitive] == kept
     assert kept_and_not["not"][1][primitive] == bare
+    assert kept_and_not["unnamed"][1][primitive] == unnamed
 
 
 @pytest.mark.parametrize("preset,batch,budget,want", [
@@ -464,10 +507,14 @@ def test_published_depth_keeps_what_the_budget_holds_and_no_more():
 def test_layers_past_the_budget_still_differentiate(seeded, kept_and_not,
                                                     monkeypatch):
     """A budget that holds the first two layers' outputs only: the other
-    two fall back to the plain remat, and the gradient is the same."""
+    two (a DeltaNet layer and the attention layer) keep their expert
+    layer's dispatch and nothing else, and the gradient is the same."""
     monkeypatch.setattr(prog, "kept_budget", lambda batch, length: 393_344 * 2)
-    out, count = gradient_and_primitives(*seeded)
+    out, count = tiny_gradient(*seeded)
     assert count["triangular_solve"] == 4 * 2 + 5
-    assert count["reduce_max"] == 14 + 2
+    # The routers' 4 * 2 and the loss's 2 as kept; the attention layer's
+    # replay runs its two query blocks a third time.
+    assert count["reduce_max"] == 4 * 2 + 2 + 2 * 3
+    assert (count["sort"], count["top_k"]) == (4, 4)
     for (name, a), (_, b) in zip(leaves(out), leaves(kept_and_not["kept"][0])):
         assert np.array_equal(np.asarray(a), np.asarray(b)), name
