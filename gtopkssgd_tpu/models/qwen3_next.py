@@ -11,15 +11,20 @@ here is how they are computed:
     tokens the recurrence is a unit lower-triangular system, solved for all
     chunks at once on the MXU (the WY form: ``delta = U - W S``,
     ``delta_chunks``, ``GDN_SEQUENCES`` at a time), and only the d_k x d_v
-    state crosses chunks, in one ``lax.scan`` for the whole batch
-    (``scan_chunks``; forward and, by autodiff through the same scan,
-    backward). The chunks' algebra has two forms, chosen by ``delta_form``
-    from what it observes (no flag): on a TPU at chunks of 64, heads of
-    whole lane rows and whole blocks of chunks the fused kernels of
-    ``ops/delta_chunks.py``, forward and backward, in which a chunk's
-    blocks stay in VMEM and the triangular system is inverted by products;
-    elsewhere XLA's products and ``triangular_solve``, the oracle of the
-    kernels' tests (``forms`` says which compiled: ``delta_form``);
+    state crosses chunks, in one pass for the whole batch. Both have two
+    forms, chosen by ``delta_form`` and ``scan_form`` from what they
+    observe (no flag): on a TPU at chunks of 64, heads of whole lane rows
+    and whole blocks of chunks the fused kernels of ``ops/delta_chunks.py``,
+    forward and backward, in which a chunk's blocks stay in VMEM and the
+    triangular system is inverted by products, and for the state's pass
+    the two kernels of ``ops/delta_scan.py`` (``kernel_scan_chunks``), in
+    which the state stays in VMEM across the chunks, forward, and its
+    cotangent across the same chunks in reverse, backward, from the state
+    the forward rule saved at every chunk's start; elsewhere XLA's
+    products and ``triangular_solve`` and one ``lax.scan``
+    (``scan_chunks``; backward by autodiff through the same scan), the
+    oracles of the kernels' tests (``forms`` says which compiled:
+    ``delta_form``, ``scan_form``);
   * a DeltaNet layer's depthwise causal convolution, SiLU and unit norms
     in two forms as well, chosen by ``conv_form`` (no flag): on a TPU at
     heads of whole lane rows and whole token blocks the two fused kernels
@@ -81,6 +86,7 @@ from gtopkssgd_tpu.models.decoder import (
     blocked_causal_attention, decoder_shell, dense, kept_by_name,
     query_block_of, rms_norm0, rotary)
 from gtopkssgd_tpu.ops import delta_chunks as delta_kernels
+from gtopkssgd_tpu.ops import delta_scan as scan_kernels
 from gtopkssgd_tpu.ops import gdn_conv as conv_kernels
 
 # The chunked delta rule's float32 products (module docstring, Precision).
@@ -369,6 +375,49 @@ def scan_chunks(u, w, attn, q_in, k_out, decay):
         batch, n * chunk, heads, d_v)
 
 
+def scan_form(length, chunk, d_k, d_v):
+    """``kernel`` where the state's pass runs as the Pallas kernels of
+    ``ops/delta_scan.py``, ``xla`` where as ``scan_chunks``' ``lax.scan``:
+    where the chunks' algebra is in kernels (``delta_form``) the state's
+    pass over what they wrote is too."""
+    return delta_form(length, chunk, d_k, d_v)
+
+
+@jax.custom_vjp
+def _scan_kernels(u, w, attn, q_in, k_out, decay):
+    return scan_kernels.forward(u, w, attn, q_in, k_out, decay,
+                                interpret=not decoder.on_tpu())
+
+
+def _scan_kernels_fwd(*prepared):
+    # What the backward kernel takes besides the inputs: the state at the
+    # start of every chunk. A layer's first forward under its remat asks
+    # for no residuals and runs the primal above, which writes none
+    # (``optimize_remat``: an opaque call's unused output is not dropped
+    # otherwise).
+    out, states = scan_kernels.forward(*prepared, states=True,
+                                       interpret=not decoder.on_tpu())
+    return out, prepared + (states,)
+
+
+def _scan_kernels_bwd(residuals, d_out):
+    return scan_kernels.backward(*residuals, d_out,
+                                 interpret=not decoder.on_tpu())
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd,
+                     optimize_remat=True)
+
+
+def kernel_scan_chunks(u, w, attn, q_in, k_out, decay):
+    """``scan_chunks`` with the state in the kernels' VMEM (interpret mode
+    off the TPU): the same values and gradients. The kernels take ``decay``
+    [B, H, n], the chunks along the lanes, and write o as [B, n C, H, d_v]
+    themselves: no transpose on either side of them."""
+    return _scan_kernels(u, w, attn, q_in, k_out,
+                         decay[..., 0, 0].transpose(1, 2, 0))
+
+
 def pad_to_chunks(arrays, chunk):
     """(arrays padded along the sequence to a multiple of ``chunk``, the
     length before). A padded token decays nothing and writes nothing."""
@@ -387,9 +436,12 @@ def chunked_delta_rule(q, k, v, g, beta, chunk):
     S, to o [B, S, H, d_v]. The chunks' algebra in the form ``delta_form``
     finds."""
     arrays, length = pad_to_chunks((q, k, v, g, beta), chunk)
-    chunks = kernel_delta_chunks if delta_form(
-        length, chunk, q.shape[-1], v.shape[-1]) == "kernel" else delta_chunks
-    return scan_chunks(*chunks(*arrays, chunk))[:, :length]
+    shapes = (length, chunk, q.shape[-1], v.shape[-1])
+    chunks = kernel_delta_chunks if delta_form(*shapes) == "kernel" \
+        else delta_chunks
+    scan = kernel_scan_chunks if scan_form(*shapes) == "kernel" \
+        else scan_chunks
+    return scan(*chunks(*arrays, chunk))[:, :length]
 
 
 # ------------------------------------------------------------------ modules
@@ -417,6 +469,8 @@ class GatedDeltaNet(nn.Module):
         batch, length = x.shape[:2]
         chunk = chunk_of(s["seq_len"])
         kernels = delta_form(length, chunk, d_k, d_v) == "kernel"
+        scan = kernel_scan_chunks if scan_form(
+            length, chunk, d_k, d_v) == "kernel" else scan_chunks
         fused = conv_form(length, key_w, val_w, d_k) == "kernel"
         group = math.gcd(batch, GDN_SEQUENCES[
             "kernel" if fused and kernels else "xla"])
@@ -468,9 +522,9 @@ class GatedDeltaNet(nn.Module):
             whole = lambda a: jnp.moveaxis(a, 0, 1).reshape(
                 (a.shape[1], batch) + a.shape[3:])
         with jax.named_scope("layer/gdn_scan"):
-            # The state crosses the chunks of every sequence in one scan.
-            o = scan_chunks(*(checkpoint_name(whole(a), KEPT_CHUNKS)
-                              for a in prepared))[:, :length]
+            # The state crosses the chunks of every sequence in one pass.
+            o = scan(*(checkpoint_name(whole(a), KEPT_CHUNKS)
+                       for a in prepared))[:, :length]
         with jax.named_scope("layer/gdn_proj"):
             z = qkvz[..., 2 * key_w + val_w:].astype(F32).reshape(
                 batch, length, h_v, d_v)
@@ -573,6 +627,8 @@ class Qwen3Next(nn.Module):
         d_k, d_v = s["linear_key_head_dim"], s["linear_value_head_dim"]
         return {"attention_form": attention_form(length, s["head_dim"]),
                 "delta_form": delta_form(
+                    length, chunk_of(s["seq_len"]), d_k, d_v),
+                "scan_form": scan_form(
                     length, chunk_of(s["seq_len"]), d_k, d_v),
                 "conv_form": conv_form(
                     length, s["linear_num_key_heads"] * d_k,

@@ -38,7 +38,9 @@ Layouts (H value heads, H_k key heads, n chunks of C tokens, S = n C):
                                                  lanes (a last axis of 1
                                                  would cost 128 lanes a
                                                  number)
-  u, w, q_in, k_out          [n, B, H, C, d]     what ``scan_chunks`` reads
+  u, w, q_in, k_out          [n, B, H, C, d]     what the state's pass
+                                                 reads (``scan_chunks``, or
+                                                 ``ops/delta_scan.py``)
   attn                       [n, B, H, C, C]
 
 The grid is (sequence, block of ``BLOCK`` chunks, value head), the heads

@@ -136,6 +136,7 @@ def test_the_delta_rule_through_the_kernels_equals_the_recurrence(
 
     args = delta_inputs(length, 0, 1, 2, DIM, DIM)
     monkeypatch.setattr(qwen3_next, "delta_form", lambda *a: "kernel")
+    monkeypatch.setattr(qwen3_next, "scan_form", lambda *a: "xla")
     rule = lambda *a: qwen3_next.chunked_delta_rule(*a, CHUNK)
     want = recurrence(*args)
     assert "delta_chunks_forward" in pallas_calls(
@@ -176,8 +177,9 @@ def test_the_delta_form_follows_the_backend_and_the_shapes(
     ("80b_a3b_ep64", PUBLISHED["seq_len"]), ("tiny", 128)])
 def test_the_models_forms_name_the_delta_form(preset, length, monkeypatch):
     model = qwen3_next.Qwen3Next(preset)
-    assert model.forms(length) == {"attention_form": "blocked",
-                                   "delta_form": "xla", "conv_form": "xla"}
+    assert model.forms(length) == {
+        "attention_form": "blocked", "delta_form": "xla", "scan_form": "xla",
+        "conv_form": "xla"}
     monkeypatch.setattr(decoder, "on_tpu", lambda: True)
     on_chip = "xla" if preset == "tiny" else "kernel"
     assert model.forms(length)["delta_form"] == on_chip
@@ -196,7 +198,8 @@ def test_tiny_through_the_kernels_is_the_same_model(monkeypatch):
     inputs, and the outputs are kept by name), the convolution's forward
     kernel twice (``prepare``'s checkpoint makes q, k and v again for the
     chunk kernel's backward) and its backward once, and no triangular
-    solve."""
+    solve. (The state's pass stays ``scan_chunks`` here; through its own
+    kernels too is tests/test_delta_scan_kernel.py's.)"""
     monkeypatch.setitem(qwen3_next.PRESETS, "tiny", dict(
         qwen3_next.PRESETS["tiny"], num_hidden_layers=2,
         full_attention_interval=2))
@@ -204,6 +207,7 @@ def test_tiny_through_the_kernels_is_the_same_model(monkeypatch):
     grad, params = tiny_step(module, 128)
     (loss_x, _), grads_x = jax.jit(grad)(params)
     monkeypatch.setattr(qwen3_next, "delta_form", lambda *a: "kernel")
+    monkeypatch.setattr(qwen3_next, "scan_form", lambda *a: "xla")
     monkeypatch.setattr(qwen3_next, "conv_form", lambda *a: "kernel")
     monkeypatch.setattr(gdn_conv, "LANES", 16)
     jax.clear_caches()          # or the second trace is the first's
@@ -229,7 +233,7 @@ def test_the_runs_records_name_the_delta_form(tmp_path):
     from gtopkssgd_tpu.trainer import TrainConfig, Trainer
 
     forms = {"attention_form": "blocked", "delta_form": "xla",
-             "conv_form": "xla"}
+             "scan_form": "xla", "conv_form": "xla"}
     with Trainer(TrainConfig(dnn="qwen3_next", dataset="tokens",
                              model_preset="tiny", batch_size=2,
                              compression="gtopk", density=0.01,

@@ -176,8 +176,10 @@ def test_the_models_forms_name_the_conv_form(preset, length, monkeypatch):
     assert model.forms(length)["conv_form"] == "xla"
     monkeypatch.setattr(decoder, "on_tpu", lambda: True)
     on_chip = {"attention_form": "blocked", "delta_form": "xla",
+               "scan_form": "xla",
                "conv_form": "xla"} if preset == "tiny" else dict.fromkeys(
-                   ("attention_form", "delta_form", "conv_form"), "kernel")
+                   ("attention_form", "delta_form", "scan_form", "conv_form"),
+                   "kernel")
     assert model.forms(length) == on_chip
 
 
