@@ -492,8 +492,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "or tiny, keye_vl2 takes 30b_a3b_ep16 or tiny, "
                         "trinity_mini takes 26b_a3b_ep16 or tiny, kanana2 "
                         "takes 30b_a3b_ep16 or tiny, ouro takes 2p6b_l5 "
-                        "or tiny, sdar takes 30b_a3b_ep8 or tiny (each "
-                        "model's PRESETS); another name, or a model that "
+                        "or tiny, sdar takes 30b_a3b_ep8 or tiny, kimi_linear "
+                        "takes 48b_a3b_ep32 or tiny (each model's PRESETS); another name, or a model that "
                         "has no presets, is an error")
     p.add_argument("--s2d", action="store_true",
                    help="resnet50: space-to-depth stem (4x4x12 conv on 2x2 "

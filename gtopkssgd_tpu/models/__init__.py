@@ -19,10 +19,11 @@ from typing import Any, Callable, Dict, Tuple
 import flax.linen as nn
 
 from gtopkssgd_tpu.models import (
-    kanana2, keye_vl2, ouro, qwen3_next, sdar, trinity_mini)
+    kanana2, keye_vl2, kimi_linear, ouro, qwen3_next, sdar, trinity_mini)
 from gtopkssgd_tpu.models.alexnet import AlexNet
 from gtopkssgd_tpu.models.kanana2 import Kanana2
 from gtopkssgd_tpu.models.keye_vl2 import KeyeVL2
+from gtopkssgd_tpu.models.kimi_linear import KimiLinear
 from gtopkssgd_tpu.models.lstm import PTBLSTM
 from gtopkssgd_tpu.models.lstman4 import DeepSpeechAN4
 from gtopkssgd_tpu.models.ouro import Ouro
@@ -197,6 +198,19 @@ _register(
         presets=tuple(sdar.PRESETS),
     )
 )
+_register(
+    ModelSpec(
+        "kimi_linear",
+        KimiLinear,
+        "tokens",
+        (8192,),  # one sequence of token ids
+        # As kanana2: the balancing bias rides in ``batch_stats``.
+        has_batchnorm=False,
+        input_key="tokens",
+        loss="own",
+        presets=tuple(kimi_linear.PRESETS),
+    )
+)
 
 
 def get_model(dnn: str, **kwargs: Any) -> Tuple[nn.Module, ModelSpec]:
@@ -250,4 +264,5 @@ __all__ = [
     "Kanana2",
     "Ouro",
     "SDAR",
+    "KimiLinear",
 ]

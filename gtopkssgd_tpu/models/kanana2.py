@@ -20,7 +20,11 @@ file's own is the mixer; the rest is the zoo's (``models/decoder.py``):
     with 32 key-value heads of a 192-wide key and a 128-wide value, scaled
     by 1 / sqrt(192). On a TPU at whole tiles it runs as the fused flash
     kernels of ``ops/flash_attention.py`` (``forms`` says which compiled:
-    ``attention_form``), everywhere else as XLA's query blocks;
+    ``attention_form``), everywhere else as XLA's query blocks. The rotary
+    is applied iff the sizes say so: a model whose latent attention carries
+    no position (``mla_use_nope``; ``models/kimi_linear.py`` builds its
+    full-attention layers from this class) keeps q_pe and k_pe as they are
+    projected, and everything else of the mixer is the same;
   * the expert layer is ``SparseMoE``, told by ``moe_sizes`` that its router
     scores with a sigmoid, chooses on score + bias, weighs by the score
     alone, renormalises and scales by ``routed_scaling_factor``, and that
@@ -147,7 +151,11 @@ class LatentAttention(nn.Module):
             # Every parameter is made; the rest would be traced for shapes
             # alone at every start.
             return jnp.zeros(h.shape, dtype)
-        theta = s["rope_theta"]
+        # A model whose latent attention carries no position
+        # (``mla_use_nope``) keeps the p-wide parts of q and the shared key
+        # as they are projected.
+        turned = (lambda a: a.astype(F32)) if s.get("mla_use_nope") else (
+            lambda a: rotary_interleaved(a.astype(F32), s["rope_theta"]))
         with jax.named_scope("part/proj"):
             q = dense(h, w_q, dtype).reshape(batch, length, heads, nope + rope)
             latent = dense(h, w_kva, dtype)
@@ -156,8 +164,7 @@ class LatentAttention(nn.Module):
             c_kv, k_pe = latent[..., :rank], latent[:, :, None, rank:]
         with jax.named_scope("part/pointwise"):
             c_kv = rms_norm0(c_kv, w_kvn, s["rms_norm_eps"])
-            q_pe, k_pe = (rotary_interleaved(a.astype(F32), theta)
-                          for a in (q_pe, k_pe))
+            q_pe, k_pe = turned(q_pe), turned(k_pe)
         with jax.named_scope("part/proj"):
             kv = dense(c_kv, w_kvb, dtype).reshape(
                 batch, length, heads, nope + value)

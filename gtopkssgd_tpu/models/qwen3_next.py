@@ -11,7 +11,12 @@ here is how they are computed:
     tokens the recurrence is a unit lower-triangular system, solved for all
     chunks at once on the MXU (the WY form: ``delta = U - W S``,
     ``delta_chunks``, ``GDN_SEQUENCES`` at a time), and only the d_k x d_v
-    state crosses chunks, in one pass for the whole batch. Both have two
+    state crosses chunks, in one pass for the whole batch. The XLA form of
+    both (``delta_chunks``, ``scan_chunks``, with ``pad_to_chunks``,
+    ``chunk_of`` and ``causal_conv``) lives in ``models/delta_rule.py``,
+    which ``kimi_linear`` imports too: there the rule is told by the shape
+    of ``g`` whether the decay is a number a head (this model) or a number
+    a key channel; this file keeps what is Qwen's own. Both have two
     forms, chosen by ``delta_form`` and ``scan_form`` from what they
     observe (no flag): on a TPU at chunks of 64, heads of whole lane rows
     and whole blocks of chunks the fused kernels of ``ops/delta_chunks.py``,
@@ -82,15 +87,14 @@ from jax.numpy import log as _ln   # graftlint reads any x.log(...) as a metrics
 
 from gtopkssgd_tpu.models import decoder
 from gtopkssgd_tpu.models.decoder import (
-    F32, HIGHEST, MOE_COUNTS, SparseMoE, _normal, attention_form,
+    F32, MOE_COUNTS, SparseMoE, _normal, attention_form,
     blocked_causal_attention, decoder_shell, dense, kept_by_name,
     query_block_of, rms_norm0, rotary)
+from gtopkssgd_tpu.models.delta_rule import (
+    causal_conv, chunk_of, delta_chunks, pad_to_chunks, scan_chunks)
 from gtopkssgd_tpu.ops import delta_chunks as delta_kernels
 from gtopkssgd_tpu.ops import delta_scan as scan_kernels
 from gtopkssgd_tpu.ops import gdn_conv as conv_kernels
-
-# The chunked delta rule's float32 products (module docstring, Precision).
-_mm = functools.partial(jnp.einsum, precision=HIGHEST)
 
 # The published sizes (config.json of Qwen3-Next-80B-A3B-Instruct) with the
 # three cuts of perfbench/configs/qwen3_next_80b_a3b_ep64.json, whose
@@ -123,7 +127,8 @@ PRESETS = {
 
 
 # Sequences whose convolution and chunk algebra are live at once in a
-# Gated DeltaNet layer, by the form of the two. In the XLA form their
+# Gated DeltaNet layer, by the form of the two (the XLA form of the algebra
+# is ``delta_rule.delta_chunks``, a number a head). In the XLA form their
 # float32 intermediates are what fills the chip: 2.7 GB a sequence of 4,096
 # tokens at the published widths, so one at a time, in a ``lax.map``. With
 # the chunks' blocks in VMEM (PR 38) a sequence still cost the convolution's
@@ -148,6 +153,8 @@ GDN_SEQUENCES = {"xla": 1, "kernel": 4}
 # the names the layer's replay would run both a second time just to hand
 # their own backward passes the same values.
 KEPT_CHUNKS, KEPT_ATTENTION = "gdn_chunks", "attn_out"
+# (Both are this model's readings at its own N and shapes: ``kimi_linear``,
+# which shares ``delta_rule``, decides what it keeps from its own step.)
 # Bytes the layers of one step may keep so, together: what a v5e (15.75
 # GiB, ``bytes_limit`` 16.909 GB) has left after a GiB of margin and the
 # step itself. With nothing kept XLA's analysis gives the benchmark's step
@@ -163,12 +170,6 @@ KEPT_CHUNKS, KEPT_ATTENTION = "gdn_chunks", "attn_out"
 # and 11.99 (compiled for a described v5e, PR 38).
 KEPT_BYTES = 16_909_000_000 - 2 ** 30 - 5_146_000_000
 STEP_BYTES_A_TOKEN = 334_844
-
-
-def chunk_of(seq_len: int) -> int:
-    """Tokens in a chunk of the delta rule: 64, less for a short sequence."""
-    return min(64, max(1, seq_len // 4))
-
 
 
 def is_attention(sizes, i):
@@ -215,13 +216,6 @@ def _conv(key, shape, dtype=F32):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
-def causal_conv(x, kernel):
-    """Depthwise causal convolution, x [B, S, C], kernel [K, C]."""
-    width, length = kernel.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    return sum(kernel[i] * padded[:, i:i + length] for i in range(width))
-
-
 def conv_form(length, key_width, value_width, d_k):
     """``kernel`` where a Gated DeltaNet layer's convolution, SiLU and unit
     norms run as the two Pallas kernels of ``ops/gdn_conv.py``, ``xla`` where
@@ -261,49 +255,6 @@ kernel_conv.defvjp(_kernel_conv_fwd, _kernel_conv_bwd)
 
 
 # ----------------------------------------------------- chunked delta rule
-def delta_chunks(q, k, v, g, beta, chunk):
-    """What the chunked delta rule needs of every chunk that does not
-    depend on the state: q, k [B, S, H, d_k], v [B, S, H, d_v], g (log
-    decay, <= 0) and beta [B, S, H], float32, S a multiple of ``chunk``,
-    to ``(u, w, attn, q_in, k_out, decay)`` with the chunk axis in front
-    ([n, B, H, C, ...]; ``decay`` [n, B, H, 1, 1]).
-
-    With gamma_t the running sum of g inside a chunk and S_0 the state at
-    its start, the recurrence  S_t = e^{g_t} S_{t-1} + k_t delta_t^T,
-    delta_t = beta_t (v_t - (e^{g_t} S_{t-1})^T k_t)  unrolls to
-
-        (I + A) Delta = beta V - (beta e^gamma K) S_0,
-        A_tj = beta_t e^{gamma_t - gamma_j} k_t.k_j   for j < t,
-
-    so  Delta = U - W S_0  with  [U | W] = (I + A)^-1 [beta V | beta e^gamma K]:
-    one triangular solve a chunk, all chunks at once. ``attn`` is
-    M * Q K^T with M_tj = e^{gamma_t - gamma_j} for j <= t, ``q_in`` is
-    e^gamma Q, ``k_out`` is e^{gamma_C - gamma} K and ``decay`` e^{gamma_C}."""
-    batch, length, heads, _ = q.shape
-    d_v = v.shape[-1]
-    n = length // chunk
-    # [B, S, H, ...] -> [n, B, H, C, ...]
-    split = lambda a: jnp.moveaxis(
-        a.reshape((batch, n, chunk, heads) + a.shape[3:]), (1, 3), (0, 2))
-    q, k, v, g, beta = map(split, (q, k, v, g, beta))
-    gamma = jnp.cumsum(g, axis=-1)                              # [n, B, H, C]
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    decay = jnp.exp(jnp.where(
-        lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
-    a = jnp.where(strict, beta[..., :, None] * decay
-                  * _mm("...td,...jd->...tj", k, k), 0.0)
-    rhs = jnp.concatenate(
-        [beta[..., None] * v, (beta * jnp.exp(gamma))[..., None] * k], -1)
-    solved = lax.linalg.triangular_solve(
-        a, rhs, left_side=True, lower=True, unit_diagonal=True)
-    return (solved[..., :d_v], solved[..., d_v:],
-            decay * _mm("...td,...jd->...tj", q, k),
-            q * jnp.exp(gamma)[..., None],
-            k * jnp.exp(gamma[..., -1:] - gamma)[..., None],
-            jnp.exp(gamma[..., -1])[..., None, None])
-
-
 def delta_form(length, chunk, d_k, d_v):
     """``kernel`` where the chunks' algebra runs as the Pallas kernels of
     ``ops/delta_chunks.py``, ``xla`` where as ``delta_chunks``' products and
@@ -353,28 +304,6 @@ def kernel_delta_chunks(q, k, v, g, beta, chunk):
     return out + (jnp.exp(gamma[..., -1]).transpose(2, 0, 1)[..., None, None],)
 
 
-def scan_chunks(u, w, attn, q_in, k_out, decay):
-    """The state's pass over the chunks ``delta_chunks`` prepared, S_0 = 0:
-
-        Delta = U - W S,   O = (e^gamma Q) S + (M * Q K^T) Delta,
-        S <- e^{gamma_C} S + (e^{gamma_C - gamma} K)^T Delta.
-
-    Returns o [B, n C, H, d_v]."""
-    n, batch, heads, chunk, d_v = u.shape
-
-    def step(state, xs):
-        u_i, w_i, attn_i, q_i, k_i, decay_i = xs
-        delta = u_i - _mm("...cd,...dv->...cv", w_i, state)
-        out = _mm("...cd,...dv->...cv", q_i, state) \
-            + _mm("...tj,...jv->...tv", attn_i, delta)
-        return decay_i * state + _mm("...cd,...cv->...dv", k_i, delta), out
-
-    state = jnp.zeros((batch, heads, w.shape[-1], d_v), F32)
-    _, out = lax.scan(step, state, (u, w, attn, q_in, k_out, decay))
-    return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(
-        batch, n * chunk, heads, d_v)
-
-
 def scan_form(length, chunk, d_k, d_v):
     """``kernel`` where the state's pass runs as the Pallas kernels of
     ``ops/delta_scan.py``, ``xla`` where as ``scan_chunks``' ``lax.scan``:
@@ -416,18 +345,6 @@ def kernel_scan_chunks(u, w, attn, q_in, k_out, decay):
     themselves: no transpose on either side of them."""
     return _scan_kernels(u, w, attn, q_in, k_out,
                          decay[..., 0, 0].transpose(1, 2, 0))
-
-
-def pad_to_chunks(arrays, chunk):
-    """(arrays padded along the sequence to a multiple of ``chunk``, the
-    length before). A padded token decays nothing and writes nothing."""
-    length = arrays[0].shape[1]
-    pad = -length % chunk
-    if pad:
-        arrays = tuple(
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in arrays)
-    return arrays, length
 
 
 def chunked_delta_rule(q, k, v, g, beta, chunk):

@@ -543,6 +543,18 @@ BD_FIELDS = (
     "bd_empty_blocks",
 )
 
+# What a decoder whose delta rule decays every key channel by a number of
+# its own counts (models/kimi_linear.py), per optimizer step, over its Kimi
+# Delta Attention layers: the most negative log decay any channel adds up to
+# inside one chunk (gamma_C; e^gamma_C is the chunk's decay of the state's
+# row, and below -87 it is under float32's smallest normal number: how near
+# the chunked form's bounded exponents run to underflow), and the mean of
+# beta, the delta rule's write strength.
+KDA_FIELDS = (
+    "kda_log_decay_min",
+    "kda_beta_mean",
+)
+
 # The model counters last read on the host (``model_scalars``): like the
 # span buffer, it outlives the trainer, so a reader can ask afterwards.
 _last_model: Dict[str, float] = {}
@@ -606,6 +618,14 @@ def bd_counters(*counts: Array) -> Dict[str, Array]:
             for name, count in zip(BD_FIELDS, counts)}
 
 
+@jax.named_scope(SCOPE)
+def kda_counters(log_decay_min: Array, beta_mean: Array) -> Dict[str, Array]:
+    """``KDA_FIELDS`` as f32 scalars from the KDA layers' counts, both
+    [layers]."""
+    return {"kda_log_decay_min": jnp.min(log_decay_min),
+            "kda_beta_mean": jnp.mean(beta_mean)}
+
+
 # The registry of model counters: each group's fields, the keys of the
 # model's counts it is computed from and its device function. A model's
 # ``aux`` holds the groups whose counts it returns; ``model_scalars`` reads
@@ -619,6 +639,7 @@ MODEL_COUNTERS = {
     "loop": (LOOP_FIELDS, ("loop_loss", "loop_exit_share",
                            "loop_exit_entropy"), loop_counters),
     "bd": (BD_FIELDS, BD_FIELDS, bd_counters),
+    "kda": (KDA_FIELDS, KDA_FIELDS, kda_counters),
 }
 
 

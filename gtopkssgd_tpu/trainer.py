@@ -140,7 +140,8 @@ class TrainConfig:
                                    # '26b_a3b_ep16', 'tiny'; kanana2:
                                    # '30b_a3b_ep16', 'tiny'; ouro:
                                    # '2p6b_l5', 'tiny'; sdar:
-                                   # '30b_a3b_ep8', 'tiny'): which of the
+                                   # '30b_a3b_ep8', 'tiny'; kimi_linear:
+                                   # '48b_a3b_ep32', 'tiny'): which of the
                                    # model's PRESETS to build, the
                                    # published sizes as one chip's share
                                    # of an expert group (ouro: the first

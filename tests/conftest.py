@@ -39,8 +39,8 @@ def v5e():
     """One described v5e device, for the files that ask the chip's compiler
     without the chip (``test_pallas_compile.py``, ``test_flash_compile.py``,
     ``test_hybrid_compile.py``, ``test_looped_compile.py``,
-    ``test_mla_compile.py``: one published step a file, so that each has a
-    worker of its own). Nothing but a test
+    ``test_mla_compile.py``, ``test_kda_compile.py``: one published step a
+    file, so that each has a worker of its own). Nothing but a test
     that asks for it describes the topology. The persistent cache is off
     around the module (a compile for a described device is written to it
     but cannot be read back without a chip, and the next one warns)."""
@@ -132,21 +132,22 @@ def run_two_process(worker_src: str, tmp_path, ok_token: str) -> str:
 # allows up to 16 reduced keys. No file of the benchmark may be edited by
 # the PR that adds a configuration, and ``tests/perfbench/conftest.py`` (a
 # benchmark file since PR 26) names only that PR's case, so the cases of
-# PRs 31, 35, 39, 41 and 45 are marked here, strictly and with the same wording: when
+# PRs 31, 35, 39, 41, 45 and 48 are marked here, strictly and with the same wording: when
 # a ``benchmark`` PR relaxes the assertion the cases pass, these marks fail,
 # and these lines go. Everything else that test checks of an entry is
 # checked for Keye's and Trinity's configurations, by name, in
 # ``perfbench/test_perfbench_entries_by_name.py``, for Kanana's in
 # ``perfbench/test_perfbench_cell_kanana2.py``, for Ouro's in
-# ``perfbench/test_perfbench_cell_ouro.py`` and for SDAR's in
-# ``perfbench/test_perfbench_cell_sdar.py``.
+# ``perfbench/test_perfbench_cell_ouro.py``, for SDAR's in
+# ``perfbench/test_perfbench_cell_sdar.py`` and for Kimi-Linear's in
+# ``perfbench/test_perfbench_cell_kimi_linear.py``.
 STALE = tuple(
     "test_perfbench_contract.py::"
     "test_entry_has_just_the_contracts_keys_and_characters"
     f"[configs-{config}]"
     for config in ("keye_vl2_30b_a3b_ep16", "trinity_mini_26b_a3b_ep16",
                    "kanana2_30b_a3b_ep16", "ouro_2p6b_l5",
-                   "sdar_30b_a3b_ep8"))
+                   "sdar_30b_a3b_ep8", "kimi_linear_48b_a3b_ep32"))
 # PR 31's own letter test asserts that its configuration, its cell and its six
 # metrics are the LAST entries of their lists in BENCHMARK.json: true of the
 # PR that appended them, false as soon as the next one appends (PR 35: one

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import pytest
 
 from gtopkssgd_tpu.models import (
-    decoder, kanana2, keye_vl2, ouro, qwen3_next, sdar, trinity_mini)
+    decoder, kanana2, keye_vl2, kimi_linear, ouro, qwen3_next, sdar,
+    trinity_mini)
 from gtopkssgd_tpu.ops import dsa_attention, dsa_index, flash_attention
 from perfbench.metrics import layer_ms, part_ms, scoped
 
@@ -89,6 +90,15 @@ def kanana():
     return layer, sizes, lambda out: jnp.sum(out[0])
 
 
+def kimi():
+    # The hybrid's latent-attention layer: Kanana's mixer, no rotary.
+    sizes = kimi_linear.PRESETS["tiny"]
+    layer = remat_layer(kimi_linear.Layer, [
+        kimi_linear.KEPT_CHUNKS, kanana2.KEPT_ATTENTION])(
+            sizes, jnp.float32, "mla")
+    return layer, sizes, lambda out: jnp.sum(out[0])
+
+
 def looped():
     sizes = ouro.PRESETS["tiny"]
     layer = remat_layer(ouro.Layer, [ouro.KEPT_ATTENTION])(sizes, jnp.float32)
@@ -122,7 +132,8 @@ def keye():
 LAYERS = {"trinity_sliding": (lambda: trinity(True), "attn_window"),
           "trinity_full": (lambda: trinity(False), "attn_full"),
           "qwen": (qwen, "attn"), "keye": (keye, "attn"),
-          "kanana": (kanana, "attn_latent"), "ouro": (looped, "attn"),
+          "kanana": (kanana, "attn_latent"), "kimi": (kimi, "attn_latent"),
+          "ouro": (looped, "attn"),
           "sdar": (block_diffusion, "attn")}
 
 
@@ -324,6 +335,7 @@ def test_the_one_device_step_works_on_leaves_under_the_same_stages(
     dict(dnn="kanana2", model_preset="tiny"),
     dict(dnn="ouro", model_preset="tiny"),
     dict(dnn="sdar", model_preset="tiny"),
+    dict(dnn="kimi_linear", model_preset="tiny"),
     dict(dnn="resnet20", dataset="cifar10"),
 ], ids=lambda f: f["dnn"])
 def test_the_one_device_step_moves_no_whole_vector(tmp_path, monkeypatch,
